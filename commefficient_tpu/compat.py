@@ -1,37 +1,15 @@
-"""JAX version-compatibility shims.
-
-The codebase targets the modern ``jax.shard_map`` entry point (with its
-``check_vma`` replication-check flag). Older jax releases (< 0.6) only ship
-``jax.experimental.shard_map.shard_map`` whose equivalent flag is named
-``check_rep``. Every module uses this one wrapper so the version split lives
-in exactly one place.
-"""
+"""The one ``shard_map`` spelling every module uses: ``jax.shard_map`` with
+positional ``mesh``/``in_specs``/``out_specs`` and the replication check
+off by default (the round's collectives are explicit psums)."""
 
 from __future__ import annotations
 
-try:  # jax >= 0.6: public top-level API
-    from jax import shard_map as _shard_map_new
-
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-        return _shard_map_new(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=check_vma)
-
-except ImportError:  # older jax: experimental API, check_vma spelled check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
+import jax
 
 
-def tpu_smem_space():
-    """The Pallas-TPU SMEM memory-space enum value across jax versions:
-    ``pltpu.MemorySpace.SMEM`` on modern jax, ``pltpu.TPUMemorySpace.SMEM``
-    before the rename (jax < 0.5)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    ms = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
-    return ms.SMEM
+def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
-__all__ = ["shard_map", "tpu_smem_space"]
+__all__ = ["shard_map"]

@@ -214,11 +214,10 @@ class FedModel:
                  init_params=None, model_state=None):
         self.model = model
         self.args = args
-        # --device tpu is a hard request: when platform selection resolved
-        # to something else (e.g. JAX default priority picked CPU on a
-        # TPU-less host, which config.validate_args deliberately leaves
-        # alone so plugin-named TPUs keep working), fail loudly here —
-        # the backend is initialized by now, so this check is reliable.
+        # --device tpu is a hard request: when the flag came too late
+        # (backend already initialized on another platform,
+        # config.validate_args) fail loudly here — the backend is
+        # initialized by now, so this check is reliable.
         if getattr(args, "device", None) == "tpu":
             from commefficient_tpu.utils import is_tpu_backend
 
@@ -754,9 +753,7 @@ class FedModel:
         replicated on the replicated plane; with --server_shard, dense
         velocity/error and the qres carry are dim-0-sharded over the
         worker axis (the jit outputs carry those shardings, so — like
-        ``_place_replicated`` — this also avoids the round-1 retrace AND
-        the jax 0.4.37 hazard of donating an unplaced single-device buffer
-        into a mesh-sharded step)."""
+        ``_place_replicated`` — this also avoids the round-1 retrace)."""
         from commefficient_tpu.federated.server import place_server_state
 
         return place_server_state(state, self.mesh,

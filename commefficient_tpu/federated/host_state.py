@@ -81,17 +81,13 @@ def _host_ctx(enabled: bool):
 
 def _supported_kind(mesh: Mesh, kind: str) -> str:
     """Degrade a memory kind to the device's default when the backend does
-    not expose it — the module-docstring fallback made real: CPU devices
-    (jax 0.4.x) address only ``unpinned_host``, so asking for ``device`` /
-    ``pinned_host`` placements there is a hard error rather than a no-op."""
+    not expose it — the module-docstring fallback made real: asking a
+    device for a memory kind it does not address is a hard error rather
+    than a no-op."""
     dev = mesh.devices.flat[0]
-    try:
-        kinds = {m.kind for m in dev.addressable_memories()}
-        if kind in kinds:
-            return kind
-        return dev.default_memory().kind
-    except Exception:  # very old jaxlib without the memories API
+    if kind in {m.kind for m in dev.addressable_memories()}:
         return kind
+    return dev.default_memory().kind
 
 
 class RowStreamer:

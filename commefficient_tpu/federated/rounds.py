@@ -989,6 +989,22 @@ def build_round_step(
                 check_vma=False,
             )(grad_stacked, server_state, jnp.asarray(lr_), rng_, count_)
 
+    def _manual(f, in_specs, out_specs=P()):
+        """``f`` inside a shard_map over the whole mesh. The server phase
+        dispatches to Pallas kernels on TPU, and XLA's SPMD partitioner
+        refuses a Mosaic custom call outside a manual region ("Mosaic
+        kernels cannot be automatically partitioned") — so on a
+        multi-device mesh every kernel call site of the phase is a manual
+        region: per device on replicated data (what the partitioner does
+        with the XLA path anyway), or on the device's own client rows."""
+        if mesh is None or mesh.size == 1:
+            return f
+        return shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+    def _on_every_device(f, *args):
+        return _manual(f, (P(),) * len(args))(*args)
+
     def server_step(ps_weights, server_state: ServerState,
                     client_states: ClientStates, ctx: RoundContext, lr, rng):
         flat_caller = chunked and ps_weights.ndim == 1
@@ -1005,9 +1021,11 @@ def build_round_step(
             update, new_server_state, resketched = _sharded_server(
                 ctx.gradient, server_state, eff_lr, rng, ctx.count)
         else:
-            update, new_server_state = server_update(
-                ctx.gradient, server_state, scfg, eff_lr, sketch=sketch,
-                rng=rng, layout=layout)
+            update, new_server_state = _on_every_device(
+                lambda g, st, lr_, rng_: server_update(
+                    g, st, scfg, lr_, sketch=sketch, rng=rng_,
+                    layout=layout),
+                ctx.gradient, server_state, jnp.asarray(eff_lr), rng)
         new_ps = ps_weights - update
 
         # On-device health guard (--guards, docs/fault_tolerance.md): one
@@ -1053,7 +1071,8 @@ def build_round_step(
                 sketched_update = resketched * eff_lr
             else:
                 resketch = sketch_chunks if chunked else sketch_vec
-                sketched_update = resketch(sketch, update)
+                sketched_update = _on_every_device(
+                    lambda u: resketch(sketch, u), update)
             cell_keep = (sketched_update == 0).astype(jnp.float32)[None]
             keep_vel = keep_err = cell_keep
 
@@ -1088,9 +1107,10 @@ def build_round_step(
         # duplicating a real slot's id would otherwise land the SAME delta
         # twice (2*used - stale instead of used).
         if wcfg.do_topk_down and cs.weights is not None:
-            used = jax.vmap(lambda s: get_new_worker_weights(ps_weights, s,
-                                                             wcfg.k, True))(
-                ctx.stale_rows)
+            used = _manual(
+                lambda w, rows: jax.vmap(lambda s: get_new_worker_weights(
+                    w, s, wcfg.k, True))(rows),
+                (P(), P(axis)), P(axis))(ps_weights, ctx.stale_rows)
             w = ctx.wmask.reshape(-1, 1)
             stale_delta = (used - ctx.stale_rows) * w
             if guard_ok is not None:

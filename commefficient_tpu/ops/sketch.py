@@ -67,6 +67,8 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
+from commefficient_tpu.ops.topk import require_equal
+
 _LANES = 128
 _M1 = np.int32(np.uint32(0x85EBCA6B).astype(np.int64) - (1 << 32))
 _M2 = np.int32(np.uint32(0xC2B2AE35).astype(np.int64) - (1 << 32))
@@ -383,33 +385,118 @@ def _use_pallas_estimates() -> bool:
             and os.environ.get("COMMEFFICIENT_PALLAS_ESTIMATES", "1") != "0")
 
 
+def _trace_state_clean() -> bool:
+    """True when no jit trace is active (private API; a jax that moves it
+    raises here rather than silently skipping the self-checks)."""
+    from jax._src import core as _core
+
+    return bool(_core.trace_state_clean())
+
+
+# The kernel self-checks. Each compares one compiled kernel (or, with
+# ``interpret=True``, its interpreted body — the CPU rehearsal of
+# chip_smoke.py) against the pure ``jnp`` path on geometry ``cs`` and
+# raises on any difference; a kernel Mosaic refuses raises from the
+# compile itself. There is NO fallback: on a TPU a kernel that cannot be
+# trusted stops the run instead of quietly moving it onto the XLA path.
+# chip_smoke.py runs every one of them at the full ResNet9 geometry; the
+# ``_check_*_once`` wrappers below run them once per process, at a small
+# multi-chunk geometry, before first use.
+
+def _check_geometry() -> CountSketch:
+    # S > 1024 sublanes: the estimates kernel's multi-sub-block (G > 1)
+    # window path, whose DMA starts reach into the doubled+padded region
+    return make_sketch(d=450_000, c=140_000, r=3, seed=11, num_blocks=2)
+
+
+def _check_vec(cs: CountSketch, seed: int):
+    return jnp.asarray(np.random.RandomState(seed).randn(cs.d), jnp.float32)
+
+
+def _check_table(cs: CountSketch, seed: int):
+    return jnp.asarray(
+        np.random.RandomState(seed).randn(*cs.table_shape), jnp.float32)
+
+
+def check_estimates_kernel(cs: CountSketch, interpret: bool = False) -> None:
+    """``_estimates_pallas`` == ``_estimates_jax``, plus the sharded-server
+    local query (t0 ≠ 0, pre-sliced shifts) == the full path's slice."""
+    tbl = _check_table(cs, 5)
+    got = _estimates_pallas(
+        _doubled_table(cs, tbl), cs.shift_q, cs.shift_w, cs.sign_keys,
+        _T0, S=cs.sublanes, T=cs.T, c_pad=cs.c_pad, interpret=interpret)
+    require_equal(np.asarray(got).reshape(-1)[: cs.d],
+                  _estimates_jax(cs, tbl), "estimates")
+    t0v, Tn = 1, min(2, cs.T - 1)
+    got_l = estimates_chunks_local(cs, tbl, jnp.int32(t0v), Tn,
+                                   interpret=interpret)
+    want_l = cs.chunk_layout.mask_tail(got)[t0v:t0v + Tn]
+    require_equal(got_l, want_l, "estimates (local query)")
+
+
+def check_sketch_vec_kernel(cs: CountSketch, interpret: bool = False) -> None:
+    """``_sketch_vec_pallas`` == ``_sketch_vec_jax``, plus the
+    sharded-server partial accumulate (t0 ≠ 0) == the pure partial."""
+    v = _check_vec(cs, 6)
+    v3 = _chunks3(cs, v)
+    got = _sketch_vec_pallas(
+        v3, cs.shift_q, cs.shift_w, cs.sign_keys, _T0,
+        S=cs.sublanes, T=cs.T, interpret=interpret).reshape(cs.r, cs.c_pad)
+    require_equal(got, _sketch_vec_jax(cs, v), "sketch_vec")
+    t0v, Tn = 1, min(2, cs.T - 1)
+    got_l = sketch_chunks_local(cs, v3[t0v:t0v + Tn], jnp.int32(t0v),
+                                interpret=interpret)
+    want_l = _sketch_chunks_jax(cs, v3[t0v:t0v + Tn], jnp.int32(t0v))
+    require_equal(got_l, want_l, "sketch_vec (local accumulate)")
+
+
+def _check_segment(cs: CountSketch):
+    """A vector, a running table, and the bounds of an unaligned flat
+    segment of the vector that spans a chunk boundary."""
+    return (_check_vec(cs, 6), _check_table(cs, 8), 137,
+            min(cs.d, cs.c_pad + 50_011))
+
+
+def check_sketch_accum_kernel(cs: CountSketch,
+                              interpret: bool = False) -> None:
+    """``_sketch_accum_pallas`` (--stream_sketch, docs/stream_sketch.md):
+    the running-table kernel must bit-continue the pure fold at an
+    unaligned element offset spanning a chunk boundary."""
+    v, tbl0, a, b = _check_segment(cs)
+    seg3, t_a = _segment_chunks(cs, v[a:b], a)
+    got = _sketch_accum_pallas(
+        tbl0.reshape(cs.r, cs.sublanes, _LANES), seg3,
+        cs.shift_q[:, t_a:t_a + seg3.shape[0]],
+        cs.shift_w[:, t_a:t_a + seg3.shape[0]], cs.sign_keys,
+        np.full(1, t_a, np.int32), S=cs.sublanes, T=seg3.shape[0],
+        interpret=interpret).reshape(cs.r, cs.c_pad)
+    require_equal(got, _sketch_accum_chunks_jax(cs, tbl0, seg3, t_a),
+                  "sketch_accum")
+
+
+def check_sketch_segments_kernel(cs: CountSketch,
+                                 interpret: bool = False) -> None:
+    """``_sketch_segments_pallas`` (--sketch_coalesce): ONE launch over a
+    group of contiguous segments == the same span's single-segment fold
+    (through the dispatcher, which assembles the group)."""
+    v, tbl0, a, b = _check_segment(cs)
+    cuts = (a, a + 11_003, a + 11_004, b)
+    got = sketch_segments_accum(
+        cs, tbl0, [v[x:y] for x, y in zip(cuts[:-1], cuts[1:])], a,
+        interpret=interpret)
+    seg3, t_a = _segment_chunks(cs, v[a:b], a)
+    require_equal(got, _sketch_accum_chunks_jax(cs, tbl0, seg3, t_a),
+                  "sketch_segments")
+
+
 _ESTIMATES_KERNEL_CHECKED = False
 
 
-def _trace_state_clean() -> bool:
-    """True when no jit trace is active. Private API, so fail closed
-    ('might be in a trace'); callers that are eager by construction pass
-    ``eager=True`` to the check instead of relying on this probe."""
-    try:
-        from jax._src import core as _core
-
-        return bool(_core.trace_state_clean())
-    except Exception:  # noqa: BLE001
-        return False
-
-
 def _check_estimates_kernel_once(eager: bool = False) -> None:
-    """One-time on-TPU self-check of the DMA query kernel before first use,
-    process-wide: any compile failure or mismatch against the pure XLA path
-    disables the kernel (env kill-switch) instead of silently corrupting
-    every ``unsketch`` of the run. The check geometry has S > 1024 sublanes
-    so it runs the multi-sub-block (G > 1) window path — the one the
-    FetchSGD-scale workload uses, whose DMA starts reach into the
-    doubled+padded region. Must run OUTSIDE any jit trace (inside one, every
-    jax op — concrete inputs or not — lifts into the trace); the primary
-    trigger is ``make_sketch`` — always host-side eager setup — which
-    passes ``eager=True`` so the check survives even if the trace-state
-    probe's private import breaks."""
+    """One-time on-TPU self-check of the DMA query kernel before first
+    use, process-wide. Must run OUTSIDE any jit trace (inside one, every
+    jax op lifts into the trace); the primary trigger is ``make_sketch`` —
+    always host-side eager setup — which passes ``eager=True``."""
     global _ESTIMATES_KERNEL_CHECKED
     if _ESTIMATES_KERNEL_CHECKED:
         return
@@ -420,46 +507,17 @@ def _check_estimates_kernel_once(eager: bool = False) -> None:
     if not eager and not _trace_state_clean():
         return
     _ESTIMATES_KERNEL_CHECKED = True
-    import os
-    import warnings
-
-    try:
-        cs = make_sketch(d=450_000, c=140_000, r=3, seed=11, num_blocks=2)
-        tbl = jnp.asarray(
-            np.random.RandomState(5).randn(*cs.table_shape), jnp.float32)
-        got = _estimates_pallas(
-            _doubled_table(cs, tbl), cs.shift_q, cs.shift_w, cs.sign_keys,
-            _T0, S=cs.sublanes, T=cs.T, c_pad=cs.c_pad)
-        want = _estimates_jax(cs, tbl)
-        if not np.array_equal(np.asarray(got).reshape(-1)[: cs.d],
-                              np.asarray(want)):
-            raise AssertionError("kernel output != pure XLA path")
-        # sharded-server local query (t0 ≠ 0, pre-sliced shifts) must equal
-        # the full path's slice bit-for-bit — the same kernel, offset base
-        t0v, Tn = 1, 2
-        got_l = estimates_chunks_local(cs, tbl, jnp.int32(t0v), Tn)
-        want_l = np.asarray(got)[t0v:t0v + Tn]
-        if not np.array_equal(np.asarray(got_l), want_l):
-            raise AssertionError("local query != full-path slice")
-    except Exception as e:  # noqa: BLE001 — any failure means: don't use it
-        os.environ["COMMEFFICIENT_PALLAS_ESTIMATES"] = "0"
-        warnings.warn(
-            f"Pallas estimates kernel self-check failed "
-            f"({type(e).__name__}: {str(e)[:200]}); falling back to the "
-            f"pure XLA query path", RuntimeWarning)
+    check_estimates_kernel(_check_geometry())
 
 
 _SKETCH_KERNEL_CHECKED = False
 
 
 def _check_sketch_kernel_once(eager: bool = False) -> None:
-    """One-time on-TPU self-check of the accumulate kernel, mirroring
-    ``_check_estimates_kernel_once``: bit-compare ``_sketch_vec_pallas``
-    against ``_sketch_vec_jax`` at a multi-chunk (T > 1) geometry and
-    disable the kernel via its env kill-switch on any compile failure or
-    mismatch — a Mosaic regression here would otherwise silently corrupt
-    every sketched round. Primary trigger is ``make_sketch`` (always eager
-    host-side setup); ``sketch_vec`` also triggers it when called eagerly,
+    """One-time on-TPU self-check of the accumulate kernels (zero-init,
+    running-table, multi-segment), mirroring
+    ``_check_estimates_kernel_once``. Primary trigger is ``make_sketch``;
+    the accumulate entry points also trigger it when called eagerly,
     covering CountSketch objects that bypassed ``make_sketch`` (e.g.
     deserialized ones)."""
     global _SKETCH_KERNEL_CHECKED
@@ -470,59 +528,10 @@ def _check_sketch_kernel_once(eager: bool = False) -> None:
     if not eager and not _trace_state_clean():
         return
     _SKETCH_KERNEL_CHECKED = True
-    import os
-    import warnings
-
-    try:
-        cs = make_sketch(d=450_000, c=140_000, r=3, seed=11, num_blocks=2)
-        v = jnp.asarray(
-            np.random.RandomState(6).randn(cs.d), jnp.float32)
-        v3 = _chunks3(cs, v)
-        got = _sketch_vec_pallas(
-            v3, cs.shift_q, cs.shift_w, cs.sign_keys, _T0,
-            S=cs.sublanes, T=cs.T).reshape(cs.r, cs.c_pad)
-        want = _sketch_vec_jax(cs, v)
-        if not np.array_equal(np.asarray(got), np.asarray(want)):
-            raise AssertionError("kernel output != pure XLA path")
-        # sharded-server partial accumulate (t0 ≠ 0): must equal the pure
-        # path's partial table for the same chunk range bit-for-bit
-        t0v, Tn = 1, 2
-        got_l = sketch_chunks_local(cs, v3[t0v:t0v + Tn], jnp.int32(t0v))
-        want_l = _sketch_chunks_jax(cs, v3[t0v:t0v + Tn], jnp.int32(t0v))
-        if not np.array_equal(np.asarray(got_l), np.asarray(want_l)):
-            raise AssertionError("local accumulate != pure XLA partial")
-        # streaming segment accumulate (docs/stream_sketch.md): the
-        # running-table kernel must bit-continue the pure fold at an
-        # unaligned element offset spanning a chunk boundary
-        tbl0 = jnp.asarray(
-            np.random.RandomState(8).randn(cs.r, cs.c_pad), jnp.float32)
-        a, b = 137, cs.c_pad + 50_011
-        seg3, t_a = _segment_chunks(cs, v[a:b], a)
-        got_a = _sketch_accum_pallas(
-            tbl0.reshape(cs.r, cs.sublanes, _LANES), seg3,
-            cs.shift_q[:, t_a:t_a + seg3.shape[0]],
-            cs.shift_w[:, t_a:t_a + seg3.shape[0]], cs.sign_keys,
-            np.full(1, t_a, np.int32), S=cs.sublanes,
-            T=seg3.shape[0]).reshape(cs.r, cs.c_pad)
-        want_a = _sketch_accum_chunks_jax(cs, tbl0, seg3, t_a)
-        if not np.array_equal(np.asarray(got_a), np.asarray(want_a)):
-            raise AssertionError("segment accumulate != pure XLA fold")
-        # coalesced multi-segment accumulate (--sketch_coalesce,
-        # docs/stream_sketch.md): ONE launch over a group of contiguous
-        # segments must equal the same span's single-segment accumulate
-        # (== comparison: fewer boundary ±0.0 terms is the one allowed
-        # deviation, same caveat class as the fused epilogue's)
-        cuts = (a, a + 11_003, a + 11_004, b)
-        got_g = sketch_segments_accum(
-            cs, tbl0, [v[x:y] for x, y in zip(cuts[:-1], cuts[1:])], a)
-        if not np.array_equal(np.asarray(got_g), np.asarray(want_a)):
-            raise AssertionError("multi-segment accumulate != segment fold")
-    except Exception as e:  # noqa: BLE001 — any failure means: don't use it
-        os.environ["COMMEFFICIENT_PALLAS_SKETCH"] = "0"
-        warnings.warn(
-            f"Pallas sketch accumulate kernel self-check failed "
-            f"({type(e).__name__}: {str(e)[:200]}); falling back to the "
-            f"pure XLA accumulate path", RuntimeWarning)
+    cs = _check_geometry()
+    check_sketch_vec_kernel(cs)
+    check_sketch_accum_kernel(cs)
+    check_sketch_segments_kernel(cs)
 
 
 def sketch_vec(cs: CountSketch, v: jax.Array) -> jax.Array:
@@ -1321,23 +1330,48 @@ def fused_epilogue_chunks_local(cs: CountSketch, est3: jax.Array, t0, k: int,
     return upd, _fold_ext_table(cs, ext)
 
 
+def check_fused_epilogue_kernel(cs: CountSketch, k: int = 5_000,
+                                interpret: bool = False) -> None:
+    """``_fused_epilogue_pallas`` == the composed ``topk_dense_nd`` +
+    ``sketch_chunks`` pair (update bits and re-sketch values), plus the
+    sharded local variant (t0 ≠ 0, pre-sliced shifts) == the composed
+    local pair on the same slice — outside a shard_map there is no psum'd
+    threshold, so the reference is slice-local, not the full update."""
+    from commefficient_tpu.ops.topk import _topk_threshold_1d
+
+    def topk_ref(x):  # the pure-XLA descent; shape-agnostic despite its name
+        return _topk_threshold_1d(x, k)
+
+    est = cs.chunk_layout.mask_tail(
+        _estimates_chunks_jax(cs, _check_table(cs, 5)))
+    upd_f, tbl_f = fused_epilogue_chunks(cs, est, k, interpret=interpret)
+    upd_c = topk_ref(est)
+    require_equal(upd_f, upd_c, "fused epilogue (update)")
+    require_equal(tbl_f, _sketch_chunks_jax(cs, upd_c),
+                  "fused epilogue (re-sketch)")
+    Tn = -(-cs.T // 2)
+    est_p = jnp.pad(est, ((0, 2 * Tn - cs.T), (0, 0), (0, 0)))
+    sl = est_p[Tn:2 * Tn]
+    u_l, t_l = fused_epilogue_chunks_local(cs, sl, jnp.int32(Tn), k,
+                                           interpret=interpret)
+    u_ref = topk_ref(sl)
+    require_equal(u_l, u_ref, "fused epilogue (local update)")
+    require_equal(t_l, _sketch_chunks_jax(cs, u_ref, jnp.int32(Tn)),
+                  "fused epilogue (local re-sketch)")
+
+
 _FUSED_EPILOGUE_CHECKED = False
 
 
 def _check_fused_epilogue_once(eager: bool = False) -> None:
     """One-time on-TPU self-check of the fused epilogue megakernel before
-    first use, mirroring ``_check_sketch_kernel_once``: compare update and
-    re-sketch table against the composed ``topk_dense_nd`` +
-    ``sketch_chunks`` pair at a multi-chunk geometry and disable the
-    kernel via its env kill-switch on any compile failure or mismatch —
-    the composed path is always available and correct. UNLIKE the
-    accumulate/query checks this is NOT triggered from ``make_sketch``:
-    those kernels run unconditionally, while the megakernel is opt-in
-    (--fused_epilogue), and a d=450k sketch build + Mosaic compile at
-    every TPU ``make_sketch`` would tax processes that never use it.
-    Triggers: ``rounds.build_round_step`` when the server config opts in
-    (always eager host-side setup), and an eager first call of
-    ``fused_epilogue_chunks``/``_local`` for direct users."""
+    first use. UNLIKE the accumulate/query checks this is NOT triggered
+    from ``make_sketch``: those kernels run unconditionally, while the
+    megakernel is opt-in (--fused_epilogue), and a d=450k sketch build +
+    Mosaic compile at every TPU ``make_sketch`` would tax processes that
+    never use it. Triggers: ``rounds.build_round_step`` when the server
+    config opts in (always eager host-side setup), and an eager first call
+    of ``fused_epilogue_chunks``/``_local`` for direct users."""
     global _FUSED_EPILOGUE_CHECKED
     if _FUSED_EPILOGUE_CHECKED:
         return
@@ -1348,46 +1382,7 @@ def _check_fused_epilogue_once(eager: bool = False) -> None:
     if not eager and not _trace_state_clean():
         return
     _FUSED_EPILOGUE_CHECKED = True
-    import os
-    import warnings
-
-    try:
-        from commefficient_tpu.ops.topk import topk_dense_nd
-
-        cs = make_sketch(d=450_000, c=140_000, r=3, seed=11, num_blocks=2)
-        tbl = jnp.asarray(
-            np.random.RandomState(5).randn(*cs.table_shape), jnp.float32)
-        est = estimates_chunks(cs, tbl)
-        upd_f, tbl_f = fused_epilogue_chunks(cs, est, k=5_000)
-        upd_c = topk_dense_nd(est, 5_000)
-        tbl_c = sketch_chunks(cs, upd_c)
-        if not np.array_equal(np.asarray(upd_f), np.asarray(upd_c)):
-            raise AssertionError("fused update != composed update")
-        if not np.array_equal(np.asarray(tbl_f), np.asarray(tbl_c),
-                              equal_nan=True):
-            # == comparison: the documented ±0.0 sign deviation is allowed,
-            # value deviations are not
-            raise AssertionError("fused re-sketch != composed re-sketch")
-        # sharded local variant (t0 ≠ 0, pre-sliced shifts): must equal the
-        # composed local pair bit-for-bit on the same slice — outside a
-        # shard_map there is no psum'd threshold, so the reference is the
-        # slice-local composed path, not the full update
-        Tn = -(-cs.T // 2)
-        est_p = jnp.pad(est, ((0, 2 * Tn - cs.T), (0, 0), (0, 0)))
-        sl = est_p[Tn:2 * Tn]
-        u_l, t_l = fused_epilogue_chunks_local(cs, sl, jnp.int32(Tn), 5_000)
-        u_ref = topk_dense_nd(sl, 5_000)
-        t_ref = sketch_chunks_local(cs, u_ref, jnp.int32(Tn))
-        if not np.array_equal(np.asarray(u_l), np.asarray(u_ref)):
-            raise AssertionError("local fused update != composed local")
-        if not np.array_equal(np.asarray(t_l), np.asarray(t_ref)):
-            raise AssertionError("local fused table != composed local")
-    except Exception as e:  # noqa: BLE001 — any failure means: don't use it
-        os.environ["COMMEFFICIENT_FUSED_EPILOGUE"] = "0"
-        warnings.warn(
-            f"fused epilogue megakernel self-check failed "
-            f"({type(e).__name__}: {str(e)[:200]}); falling back to the "
-            f"composed topk+re-sketch path", RuntimeWarning)
+    check_fused_epilogue_kernel(_check_geometry())
 
 
 def l2estimate(table: jax.Array) -> jax.Array:

@@ -105,31 +105,32 @@ def _count_ge_pallas(v3, ts, *, T, sub=_SUB, interpret=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from commefficient_tpu.compat import tpu_smem_space
-
     def kernel(ts_ref, v_ref, out_ref):
         @pl.when(pl.program_id(0) == 0)
         def _():
             for j in range(16):
-                out_ref[j] = 0
+                out_ref[0, j] = 0
 
         m = v_ref[0] & _ABS_MASK
         m = jnp.where(m > _INF_BITS, 0, m)
         for j in range(16):
-            out_ref[j] += jnp.sum((m >= ts_ref[j]).astype(jnp.int32))
+            out_ref[0, j] += jnp.sum((m >= ts_ref[j]).astype(jnp.int32))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(T,),
         in_specs=[pl.BlockSpec((1, sub, _LANES), lambda t, *_: (t, 0, 0))],
-        out_specs=pl.BlockSpec(memory_space=tpu_smem_space()),
+        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.SMEM),
     )
+    # (1, 16), not (16,): under vmap (per-client top-k) the batched SMEM
+    # block is then (Squeezed, 1, 16), whose last two dims equal the
+    # array's — Mosaic refuses the batched 1-D block (Squeezed, 16)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((16,), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((1, 16), jnp.int32),
         interpret=interpret,
-    )(ts, v3)
+    )(ts, v3)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("T", "sub", "interpret"))
@@ -146,8 +147,6 @@ def _descent_pallas(v3, kk, *, T, sub=_SUB, interpret=False):
     Returns the scalar k-th-magnitude bit-pattern threshold."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-
-    from commefficient_tpu.compat import tpu_smem_space
 
     def kernel(kk_ref, v_ref, out_ref, counts, prefix):
         p_id = pl.program_id(0)
@@ -190,7 +189,7 @@ def _descent_pallas(v3, kk, *, T, sub=_SUB, interpret=False):
         num_scalar_prefetch=1,
         grid=(8, T),
         in_specs=[pl.BlockSpec((1, sub, _LANES), lambda p, t, *_: (t, 0, 0))],
-        out_specs=pl.BlockSpec(memory_space=tpu_smem_space()),
+        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.SMEM),
         scratch_shapes=[pltpu.SMEM((15,), jnp.int32),
                         pltpu.SMEM((1,), jnp.int32)],
     )
@@ -286,6 +285,52 @@ def _topk_threshold_1d_pallas(vec: jax.Array, k: int,
     raw = vec.view(jnp.int32)
     p = _threshold_descent_pallas(raw, k, interpret=interpret)
     return _apply_threshold(raw, vec, p)
+
+
+class KernelMismatch(RuntimeError):
+    """A compiled Pallas kernel disagreed with its ``jnp`` reference."""
+
+
+def require_equal(got, want, what: str) -> None:
+    """Raise ``KernelMismatch`` unless ``got == want`` elementwise (``==``:
+    the documented ±0.0 sign deviation of the running-table / fused sketch
+    kernels is allowed, value deviations are not)."""
+    import numpy as np
+
+    if not np.array_equal(np.asarray(got), np.asarray(want)):
+        raise KernelMismatch(f"{what}: kernel output != jnp reference")
+
+
+def _check_vec(d: int) -> jax.Array:
+    import numpy as np
+
+    # heavy-tailed magnitudes so the descent resolves several nibble levels
+    return jnp.asarray(np.random.RandomState(9).randn(d) ** 3, jnp.float32)
+
+
+def check_count_descent_kernel(d: int, k: int,
+                               interpret: bool = False) -> None:
+    """Per-pass Pallas count descent == the pure-XLA descent (part of the
+    kernel self-check family of ops/sketch.py; run by chip_smoke.py) —
+    alone, and vmapped over rows as the per-client top-k of
+    ``--mode local_topk`` / ``--topk_down`` batches it."""
+    vec = _check_vec(d)
+    require_equal(_topk_threshold_1d_pallas(vec, k, interpret=interpret),
+                  _topk_threshold_1d(vec, k), "count-pass descent")
+    rows = jnp.stack([vec, vec[::-1]])
+    require_equal(
+        jax.vmap(lambda v: _topk_threshold_1d_pallas(
+            v, k, interpret=interpret))(rows),
+        jax.vmap(lambda v: _topk_threshold_1d(v, k))(rows),
+        "count-pass descent (vmapped)")
+
+
+def check_fused_descent_kernel(d: int, k: int,
+                               interpret: bool = False) -> None:
+    """Whole-descent Pallas kernel == the pure-XLA descent."""
+    vec = _check_vec(d)
+    require_equal(_topk_threshold_1d_fused(vec, k, interpret=interpret),
+                  _topk_threshold_1d(vec, k), "fused descent")
 
 
 def _select_threshold_impl(d: int):

@@ -475,7 +475,7 @@ WATCH_TRACE_ROUNDS = 3    # default trace-reaction window length
 # what-tripped transmit blowup, EF-carry blowup (error/qres/dres),
 # resolved-k (threshold) collapse, in-flight occupancy drop, prefetch
 # miss storms, and host rounds/sec regression. Absolute budgets (e.g. a
-# leg_budgets.json rounds/sec floor) go in --watch_rules. The io_* /
+# rounds/sec floor) go in --watch_rules. The io_* /
 # worker_queue_age rules are the storage-fault ladder's watch rungs
 # (docs/fault_tolerance.md §storage faults): a retry storm logs, an
 # exhausted op (= a row quarantine or the terminal rung approaching)

@@ -57,6 +57,8 @@ from commefficient_tpu.utils import (
     PiecewiseLinear,
     TableLogger,
     Timer,
+    announce_devices,
+    configure_compile_cache,
     make_logdir,
 )
 
@@ -398,6 +400,8 @@ def main(argv=None):
     # the first jax.devices() call, so the mesh sees the global device set
     maybe_init_distributed()
     args = parse_args(argv=argv)
+    configure_compile_cache()
+    announce_devices()
     assert args.model_devices == 1, (
         "--model_devices (tensor parallelism) is GPT-2 only; the CV models "
         "have no model axis — use gpt2_train.py")

@@ -58,6 +58,8 @@ from commefficient_tpu.utils import (
     PiecewiseLinear,
     TableLogger,
     Timer,
+    announce_devices,
+    configure_compile_cache,
     make_logdir,
 )
 from cv_train import union
@@ -286,6 +288,8 @@ def train(argv=None):
     # the first jax.devices() call, so the mesh sees the global device set
     maybe_init_distributed()
     args = parse_args(default_lr=4e-2, argv=argv)
+    configure_compile_cache()
+    announce_devices()
     if not args.dataset_name:
         args.dataset_name = "PERSONA"
     if args.stream_sketch:

@@ -320,21 +320,9 @@ class TestEPRound:
                             num_workers=W, expert_axis=expert_axis)
         scfg = ServerConfig(mode="uncompressed", error_type="virtual",
                             grad_size=d, virtual_momentum=0.9)
-        # donate=False: on jax 0.4.37, a DONATING train_step executable
-        # loaded from the persistent compilation cache (tests/conftest.py)
-        # on a SUBMESH (these 2x2 meshes use 4 of the 8 forced CPU
-        # devices) returns the stale donated ps_weights — every weight
-        # delta zero — while the same HLO freshly compiled is correct
-        # (verified both ways; the cache-deserialized executable loses the
-        # input-output aliasing). This was CHANGES.md round 1's "zero
-        # expert update": a donation/cache miscompile, not a gradient-flow
-        # bug — client gradients were always correct, and it also made
-        # test_round_matches_unsharded vacuously compare two stale runs.
-        # Donation coverage itself lives in tests/test_engine.py on the
-        # full mesh, where the cache round-trip is sound.
         cfg = RoundConfig(worker=wcfg, server=scfg, grad_size=d,
                           ep_sliced=ep_sliced_param if expert_axis else None,
-                          fuse_gradients=fuse, donate=False)
+                          fuse_gradients=fuse)
         # aux active: the round parity below then also pins the sliced-aux
         # router gradients under expert parallelism
         lt, lv = make_gpt2_losses(model, moe_aux_coef=0.01)
@@ -355,13 +343,9 @@ class TestEPRound:
         cs = init_client_states(4, d, wcfg)
         # Pre-place PS/server/client state replicated on the mesh, exactly
         # as the production entrypoints do (FedModel._place_replicated).
-        # Without it, jax 0.4.37 mis-executes the DONATING fused train_step
-        # on a submesh (here 4 of the 8 forced CPU devices): the returned
-        # ps_weights is the stale donated input — every weight delta zero,
-        # while the (equally donated) server velocity updates correctly.
-        # Verified: donate=False or this placement both fix it; client
-        # gradients were always correct (the "zero expert update" of
-        # CHANGES.md round 1 was this, not a gradient-flow bug).
+        # The round donates (the default): re-tested on jax 0.9.0 with a
+        # warm persistent compile cache, the jax 0.4.37 stale-donated-input
+        # bug these tests once pinned donate=False against is gone.
         from jax.sharding import NamedSharding
 
         rep = NamedSharding(mesh, P())

@@ -38,29 +38,6 @@ sys.path.insert(0, _REPO)
 N = 8
 
 
-def _cpu_multiprocess_supported() -> bool:
-    """The demo needs a jaxlib whose CPU backend can COMPILE multi-process
-    computations. Through at least jax 0.4.37 that path is unimplemented —
-    every child dies in backend_compile with ``XlaRuntimeError:
-    INVALID_ARGUMENT: Multiprocess computations aren't implemented on the
-    CPU backend`` — so gate on the version rather than burning ~10 min of
-    subprocess startup to rediscover it. Bump the floor when a jaxlib that
-    implements it (cross-process CPU collectives) is in the image."""
-    try:
-        version = tuple(int(p) for p in jax.__version__.split(".")[:2])
-    except ValueError:
-        return True  # unknown scheme: let the test speak for itself
-    return version >= (0, 6)
-
-
-_GATE = pytest.mark.skipif(
-    not _cpu_multiprocess_supported(),
-    reason="jaxlib CPU backend cannot compile multi-process computations "
-           "on this jax (XlaRuntimeError: 'Multiprocess computations "
-           "aren't implemented on the CPU backend', observed on 0.4.37); "
-           "needs a newer jaxlib or a real multi-host backend")
-
-
 def _run_demo(*argv):
     # bounded by the subprocess timeout below (no pytest-timeout plugin)
     proc = subprocess.run(
@@ -75,7 +52,6 @@ def _run_demo(*argv):
 
 
 @pytest.mark.heavy
-@_GATE
 @pytest.mark.parametrize("mode,plan", [
     ("sketch", ""),
     ("uncompressed", ""),
@@ -90,7 +66,6 @@ def test_two_process_round_matches_single_process(mode, plan):
 
 
 @pytest.mark.heavy
-@_GATE
 def test_two_process_engine_checkpoint_elastic_resume():
     """The FULL engine path across two processes: pipelined dispatch on
     the 2D (clients x shard) hybrid mesh, a coordinated mid-run

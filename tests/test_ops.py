@@ -255,45 +255,41 @@ class TestSketchKernelSelfCheck:
         monkeypatch.setattr(sk, "_sketch_vec_pallas", fake_pallas)
         return sk
 
-    def test_forced_mismatch_disables_kernel_with_warning(self, monkeypatch):
-        """A mismatching accumulate kernel must be disabled at make_sketch
-        (env kill-switch + warning) so sketched rounds fall back to the
-        bit-correct pure XLA path instead of silently corrupting — the same
-        contract as the estimates kernel's self-check."""
+    def test_forced_mismatch_raises(self, monkeypatch):
+        """A mismatching accumulate kernel is a hard error at make_sketch:
+        on a TPU nothing flips an env kill-switch and carries on in XLA —
+        the same contract as the estimates kernel's self-check."""
         import os
+
+        from commefficient_tpu.ops.topk import KernelMismatch
 
         def zeros_kernel(v3, q, w, k, t0, *, S, T, interpret=False):
             return jnp.zeros((3, T * 0 + 140032), jnp.float32)
 
         sk = self._arm(monkeypatch, zeros_kernel)
-        with pytest.warns(RuntimeWarning,
-                          match="sketch accumulate kernel self-check"):
-            cs = sk.make_sketch(d=2048, c=256, r=3, seed=1)
-        assert os.environ["COMMEFFICIENT_PALLAS_SKETCH"] == "0"
-        assert not sk._use_pallas_sketch()
-        # and sketch_vec now computes through the pure path, correctly
-        v = jnp.asarray(np.random.RandomState(0).randn(2048), jnp.float32)
-        np.testing.assert_array_equal(np.asarray(sk.sketch_vec(cs, v)),
-                                      np.asarray(sk._sketch_vec_jax(cs, v)))
+        with pytest.raises(KernelMismatch, match="sketch_vec"):
+            sk.make_sketch(d=2048, c=256, r=3, seed=1)
+        assert os.environ["COMMEFFICIENT_PALLAS_SKETCH"] == "1"
+        assert sk._use_pallas_sketch()
 
-    def test_compile_failure_disables_kernel(self, monkeypatch):
-        """A kernel that cannot even compile (Mosaic regression) is likewise
-        caught and disabled rather than sinking the run."""
+    def test_compile_failure_raises(self, monkeypatch):
+        """A kernel that cannot even compile (Mosaic regression) stops the
+        run with the compiler's own error."""
         import os
 
         def exploding_kernel(*a, **kw):
             raise RuntimeError("mosaic lowering failed")
 
         sk = self._arm(monkeypatch, exploding_kernel)
-        with pytest.warns(RuntimeWarning,
-                          match="sketch accumulate kernel self-check"):
+        with pytest.raises(RuntimeError, match="mosaic lowering failed"):
             sk.make_sketch(d=2048, c=256, r=3, seed=1)
-        assert os.environ["COMMEFFICIENT_PALLAS_SKETCH"] == "0"
+        assert os.environ["COMMEFFICIENT_PALLAS_SKETCH"] == "1"
 
     def test_eager_sketch_vec_triggers_check(self, monkeypatch):
         """A CountSketch that bypassed make_sketch (e.g. deserialized) still
         gets the self-check on an eager first sketch_vec call."""
         import commefficient_tpu.ops.sketch as sk
+        from commefficient_tpu.ops.topk import KernelMismatch
 
         cs = sk.make_sketch(d=2048, c=256, r=3, seed=1)
 
@@ -302,11 +298,8 @@ class TestSketchKernelSelfCheck:
 
         sk = self._arm(monkeypatch, zeros_kernel)
         v = jnp.asarray(np.random.RandomState(0).randn(2048), jnp.float32)
-        with pytest.warns(RuntimeWarning,
-                          match="sketch accumulate kernel self-check"):
-            out = sk.sketch_vec(cs, v)
-        np.testing.assert_array_equal(np.asarray(out),
-                                      np.asarray(sk._sketch_vec_jax(cs, v)))
+        with pytest.raises(KernelMismatch, match="sketch_vec"):
+            sk.sketch_vec(cs, v)
 
 
 class TestEstimatesPallasKernel:

@@ -1,0 +1,127 @@
+"""AOT-compile the full ResNet9 sketched round FOR THE TPU, without one.
+
+The installed libtpu can describe a v5e host it does not own
+(``jax.experimental.topologies``), and ``jit(f).trace(...).lower(
+lowering_platforms=("tpu",)).compile()`` on those devices gives Mosaic's and
+XLA:TPU's verdict on the real kernels at the real geometry. This is the
+guard the forced-8-device CPU mesh can never be: every Pallas kernel is gated
+off off-TPU, so "Mosaic kernels cannot be automatically partitioned" (the
+replicated server phase calling Pallas under a mesh-sharded jit outside any
+shard_map) only ever shows at a TPU lowering. Compile only — nothing runs.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from commefficient_tpu import models
+from commefficient_tpu.federated.losses import make_cv_losses
+from commefficient_tpu.federated.rounds import (
+    RoundConfig,
+    build_round_step,
+    init_client_states,
+)
+from commefficient_tpu.federated.server import ServerConfig, init_server_state
+from commefficient_tpu.federated.worker import WorkerConfig
+from commefficient_tpu.ops import sketch as sketch_ops
+from commefficient_tpu.ops.flat import ravel_pytree
+from commefficient_tpu.parallel.mesh import default_client_mesh
+
+W, BS = 8, 8
+
+
+def _v5e_devices():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any libtpu refusal is a skip
+        pytest.skip(f"libtpu cannot describe the v5e:2x2 topology here: "
+                    f"{type(e).__name__}: {e}")
+    return list(topo.devices)
+
+
+@pytest.fixture
+def kernels_on(monkeypatch):
+    """Dispatch as on a TPU: kernels on, the (executing) one-time
+    self-checks marked done."""
+    monkeypatch.setattr("commefficient_tpu.utils.is_tpu_backend",
+                        lambda: True)
+    for flag in ("_ESTIMATES_KERNEL_CHECKED", "_SKETCH_KERNEL_CHECKED",
+                 "_FUSED_EPILOGUE_CHECKED"):
+        monkeypatch.setattr(sketch_ops, flag, True)
+
+
+def _sds(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _compile_tpu(fn, *args):
+    traced = fn.trace(*args)
+    compiled = traced.lower(lowering_platforms=("tpu",)).compile()
+    return traced, compiled
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("n_devices,server_shard",
+                         [(1, False), (4, False), (4, True)])
+def test_resnet9_sketched_round_compiles_for_v5e(kernels_on, n_devices,
+                                                 server_shard):
+    """The FetchSGD headline round (d=6,568,640, 8x8, 5x500k, k=50k), as
+    cv_train dispatches it: client_step then server_step with the default
+    telemetry vector, plus the fused train_step bench.py times."""
+    devices = _v5e_devices()[:n_devices]
+    model = models.ResNet9()
+    shapes = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 32, 32, 3)), train=False),
+        jax.random.key(0))
+    params = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype), shapes)["params"]
+    flat, unravel = ravel_pytree(params)
+    d = int(flat.size)
+    assert d == 6_568_640, f"ResNet9 geometry drifted: d={d}"
+
+    wcfg = WorkerConfig(mode="sketch", error_type="virtual", k=50_000,
+                        num_workers=W, weight_decay=5e-4)
+    scfg = ServerConfig(mode="sketch", error_type="virtual", k=50_000,
+                        grad_size=d, virtual_momentum=0.9)
+    sketch = sketch_ops.make_sketch(d, c=500_000, r=5, seed=42,
+                                    num_blocks=20)
+    cfg = RoundConfig(worker=wcfg, server=scfg, grad_size=d,
+                      server_shard=server_shard, telemetry=True,
+                      telemetry_hist=True)
+    mesh = default_client_mesh(W, devices=devices)
+    assert dict(mesh.shape) == {"clients": n_devices}
+    loss_train, loss_val = make_cv_losses(model)
+    steps = build_round_step(loss_train, loss_val, unravel,
+                             lambda t: ravel_pytree(t)[0], cfg,
+                             sketch=sketch, mesh=mesh)
+    rep = NamedSharding(mesh, P())
+    batch = _sds({
+        "inputs": jnp.zeros((W, BS, 32, 32, 3), jnp.float32),
+        "targets": jnp.zeros((W, BS), jnp.int32),
+        "mask": jnp.ones((W, BS), jnp.float32),
+        "client_ids": jnp.arange(W, dtype=jnp.int32),
+        "worker_mask": jnp.ones(W, jnp.float32),
+    }, NamedSharding(mesh, P("clients")))
+    ps = _sds(flat, rep)
+    server_state = _sds(init_server_state(scfg, sketch), rep)
+    client_states = _sds(init_client_states(10, d, wcfg), rep)
+    lr, rng = 0.1, jax.random.key(0)
+
+    traced, compiled = _compile_tpu(steps.client_step, ps, client_states, {},
+                                    batch, lr, rng)
+    # the server phase consumes the client phase's outputs where the
+    # compiler left them
+    ctx = jax.tree_util.tree_map(
+        lambda info, sh: jax.ShapeDtypeStruct(info.shape, info.dtype,
+                                              sharding=sh),
+        traced.out_info[0], compiled.output_shardings[0])
+    _compile_tpu(steps.server_step, ps, server_state, client_states, ctx,
+                 lr, rng)
+    _compile_tpu(steps.train_step, ps, server_state, client_states, {},
+                 batch, lr, rng)
