@@ -74,7 +74,8 @@ def build_parser(default_lr=None) -> argparse.ArgumentParser:
     parser.add_argument("--mode", choices=MODES, default="sketch")
     parser.add_argument("--tensorboard", dest="use_tensorboard", action="store_true")
     # jax.profiler trace window (replaces the reference's commented cProfile
-    # scaffolding, fed_aggregator.py:32-52)
+    # scaffolding, fed_aggregator.py:32-52): a profiling.RoundTracer window
+    # over rounds 2 … 2+profile_steps-1, written to profile_dir
     parser.add_argument("--profile", action="store_true", dest="do_profile")
     parser.add_argument("--profile_dir", type=str, default="profiles")
     parser.add_argument("--profile_steps", type=int, default=3)
@@ -441,11 +442,14 @@ def build_parser(default_lr=None) -> argparse.ArgumentParser:
     # transmit / update / error-feedback carries, resolved top-k
     # threshold, guard detail) ride the batched metric drain into a
     # structured per-run JSONL event log with round-lifecycle spans
-    # (dispatch -> device compute -> drain, in-flight occupancy). ON by
-    # default: the overhead budget is <= 2% rounds/sec (the bench
-    # `telemetry` A/B leg measures it) and the fp32 trajectory is
-    # bit-identical either way (tests/test_telemetry.py). Render the log
-    # with scripts/obs_report.py.
+    # (dispatch -> window wait -> drain, in-flight occupancy). ON by
+    # default, and not cheap on the device while the histograms are what
+    # they are: 83 ms of a 156 ms round in resnet9_sketch_1c, 91% and 98%
+    # of device time in the two GPT-2 cells (PERF_LEDGER.jsonl, PR 25;
+    # PERF.md section 5 — the per-layer metric telemetry_device_ms reads
+    # it). The fp32 trajectory is bit-identical either way
+    # (tests/test_telemetry.py). Render the log with
+    # scripts/obs_report.py.
     parser.add_argument("--telemetry", action="store_true", dest="telemetry",
                         default=True,
                         help="Per-round on-device metrics + JSONL run "

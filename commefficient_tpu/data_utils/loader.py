@@ -44,6 +44,7 @@ import threading
 import numpy as np
 
 from commefficient_tpu import native
+from commefficient_tpu.profiling import annotate
 
 __all__ = ["FedLoader", "PrefetchLoader", "cv_collate"]
 
@@ -293,7 +294,16 @@ class PrefetchLoader:
 
         def worker():
             try:
-                for batch in self.loader:
+                it = iter(self.loader)
+                while True:
+                    # one batch's assembly (sampler draw, the native
+                    # calls) on this thread: what the loader can sustain,
+                    # whether or not the loop is waiting for it
+                    with annotate("fed_input_produce"):
+                        try:
+                            batch = next(it)
+                        except StopIteration:
+                            break
                     while not stop.is_set():
                         try:
                             q.put(batch, timeout=0.1)
@@ -317,7 +327,8 @@ class PrefetchLoader:
         t.start()
         try:
             while True:
-                item = q.get()
+                with annotate("fed_input_wait"):
+                    item = q.get()
                 if item is self._END:
                     break
                 yield item

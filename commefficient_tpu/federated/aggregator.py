@@ -133,12 +133,26 @@ def _device_copy(tree):
     return jax.tree_util.tree_map(jnp.copy, tree)
 
 
+# The download accounting's device programs. The scope sits INSIDE each
+# jitted function: a scope around an eager call is lost to the cache of
+# whoever traced the callee first.
 @jax.jit
+@jax.named_scope("fed_accounting")
 def _mark_changed(last_changed, cur, prev, round_idx):
     return jnp.where(cur != prev, round_idx, last_changed)
 
 
 @jax.jit
+@jax.named_scope("fed_accounting")
+def _fold_updated(updated, cur, prev):
+    """Regime (a): fold the latest server update into the changed-since-
+    init mask; returns the mask and its popcount."""
+    updated = updated | (cur - prev != 0)
+    return updated, jnp.sum(updated)
+
+
+@jax.jit
+@jax.named_scope("fed_accounting")
 def _changed_since_counts(last_changed, since):
     # last_changed is (d,) flat or (T, S, 128) chunked-resident; padded tail
     # positions stay at their -1 init (cur == prev == 0 there forever) so
@@ -921,7 +935,8 @@ class FedModel:
 
         download_dev, upload = self._account_bytes_deferred(participating)
 
-        jbatch = {k: jnp.asarray(v) for k, v in batch.items()}
+        with annotate("fed_h2d", round=round_no):
+            jbatch = {k: jnp.asarray(v) for k, v in batch.items()}
         lr = self._current_lr()
         states_in = self.client_states
         proxy_ids = None
@@ -933,7 +948,7 @@ class FedModel:
             # this round's rows were already read while the previous round
             # computed (host_state.CohortPrefetcher, docs/host_offload.md)
             t0 = time.perf_counter()
-            with annotate("fed_offload_gather"):
+            with annotate("fed_offload_gather", round=round_no):
                 self._stream_round, hit = self._prefetcher.take(
                     np.asarray(batch["client_ids"]))
             proxy_ids = jnp.arange(int(jbatch["client_ids"].shape[0]),
@@ -989,10 +1004,8 @@ class FedModel:
                         kind = ev.pop("kind", "row_quarantined")
                         self.telemetry.event(kind, round=round_no, **ev)
         pre_model_state = self._model_state
-        # round-scoped trace span (docs/observability.md §trace capture):
-        # names the client phase's dispatch inside a profiler capture; a
-        # TraceAnnotation is host-side and near-free when no trace is on
-        with annotate("fed_client_phase"):
+        # names the client phase's dispatch (docs/observability.md)
+        with annotate("fed_client_phase", round=round_no):
             ctx, self._model_state, metrics = self.steps.client_step(
                 self.ps_weights, states_in, self._model_state, jbatch,
                 lr, self._next_rng())
@@ -1277,8 +1290,9 @@ class FedModel:
             return server_state
         ctx = self._round_ctx
         rng = self._next_rng()
+        round_no = self._rounds_dispatched - 1  # begin_round counted it
         if not self.streaming:
-            with annotate("fed_server_phase"):
+            with annotate("fed_server_phase", round=round_no):
                 out = self.steps.server_step(
                     self.ps_weights, server_state, self.client_states, ctx,
                     lr, rng)
@@ -1292,7 +1306,7 @@ class FedModel:
                 errors=ctx.err_rows if proxy.errors is not None else None,
                 weights=(ctx.stale_rows if proxy.weights is not None
                          else None))
-            with annotate("fed_server_phase"):
+            with annotate("fed_server_phase", round=round_no):
                 out = self.steps.server_step(
                     self.ps_weights, server_state, proxy, ctx, lr, rng)
             new_ps, new_ss, new_proxy = out[:3]
@@ -1369,11 +1383,10 @@ class FedModel:
 
         download_dev = None
         if self._simple_download:
-            diff = self.ps_weights - self._prev_ps
-            self._updated_since_init = self._updated_since_init | (diff != 0)
-            self._prev_ps = self.ps_weights
             # scalar popcount, broadcast over participants at materialize
-            download_dev = jnp.sum(self._updated_since_init)
+            self._updated_since_init, download_dev = _fold_updated(
+                self._updated_since_init, self.ps_weights, self._prev_ps)
+            self._prev_ps = self.ps_weights
         else:
             # fold the latest server update into the last-changed index
             self._last_changed = _mark_changed(self._last_changed,

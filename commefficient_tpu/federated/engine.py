@@ -40,7 +40,6 @@ seam). ``bench.py`` reports the measured count per round.
 
 from __future__ import annotations
 
-import time
 from collections import deque
 from typing import Any, Deque, List, NamedTuple, Optional, Tuple
 
@@ -130,10 +129,11 @@ class PipelinedRoundEngine:
         self._next_index = 0
         self.rounds_submitted = 0
         self.drains = 0
-        # Telemetry plane (docs/observability.md): the engine records the
-        # round-lifecycle spans the host holds for free — dispatch start,
-        # seal, the window wait's completion stamp, drain fetch latency,
-        # in-flight occupancy. Span data buffers in memory and is written
+        # Telemetry plane (docs/observability.md): the engine hands the
+        # recorder its own spans as they close — fed_round (dispatch),
+        # fed_window_wait, fed_drain — and the in-flight occupancy; the
+        # recorder reads their durations, it stamps no clock of its own.
+        # Span data buffers in memory and is written
         # only when the round drains, so the dispatch path stays fetch-free
         # (the zero-syncs audit covers telemetry-on runs,
         # tests/test_telemetry.py). Defaults to the model's attached
@@ -161,7 +161,6 @@ class PipelinedRoundEngine:
     def submit(self, batch) -> List[RoundResult]:
         """Dispatch one training round; no blocking host transfer happens
         here unless this is a drain round (every ``drain_every``-th)."""
-        t_start = time.monotonic()
         # the round_no this dispatch will get (the model's global counter;
         # models without one fall back to the engine-local index)
         rn_next = getattr(self.model, "rounds_dispatched",
@@ -170,10 +169,11 @@ class PipelinedRoundEngine:
             # may start a windowed jax.profiler capture BEFORE dispatch,
             # so this round's dispatch + device compute land in the trace
             self.tracer.on_submit(rn_next)
-        # StepTraceAnnotation marks the round on the profiler timeline
-        # keyed by the global round_no (near-free when no trace is active)
-        with jax.profiler.StepTraceAnnotation("fed_round",
-                                              step_num=rn_next):
+        # the step span marks the round on the profiler timeline (_r and
+        # step_num are what jax.profiler.StepTraceAnnotation sets), keyed
+        # by the global round_no like every other program span
+        with annotate("fed_round", _r=1, step_num=rn_next,
+                      round=rn_next) as round_span:
             if self.lr_scheduler is not None:
                 self.lr_scheduler.step()
             handle = self.model.begin_round(batch)
@@ -191,17 +191,21 @@ class PipelinedRoundEngine:
         self.rounds_submitted += 1
         if self.telemetry is not None:
             self.telemetry.on_dispatch(
-                self._round_no(handle, self._next_index - 1), t_start,
+                self._round_no(handle, self._next_index - 1), round_span,
                 occupancy=len(self._pending))
 
         if len(self._pending) > self.window:
             # bound host run-ahead: wait for the computation of the round
-            # `window` back — completion only, its values stay on device
+            # `window` back — completion only, its values stay on device.
+            # The one place where the host waits for the device and the
+            # device's idle time is NOT the host's doing.
             oidx, old = self._pending[-1 - self.window]
-            jax.block_until_ready(old.metrics)
+            waited = self._round_no(old, oidx)
+            with annotate("fed_window_wait", round=waited) as wait_span:
+                jax.block_until_ready(old.metrics)
             if self.telemetry is not None:
                 # the wait doubles as the round's device-completion stamp
-                self.telemetry.on_complete(self._round_no(old, oidx))
+                self.telemetry.on_complete(waited, wait_span)
 
         if len(self._pending) >= self.drain_every:
             return self.drain()
@@ -218,14 +222,14 @@ class PipelinedRoundEngine:
         """Materialize every dispatched-but-unfetched round, oldest first —
         the batched host sync. Safe to call with nothing pending."""
         results = []
-        t0 = time.monotonic()
+        drain_ms = 0.0
         while self._pending:
             idx, handle = self._pending.popleft()
-            t_fetch = time.monotonic()
-            with annotate("fed_drain"):
+            rn = self._round_no(handle, idx)
+            with annotate("fed_drain", round=rn) as drain_span:
                 results.append(RoundResult(idx,
                                            self.model.finish_round(handle)))
-            rn = self._round_no(handle, idx)
+            drain_ms += drain_span.ms
             if self.heartbeat.enabled:
                 # minimal live monitor even with telemetry off: the
                 # drained round's mean loss + guard verdict ride the
@@ -261,8 +265,7 @@ class PipelinedRoundEngine:
                     guard_ok=getattr(self.model, "last_guard_ok", None),
                     buffer=hb_buf, stale=hb_stale, population=hb_pop)
             if self.telemetry is not None:
-                self.telemetry.on_drained(rn,
-                                          time.monotonic() - t_fetch)
+                self.telemetry.on_drained(rn, drain_span)
             if self.tracer is not None:
                 # stop an active capture once its window's last round has
                 # drained (device compute provably complete), and log the
@@ -273,9 +276,8 @@ class PipelinedRoundEngine:
         if results:
             self.drains += 1
             if self.telemetry is not None:
-                self.telemetry.event(
-                    "drain", rounds=len(results),
-                    ms=round((time.monotonic() - t0) * 1e3, 3))
+                self.telemetry.event("drain", rounds=len(results),
+                                     ms=round(drain_ms, 3))
         return results
 
     def close(self) -> List[RoundResult]:

@@ -585,6 +585,11 @@ def build_round_step(
         assert mesh is not None and wcfg.pp_axis in mesh.axis_names, \
             f"pp_axis {wcfg.pp_axis!r} not in mesh axes"
 
+    # The round's stages carry ``jax.named_scope`` names on the device
+    # (profiling.DEVICE_STAGES; docs/observability.md lists who reads each).
+    # Scopes never nest: an operation's path holds exactly one stage.
+    scope = jax.named_scope
+
     def fused_clients(ps_weights, model_state, batch, rng_keys, worker_mask):
         """One-gradient client phase for a shard's W client slots. Returns
         (local_dense_sum incl. weight decay and seq psum, stacked per-client
@@ -628,32 +633,34 @@ def build_round_step(
         init = (jnp.zeros_like(ps_weights), jnp.zeros(W),
                 tuple(jnp.zeros(W) for _ in range(n_metrics)), jnp.zeros(W),
                 mstates0, rng_keys)
-        (g_sum, loss_sums, m_sums, counts, new_ms, _), _ = jax.lax.scan(
-            body, init, stacked)
+        with scope("fed_client_grad"):
+            (g_sum, loss_sums, m_sums, counts, new_ms, _), _ = jax.lax.scan(
+                body, init, stacked)
+            denom = jnp.maximum(counts, 1.0)
+            metrics = (loss_sums / denom,) \
+                + tuple(m / denom for m in m_sums) + (counts,)
 
-        if wcfg.seq_axis is not None:
-            # shards backpropagated their local sequence slice (linear, so
-            # one psum of the sum replaces the per-client psums)
-            g_sum = jax.lax.psum(g_sum, wcfg.seq_axis)
-        if wcfg.model_axis is not None:
-            # reconcile sliced/replicated segments (see worker.forward_grad)
-            g_sum = jax.lax.psum(g_sum, wcfg.model_axis) * tp_scale_res
-        if wcfg.pp_axis is not None:
-            # disjoint stage-local gradient segments -> full gradient
-            g_sum = jax.lax.psum(g_sum, wcfg.pp_axis)
-        if wcfg.expert_axis is not None:
-            # expert-sliced/replicated reconciliation (see worker.forward_grad)
-            g_sum = jax.lax.psum(g_sum, wcfg.expert_axis) * ep_scale_res
-        if wcfg.weight_decay != 0:
-            # per-client (wd/num_workers)·w scaled by the client's datum
-            # count (worker.forward_grad + local_step ×count)
-            wd_scale = jnp.sum(worker_mask * counts)
-            g_sum = g_sum + (wcfg.weight_decay / wcfg.num_workers) * \
-                wd_scale * ps_weights
-
-        denom = jnp.maximum(counts, 1.0)
-        metrics = (loss_sums / denom,) + tuple(m / denom for m in m_sums) \
-            + (counts,)
+        with scope("fed_client_compress"):
+            if wcfg.seq_axis is not None:
+                # shards backpropagated their local sequence slice (linear,
+                # so one psum of the sum replaces the per-client psums)
+                g_sum = jax.lax.psum(g_sum, wcfg.seq_axis)
+            if wcfg.model_axis is not None:
+                # reconcile sliced/replicated segments (worker.forward_grad)
+                g_sum = jax.lax.psum(g_sum, wcfg.model_axis) * tp_scale_res
+            if wcfg.pp_axis is not None:
+                # disjoint stage-local gradient segments -> full gradient
+                g_sum = jax.lax.psum(g_sum, wcfg.pp_axis)
+            if wcfg.expert_axis is not None:
+                # expert-sliced/replicated reconciliation
+                # (see worker.forward_grad)
+                g_sum = jax.lax.psum(g_sum, wcfg.expert_axis) * ep_scale_res
+            if wcfg.weight_decay != 0:
+                # per-client (wd/num_workers)·w scaled by the client's
+                # datum count (worker.forward_grad + local_step ×count)
+                wd_scale = jnp.sum(worker_mask * counts)
+                g_sum = g_sum + (wcfg.weight_decay / wcfg.num_workers) * \
+                    wd_scale * ps_weights
         return g_sum, new_ms, metrics
 
     def fused_clients_stream(ps_weights, model_state, batch, rng_keys,
@@ -689,7 +696,8 @@ def build_round_step(
             lambda x: jnp.broadcast_to(x[None], (W,) + x.shape), model_state)
         # the ONE model boundary: leaves sliced straight from the resident
         # chunk plane (ops/flat.chunked_unravel — every op < d-sized)
-        params = stream_unravel(ps_weights)
+        with scope("fed_client_grad"):
+            params = stream_unravel(ps_weights)
 
         def step_loss(p, mstates, micro, subs):
             def per_client(ms, b, r):
@@ -708,16 +716,18 @@ def build_round_step(
 
         def body(carry, micro):
             table, loss_acc, m_acc, n_acc, mstates, keys = carry
-            keys2, subs = jax.vmap(next_rng)(keys)
-            (_, (loss_sums, msums, counts, new_ms)), g_tree = grad_fn(
-                params, mstates, micro, subs)
+            with scope("fed_client_grad"):
+                keys2, subs = jax.vmap(next_rng)(keys)
+                (_, (loss_sums, msums, counts, new_ms)), g_tree = grad_fn(
+                    params, mstates, micro, subs)
+                m_acc = tuple(a + m for a, m in zip(m_acc, msums))
             # leaf gradients -> table, right where the backward made them
             # (one accumulate per leaf, or per coalesced group when the
             # --sketch_coalesce plan is set)
-            table = sketch_grad_tree(sketch, table, g_tree, stream_segs,
-                                     scales=stream_scales,
-                                     groups=stream_groups)
-            m_acc = tuple(a + m for a, m in zip(m_acc, msums))
+            with scope("fed_client_compress"):
+                table = sketch_grad_tree(sketch, table, g_tree, stream_segs,
+                                         scales=stream_scales,
+                                         groups=stream_groups)
             return (table, loss_acc + loss_sums, m_acc, n_acc + counts,
                     new_ms, keys2), None
 
@@ -730,30 +740,34 @@ def build_round_step(
         # the composed path's post-scan psums, riding the table: sketches
         # are linear, so psum(sketch(g)) == sketch(psum(g)); the tp/ep
         # rescales already happened per leaf above
-        for ax in (wcfg.seq_axis, wcfg.model_axis, wcfg.pp_axis,
-                   wcfg.expert_axis):
-            if ax is not None:
-                table = jax.lax.psum(table, ax)
-        if wcfg.weight_decay != 0:
-            # (wd/num_workers)·Σ_i mask_i·count_i · w, as one extra
-            # full-range segment-sketch of the resident chunked weights —
-            # AFTER the axis psums (w is replicated across them, exactly
-            # like the composed path adds wd after its psums)
-            wd_scale = jnp.sum(worker_mask * counts)
-            coef = (wcfg.weight_decay / wcfg.num_workers) * wd_scale
-            table = sketch_chunks_accum(sketch, table, ps_weights * coef)
+        with scope("fed_client_compress"):
+            for ax in (wcfg.seq_axis, wcfg.model_axis, wcfg.pp_axis,
+                       wcfg.expert_axis):
+                if ax is not None:
+                    table = jax.lax.psum(table, ax)
+            if wcfg.weight_decay != 0:
+                # (wd/num_workers)·Σ_i mask_i·count_i · w, as one extra
+                # full-range segment-sketch of the resident chunked
+                # weights — AFTER the axis psums (w is replicated across
+                # them, exactly like the composed path adds wd after its
+                # psums)
+                wd_scale = jnp.sum(worker_mask * counts)
+                coef = (wcfg.weight_decay / wcfg.num_workers) * wd_scale
+                table = sketch_chunks_accum(sketch, table, ps_weights * coef)
 
-        denom = jnp.maximum(counts, 1.0)
-        metrics = (loss_sums / denom,) + tuple(m / denom for m in m_sums) \
-            + (counts,)
+        with scope("fed_client_grad"):
+            denom = jnp.maximum(counts, 1.0)
+            metrics = (loss_sums / denom,) \
+                + tuple(m / denom for m in m_sums) + (counts,)
         return table, new_ms, metrics
 
     def one_client(ps_weights, vel_row, err_row, stale_row, model_state,
                    batch_row, lr, rng, slot_mask):
         # choose weights (topk-down stale path, fed_worker.py:150-159)
         if wcfg.do_topk_down:
-            weights_used = get_new_worker_weights(ps_weights, stale_row,
-                                                  wcfg.k, True)
+            with scope("fed_client_compress"):
+                weights_used = get_new_worker_weights(ps_weights, stale_row,
+                                                      wcfg.k, True)
         else:
             weights_used = ps_weights
 
@@ -790,11 +804,12 @@ def build_round_step(
                                                    res.new_error, res.metrics)
 
         # padded slots contribute nothing and keep their state
-        transmit = transmit * slot_mask
-        if new_vel is not None:
-            new_vel = jnp.where(slot_mask > 0, new_vel, vel_row)
-        if new_err is not None:
-            new_err = jnp.where(slot_mask > 0, new_err, err_row)
+        with scope("fed_client_compress"):
+            transmit = transmit * slot_mask
+            if new_vel is not None:
+                new_vel = jnp.where(slot_mask > 0, new_vel, vel_row)
+            if new_err is not None:
+                new_err = jnp.where(slot_mask > 0, new_err, err_row)
         return transmit, new_vel, new_err, new_ms, metrics
 
     def clients_shard(ps_weights, vel_rows, err_rows, stale_rows, model_state,
@@ -815,20 +830,31 @@ def build_round_step(
             # per-client path: the worker math (local_step/fedavg_local)
             # runs on the flat vector; a chunked round materializes the
             # flat view once per round here (the model boundary)
-            ps_flat = layout.unchunk(ps_weights) if chunked else ps_weights
+            with scope("fed_client_grad"):
+                ps_flat = layout.unchunk(ps_weights) if chunked \
+                    else ps_weights
             f = partial(one_client, ps_flat)
             transmit, new_vel, new_err, new_ms, metrics = jax.vmap(
                 f, in_axes=(0, 0, 0, None, 0, None, 0, 0),
                 out_axes=(0, 0, 0, 0, 0),
             )(vel_rows, err_rows, stale_rows, model_state, batch, lr,
               rng_keys, worker_mask)
-            local_sum = jnp.sum(transmit, axis=0)
+            with scope("fed_client_compress"):
+                local_sum = jnp.sum(transmit, axis=0)
+        with scope("fed_client_compress"):
+            total = _reduce_transmit(local_sum)
+        with scope("fed_client_grad"):
+            new_ms = _average_model_state(new_ms, model_state, worker_mask)
+        return total, new_vel, new_err, new_ms, metrics
+
+    def _reduce_transmit(local_sum):
+        """The shard's transmit sum -> the round's (fed_client_compress)."""
         if sketch_after_sum and not stream:
             # one sketch of the shard's dense gradient sum (see fusion note
             # above); the psum then rides the small (r, c_pad) table exactly
             # as the per-client path would. The fused chunked gradient is
             # already in the kernel's (T, S, 128) layout — no pad/reshape.
-            # (The streaming path above already produced the table.)
+            # (The streaming path already produced the table.)
             if chunked and fused_grad:
                 local_sum = sketch_chunks(sketch, local_sum)
             else:
@@ -839,11 +865,12 @@ def build_round_step(
             # no data moves), so the server phase owns the reduce (and,
             # under a quantized collective plan, the quantization + the
             # qres/dres error-feedback carries)
-            total = local_sum[None]
-        elif mesh is not None:
-            total = jax.lax.psum(local_sum, axis)
-        else:
-            total = local_sum
+            return local_sum[None]
+        if mesh is not None:
+            return jax.lax.psum(local_sum, axis)
+        return local_sum
+
+    def _average_model_state(new_ms, model_state, worker_mask):
         # model_state (e.g. BatchNorm stats): average over clients, weighted
         # by slot mask — a documented deviation; the reference lets each
         # worker process's BN stats drift independently. A shard whose slots
@@ -864,10 +891,9 @@ def build_round_step(
             total_w = wsum
             new_ms = local_mean
         # an entirely-empty round keeps the old state rather than zeroing it
-        new_ms = jax.tree_util.tree_map(
+        return jax.tree_util.tree_map(
             lambda new, old: jnp.where(total_w > 0, new, old),
             new_ms, model_state)
-        return total, new_vel, new_err, new_ms, metrics
 
     seq_axis = wcfg.seq_axis
     if mesh is not None and seq_axis is not None:
@@ -915,10 +941,12 @@ def build_round_step(
         data_batch = {k: v for k, v in batch.items()
                       if k not in ("client_ids", "worker_mask")}
 
-        vel_rows = _maybe_rows(client_states.velocities, ids, W)
-        err_rows = _maybe_rows(client_states.errors, ids, W)
-        stale_rows = _maybe_rows(client_states.weights, ids, W)
-        rngs = jax.random.split(rng, W)
+        with scope("fed_client_compress"):
+            vel_rows = _maybe_rows(client_states.velocities, ids, W)
+            err_rows = _maybe_rows(client_states.errors, ids, W)
+            stale_rows = _maybe_rows(client_states.weights, ids, W)
+        with scope("fed_client_grad"):
+            rngs = jax.random.split(rng, W)
 
         total, new_vel, new_err, new_model_state, metrics = _shard_clients(
             data_batch)(
@@ -926,14 +954,15 @@ def build_round_step(
             model_state, data_batch, lr, rngs, worker_mask)
 
         # data-weighted average (reference fed_aggregator.py:332)
-        total_count = jnp.maximum(batch["mask"].sum(), 1.0)
-        if server_shard:
-            # keep the per-shard sums raw: the division happens after the
-            # server phase's reduce, so Σ then ÷ matches the replicated
-            # path's psum-then-÷ bit-for-bit
-            gradient, count = total, total_count
-        else:
-            gradient, count = total / total_count, None
+        with scope("fed_client_compress"):
+            total_count = jnp.maximum(batch["mask"].sum(), 1.0)
+            if server_shard:
+                # keep the per-shard sums raw: the division happens after
+                # the server phase's reduce, so Σ then ÷ matches the
+                # replicated path's psum-then-÷ bit-for-bit
+                gradient, count = total, total_count
+            else:
+                gradient, count = total / total_count, None
 
         ctx = RoundContext(gradient, ids, worker_mask, vel_rows, err_rows,
                            stale_rows, new_vel, new_err, count)
@@ -1026,23 +1055,24 @@ def build_round_step(
                     g, st, scfg, lr_, sketch=sketch, rng=rng_,
                     layout=layout),
                 ctx.gradient, server_state, jnp.asarray(eff_lr), rng)
-        new_ps = ps_weights - update
+        with scope("fed_server_apply"):
+            new_ps = ps_weights - update
 
-        # On-device health guard (--guards, docs/fault_tolerance.md): one
-        # scalar verdict gates the WHOLE state transition. A select against
-        # the pre-round state (never arithmetic like `update * ok` — a NaN
-        # times zero is still NaN) makes a tripped round a no-op: weights,
-        # server (velocity, error, qres) and every client-state scatter
-        # below keep their pre-round values, so the poisoned contribution
-        # is discarded rather than telescoped through error feedback.
-        guard_ok = None
-        if cfg.guards:
-            guard_ok = round_health(ctx.gradient, new_ps,
-                                    cfg.guard_max_abs)
-            new_ps = jnp.where(guard_ok, new_ps, ps_weights)
-            new_server_state = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(guard_ok, new, old),
-                new_server_state, server_state)
+            # On-device health guard (--guards, docs/fault_tolerance.md): one
+            # scalar verdict gates the WHOLE state transition. A select against
+            # the pre-round state (never arithmetic like `update * ok` — a NaN
+            # times zero is still NaN) makes a tripped round a no-op: weights,
+            # server (velocity, error, qres) and every client-state scatter
+            # below keep their pre-round values, so the poisoned contribution
+            # is discarded rather than telescoped through error feedback.
+            guard_ok = None
+            if cfg.guards:
+                guard_ok = round_health(ctx.gradient, new_ps,
+                                        cfg.guard_max_abs)
+                new_ps = jnp.where(guard_ok, new_ps, ps_weights)
+                new_server_state = jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(guard_ok, new, old),
+                    new_server_state, server_state)
 
         ids = ctx.ids
 
@@ -1059,7 +1089,8 @@ def build_round_step(
         #   working completion of that design.
         keep_vel = keep_err = None
         if wcfg.mode == "true_topk" and wcfg.local_momentum > 0:
-            keep_vel = (update == 0).astype(jnp.float32)[None, :]
+            with scope("fed_server_apply"):
+                keep_vel = (update == 0).astype(jnp.float32)[None, :]
         elif wcfg.mode == "sketch" and (wcfg.has_velocity or wcfg.has_error):
             if resketched is not None and jnp.ndim(eff_lr) == 0:
                 # sharded server: the psum'd partial re-sketch (of the
@@ -1068,57 +1099,62 @@ def build_round_step(
                 # scaled update — no replicated d-sized re-sketch. A
                 # per-coordinate lr vector scales before the sketch, so
                 # that case recomputes below.
-                sketched_update = resketched * eff_lr
+                with scope("fed_server_apply"):
+                    sketched_update = resketched * eff_lr
             else:
                 resketch = sketch_chunks if chunked else sketch_vec
-                sketched_update = _on_every_device(
-                    lambda u: resketch(sketch, u), update)
-            cell_keep = (sketched_update == 0).astype(jnp.float32)[None]
+                with scope("fed_server_resketch"):
+                    sketched_update = _on_every_device(
+                        lambda u: resketch(sketch, u), update)
+            with scope("fed_server_apply"):
+                cell_keep = (sketched_update == 0).astype(
+                    jnp.float32)[None]
             keep_vel = keep_err = cell_keep
 
         # One delta-scatter per state array writes the masked new rows for
         # *participating* slots only. Padded slots carry a duplicate client
         # id (the loader pads with id 0) but have wmask 0, so they add delta
         # 0 while a real slot for the same id still lands its full value.
-        def scatter(state_arr, old_rows, new_rows, keep):
-            if state_arr is None:
-                return None
-            final = new_rows if keep is None else new_rows * keep
-            w = ctx.wmask.reshape((-1,) + (1,) * (old_rows.ndim - 1))
-            delta = (final - old_rows) * w
-            if guard_ok is not None:
-                # quarantined round: every participating row keeps its
-                # pre-round state (select, not multiply — NaN rows)
-                delta = jnp.where(guard_ok, delta, jnp.zeros_like(delta))
-            return state_arr.at[ids].add(delta)
+        with scope("fed_server_apply"):
+            def scatter(state_arr, old_rows, new_rows, keep):
+                if state_arr is None:
+                    return None
+                final = new_rows if keep is None else new_rows * keep
+                w = ctx.wmask.reshape((-1,) + (1,) * (old_rows.ndim - 1))
+                delta = (final - old_rows) * w
+                if guard_ok is not None:
+                    # quarantined round: every participating row keeps its
+                    # pre-round state (select, not multiply — NaN rows)
+                    delta = jnp.where(guard_ok, delta, jnp.zeros_like(delta))
+                return state_arr.at[ids].add(delta)
 
-        cs = ClientStates(
-            velocities=scatter(client_states.velocities, ctx.vel_rows,
-                               ctx.new_vel, keep_vel),
-            errors=scatter(client_states.errors, ctx.err_rows, ctx.new_err,
-                           keep_err),
-            weights=client_states.weights,
-        )
-        # topk-down: participating clients' stale weights advance to the
-        # weights they actually used this round. wmask gates the delta like
-        # the velocity/error scatters above: a padded slot (the loader pads
-        # with client id 0, wmask 0) or a --client_dropout-zeroed slot must
-        # not advance its client's stale weights — and a padded slot
-        # duplicating a real slot's id would otherwise land the SAME delta
-        # twice (2*used - stale instead of used).
-        if wcfg.do_topk_down and cs.weights is not None:
-            used = _manual(
-                lambda w, rows: jax.vmap(lambda s: get_new_worker_weights(
-                    w, s, wcfg.k, True))(rows),
-                (P(), P(axis)), P(axis))(ps_weights, ctx.stale_rows)
-            w = ctx.wmask.reshape(-1, 1)
-            stale_delta = (used - ctx.stale_rows) * w
-            if guard_ok is not None:
-                # a quarantined round is discarded end to end — its clients'
-                # stale weights must not advance either
-                stale_delta = jnp.where(guard_ok, stale_delta,
-                                        jnp.zeros_like(stale_delta))
-            cs = cs._replace(weights=cs.weights.at[ids].add(stale_delta))
+            cs = ClientStates(
+                velocities=scatter(client_states.velocities, ctx.vel_rows,
+                                   ctx.new_vel, keep_vel),
+                errors=scatter(client_states.errors, ctx.err_rows, ctx.new_err,
+                               keep_err),
+                weights=client_states.weights,
+            )
+            # topk-down: participating clients' stale weights advance to the
+            # weights they actually used this round. wmask gates the delta like
+            # the velocity/error scatters above: a padded slot (the loader pads
+            # with client id 0, wmask 0) or a --client_dropout-zeroed slot must
+            # not advance its client's stale weights — and a padded slot
+            # duplicating a real slot's id would otherwise land the SAME delta
+            # twice (2*used - stale instead of used).
+            if wcfg.do_topk_down and cs.weights is not None:
+                used = _manual(
+                    lambda w, rows: jax.vmap(lambda s: get_new_worker_weights(
+                        w, s, wcfg.k, True))(rows),
+                    (P(), P(axis)), P(axis))(ps_weights, ctx.stale_rows)
+                w = ctx.wmask.reshape(-1, 1)
+                stale_delta = (used - ctx.stale_rows) * w
+                if guard_ok is not None:
+                    # a quarantined round is discarded end to end — its clients'
+                    # stale weights must not advance either
+                    stale_delta = jnp.where(guard_ok, stale_delta,
+                                            jnp.zeros_like(stale_delta))
+                cs = cs._replace(weights=cs.weights.at[ids].add(stale_delta))
         # Zero-sync telemetry (cfg.telemetry, docs/observability.md): one
         # fixed-schema device vector of round metrics, computed AFTER the
         # guard select so a quarantined round's metrics show exactly what
@@ -1133,7 +1169,8 @@ def build_round_step(
                                        new_server_state, guard_ok=guard_ok,
                                        hists=cfg.telemetry_hist)
         if flat_caller:
-            new_ps = layout.unchunk(new_ps)
+            with scope("fed_server_apply"):
+                new_ps = layout.unchunk(new_ps)
         ret = (new_ps, new_server_state, cs)
         if cfg.guards:
             ret += (guard_ok,)
@@ -1161,6 +1198,7 @@ def build_round_step(
         return (new_ps, new_server_state, cs, new_model_state,
                 metrics) + tuple(out[3:])
 
+    @jax.named_scope("fed_val")
     def val_step(ps_weights, model_state, batch):
         def _val(w, ms, b):
             w_flat = layout.unchunk(w) if (chunked and w.ndim != 1) else w

@@ -45,7 +45,6 @@ from commefficient_tpu.ops.sketch import (
     fused_epilogue_mode,
     sketch_chunks,
     sketch_chunks_local,
-    unsketch_chunks,
 )
 from commefficient_tpu.ops.topk import topk, topk_dense_nd
 
@@ -365,6 +364,7 @@ def server_update(
     return helper(gradient, state, cfg, lr)
 
 
+@jax.named_scope("fed_server_apply")
 def _fedavg(avg_update, state, cfg, lr):
     # lr already applied on-worker; server asserts lr == 1
     # (reference fed_aggregator.py:483-495).
@@ -372,6 +372,7 @@ def _fedavg(avg_update, state, cfg, lr):
     return velocity, ServerState(velocity, state.error)
 
 
+@jax.named_scope("fed_server_apply")
 def _uncompressed(gradient, state, cfg, lr, rng):
     velocity = gradient + cfg.virtual_momentum * state.velocity
     update = velocity
@@ -384,17 +385,21 @@ def _uncompressed(gradient, state, cfg, lr, rng):
 
 
 def _true_topk(gradient, state, cfg, lr):
-    velocity = gradient + cfg.virtual_momentum * state.velocity
-    error = state.error + velocity
-    update = topk(error, cfg.k)
-    nz = update != 0
-    # error feedback + momentum factor masking at the chosen coordinates
-    # (reference fed_aggregator.py:536-540)
-    error = jnp.where(nz, 0.0, error)
-    velocity = jnp.where(nz, 0.0, velocity)
-    return update * lr, ServerState(velocity, error)
+    with jax.named_scope("fed_server_apply"):
+        velocity = gradient + cfg.virtual_momentum * state.velocity
+        error = state.error + velocity
+    with jax.named_scope("fed_server_topk"):
+        update = topk(error, cfg.k)
+    with jax.named_scope("fed_server_apply"):
+        nz = update != 0
+        # error feedback + momentum factor masking at the chosen
+        # coordinates (reference fed_aggregator.py:536-540)
+        error = jnp.where(nz, 0.0, error)
+        velocity = jnp.where(nz, 0.0, velocity)
+        return update * lr, ServerState(velocity, error)
 
 
+@jax.named_scope("fed_server_apply")
 def _local_topk(local_topk_grad, state, cfg, lr):
     # no virtual error, no masking (rationale: reference
     # fed_aggregator.py:559-563)
@@ -514,37 +519,40 @@ def sharded_server_update(
     if up_q and down_q:
         rng_up, rng_down = jax.random.split(rng)
 
+    scope = jax.named_scope
     if cfg.mode == "sketch":
         assert sketch is not None and layout is not None
-        if isinstance(up_low, tuple):
-            # per-axis table exchange: level-by-level all-reduce, each
-            # quantized level folding ITS carry slot's local row
-            table, new_slots = hierarchical_psum(
-                transmit_local, up_low, rng_up,
-                residuals=[None if q is None else q[0]
-                           for q in qres_local],
-                block=sketch.c_pad)
-            new_qres = tuple(None if r is None else r[None]
-                             for r in new_slots)
-        elif up_q:
-            # block = one table row (c_pad = S·128 lanes) per scale
-            table, new_qres = quantized_psum(
-                transmit_local, axis, rng_up, residual=qres_local[0],
-                block=sketch.c_pad, dtype=up_low)
-            new_qres = new_qres[None]
-        else:
-            table = jax.lax.psum(transmit_local, axis)
-            new_qres = qres_local
-        table = table / count
-        velocity = table + cfg.virtual_momentum * state.velocity
-        if cfg.error_type == "virtual":
-            error = state.error + velocity
-        else:  # "local" and the documented "none" deviation alike
-            error = velocity
+        with scope("fed_server_apply"):
+            if isinstance(up_low, tuple):
+                # per-axis table exchange: level-by-level all-reduce, each
+                # quantized level folding ITS carry slot's local row
+                table, new_slots = hierarchical_psum(
+                    transmit_local, up_low, rng_up,
+                    residuals=[None if q is None else q[0]
+                               for q in qres_local],
+                    block=sketch.c_pad)
+                new_qres = tuple(None if r is None else r[None]
+                                 for r in new_slots)
+            elif up_q:
+                # block = one table row (c_pad = S·128 lanes) per scale
+                table, new_qres = quantized_psum(
+                    transmit_local, axis, rng_up, residual=qres_local[0],
+                    block=sketch.c_pad, dtype=up_low)
+                new_qres = new_qres[None]
+            else:
+                table = jax.lax.psum(transmit_local, axis)
+                new_qres = qres_local
+            table = table / count
+            velocity = table + cfg.virtual_momentum * state.velocity
+            if cfg.error_type == "virtual":
+                error = state.error + velocity
+            else:  # "local" and the documented "none" deviation alike
+                error = velocity
 
         Tn = -(-sketch.T // n_shard)
-        t0 = jax.lax.axis_index(axis) * Tn
-        est_local = estimates_chunks_local(sketch, error, t0, Tn)
+        with scope("fed_server_estimate"):
+            t0 = jax.lax.axis_index(axis) * Tn
+            est_local = estimates_chunks_local(sketch, error, t0, Tn)
         fe_mode = fused_epilogue_mode(sketch) if cfg.fused_epilogue else "off"
         if fe_mode != "off":
             # per-shard one-sweep epilogue: the threshold comes from the
@@ -556,114 +564,126 @@ def sharded_server_update(
             upd_local, part = fused_epilogue_chunks_local(
                 sketch, est_local, t0, cfg.k, axis_name=axis,
                 interpret=(fe_mode == "interpret"))
-            resketched = jax.lax.psum(part, axis)
+            with scope("fed_server_resketch"):
+                resketched = jax.lax.psum(part, axis)
         else:
-            upd_local = topk_dense_nd(est_local, cfg.k, axis_name=axis)
-            resketched = jax.lax.psum(
-                sketch_chunks_local(sketch, upd_local, t0), axis)
-        cell_nz = resketched != 0
-        if cfg.error_type == "virtual":
-            error = jnp.where(cell_nz, 0.0, error)
-        velocity = jnp.where(cell_nz, 0.0, velocity)
-        if cfg.error_type == "local":
-            # torch aliasing parity (see _sketched)
-            error = velocity
-        if isinstance(down_low, tuple):
-            # per-axis downlink: gather level by level in reverse reduce
-            # order; slot j's local view IS level j's input tile
-            full, new_dres = hierarchical_all_gather(
-                upd_local, down_low, rng_down, residuals=dres_local,
-                block=sketch.sublanes * 128)
-            update = full[: sketch.T]
-        elif down_q:
-            # downlink leg: quantize this shard's update chunks (one scale
-            # per (S, 128) resident chunk) before the gather; the
-            # remainder telescopes through dres like qres on the uplink
-            full, new_dres = quantized_all_gather(
-                upd_local, axis, rng_down, residual=dres_local,
-                block=sketch.sublanes * 128, dtype=down_low)
-            update = full[: sketch.T]
-        else:
-            update = all_gather_tiled(upd_local, axis)[: sketch.T]
-            new_dres = dres_local
-        return (update * lr,
-                ServerState(velocity, error, new_qres, new_dres),
-                resketched)
+            with scope("fed_server_topk"):
+                upd_local = topk_dense_nd(est_local, cfg.k, axis_name=axis)
+            with scope("fed_server_resketch"):
+                resketched = jax.lax.psum(
+                    sketch_chunks_local(sketch, upd_local, t0), axis)
+        with scope("fed_server_apply"):
+            cell_nz = resketched != 0
+            if cfg.error_type == "virtual":
+                error = jnp.where(cell_nz, 0.0, error)
+            velocity = jnp.where(cell_nz, 0.0, velocity)
+            if cfg.error_type == "local":
+                # torch aliasing parity (see _sketched)
+                error = velocity
+            if isinstance(down_low, tuple):
+                # per-axis downlink: gather level by level in reverse reduce
+                # order; slot j's local view IS level j's input tile
+                full, new_dres = hierarchical_all_gather(
+                    upd_local, down_low, rng_down, residuals=dres_local,
+                    block=sketch.sublanes * 128)
+                update = full[: sketch.T]
+            elif down_q:
+                # downlink leg: quantize this shard's update chunks (one scale
+                # per (S, 128) resident chunk) before the gather; the
+                # remainder telescopes through dres like qres on the uplink
+                full, new_dres = quantized_all_gather(
+                    upd_local, axis, rng_down, residual=dres_local,
+                    block=sketch.sublanes * 128, dtype=down_low)
+                update = full[: sketch.T]
+            else:
+                update = all_gather_tiled(upd_local, axis)[: sketch.T]
+                new_dres = dres_local
+            return (update * lr,
+                    ServerState(velocity, error, new_qres, new_dres),
+                    resketched)
 
     # ---- dense modes: flat (d,) transmit, state as local slices --------
-    d = cfg.grad_size
-    d_pad = -(-d // n_shard) * n_shard
-    x = jnp.pad(transmit_local, (0, d_pad - d))
-    if isinstance(up_low, tuple):
-        tile, new_slots = hierarchical_psum_scatter(
-            x, up_low, rng_up,
-            residuals=[None if q is None else q[0] for q in qres_local])
-        new_qres = tuple(None if r is None else r[None] for r in new_slots)
-    elif up_q:
-        tile, new_qres = quantized_psum_scatter(x, axis, rng_up,
-                                                residual=qres_local[0],
-                                                dtype=up_low)
-        new_qres = new_qres[None]
-    else:
-        tile = reduce_scatter_sum(x, axis)
-        new_qres = qres_local
-    grad = tile / count
+    with scope("fed_server_apply"):
+        d = cfg.grad_size
+        d_pad = -(-d // n_shard) * n_shard
+        x = jnp.pad(transmit_local, (0, d_pad - d))
+        if isinstance(up_low, tuple):
+            tile, new_slots = hierarchical_psum_scatter(
+                x, up_low, rng_up,
+                residuals=[None if q is None else q[0] for q in qres_local])
+            new_qres = tuple(None if r is None else r[None] for r in new_slots)
+        elif up_q:
+            tile, new_qres = quantized_psum_scatter(x, axis, rng_up,
+                                                    residual=qres_local[0],
+                                                    dtype=up_low)
+            new_qres = new_qres[None]
+        else:
+            tile = reduce_scatter_sum(x, axis)
+            new_qres = qres_local
+        grad = tile / count
 
-    velocity = grad + cfg.virtual_momentum * state.velocity
-    error = state.error
+        velocity = grad + cfg.virtual_momentum * state.velocity
+        error = state.error
     if cfg.mode == "true_topk":
-        error = error + velocity
-        upd_local = topk_dense_nd(error, cfg.k, axis_name=axis)
-        nz = upd_local != 0
-        error = jnp.where(nz, 0.0, error)
-        velocity = jnp.where(nz, 0.0, velocity)
+        with scope("fed_server_apply"):
+            error = error + velocity
+        with scope("fed_server_topk"):
+            upd_local = topk_dense_nd(error, cfg.k, axis_name=axis)
+        with scope("fed_server_apply"):
+            nz = upd_local != 0
+            error = jnp.where(nz, 0.0, error)
+            velocity = jnp.where(nz, 0.0, velocity)
     else:  # uncompressed / local_topk / fedavg: update IS the velocity
-        upd_local = velocity
-        if cfg.mode == "uncompressed" and cfg.do_dp \
-                and cfg.dp_mode == "server":
-            assert rng is not None, "server DP needs an rng key"
-            # one replicated (d_pad,)-stream draw, locally sliced, so every
-            # shard agrees on the full noise vector (the stream differs
-            # from the replicated path's (d,)-shaped draw — documented in
-            # docs/sharded_server.md). Under a quantized plan the raw key
-            # (or its split children) already feeds the collectives' SR
-            # draws — fold to a distinct stream so the DP noise stays
-            # statistically independent of the quantization dither; the
-            # fp32 plan keeps the pre-plan draw bit for bit.
-            noise_rng = rng
-            if up_q or down_q:
-                noise_rng = jax.random.fold_in(rng, 2)
-            noise = jax.random.normal(noise_rng, (d_pad,), upd_local.dtype)
-            per = d_pad // n_shard
-            upd_local = upd_local + cfg.noise_multiplier * \
-                jax.lax.dynamic_slice_in_dim(
-                    noise, jax.lax.axis_index(axis) * per, per)
+        with scope("fed_server_apply"):
+            upd_local = velocity
+            if cfg.mode == "uncompressed" and cfg.do_dp \
+                    and cfg.dp_mode == "server":
+                assert rng is not None, "server DP needs an rng key"
+                # one replicated (d_pad,)-stream draw, locally sliced, so every
+                # shard agrees on the full noise vector (the stream differs
+                # from the replicated path's (d,)-shaped draw — documented in
+                # docs/sharded_server.md). Under a quantized plan the raw key
+                # (or its split children) already feeds the collectives' SR
+                # draws — fold to a distinct stream so the DP noise stays
+                # statistically independent of the quantization dither; the
+                # fp32 plan keeps the pre-plan draw bit for bit.
+                noise_rng = rng
+                if up_q or down_q:
+                    noise_rng = jax.random.fold_in(rng, 2)
+                noise = jax.random.normal(noise_rng, (d_pad,), upd_local.dtype)
+                per = d_pad // n_shard
+                upd_local = upd_local + cfg.noise_multiplier * \
+                    jax.lax.dynamic_slice_in_dim(
+                        noise, jax.lax.axis_index(axis) * per, per)
 
-    if isinstance(down_low, tuple):
-        full, new_dres = hierarchical_all_gather(
-            upd_local, down_low, rng_down, residuals=dres_local)
-        update = full[:d]
-    elif down_q:
-        full, new_dres = quantized_all_gather(
-            upd_local, axis, rng_down, residual=dres_local,
-            dtype=down_low)
-        update = full[:d]
-    else:
-        update = all_gather_tiled(upd_local, axis)[:d]
-        new_dres = dres_local
-    return (update * lr, ServerState(velocity, error, new_qres, new_dres),
-            None)
+    with scope("fed_server_apply"):
+        if isinstance(down_low, tuple):
+            full, new_dres = hierarchical_all_gather(
+                upd_local, down_low, rng_down, residuals=dres_local)
+            update = full[:d]
+        elif down_q:
+            full, new_dres = quantized_all_gather(
+                upd_local, axis, rng_down, residual=dres_local,
+                dtype=down_low)
+            update = full[:d]
+        else:
+            update = all_gather_tiled(upd_local, axis)[:d]
+            new_dres = dres_local
+        return (update * lr, ServerState(velocity, error, new_qres, new_dres),
+                None)
 
 
 def _sketched(sketched_grad, state, cfg, lr, sketch: CountSketch,
               layout: Optional[ChunkLayout] = None):
-    velocity = sketched_grad + cfg.virtual_momentum * state.velocity
-    if cfg.error_type == "local":
-        error = velocity
-    elif cfg.error_type == "virtual":
-        error = state.error + velocity
-    else:  # "none": deviation — unsketch the velocity (see module docstring)
-        error = velocity
+    scope = jax.named_scope
+    with scope("fed_server_apply"):
+        velocity = sketched_grad + cfg.virtual_momentum * state.velocity
+        if cfg.error_type == "local":
+            error = velocity
+        elif cfg.error_type == "virtual":
+            error = state.error + velocity
+        else:  # "none": deviation — unsketch the velocity (module docstring)
+            error = velocity
 
     # chunked-resident: top-k'd estimates stay in the (T, S, 128) layout and
     # re-sketch without the pad/reshape round trip; same values as the flat
@@ -679,12 +699,14 @@ def _sketched(sketched_grad, state, cfg, lr, sketch: CountSketch,
             # update, and accumulates its re-sketch — the composed path's
             # separate compare_select and sketch_chunks d-plane sweeps
             # collapse into it. Bit-identical values by construction.
-            est = estimates_chunks(sketch, error)
+            with scope("fed_server_estimate"):
+                est = estimates_chunks(sketch, error)
+            # (threshold and kernel carry their own stage scopes)
             update, sketched_update = fused_epilogue_chunks(
                 sketch, est, cfg.k, interpret=(fe_mode == "interpret"))
         else:
-            update = unsketch_chunks(sketch, error, cfg.k)
-            sketched_update = sketch_chunks(sketch, update)
+            update, sketched_update = _unsketch_resketch(sketch, error,
+                                                         cfg.k)
     else:
         # flat caller: ONE shared (T, S, 128) view end-to-end. The old
         # formulation (unsketch → flat update → sketch_vec) flattened the
@@ -697,16 +719,30 @@ def _sketched(sketched_grad, state, cfg, lr, sketch: CountSketch,
         # threshold-descent counts). The nonzero cells of the re-sketch
         # are where error feedback and momentum masking happen (reference
         # fed_aggregator.py:592-611).
-        upd3 = unsketch_chunks(sketch, error, cfg.k)
-        sketched_update = sketch_chunks(sketch, upd3)
-        update = sketch.chunk_layout.unchunk(upd3)
-    cell_nz = sketched_update != 0
-    if cfg.error_type == "virtual":
-        error = jnp.where(cell_nz, 0.0, error)
-    velocity = jnp.where(cell_nz, 0.0, velocity)
-    if cfg.error_type == "local":
-        # torch aliasing: Verror and Vvelocity are the same tensor after
-        # fed_aggregator.py:580, so masking velocity also masks error
-        error = velocity
-    return update * lr, ServerState(velocity, error)
+        upd3, sketched_update = _unsketch_resketch(sketch, error, cfg.k)
+        with scope("fed_server_apply"):
+            update = sketch.chunk_layout.unchunk(upd3)
+    with scope("fed_server_apply"):
+        cell_nz = sketched_update != 0
+        if cfg.error_type == "virtual":
+            error = jnp.where(cell_nz, 0.0, error)
+        velocity = jnp.where(cell_nz, 0.0, velocity)
+        if cfg.error_type == "local":
+            # torch aliasing: Verror and Vvelocity are the same tensor after
+            # fed_aggregator.py:580, so masking velocity also masks error
+            error = velocity
+        return update * lr, ServerState(velocity, error)
+
+
+def _unsketch_resketch(sketch: CountSketch, table, k: int):
+    """The composed epilogue, one named stage each: estimates of every
+    coordinate, the top-k of them (``ops.sketch.unsketch_chunks`` is these
+    two), and the re-sketch of that update. Returns ``(update chunks,
+    re-sketched table)``."""
+    with jax.named_scope("fed_server_estimate"):
+        est = estimates_chunks(sketch, table)
+    with jax.named_scope("fed_server_topk"):
+        update = topk_dense_nd(est, k)
+    with jax.named_scope("fed_server_resketch"):
+        return update, sketch_chunks(sketch, update)
 

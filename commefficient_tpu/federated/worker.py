@@ -234,6 +234,7 @@ def sketch_grad_tree(sketch: CountSketch, table, grad_tree, segments,
     return table
 
 
+@jax.named_scope("fed_client_grad")
 def _microbatch_grads(compute_loss, params, model_state, batch, rng,
                       cfg: WorkerConfig):
     """Per-example-mean gradient over the masked batch, accumulated over
@@ -275,23 +276,12 @@ def _microbatch_grads(compute_loss, params, model_state, batch, rng,
             new_state)
 
 
-def forward_grad(compute_loss, params_flat, unravel, ravel, model_state,
-                 batch, rng, cfg: WorkerConfig, sketch: Optional[CountSketch],
-                 compute_grad: bool = True, tp_scale=None, ep_scale=None):
-    """reference fed_worker.py:249-335 as a pure function.
-
-    Returns (transmit_or_None, (loss_mean, *metric_means, count),
-    new_model_state, dense_mean_grad)."""
-    params = unravel(params_flat)
-    if not compute_grad:
-        loss_sum, msums, count, new_state = compute_loss(
-            params, model_state, batch, rng, False)
-        denom = jnp.maximum(count, 1.0)
-        metrics = (loss_sum / denom,) + tuple(m / denom for m in msums) + (count,)
-        return None, metrics, new_state, None
-
-    g_mean_tree, loss_mean, metric_means, count, new_state = _microbatch_grads(
-        compute_loss, params, model_state, batch, rng, cfg)
+def _compress_grad(g_mean_tree, params_flat, ravel, rng, cfg: WorkerConfig,
+                   sketch, tp_scale, ep_scale):
+    """forward_grad's second half (the fed_client_compress stage): the
+    mean-gradient pytree flattened, reconciled across the parallel axes,
+    decayed, clipped, noised and — in sketch mode — sketched. Returns
+    ``(dense grad, transmit)``."""
     grad = ravel(g_mean_tree)
     if cfg.seq_axis is not None:
         # per-shard partial gradients (each shard backpropagated its local
@@ -338,6 +328,31 @@ def forward_grad(compute_loss, params_flat, unravel, ravel, model_state,
     else:
         g = grad
 
+    return grad, g
+
+
+def forward_grad(compute_loss, params_flat, unravel, ravel, model_state,
+                 batch, rng, cfg: WorkerConfig, sketch: Optional[CountSketch],
+                 compute_grad: bool = True, tp_scale=None, ep_scale=None):
+    """reference fed_worker.py:249-335 as a pure function.
+
+    Returns (transmit_or_None, (loss_mean, *metric_means, count),
+    new_model_state, dense_mean_grad)."""
+    if not compute_grad:
+        # validation: the caller's scope (rounds.val_step, fed_val) names it
+        loss_sum, msums, count, new_state = compute_loss(
+            unravel(params_flat), model_state, batch, rng, False)
+        denom = jnp.maximum(count, 1.0)
+        metrics = (loss_sum / denom,) + tuple(m / denom for m in msums) + (count,)
+        return None, metrics, new_state, None
+
+    with jax.named_scope("fed_client_grad"):
+        params = unravel(params_flat)
+    g_mean_tree, loss_mean, metric_means, count, new_state = _microbatch_grads(
+        compute_loss, params, model_state, batch, rng, cfg)
+    with jax.named_scope("fed_client_compress"):
+        grad, g = _compress_grad(g_mean_tree, params_flat, ravel, rng, cfg,
+                                 sketch, tp_scale, ep_scale)
     metrics = (loss_mean,) + metric_means + (count,)
     return g, metrics, new_state, grad
 
@@ -350,30 +365,31 @@ def local_step(compute_loss, params_flat, unravel, ravel, model_state,
     g, metrics, new_state, _ = forward_grad(
         compute_loss, params_flat, unravel, ravel, model_state, batch, rng,
         cfg, sketch, tp_scale=tp_scale, ep_scale=ep_scale)
-    count = metrics[-1]
-    # sum-of-example-gradients scaling (fed_worker.py:190); linear, so it
-    # applies to sketch tables too
-    g = g * count
+    with jax.named_scope("fed_client_compress"):
+        count = metrics[-1]
+        # sum-of-example-gradients scaling (fed_worker.py:190); linear, so it
+        # applies to sketch tables too
+        g = g * count
 
-    new_velocity, new_error = velocity, error
-    if cfg.has_velocity:
-        new_velocity = g + cfg.local_momentum * velocity
-        carrier = new_velocity
-    else:
-        carrier = g
-    if cfg.has_error:
-        new_error = error + carrier
-        to_transmit = new_error
-    else:
-        to_transmit = carrier
-
-    if cfg.mode == "local_topk":
-        to_transmit = topk(to_transmit, cfg.k)
-        nz = to_transmit != 0
-        if cfg.has_error:
-            new_error = jnp.where(nz, 0.0, new_error)
+        new_velocity, new_error = velocity, error
         if cfg.has_velocity:
-            new_velocity = jnp.where(nz, 0.0, new_velocity)
+            new_velocity = g + cfg.local_momentum * velocity
+            carrier = new_velocity
+        else:
+            carrier = g
+        if cfg.has_error:
+            new_error = error + carrier
+            to_transmit = new_error
+        else:
+            to_transmit = carrier
+
+        if cfg.mode == "local_topk":
+            to_transmit = topk(to_transmit, cfg.k)
+            nz = to_transmit != 0
+            if cfg.has_error:
+                new_error = jnp.where(nz, 0.0, new_error)
+            if cfg.has_velocity:
+                new_velocity = jnp.where(nz, 0.0, new_velocity)
 
     return ClientResult(to_transmit, new_velocity, new_error, metrics), new_state
 
@@ -431,15 +447,17 @@ def fedavg_local(compute_loss, params_flat, unravel, ravel, model_state,
 
     init = (params_flat, model_state, rng, jnp.zeros(()), jnp.zeros(()),
             tuple(jnp.zeros(()) for _ in range(n_metrics)), jnp.zeros(()))
-    for _ in range(cfg.num_fedavg_epochs):
-        (w, mstate, rng, step, loss_acc, m_acc, n_steps), _ = jax.lax.scan(
-            body, init, chunks)
-        init = (w, mstate, rng, step, loss_acc, m_acc, n_steps)
+    with jax.named_scope("fed_client_grad"):
+        for _ in range(cfg.num_fedavg_epochs):
+            (w, mstate, rng, step, loss_acc, m_acc, n_steps), _ = \
+                jax.lax.scan(body, init, chunks)
+            init = (w, mstate, rng, step, loss_acc, m_acc, n_steps)
     w, mstate, _, _, loss_acc, m_acc, n_steps = init
 
     count = batch["mask"].sum()
     # weight the delta by client dataset size (fed_worker.py:104-108)
-    transmit = (params_flat - w) * count
+    with jax.named_scope("fed_client_compress"):
+        transmit = (params_flat - w) * count
     denom = jnp.maximum(n_steps, 1.0)
     metrics = (loss_acc / denom,) + tuple(m / denom for m in m_acc) + (count,)
     return ClientResult(transmit, None, None, metrics), mstate

@@ -341,6 +341,7 @@ def _sketch_vec_pallas(v3, shift_q, shift_w, sign_keys, t0, *, S, T,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, S, _LANES), jnp.float32),
         interpret=interpret,
+        name="fed_sketch_vec",
     )(shift_q, shift_w, sign_keys, t0, v3)
     return out
 
@@ -573,10 +574,9 @@ def _accum_pallas_call(tbl3, v3, shift_q, shift_w, sign_keys, t0, S, T,
                        interpret):
     """Shared lowering of the RUNNING-TABLE accumulate kernels
     (``_sketch_accum_pallas`` / ``_sketch_segments_pallas`` — one body so
-    the per-leaf and coalesced client phases cannot drift bit-wise; the
-    two jit wrappers exist so each path keeps its own name in traces and
-    the client-launch counter stays attributable,
-    scripts/tpu_profile.py)."""
+    the per-leaf and coalesced client phases cannot drift bit-wise; on
+    the device both are the kernel ``fed_sketch_accum``, and the two jit
+    wrappers keep each path's own name in the operation's scope path)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -620,6 +620,7 @@ def _accum_pallas_call(tbl3, v3, shift_q, shift_w, sign_keys, t0, S, T,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, S, _LANES), jnp.float32),
         interpret=interpret,
+        name="fed_sketch_accum",
     )(shift_q, shift_w, sign_keys, t0, tbl3, v3)
     return out
 
@@ -971,6 +972,7 @@ def _estimates_pallas(tbl2, shift_q, shift_w, sign_keys, t0, *, S, T, c_pad,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, S, _LANES), jnp.float32),
         interpret=interpret,
+        name="fed_estimates",
     )(shift_q, shift_w, sign_keys, t0, tbl2)
 
 
@@ -1250,6 +1252,7 @@ def _fused_epilogue_pallas(est3, shift_q, shift_w, sign_keys, t0, p, *,
             jax.ShapeDtypeStruct((r, S_ext, _LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="fed_epilogue",
     )(shift_q, shift_w, sign_keys, t0, p, est3)
 
 
@@ -1299,11 +1302,15 @@ def fused_epilogue_chunks(cs: CountSketch, est3: jax.Array, k: int,
 
     if _trace_state_clean():
         _check_fused_epilogue_once(eager=True)
-    p = resolve_threshold(est3, k, interpret=interpret)
-    upd, ext = _fused_epilogue_pallas(
-        est3, cs.shift_q, cs.shift_w, cs.sign_keys, _T0, p.reshape(1),
-        S=cs.sublanes, T=cs.T, interpret=interpret)
-    return upd, _fold_ext_table(cs, ext)
+    with jax.named_scope("fed_server_topk"):
+        p = resolve_threshold(est3, k, interpret=interpret)
+    # the one kernel masks AND re-sketches; the whole sweep is the
+    # re-sketch stage's (the mask alone is a compare on the way)
+    with jax.named_scope("fed_server_resketch"):
+        upd, ext = _fused_epilogue_pallas(
+            est3, cs.shift_q, cs.shift_w, cs.sign_keys, _T0, p.reshape(1),
+            S=cs.sublanes, T=cs.T, interpret=interpret)
+        return upd, _fold_ext_table(cs, ext)
 
 
 def fused_epilogue_chunks_local(cs: CountSketch, est3: jax.Array, t0, k: int,
@@ -1321,13 +1328,16 @@ def fused_epilogue_chunks_local(cs: CountSketch, est3: jax.Array, t0, k: int,
     if _trace_state_clean():
         _check_fused_epilogue_once(eager=True)
     Tn = est3.shape[0]
-    p = resolve_threshold(est3, k, interpret=interpret, axis_name=axis_name)
-    q_cols, w_cols = _local_shift_cols(cs.shift_q, cs.shift_w, t0, Tn)
-    upd, ext = _fused_epilogue_pallas(
-        est3, q_cols, w_cols, cs.sign_keys,
-        jnp.asarray(t0, jnp.int32).reshape(1), p.reshape(1),
-        S=cs.sublanes, T=Tn, interpret=interpret)
-    return upd, _fold_ext_table(cs, ext)
+    with jax.named_scope("fed_server_topk"):
+        p = resolve_threshold(est3, k, interpret=interpret,
+                              axis_name=axis_name)
+    with jax.named_scope("fed_server_resketch"):
+        q_cols, w_cols = _local_shift_cols(cs.shift_q, cs.shift_w, t0, Tn)
+        upd, ext = _fused_epilogue_pallas(
+            est3, q_cols, w_cols, cs.sign_keys,
+            jnp.asarray(t0, jnp.int32).reshape(1), p.reshape(1),
+            S=cs.sublanes, T=Tn, interpret=interpret)
+        return upd, _fold_ext_table(cs, ext)
 
 
 def check_fused_epilogue_kernel(cs: CountSketch, k: int = 5_000,

@@ -130,6 +130,7 @@ def _count_ge_pallas(v3, ts, *, T, sub=_SUB, interpret=False):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, 16), jnp.int32),
         interpret=interpret,
+        name="fed_topk_count",
     )(ts, v3)[0]
 
 
@@ -198,6 +199,7 @@ def _descent_pallas(v3, kk, *, T, sub=_SUB, interpret=False):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1,), jnp.int32),
         interpret=interpret,
+        name="fed_topk_descent",
     )(kk, v3)
 
 

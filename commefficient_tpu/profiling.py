@@ -7,9 +7,16 @@ replacement is ``jax.profiler``: XLA-level traces viewable in
 TensorBoard/Perfetto, capturing device compute, HBM transfers, and collective
 time — strictly more information than the reference's host-side cProfile.
 
-``StepProfiler`` traces a fixed window of training steps (skipping warmup /
-compile steps); ``annotate`` marks host-side phases so they show up on the
-trace timeline.
+``RoundTracer`` is the one class that starts and stops a profiler session
+(``--profile``, ``--trace_rounds``, the watch plane's trace reaction: all
+windows over the global round index). ``annotate`` is the one way to open
+a host span: it lands on the profiler's clock beside the device operations
+AND adds its duration to ``SPAN_TOTALS``, which the event log reads
+(``RunTelemetry``), so a run with the profiler off still leaves the host's
+waits in its run dir. ``DEVICE_STAGES`` / ``KERNEL_NAMES`` are the names
+the jitted round and its Pallas kernels carry on the device
+(``jax.named_scope`` / ``pallas_call(name=)``); docs/observability.md lists
+every name with its reader.
 
 ``host_sync_monitor`` is the pipelined round engine's audit hook
 (federated/engine.py, docs/round_engine.md): it counts blocking
@@ -34,12 +41,30 @@ import os
 import re
 import sys
 import threading
+import time
 
 import jax
 
-__all__ = ["StepProfiler", "annotate", "SyncCounter", "host_sync_monitor",
+__all__ = ["annotate", "SPAN_TOTALS", "span_totals", "DEVICE_STAGES",
+           "KERNEL_NAMES", "SyncCounter", "host_sync_monitor",
            "materialize", "offpath_fetches", "Heartbeat", "RoundTracer",
            "parse_trace_rounds", "HEARTBEAT_RE", "parse_heartbeat"]
+
+# The round's stages on the device: one ``jax.named_scope`` each, where the
+# work is traced (worker.py, rounds.py, server.py, ops/sketch.py,
+# telemetry.py, aggregator.py). An operation's scope path holds exactly one
+# of them (tests/test_tracing.py), under whatever transform wrapped it
+# (``transpose(jvp(fed_client_grad))`` for the backward pass).
+DEVICE_STAGES = ("fed_client_grad", "fed_client_compress",
+                 "fed_server_estimate", "fed_server_topk",
+                 "fed_server_resketch", "fed_server_apply",
+                 "fed_telemetry_metrics", "fed_accounting", "fed_val")
+# ``name=`` of every pallas_call (ops/sketch.py, ops/topk.py). The sketch
+# kernels keep ``sketch`` / ``estimates`` / ``epilogue`` in theirs and the
+# top-k kernels do not: benchmark/metrics/sketch_kernel_roofline.py tells
+# them apart by those words.
+KERNEL_NAMES = ("fed_sketch_vec", "fed_sketch_accum", "fed_estimates",
+                "fed_epilogue", "fed_topk_count", "fed_topk_descent")
 
 
 # THE heartbeat line format, one producer (Heartbeat.round) and one parser
@@ -176,47 +201,37 @@ def parse_trace_rounds(spec: str) -> list:
     return sorted(windows)
 
 
-# JAX allows ONE active profiler session per process: StepProfiler
-# (--profile, loop-index window) and RoundTracer (--trace_rounds / the
-# watch trace reaction, round_no window) must not both call start_trace.
-# Both starters consult this flag and DEFER/SKIP instead of crashing a
-# training run with "profiler already started"; the try/except around
-# each start covers third-party sessions the flag cannot see.
-_profiler_busy = False
-
-
 def _try_start_trace(logdir: str) -> bool:
-    global _profiler_busy
-    if _profiler_busy:
-        return False
-    # dir created only once the session is actually ours — a deferred
-    # window must not litter empty trace_round_* dirs while it retries
+    """Start a profiler session into ``logdir``; False (and a message)
+    when one is already running. RoundTracer is the program's only
+    starter and holds at most one window open, so that can only be a
+    session somebody else started (a harness, a notebook): the window is
+    skipped, the run goes on."""
     os.makedirs(logdir, exist_ok=True)
     try:
         jax.profiler.start_trace(logdir)
     except Exception as e:  # noqa: BLE001 — a foreign active session
         print(f"trace capture skipped: profiler unavailable ({e})")
         return False
-    _profiler_busy = True
     return True
 
 
 def _stop_trace() -> None:
-    global _profiler_busy
     with contextlib.suppress(Exception):
         jax.profiler.stop_trace()
-    _profiler_busy = False
 
 
 class RoundTracer:
     """Round-scoped programmatic XLA trace capture (docs/observability.md).
 
-    ``StepProfiler`` traces a window of LOOP indices from one epoch's
-    loop; this tracer is addressed in the global round_no timeline instead
-    — ``--trace_rounds start:count`` windows, plus dynamic ``request(n)``
-    windows from the watch plane's trace reaction — so a capture is
-    aimable at an absolute round ("trace rounds 2000-2004 where the alert
-    fired") without hand-aiming a profiler session.
+    Addressed in the global round_no timeline: ``--trace_rounds
+    start:count`` windows, ``--profile``'s window (rounds 2 … 2+N-1, into
+    ``--profile_dir``), plus dynamic ``request(n)`` windows from the watch
+    plane's trace reaction — so a capture is aimable at an absolute round
+    ("trace rounds 2000-2004 where the alert fired") without hand-aiming a
+    profiler session. A static window is ``(start, count)`` or ``(start,
+    count, dir)``; without a directory of its own it lands in
+    ``<logdir>/trace_round_<start>``.
 
     Driven by the engine: ``on_submit(round_no)`` BEFORE a round's
     dispatch (starts ``jax.profiler.start_trace`` into
@@ -228,12 +243,12 @@ class RoundTracer:
     record for the engine to log as a ``trace_captured`` JSONL event.
     Pipelining caveat, by design: neighbors of the window that were in
     flight during it appear in the trace too; the named window is a lower
-    bound, and round-aligned ``fed_round`` StepTraceAnnotations mark the
-    exact spans inside the capture."""
+    bound, and the round-aligned ``fed_round`` step spans (``round=`` in
+    their metadata) mark the exact spans inside the capture."""
 
     def __init__(self, logdir: str, windows=None):
         self.logdir = logdir
-        self._pending = list(windows or [])   # static (start, count)
+        self._pending = sorted(windows or [])  # static (start, count[, dir])
         self._requests = 0                    # dynamic: rounds still owed
         self._active = None                   # {start, until, dir}
         self.captures = []                    # completed capture records
@@ -252,26 +267,21 @@ class RoundTracer:
         capture."""
         if self._active is not None:
             return
-        static = False
-        if self._requests:
-            count = self._requests
-        elif self._pending and round_no >= self._pending[0][0]:
-            # a static window whose start round is due (or was skipped
-            # over, e.g. resumed past it — start now rather than never)
-            count, static = self._pending[0][1], True
-        else:
-            return
         trace_dir = os.path.join(self.logdir,
                                  f"trace_round_{round_no:06d}")
-        if not _try_start_trace(trace_dir):
-            # another profiler session is active (e.g. --profile's
-            # StepProfiler window): DEFER — the window stays pending and
-            # retries at the next submit rather than crashing the run
-            return
-        if static:
-            self._pending.pop(0)
+        if self._requests:
+            count, self._requests = self._requests, 0
+        elif self._pending and round_no >= self._pending[0][0]:
+            # a static window whose start round is due (or was skipped
+            # over: resumed past it, or another window was still open —
+            # start now rather than never)
+            _, count, *own_dir = self._pending.pop(0)
+            if own_dir:
+                trace_dir = own_dir[0]
         else:
-            self._requests = 0
+            return
+        if not _try_start_trace(trace_dir):
+            return
         self._active = {"start": round_no,
                         "until": round_no + count - 1,
                         "dir": trace_dir}
@@ -302,9 +312,57 @@ class RoundTracer:
         return rec
 
 
-def annotate(name: str):
-    """Context manager marking a host-side phase on the profiler timeline."""
-    return jax.profiler.TraceAnnotation(name)
+# name -> [count, ns] of every span closed in this process, whatever
+# thread closed it. The event log's per-round durations are differences of
+# these totals (telemetry.RunTelemetry) and ``run_end`` carries them whole:
+# the host's numbers of a run whose profiler was never on.
+SPAN_TOTALS: dict = {}
+_span_lock = threading.Lock()
+
+
+class annotate:
+    """THE way to open a host span: ``with annotate("fed_h2d", round=n):``.
+
+    Opens a ``jax.profiler.TraceAnnotation`` (``ids`` become the event's
+    metadata), so every program span shares the device trace's clock, and
+    on exit adds one count and the span's ``time.perf_counter_ns``
+    duration to ``SPAN_TOTALS[name]``. ``start_ns`` / ``end_ns`` / ``ms``
+    stay readable on the object after the block, for the caller that
+    records this very span (the engine's round record). Near-free with the
+    profiler off: two clock reads and one locked add."""
+
+    __slots__ = ("name", "_ann", "start_ns", "end_ns")
+
+    def __init__(self, name: str, **ids):
+        self.name = name
+        self._ann = jax.profiler.TraceAnnotation(name, **ids)
+        self.start_ns = self.end_ns = 0
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.end_ns = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
+        with _span_lock:
+            tot = SPAN_TOTALS.setdefault(self.name, [0, 0])
+            tot[0] += 1
+            tot[1] += self.end_ns - self.start_ns
+        return False
+
+    @property
+    def ms(self) -> float:
+        return (self.end_ns - self.start_ns) / 1e6
+
+
+def span_totals() -> dict:
+    """``{name: {"count", "ms"}}`` of ``SPAN_TOTALS`` — what ``run_end``
+    records and ``scripts/obs_report.py`` prints."""
+    with _span_lock:
+        return {name: {"count": c, "ms": round(ns / 1e6, 3)}
+                for name, (c, ns) in sorted(SPAN_TOTALS.items())}
 
 
 class SyncCounter:
@@ -450,43 +508,3 @@ def host_sync_monitor(strict: bool = False):
     finally:
         with _lock:
             _active.remove(counter)
-
-
-class StepProfiler:
-    """Trace steps [start_step, start_step + num_steps) of a training loop.
-
-    Usage::
-
-        prof = StepProfiler(logdir, enabled=args.profile)
-        for i, batch in enumerate(loader):
-            prof.step(i)      # starts/stops the trace at the window edges
-            ...
-        prof.close()          # stop if the loop ended inside the window
-    """
-
-    def __init__(self, logdir: str = "profiles", start_step: int = 2,
-                 num_steps: int = 3, enabled: bool = False):
-        self.logdir = logdir
-        self.start_step = start_step
-        self.stop_step = start_step + num_steps
-        self.enabled = enabled
-        self._active = False
-
-    def step(self, i: int):
-        if not self.enabled:
-            return
-        if i == self.start_step and not self._active:
-            # one profiler session per process: skip (not crash) when a
-            # RoundTracer window is already capturing
-            if not _try_start_trace(self.logdir):
-                return
-            self._active = True
-        elif i >= self.stop_step and self._active:
-            _stop_trace()
-            self._active = False
-            print(f"profiler: trace written to {self.logdir}")
-
-    def close(self):
-        if self._active:
-            _stop_trace()
-            self._active = False

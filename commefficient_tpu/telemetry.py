@@ -29,12 +29,16 @@ separated layers:
    bit-identical with telemetry on or off, pinned in
    tests/test_telemetry.py on both server planes.
 
-2. **Host-side spans** (``RunTelemetry``): round-lifecycle timestamps the
-   host already holds for free — dispatch start, seal, the in-flight
-   window's completion wait, drain fetch — plus in-flight-window occupancy
-   at dispatch. Buffered in memory per round; nothing is written until the
-   round drains, so the dispatch path stays allocation-cheap and
-   fetch-free.
+2. **Host-side spans** (``RunTelemetry``): the durations of the program's
+   own ``profiling.annotate`` spans — ``fed_round`` (dispatch),
+   ``fed_window_wait``, ``fed_h2d``, ``fed_input_wait``, ``fed_drain`` —
+   plus in-flight-window occupancy at dispatch. The recorder stamps no
+   clock of its own: the engine hands it each span as it closes, and the
+   spans of other modules are read as differences of
+   ``profiling.SPAN_TOTALS``. Buffered in memory per round; nothing is
+   written until the round drains, so the dispatch path stays
+   allocation-cheap and fetch-free. These are the host numbers of a run
+   whose profiler was never on.
 
 3. **The JSONL event log**: one line per drained round (spans + metrics +
    loss + guard verdict), plus immediate lines for run_start / guard_trip
@@ -74,6 +78,8 @@ from typing import (
 
 import jax
 import jax.numpy as jnp
+
+from commefficient_tpu.profiling import SPAN_TOTALS, annotate, span_totals
 
 __all__ = [
     "METRIC_FIELDS",
@@ -194,6 +200,7 @@ def log_magnitude_histogram(x):
         nz.astype(jnp.float32))
 
 
+@jax.named_scope("fed_telemetry_metrics")
 def device_round_metrics(transmit, update, new_ps, state, guard_ok=None,
                          hists: bool = False):
     """The jit-side half: one ``(len(metric_schema(hists)),)`` f32 device
@@ -736,6 +743,10 @@ class RunTelemetry:
             os.makedirs(parent, exist_ok=True)
         self._f = open(path, "a")
         self._spans: Dict[int, Dict[str, Any]] = {}
+        # SPAN_TOTALS is process-wide: start from what earlier runs of
+        # this process left there
+        self._seen_ns: Dict[str, int] = {
+            name: tot[1] for name, tot in SPAN_TOTALS.items()}
         self.rounds = 0
         self.events = 0
         self._closed = False
@@ -757,35 +768,58 @@ class RunTelemetry:
     def event(self, ev: str, **fields) -> None:
         if self._closed:
             return
-        rec = {"ev": ev, "t": time.time()}
-        rec.update(fields)
-        self._f.write(json.dumps(_json_safe(rec), allow_nan=False) + "\n")
-        self._f.flush()
-        self.events += 1
+        with annotate("fed_telemetry_host", round=fields.get("round", -1)):
+            rec = {"ev": ev, "t": time.time()}
+            rec.update(fields)
+            self._f.write(json.dumps(_json_safe(rec), allow_nan=False)
+                          + "\n")
+            self._f.flush()
+            self.events += 1
 
     # -- round-lifecycle spans (buffered; written at drain) ----------------
 
-    def on_dispatch(self, round_no: int, t_start: float,
-                    occupancy: int) -> None:
-        """Called by the engine after seal: ``t_start`` is the monotonic
-        stamp taken before ``begin_round`` (so the span covers LR step +
-        client dispatch + server dispatch + seal), ``occupancy`` the
-        in-flight window depth including this round."""
-        now = time.monotonic()
-        self._spans[round_no] = {
-            "t_wall": time.time(),
-            "t0": t_start,
-            "dispatch_ms": (now - t_start) * 1e3,
-            "t_sealed": now,
-            "occupancy": occupancy,
-        }
+    def _since_last(self, name: str) -> float:
+        """Milliseconds the spans called ``name`` took since this was last
+        asked: how the recorder reads spans that other modules open
+        (``fed_h2d`` in ``FedModel.begin_round``, ``fed_input_wait`` in
+        ``PrefetchLoader``) without being handed them."""
+        ns = SPAN_TOTALS.get(name, (0, 0))[1]
+        ms = (ns - self._seen_ns.get(name, 0)) / 1e6
+        self._seen_ns[name] = ns
+        return ms
 
-    def on_complete(self, round_no: int) -> None:
-        """The engine's window wait just returned for this round: its
-        device computation is complete (a completion wait, not a fetch)."""
-        span = self._spans.get(round_no)
-        if span is not None and "compute_ms" not in span:
-            span["compute_ms"] = (time.monotonic() - span["t_sealed"]) * 1e3
+    def on_dispatch(self, round_no: int, span, occupancy: int) -> None:
+        """Called by the engine after seal with the round's closed
+        ``fed_round`` span (LR step + client dispatch, the batch's
+        host-to-device copy included + server dispatch + seal);
+        ``occupancy`` is the in-flight window depth including this round.
+        ``h2d_ms`` is the ``fed_h2d`` time of this dispatch,
+        ``input_wait_ms`` the ``fed_input_wait`` time since the previous
+        dispatch: what the loop waited for this round's batch (a
+        validation pass's waits land on the round after it)."""
+        with annotate("fed_telemetry_host", round=round_no):
+            self._spans[round_no] = {
+                "t_wall": time.time(),
+                "start_ns": span.start_ns,
+                "sealed_ns": span.end_ns,
+                "dispatch_ms": span.ms,
+                "h2d_ms": self._since_last("fed_h2d"),
+                "input_wait_ms": self._since_last("fed_input_wait"),
+                "occupancy": occupancy,
+            }
+
+    def on_complete(self, round_no: int, span) -> None:
+        """The engine's ``fed_window_wait`` span for this round just
+        closed: its device computation is complete (a completion wait, not
+        a fetch). ``window_wait_ms`` is how long the host stood in that
+        wait; ``compute_ms`` runs from the round's seal to the wait's
+        return — an upper bound of the round's device time (the wait
+        starts ``window`` submits after the seal), not a measurement of
+        it."""
+        rec = self._spans.get(round_no)
+        if rec is not None and "compute_ms" not in rec:
+            rec["window_wait_ms"] = span.ms
+            rec["compute_ms"] = (span.end_ns - rec["sealed_ns"]) / 1e6
 
     def on_metrics(self, round_no: int, metrics: Optional[Dict[str, float]],
                    loss: Optional[float] = None,
@@ -813,24 +847,32 @@ class RunTelemetry:
         if offload:
             span["offload"] = offload
 
-    def on_drained(self, round_no: int, fetch_s: float) -> None:
-        """The round's batched drain finished: derive the span fields and
-        write the one ``round`` line."""
-        span = self._spans.pop(round_no, {})
-        now = time.monotonic()
+    def on_drained(self, round_no: int, span) -> None:
+        """The round's batched drain finished (``span`` is its closed
+        ``fed_drain``): derive the span fields and write the one ``round``
+        line."""
+        with annotate("fed_telemetry_host", round=round_no):
+            self._write_round(round_no, span)
+
+    def _write_round(self, round_no: int, span) -> None:
+        buf = self._spans.pop(round_no, {})
         rec: Dict[str, Any] = {"ev": "round", "round": round_no,
                                "t": time.time()}
-        if "t_wall" in span:
-            rec["t_dispatch"] = span["t_wall"]
-            rec["dispatch_ms"] = round(span["dispatch_ms"], 3)
-            rec["dispatch_to_drain_ms"] = round((now - span["t0"]) * 1e3, 3)
-            rec["occupancy"] = span["occupancy"]
-        if "compute_ms" in span:
-            rec["compute_ms"] = round(span["compute_ms"], 3)
-        rec["drain_fetch_ms"] = round(fetch_s * 1e3, 3)
+        if "t_wall" in buf:
+            rec["t_dispatch"] = buf["t_wall"]
+            rec["dispatch_ms"] = round(buf["dispatch_ms"], 3)
+            rec["h2d_ms"] = round(buf["h2d_ms"], 3)
+            rec["input_wait_ms"] = round(buf["input_wait_ms"], 3)
+            rec["dispatch_to_drain_ms"] = round(
+                (span.end_ns - buf["start_ns"]) / 1e6, 3)
+            rec["occupancy"] = buf["occupancy"]
+        if "compute_ms" in buf:
+            rec["window_wait_ms"] = round(buf["window_wait_ms"], 3)
+            rec["compute_ms"] = round(buf["compute_ms"], 3)
+        rec["drain_fetch_ms"] = round(span.ms, 3)
         for key in ("loss", "guard_ok", "cohort", "offload", "metrics"):
-            if key in span:
-                rec[key] = span[key]
+            if key in buf:
+                rec[key] = buf[key]
         self._f.write(json.dumps(_json_safe(rec), allow_nan=False) + "\n")
         self._f.flush()
         self.rounds += 1
@@ -853,13 +895,17 @@ class RunTelemetry:
         for round_no in sorted(self._spans):
             span = self._spans[round_no]
             rec = {"round": round_no}
-            for key in ("dispatch_ms", "occupancy", "compute_ms", "loss",
+            for key in ("dispatch_ms", "h2d_ms", "input_wait_ms",
+                        "occupancy", "window_wait_ms", "compute_ms", "loss",
                         "guard_ok", "cohort", "offload", "metrics"):
                 if key in span:
                     rec[key] = span[key]
             self.event("round_partial", **rec)
         self._spans.clear()
-        self.event("run_end", rounds=self.rounds, **totals)
+        # every program span of the process, by name: the host's side of
+        # the run with the profiler off (scripts/obs_report.py prints it)
+        self.event("run_end", rounds=self.rounds, spans=span_totals(),
+                   **totals)
         self._closed = True
         self._f.close()
 
@@ -870,8 +916,8 @@ def attach_run_telemetry(args, fed_model, log_dir: str,
     log the static collective ledger in run_start, and hand the recorder to
     the model (``FedModel.finish_round`` records drained metrics through
     it; the engine picks it up via ``model.telemetry`` for spans). Also
-    attaches the round-scoped trace capturer (``--trace_rounds`` windows,
-    plus the watch plane's trace reaction — ``model.tracer``, picked up by
+    attaches the round-scoped trace capturer (``--trace_rounds`` and
+    ``--profile`` windows, plus the watch plane's trace reaction — ``model.tracer``, picked up by
     the engine) and the watch/alert rule engine (``--watch``, default ON;
     rules from ``--watch_rules`` or DEFAULT_WATCH_RULES). Returns None
     when ``--no_telemetry`` (the tracer still attaches: a profiler window
@@ -880,13 +926,19 @@ def attach_run_telemetry(args, fed_model, log_dir: str,
 
     trace_spec = (getattr(args, "trace_rounds", "") or "").strip()
     watch_on = bool(getattr(args, "watch", False))
+    windows = parse_trace_rounds(trace_spec)
+    if getattr(args, "do_profile", False):
+        # --profile: rounds 2 … 2+N-1 (past the compiling rounds), into
+        # --profile_dir — one more window of the one tracer
+        windows.append((2, int(args.profile_steps), args.profile_dir))
+        print(f"profile: rounds 2-{1 + int(args.profile_steps)} -> "
+              f"{args.profile_dir}")
     tracer = None
-    if trace_spec or (watch_on and getattr(args, "telemetry", False)):
+    if windows or (watch_on and getattr(args, "telemetry", False)):
         # the watch plane's trace reaction needs a tracer even with no
-        # static --trace_rounds windows; an idle tracer is one integer
-        # compare per submitted round
-        tracer = RoundTracer(log_dir,
-                             windows=parse_trace_rounds(trace_spec))
+        # static windows; an idle tracer is one integer compare per
+        # submitted round
+        tracer = RoundTracer(log_dir, windows=windows)
         fed_model.tracer = tracer
         if trace_spec:
             print(f"trace_rounds: windowed round-aligned capture(s) "

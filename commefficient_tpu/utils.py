@@ -48,11 +48,20 @@ def configure_compile_cache() -> str:
     name, pid or time: a fresh chip machine starts cold, so the only warm
     start a second process gets is a path both agree on without being
     told. Must run before the process's first compile (jax initializes
-    its cache once)."""
-    if "JAX_COMPILATION_CACHE_DIR" in os.environ:
-        return os.environ["JAX_COMPILATION_CACHE_DIR"]
+    its cache once).
+
+    Either way the cache's key includes the programs' metadata. jax leaves
+    it out by default, and the metadata is where the round's stage names
+    live (``jax.named_scope``, profiling.DEVICE_STAGES): a cache filled by
+    a build without a scope then serves that build's executable to one
+    with it, and a capture shows the old names (seen on the v5e, PERF.md
+    PR 26: the accounting programs came back without ``fed_accounting``).
+    The price is that a moved line recompiles what it touches."""
     import jax
 
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    if "JAX_COMPILATION_CACHE_DIR" in os.environ:
+        return os.environ["JAX_COMPILATION_CACHE_DIR"]
     path = os.path.join(_CHECKOUT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path

@@ -50,7 +50,6 @@ from commefficient_tpu.federated.participation import (
     attach_churn,
     attach_participation,
 )
-from commefficient_tpu.profiling import StepProfiler
 from commefficient_tpu.telemetry import attach_run_telemetry
 from commefficient_tpu.ops.flat import ravel_pytree
 from commefficient_tpu.utils import (
@@ -112,8 +111,6 @@ def run_batches(model, opt, lr_scheduler, loader, training, epoch_fraction,
     model.train(training)
     losses, accs = [], []
     if training:
-        prof = StepProfiler(args.profile_dir, num_steps=args.profile_steps,
-                            enabled=args.do_profile)
         num_clients = loader.dataset.num_clients
         client_download = np.zeros(num_clients)
         client_upload = np.zeros(num_clients)
@@ -161,64 +158,60 @@ def run_batches(model, opt, lr_scheduler, loader, training, epoch_fraction,
                 losses.extend(loss.tolist())
                 accs.extend(acc.tolist())
 
-        try:
-            # cohort_lookahead peeks batch t+1 AFTER round t submits and
-            # hands its client_ids to the host-offload prefetcher — the
-            # next round's row gather overlaps this round's device compute
-            # (no-op without row streaming; docs/host_offload.md)
-            for i, batch in enumerate(cohort_lookahead(loader, model)):
-                if i0 + i > spe * epoch_fraction:
-                    break
-                prof.step(i)
-                consume(engine.submit(batch))
-                if nan_loss:
-                    return np.nan, np.nan, np.nan, np.nan
-                do_save = bool(save_every
-                               and (i0 + i + 1) % save_every == 0)
-                forced = False
-                if watch is not None and watch.pop_checkpoint():
-                    # the watch checkpoint reaction: force a run-state
-                    # save at this round boundary (a resumable save needs
-                    # the no-prefetch-thread constraint, like
-                    # --checkpoint_every_rounds — validate_args noted it)
-                    if args.train_dataloader_workers == 0:
-                        do_save = forced = True
-                    else:
-                        print("watch: checkpoint reaction skipped (needs "
-                              "--train_dataloader_workers 0 for a "
-                              "resumable save)")
-                if do_save:
-                    # drain the in-flight window first: the saved sampler /
-                    # RNG position must describe exactly the rounds whose
-                    # state AND metrics are folded into the checkpoint
-                    consume(engine.drain())
-                    if nan_loss:
-                        return np.nan, np.nan, np.nan, np.nan
-                    save_round_state(
-                        args, epoch, i0 + i + 1, loader.sampler.get_state(),
-                        model, opt, lr_scheduler, totals,
-                        extras={"download": client_download,
-                                "upload": client_upload,
-                                "losses": np.asarray(losses, np.float64),
-                                "accs": np.asarray(accs, np.float64)})
-                    if getattr(model, "telemetry", None) is not None:
-                        # `round` is the GLOBAL round_no the round/guard
-                        # events share (the window just drained, so the
-                        # last dispatched round is the last covered);
-                        # the epoch-local save position rides separately
-                        model.telemetry.event(
-                            "checkpoint", epoch=epoch,
-                            round=model.rounds_dispatched - 1,
-                            round_in_epoch=i0 + i + 1,
-                            **({"forced_by_watch": True} if forced
-                               else {}))
-                if args.do_test:
-                    break
-            consume(engine.drain())
+        # cohort_lookahead peeks batch t+1 AFTER round t submits and
+        # hands its client_ids to the host-offload prefetcher — the
+        # next round's row gather overlaps this round's device compute
+        # (no-op without row streaming; docs/host_offload.md)
+        for i, batch in enumerate(cohort_lookahead(loader, model)):
+            if i0 + i > spe * epoch_fraction:
+                break
+            consume(engine.submit(batch))
             if nan_loss:
                 return np.nan, np.nan, np.nan, np.nan
-        finally:
-            prof.close()
+            do_save = bool(save_every
+                           and (i0 + i + 1) % save_every == 0)
+            forced = False
+            if watch is not None and watch.pop_checkpoint():
+                # the watch checkpoint reaction: force a run-state
+                # save at this round boundary (a resumable save needs
+                # the no-prefetch-thread constraint, like
+                # --checkpoint_every_rounds — validate_args noted it)
+                if args.train_dataloader_workers == 0:
+                    do_save = forced = True
+                else:
+                    print("watch: checkpoint reaction skipped (needs "
+                          "--train_dataloader_workers 0 for a "
+                          "resumable save)")
+            if do_save:
+                # drain the in-flight window first: the saved sampler /
+                # RNG position must describe exactly the rounds whose
+                # state AND metrics are folded into the checkpoint
+                consume(engine.drain())
+                if nan_loss:
+                    return np.nan, np.nan, np.nan, np.nan
+                save_round_state(
+                    args, epoch, i0 + i + 1, loader.sampler.get_state(),
+                    model, opt, lr_scheduler, totals,
+                    extras={"download": client_download,
+                            "upload": client_upload,
+                            "losses": np.asarray(losses, np.float64),
+                            "accs": np.asarray(accs, np.float64)})
+                if getattr(model, "telemetry", None) is not None:
+                    # `round` is the GLOBAL round_no the round/guard
+                    # events share (the window just drained, so the
+                    # last dispatched round is the last covered);
+                    # the epoch-local save position rides separately
+                    model.telemetry.event(
+                        "checkpoint", epoch=epoch,
+                        round=model.rounds_dispatched - 1,
+                        round_in_epoch=i0 + i + 1,
+                        **({"forced_by_watch": True} if forced
+                           else {}))
+            if args.do_test:
+                break
+        consume(engine.drain())
+        if nan_loss:
+            return np.nan, np.nan, np.nan, np.nan
         if not losses and getattr(model, "_population", None) is not None:
             # open-world end state (--churn, docs/service.md): the live
             # population emptied before this epoch produced a single

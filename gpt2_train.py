@@ -19,7 +19,6 @@ import jax.numpy as jnp
 
 from commefficient_tpu.config import parse_args
 from commefficient_tpu.data_utils import FedLoader, PrefetchLoader
-from commefficient_tpu.profiling import StepProfiler
 from commefficient_tpu.data_utils.fed_persona import (
     FedPERSONA,
     make_personachat_collate_fn,
@@ -106,8 +105,6 @@ def run_batches(model, opt, lr_scheduler, loader, args, timer, training,
                 resume_mid=None, totals=(0.0, 0.0)):
     model.train(training)
     if training:
-        prof = StepProfiler(args.profile_dir, num_steps=args.profile_steps,
-                            enabled=args.do_profile)
         spe = loader.steps_per_epoch()
         num_clients = loader.dataset.num_clients
         client_download = np.zeros(num_clients)
@@ -161,61 +158,57 @@ def run_batches(model, opt, lr_scheduler, loader, args, timer, training,
                         union({"batch_idx": row_batch_idx, "lr": row_lr},
                               batch_stats))
 
-        try:
-            # cohort_lookahead peeks batch t+1 AFTER round t submits and
-            # hands its client_ids to the host-offload prefetcher — the
-            # next round's row gather overlaps this round's device compute
-            # (no-op without row streaming; docs/host_offload.md)
-            for batch_idx, batch in enumerate(cohort_lookahead(loader,
-                                                               model)):
-                if batch_idx > 2 and args.do_test and batch_idx < spe - 10:
-                    continue
-                if i0 + batch_idx > spe * epoch_fraction:
-                    break
-                prof.step(batch_idx)
-                done = engine.submit(batch)
-                # the scheduler stepped inside submit(); record this round's
-                # batch index and LR so its drained row logs what it ran with
-                meta_by_round[engine.rounds_submitted - 1] = (
-                    i0 + batch_idx + 1, lr_scheduler.get_last_lr()[0])
-                consume(done)
-                do_save = bool(save_every
-                               and (i0 + batch_idx + 1) % save_every == 0)
-                forced = False
-                if watch is not None and watch.pop_checkpoint():
-                    # watch checkpoint reaction: force a run-state save
-                    # at this round boundary (resumable only without a
-                    # prefetch thread — same constraint as save_every)
-                    if args.train_dataloader_workers == 0:
-                        do_save = forced = True
-                    else:
-                        print("watch: checkpoint reaction skipped (needs "
-                              "--train_dataloader_workers 0 for a "
-                              "resumable save)")
-                if do_save:
-                    # drain the in-flight window so the saved sampler/RNG
-                    # position matches the rounds folded into the state
-                    consume(engine.drain())
-                    save_round_state(
-                        args, epoch or 0, i0 + batch_idx + 1,
-                        loader.sampler.get_state(), model, opt,
-                        lr_scheduler, totals,
-                        extras={"download": client_download,
-                                "upload": client_upload,
-                                "losses": np.asarray(losses, np.float64)})
-                    if getattr(model, "telemetry", None) is not None:
-                        # `round` is the GLOBAL round_no the round/guard
-                        # events share (the window just drained); the
-                        # epoch-local save position rides separately
-                        model.telemetry.event(
-                            "checkpoint", epoch=epoch or 0,
-                            round=model.rounds_dispatched - 1,
-                            round_in_epoch=i0 + batch_idx + 1,
-                            **({"forced_by_watch": True} if forced
-                               else {}))
-            consume(engine.drain())
-        finally:
-            prof.close()
+        # cohort_lookahead peeks batch t+1 AFTER round t submits and
+        # hands its client_ids to the host-offload prefetcher — the
+        # next round's row gather overlaps this round's device compute
+        # (no-op without row streaming; docs/host_offload.md)
+        for batch_idx, batch in enumerate(cohort_lookahead(loader,
+                                                           model)):
+            if batch_idx > 2 and args.do_test and batch_idx < spe - 10:
+                continue
+            if i0 + batch_idx > spe * epoch_fraction:
+                break
+            done = engine.submit(batch)
+            # the scheduler stepped inside submit(); record this round's
+            # batch index and LR so its drained row logs what it ran with
+            meta_by_round[engine.rounds_submitted - 1] = (
+                i0 + batch_idx + 1, lr_scheduler.get_last_lr()[0])
+            consume(done)
+            do_save = bool(save_every
+                           and (i0 + batch_idx + 1) % save_every == 0)
+            forced = False
+            if watch is not None and watch.pop_checkpoint():
+                # watch checkpoint reaction: force a run-state save
+                # at this round boundary (resumable only without a
+                # prefetch thread — same constraint as save_every)
+                if args.train_dataloader_workers == 0:
+                    do_save = forced = True
+                else:
+                    print("watch: checkpoint reaction skipped (needs "
+                          "--train_dataloader_workers 0 for a "
+                          "resumable save)")
+            if do_save:
+                # drain the in-flight window so the saved sampler/RNG
+                # position matches the rounds folded into the state
+                consume(engine.drain())
+                save_round_state(
+                    args, epoch or 0, i0 + batch_idx + 1,
+                    loader.sampler.get_state(), model, opt,
+                    lr_scheduler, totals,
+                    extras={"download": client_download,
+                            "upload": client_upload,
+                            "losses": np.asarray(losses, np.float64)})
+                if getattr(model, "telemetry", None) is not None:
+                    # `round` is the GLOBAL round_no the round/guard
+                    # events share (the window just drained); the
+                    # epoch-local save position rides separately
+                    model.telemetry.event(
+                        "checkpoint", epoch=epoch or 0,
+                        round=model.rounds_dispatched - 1,
+                        round_in_epoch=i0 + batch_idx + 1,
+                        **({"forced_by_watch": True} if forced
+                           else {}))
+        consume(engine.drain())
         if not losses and getattr(model, "_population", None) is not None:
             # open-world end state (--churn, docs/service.md): the live
             # population emptied before this epoch produced a single

@@ -492,6 +492,11 @@ def summarize(events: List[dict]) -> Dict[str, Any]:
         "dispatch_ms_p90": _fin(_pct(span_list("dispatch_ms"), 0.9)),
         "compute_ms_p50": _fin(_pct(span_list("compute_ms"), 0.5)),
         "drain_fetch_ms_p50": _fin(_pct(span_list("drain_fetch_ms"), 0.5)),
+        "window_wait_ms_p50": _fin(_pct(span_list("window_wait_ms"), 0.5)),
+        "h2d_ms_p50": _fin(_pct(span_list("h2d_ms"), 0.5)),
+        "input_wait_ms_p50": _fin(_pct(span_list("input_wait_ms"), 0.5)),
+        # run_end's totals of every program span (profiling.SPAN_TOTALS)
+        "span_totals": (run_end or {}).get("spans"),
         "dispatch_to_drain_ms_p50": _fin(
             _pct(span_list("dispatch_to_drain_ms"), 0.5)),
         "occupancy_mean": _fin(
@@ -579,14 +584,27 @@ def render(events: List[dict], out=None) -> Dict[str, Any]:
     p("\n## Round lifecycle (ms)")
     p("| span | p50 | p90 |")
     p("|---|---|---|")
-    for key, label in (("dispatch_ms", "dispatch (LR+client+server+seal)"),
-                       ("compute_ms", "device compute (window wait)"),
-                       ("drain_fetch_ms", "drain fetch"),
+    # each row is a program span's duration (profiling.annotate), counted
+    # by the program itself whether or not a profiler was on
+    for key, label in (("dispatch_ms", "dispatch (fed_round: LR+client+"
+                                       "server+seal, h2d included)"),
+                       ("h2d_ms", "batch host->device (fed_h2d)"),
+                       ("input_wait_ms", "input wait before dispatch "
+                                         "(fed_input_wait)"),
+                       ("window_wait_ms", "window wait (fed_window_wait: "
+                                          "host waits for the device)"),
+                       ("compute_ms", "seal -> window wait returned "
+                                      "(>= device compute)"),
+                       ("drain_fetch_ms", "drain fetch (fed_drain)"),
                        ("dispatch_to_drain_ms", "dispatch -> drain")):
         vals = [e[key] for e in rounds if key in e]
         p(f"| {label} | {_pct(vals, 0.5)} | {_pct(vals, 0.9)} |")
     p(f"in-flight window occupancy at dispatch: mean {s['occupancy_mean']}"
       f", drains: {s['drains']}")
+    if s["span_totals"]:
+        p("\nprogram spans, whole run (count, total ms): " + ", ".join(
+            f"{name} {t['count']} x / {t['ms']}"
+            for name, t in sorted(s["span_totals"].items())))
     if s["mean_participants"] is not None:
         stale = (f", staleness mean {s['mean_staleness']:.1f} / max "
                  f"{s['max_staleness']} rounds"
@@ -1044,7 +1062,8 @@ def follow(path: str, out=None, interval: float = 2.0,
 
 # the span/metric keys the A/B delta table compares (numeric, flat)
 _COMPARE_KEYS = (
-    "log_rounds", "rounds_per_sec", "dispatch_ms_p50", "compute_ms_p50",
+    "log_rounds", "rounds_per_sec", "dispatch_ms_p50", "h2d_ms_p50",
+    "input_wait_ms_p50", "window_wait_ms_p50", "compute_ms_p50",
     "drain_fetch_ms_p50", "dispatch_to_drain_ms_p50", "occupancy_mean",
     "mean_loss", "mean_update_nnz", "mean_topk_threshold",
     "mean_error_norm", "wire_bytes_per_round", "guard_trips",

@@ -118,8 +118,11 @@ def _category(op_name: str) -> str:
         # re-sketch's share hides under custom-call; the fused kernel
         # (_fused_epilogue_pallas) has its own name exactly so the
         # epilogue share becomes attributable.
+        # (the kernels' names since their pallas_calls carry name=:
+        # profiling.KERNEL_NAMES; the jit wrappers' names before)
         (r"_fused_epilogue_pallas|_estimates_pallas|_count_ge_pallas"
-         r"|_descent_pallas|compare_select_fusion|multiply_subtract_fusion"
+         r"|_descent_pallas|fed_epilogue|fed_estimates|fed_topk_count"
+         r"|fed_topk_descent|compare_select_fusion|multiply_subtract_fusion"
          r"|convert_reduce_fusion[^=]*= s32\[(15|7|16)\]",
          "server epilogue (d-plane sweeps)"),
         # Client-phase sketch-accumulate launches (docs/stream_sketch.md):
@@ -134,7 +137,7 @@ def _category(op_name: str) -> str:
         # _sketch_vec_pallas: that zero-init kernel also serves the
         # composed client sketch AND the server re-sketch, which would
         # pollute the launch count with server-phase spans.
-        (r"_sketch_accum_pallas|_sketch_segments_pallas",
+        (r"_sketch_accum_pallas|_sketch_segments_pallas|fed_sketch_accum",
          "client sketch accumulate (launches)"),
         # Client flatten/movement (docs/stream_sketch.md): the d-sized
         # 1-D layout ops the streaming sketch exists to delete — the

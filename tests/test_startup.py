@@ -24,26 +24,57 @@ def config_updates(monkeypatch):
     return calls
 
 
+# the one thing set wherever the cache lives: its key includes the programs'
+# metadata, where the stage names are (tests/test_tracing.py)
+_KEYED = ("jax_compilation_cache_include_metadata_in_key", True)
+
+
 class TestCompileCachePlacement:
-    def test_env_wins_and_nothing_is_set_in_code(self, monkeypatch,
-                                                 config_updates):
+    def test_env_wins_and_no_other_place_is_set_in_code(self, monkeypatch,
+                                                        config_updates):
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/x")
         assert configure_compile_cache() == "/x"
-        assert config_updates == []
+        assert config_updates == [_KEYED]
 
     def test_empty_env_means_no_cache(self, monkeypatch, config_updates):
         """An empty value is jax's own 'no persistent cache'
         (scripts/crash_matrix.py children, which are SIGKILLed mid-write)."""
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "")
         assert configure_compile_cache() == ""
-        assert config_updates == []
+        assert config_updates == [_KEYED]
 
     def test_default_is_the_fixed_in_checkout_path(self, monkeypatch,
                                                    config_updates):
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         want = os.path.join(_REPO, ".jax_cache")
         assert configure_compile_cache() == want
-        assert config_updates == [("jax_compilation_cache_dir", want)]
+        assert config_updates == [_KEYED,
+                                  ("jax_compilation_cache_dir", want)]
+
+    def test_a_scope_name_changes_the_cache_key(self):
+        """What the setting is for: two programs that differ only in a
+        ``jax.named_scope`` must not share a cache entry, or a capture
+        shows the names of whichever was compiled first."""
+        import hashlib
+
+        import jax.numpy as jnp
+        from jax._src import cache_key, config as jax_config
+
+        def digest(scope, include):
+            def f(x):
+                with jax.named_scope(scope):
+                    return x + 1.0
+
+            module = jax.jit(f).lower(jnp.zeros(4)).compiler_ir()
+            h = hashlib.sha256()
+            with jax_config.compilation_cache_include_metadata_in_key(
+                    include):
+                cache_key._hash_computation(h, module,
+                                            cache_key.IgnoreCallbacks.NO)
+            return h.hexdigest()
+
+        assert digest("fed_a", False) == digest("fed_b", False)
+        assert digest("fed_a", True) != digest("fed_b", True)
 
 
 def _run(cmd, cwd, **env):

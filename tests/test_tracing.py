@@ -1,0 +1,401 @@
+"""The names the program gives its own work (docs/observability.md §names).
+
+- **Device stages**: every ``jax.named_scope`` of ``profiling.DEVICE_STAGES``
+  appears in the lowered text of ``client_step`` / ``server_step`` /
+  ``val_step`` in the modes that run that stage, and no compiled operation's
+  scope path holds two stages (the per-layer stage metrics are disjoint).
+- **Kernel names**: every ``pallas_call`` equation carries its ``name``
+  (``profiling.KERNEL_NAMES``).
+- **Host spans**: ``profiling.annotate`` feeds ``SPAN_TOTALS``; N submits
+  through ``PipelinedRoundEngine`` count ``fed_round`` N times,
+  ``fed_window_wait`` N - window times, ``fed_h2d`` N times, ``fed_drain``
+  once a drained round; ``PrefetchLoader`` counts its producer and consumer
+  sides; the ``round`` records carry ``window_wait_ms`` / ``h2d_ms`` /
+  ``input_wait_ms`` from those spans and ``run_end`` the totals; the dispatch
+  path still performs zero blocking fetches.
+- **One profiler starter**: ``--profile --profile_steps 1`` is a
+  ``RoundTracer`` window over round 2, written to ``--profile_dir``.
+"""
+
+import re
+import threading
+from types import SimpleNamespace
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import flax.linen as nn
+
+from commefficient_tpu import profiling
+from commefficient_tpu.data_utils import PrefetchLoader
+from commefficient_tpu.federated.aggregator import (
+    FedModel,
+    FedOptimizer,
+    LambdaLR,
+)
+from commefficient_tpu.federated.engine import PipelinedRoundEngine
+from commefficient_tpu.profiling import (
+    DEVICE_STAGES,
+    KERNEL_NAMES,
+    SPAN_TOTALS,
+    RoundTracer,
+    annotate,
+    host_sync_monitor,
+    span_totals,
+)
+from commefficient_tpu.telemetry import (
+    RunTelemetry,
+    attach_run_telemetry,
+    metric_schema,
+    read_events,
+)
+
+MODES = {
+    "sketch": dict(mode="sketch", error_type="virtual"),
+    "true_topk": dict(mode="true_topk", error_type="virtual"),
+    "uncompressed": dict(mode="uncompressed", error_type="none"),
+}
+# the stages each mode's two-phase round runs (fed_accounting lives in the
+# aggregator's own jitted programs, fed_val in val_step)
+CLIENT = {"fed_client_grad", "fed_client_compress"}
+SERVER = {
+    "sketch": {"fed_server_estimate", "fed_server_topk",
+               "fed_server_resketch", "fed_server_apply",
+               "fed_telemetry_metrics"},
+    "true_topk": {"fed_server_topk", "fed_server_apply",
+                  "fed_telemetry_metrics"},
+    "uncompressed": {"fed_server_apply", "fed_telemetry_metrics"},
+}
+
+
+class TinyModel(nn.Module):
+    @nn.compact
+    def __call__(self, x, train=False):
+        return nn.Dense(4, use_bias=False)(x)
+
+
+def _loss(params, model_state, batch, rng, train):
+    pred = TinyModel().apply({"params": params}, batch["inputs"])
+    err = pred - batch["targets"]
+    mask = batch["mask"]
+    return jnp.sum(jnp.square(err).mean(-1) * mask), (), jnp.sum(mask), \
+        model_state
+
+
+def _args(**over):
+    base = dict(
+        mode="sketch", error_type="virtual", k=2, num_workers=2,
+        weight_decay=0.0, local_momentum=0.0, virtual_momentum=0.9,
+        microbatch_size=-1, max_grad_norm=None, do_dp=False,
+        dp_mode="worker", l2_norm_clip=1.0, noise_multiplier=0.0,
+        num_fedavg_epochs=1, fedavg_batch_size=-1, fedavg_lr_decay=1.0,
+        do_topk_down=False, num_clients=4, num_devices=1, seed=0,
+        do_test=False, dataset_name="CIFAR10", num_epochs=2,
+        local_batch_size=2, num_cols=16, num_rows=2, num_blocks=1,
+        seq_parallel="none", seq_devices=1, telemetry=True,
+        telemetry_hist=True,
+    )
+    base.update(over)
+    return SimpleNamespace(**base)
+
+
+def _host_batch(ids, seed, d_in=3):
+    n = len(ids)
+    rng = np.random.RandomState(seed)
+    return {
+        "inputs": rng.randn(n, 2, d_in).astype(np.float32),
+        "targets": rng.randn(n, 2, 4).astype(np.float32),
+        "mask": np.ones((n, 2), np.float32),
+        "client_ids": np.asarray(ids, np.int32),
+        "worker_mask": np.ones(n, np.float32),
+    }
+
+
+def _model(mode, **over):
+    fm = FedModel(TinyModel(), _loss, _args(**MODES[mode], **over),
+                  input_shape=(3,))
+    return fm, FedOptimizer(fm, fm.args)
+
+
+def _stages_in(path):
+    return [n for n in re.findall(r"fed_[a-z_]+", path)
+            if n in DEVICE_STAGES]
+
+
+def _lowered_steps(mode):
+    """(name, Lowered) of the three jitted steps on a tiny round."""
+    fm, opt = _model(mode)
+    batch = {k: jnp.asarray(v) for k, v in _host_batch([0, 1], 0).items()}
+    rng = jax.random.key(0)
+    cargs = (fm.ps_weights, fm.client_states, fm._model_state, batch, 0.5,
+             rng)
+    ctx, _, _ = fm.steps.client_step(*cargs)
+    vbatch = {k: batch[k][0] for k in ("inputs", "targets", "mask")}
+    return {
+        "client": fm.steps.client_step.lower(*cargs),
+        "server": fm.steps.server_step.lower(
+            fm.ps_weights, opt.server_state, fm.client_states, ctx, 0.5,
+            rng),
+        "val": fm.steps.val_step.lower(fm.ps_weights, fm._model_state,
+                                       vbatch),
+    }
+
+
+@pytest.fixture(scope="module", params=sorted(MODES))
+def lowered(request):
+    return request.param, _lowered_steps(request.param)
+
+
+class TestDeviceStages:
+    def test_every_stage_is_in_the_lowered_text(self, lowered):
+        mode, steps = lowered
+        want = {"client": CLIENT, "server": SERVER[mode], "val": {"fed_val"}}
+        for step, low in steps.items():
+            text = low.as_text(debug_info=True)
+            found = {s for s in DEVICE_STAGES if s in text}
+            assert found == want[step], \
+                f"{mode} {step}_step: stages {sorted(found)}, " \
+                f"expected {sorted(want[step])}"
+
+    def test_no_operation_carries_two_stages(self, lowered):
+        """The compiled programs' ``op_name`` metadata is the scope path the
+        profiler reports: one stage per operation, under whatever transform
+        (``transpose(jvp(...))``) wrapped it."""
+        mode, steps = lowered
+        for step, low in steps.items():
+            names = set(re.findall(r'op_name="([^"]*)"',
+                                   low.compile().as_text()))
+            staged = [n for n in names if _stages_in(n)]
+            assert staged, f"{mode} {step}_step: no staged operation"
+            two = [n for n in staged if len(set(_stages_in(n))) > 1]
+            assert not two, f"{mode} {step}_step: two stages in {two[:3]}"
+
+    def test_backward_pass_keeps_its_stage(self, lowered):
+        _, steps = lowered
+        names = re.findall(r'op_name="([^"]*)"',
+                           steps["client"].compile().as_text())
+        back = [n for n in names if "transpose(" in n]
+        assert back and all(_stages_in(n) == ["fed_client_grad"]
+                            for n in back), back[:3]
+
+    def test_accounting_programs_are_scoped(self):
+        from commefficient_tpu.federated import aggregator as agg
+
+        last = jnp.zeros(8, jnp.int32)
+        w = jnp.ones(8)
+        for low in (
+                agg._mark_changed.lower(last, w, w * 2, 1),
+                agg._changed_since_counts.lower(last,
+                                                jnp.zeros(2, jnp.int32)),
+                agg._fold_updated.lower(jnp.zeros(8, bool), w, w * 2)):
+            assert "fed_accounting" in low.as_text(debug_info=True)
+
+
+def _pallas_names(jaxpr, out):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            info = eqn.params.get("name_and_src_info")
+            out.append(getattr(info, "name", None) or eqn.params.get("name"))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_names(sub, out)
+    return out
+
+
+class TestKernelNames:
+    @pytest.mark.parametrize("kernel", sorted(KERNEL_NAMES))
+    def test_pallas_call_carries_its_name(self, kernel):
+        import importlib
+
+        from commefficient_tpu.ops import sketch as sk
+
+        # (``ops.topk`` the attribute is the function, not the module)
+        tk = importlib.import_module("commefficient_tpu.ops.topk")
+
+        cs = sk.make_sketch(3000, 2048, 3, seed=0, num_blocks=1)
+        S, T = cs.sublanes, cs.T
+        v3 = jnp.zeros((T, S, 128), jnp.float32)
+        tbl3 = jnp.zeros((cs.r, S, 128), jnp.float32)
+        kw = dict(S=S, T=T, interpret=True)
+        hashes = (cs.shift_q, cs.shift_w, cs.sign_keys, sk._T0)
+        raw = jnp.zeros((4, 8, 128), jnp.int32)
+        calls = {
+            "fed_sketch_vec": lambda: sk._sketch_vec_pallas(v3, *hashes,
+                                                            **kw),
+            "fed_sketch_accum": lambda: sk._sketch_accum_pallas(
+                tbl3, v3, *hashes, **kw),
+            "fed_estimates": lambda: sk._estimates_pallas(
+                sk._doubled_table(cs, jnp.zeros(cs.table_shape)), *hashes,
+                c_pad=cs.c_pad, **kw),
+            "fed_epilogue": lambda: sk._fused_epilogue_pallas(
+                v3, *hashes, jnp.zeros(1, jnp.int32), **kw),
+            "fed_topk_count": lambda: tk._count_ge_pallas(
+                raw, jnp.zeros(16, jnp.int32), T=4, sub=8, interpret=True),
+            "fed_topk_descent": lambda: tk._descent_pallas(
+                raw, jnp.ones(1, jnp.int32), T=4, sub=8, interpret=True),
+        }
+        names = _pallas_names(jax.make_jaxpr(calls[kernel])().jaxpr, [])
+        assert names == [kernel]
+
+    def test_sketch_words_tell_the_kernels_apart(self):
+        """benchmark/metrics/sketch_kernel_roofline.py finds the sketch's
+        kernels by these words in the operation's head; the top-k kernels
+        must not match."""
+        word = re.compile(r"sketch|estimates|epilogue")
+        assert [bool(word.search(k)) for k in KERNEL_NAMES] \
+            == [True, True, True, True, False, False]
+
+
+def _engine(tmp_path, mode="sketch", window=2, drain_every=4, tracer=None):
+    fm, opt = _model(mode)
+    rt = RunTelemetry(str(tmp_path / "telemetry.jsonl"),
+                      run_info={"mode": mode},
+                      schema=metric_schema(True))
+    fm.telemetry, fm.tracer = rt, tracer
+    engine = PipelinedRoundEngine(fm, opt, LambdaLR(opt, lambda step: 0.5),
+                                  window=window, drain_every=drain_every)
+    return fm, engine, rt
+
+
+def _counts(before):
+    return {k: v["count"] - before.get(k, {"count": 0})["count"]
+            for k, v in span_totals().items()}
+
+
+class TestHostSpans:
+    def test_annotate_feeds_the_totals(self):
+        before = span_totals()
+        with annotate("fed_test_span", round=7) as span:
+            pass
+        assert span.end_ns >= span.start_ns and span.ms >= 0.0
+        assert _counts(before)["fed_test_span"] == 1
+        count, ns = SPAN_TOTALS["fed_test_span"]
+        assert ns == span.end_ns - span.start_ns or count > 1
+
+    def test_totals_survive_threads(self):
+        before = span_totals()
+
+        def spin():
+            for _ in range(200):
+                with annotate("fed_test_threads"):
+                    pass
+
+        threads = [threading.Thread(target=spin) for _ in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+            assert not t.is_alive()
+        assert _counts(before)["fed_test_threads"] == 1600
+
+    @pytest.mark.parametrize("mode", sorted(MODES))
+    def test_engine_counts_its_spans(self, tmp_path, mode):
+        N, window = 6, 2
+        fm, engine, rt = _engine(tmp_path, mode, window=window,
+                                 drain_every=N + 2)
+        before = span_totals()
+        drained = []
+        for rnd in range(N):
+            drained += engine.submit(
+                _host_batch([rnd % 4, (rnd + 1) % 4], seed=rnd))
+        counts = _counts(before)
+        assert counts["fed_round"] == N
+        assert counts["fed_h2d"] == N
+        assert counts["fed_client_phase"] == N
+        assert counts["fed_server_phase"] == N
+        assert counts["fed_window_wait"] == N - window
+        assert counts.get("fed_drain", 0) == len(drained) == 0
+        drained += engine.drain()
+        assert _counts(before)["fed_drain"] == len(drained) == N
+        rt.close()
+
+        events = list(read_events(str(tmp_path / "telemetry.jsonl")))
+        rounds = [e for e in events if e["ev"] == "round"]
+        assert [e["round"] for e in rounds] == list(range(N))
+        for e in rounds:
+            for key in ("dispatch_ms", "h2d_ms", "input_wait_ms",
+                        "drain_fetch_ms", "dispatch_to_drain_ms"):
+                assert e[key] >= 0.0, (key, e)
+            assert e["h2d_ms"] <= e["dispatch_ms"]
+        waited = [e for e in rounds if "window_wait_ms" in e]
+        assert [e["round"] for e in waited] == list(range(N - window))
+        for e in waited:
+            assert 0.0 <= e["window_wait_ms"] <= e["compute_ms"]
+        end = next(e for e in events if e["ev"] == "run_end")
+        for name in ("fed_round", "fed_window_wait", "fed_h2d", "fed_drain",
+                     "fed_telemetry_host"):
+            assert end["spans"][name]["count"] >= 1
+            assert end["spans"][name]["ms"] >= 0.0
+
+    def test_dispatch_path_still_fetches_nothing(self, tmp_path):
+        fm, engine, rt = _engine(tmp_path, drain_every=10)
+        engine.submit(_host_batch([0, 1], seed=0))  # compile round
+        with host_sync_monitor(strict=True) as counter:
+            for rnd in range(1, 6):
+                assert engine.submit(
+                    _host_batch([rnd % 4, (rnd + 1) % 4], seed=rnd)) == []
+            assert counter.count == 0
+            engine.drain()
+            assert counter.count > 0
+        rt.close()
+
+    def test_prefetch_loader_counts_both_sides(self):
+        before = span_totals()
+        assert list(PrefetchLoader(list(range(5)))) == list(range(5))
+        counts = _counts(before)
+        # five batches and the StopIteration / end sentinel on each side
+        assert counts["fed_input_produce"] == 6
+        assert counts["fed_input_wait"] == 6
+
+    def test_input_wait_lands_on_the_next_round(self, tmp_path):
+        fm, engine, rt = _engine(tmp_path, drain_every=1)
+        for rnd, _ in enumerate(PrefetchLoader([0, 1, 2])):
+            engine.submit(_host_batch([rnd, rnd + 1], seed=rnd))
+        engine.drain()
+        rt.close()
+        rounds = [e for e in read_events(str(tmp_path / "telemetry.jsonl"))
+                  if e["ev"] == "round"]
+        assert len(rounds) == 3
+        assert all(e["input_wait_ms"] > 0.0 for e in rounds)
+
+
+class TestOneProfilerStarter:
+    def test_step_profiler_is_gone(self):
+        assert not hasattr(profiling, "StepProfiler")
+        assert not hasattr(profiling, "_profiler_busy")
+
+    def test_profile_flag_is_a_round_tracer_window(self, tmp_path):
+        """--profile --profile_steps 1: the attached RoundTracer captures
+        round 2 into --profile_dir."""
+        fm, engine, rt = _engine(tmp_path, drain_every=1)
+        rt.close()
+        fm.telemetry = engine.telemetry = None
+        args = _args(do_profile=True, profile_steps=1,
+                     profile_dir=str(tmp_path / "prof"), telemetry=False,
+                     watch=False, trace_rounds="")
+        assert attach_run_telemetry(args, fm, str(tmp_path), "test") is None
+        assert isinstance(fm.tracer, RoundTracer)
+        engine.tracer = fm.tracer
+        for rnd in range(4):
+            engine.submit(_host_batch([rnd % 4, (rnd + 1) % 4], seed=rnd))
+        engine.drain()
+        assert fm.tracer.captures == [{
+            "round_start": 2, "round_until": 2,
+            "dir": str(tmp_path / "prof")}]
+        assert fm.tracer.close() is None
+        assert list((tmp_path / "prof").rglob("*.xplane.pb"))
+
+    def test_windows_queue_behind_an_open_one(self, tmp_path):
+        """One class starts the profiler, so two windows cannot collide: a
+        window due while another is open starts when that one has closed."""
+        tracer = RoundTracer(str(tmp_path),
+                             windows=[(0, 2), (1, 1, str(tmp_path / "own"))])
+        tracer.on_submit(0)
+        tracer.on_submit(1)
+        assert tracer._active["start"] == 0 and len(tracer._pending) == 1
+        assert tracer.on_drained(1)["round_until"] == 1
+        tracer.on_submit(2)
+        assert tracer._active["dir"] == str(tmp_path / "own")
+        assert tracer.close()["round_start"] == 2

@@ -575,41 +575,6 @@ class TestTraceRounds:
         with pytest.raises(AssertionError):
             parse_trace_rounds("3:0")
 
-    def test_defers_while_step_profiler_active(self, tmp_path):
-        """One profiler session per process: a RoundTracer window due
-        while --profile's StepProfiler is mid-capture DEFERS (stays
-        pending, retries next submit) instead of crashing the run with
-        'profiler already started' — and starts once the session frees."""
-        from commefficient_tpu.profiling import StepProfiler
-
-        prof = StepProfiler(str(tmp_path / "prof"), start_step=0,
-                            num_steps=2, enabled=True)
-        prof.step(0)  # StepProfiler session active
-        try:
-            tracer = RoundTracer(str(tmp_path),
-                                 windows=parse_trace_rounds("1:1"))
-            tracer.on_submit(1)
-            assert tracer._active is None and tracer._pending, \
-                "window must defer, not start into an active session"
-        finally:
-            prof.close()
-        tracer.on_submit(2)  # session free: the deferred window starts
-        assert tracer._active is not None
-        assert tracer._active["start"] == 2
-        cap = tracer.close()
-        assert cap is not None and not tracer._pending
-        # and the symmetric direction: StepProfiler skips, not crashes,
-        # while a RoundTracer capture is active
-        tracer2 = RoundTracer(str(tmp_path / "t2"))
-        tracer2.request(1)
-        tracer2.on_submit(0)
-        assert tracer2._active is not None
-        prof2 = StepProfiler(str(tmp_path / "prof2"), start_step=0,
-                             num_steps=1, enabled=True)
-        prof2.step(0)
-        assert not prof2._active
-        tracer2.close()
-
 
 class TestHeartbeatExtras:
     def test_line_carries_loss_and_guard(self, tmp_path, capfd):
