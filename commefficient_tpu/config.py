@@ -443,13 +443,13 @@ def build_parser(default_lr=None) -> argparse.ArgumentParser:
     # threshold, guard detail) ride the batched metric drain into a
     # structured per-run JSONL event log with round-lifecycle spans
     # (dispatch -> window wait -> drain, in-flight occupancy). ON by
-    # default, and not cheap on the device while the histograms are what
-    # they are: 83 ms of a 156 ms round in resnet9_sketch_1c, 91% and 98%
-    # of device time in the two GPT-2 cells (PERF_LEDGER.jsonl, PR 25;
-    # PERF.md section 5 — the per-layer metric telemetry_device_ms reads
-    # it). The fp32 trajectory is bit-identical either way
-    # (tests/test_telemetry.py). Render the log with
-    # scripts/obs_report.py.
+    # default. On the device it costs, histograms included, 0.26 ms of a
+    # 72 ms round in resnet9_sketch_1c, 7.7 of 79 ms in gpt2_sketch_1c and
+    # 9.1 of 49 ms in gpt2_uncompressed_1c, where a dozen reductions sweep
+    # d = 124M (my chip run, PR 27; PERF.md section 5 — the per-layer
+    # metric telemetry_device_ms reads it). The fp32 trajectory is
+    # bit-identical either way (tests/test_telemetry.py). Render the log
+    # with scripts/obs_report.py.
     parser.add_argument("--telemetry", action="store_true", dest="telemetry",
                         default=True,
                         help="Per-round on-device metrics + JSONL run "

@@ -182,22 +182,31 @@ def log_magnitude_histogram(x):
     """``(HIST_BINS,)`` f32 counts of ``|x|`` over fixed log10-magnitude
     bins (edges ``10**(HIST_LO + i*HIST_STEP)``). Zeros are excluded,
     under/overflow clamp into the edge bins, and non-finite elements land
-    in the last bin. Pure device reductions + one tiny scatter-add —
-    nothing feeds back into the state transition."""
+    in the last bin. Pure device reductions: the counts are eight masked
+    int32 sums, fused with the binning into one pass over ``x`` (nothing of
+    ``x``'s size is written; a count is exact up to 2**31 elements and
+    rounds to float32 only on the way out) — nothing feeds back into the
+    state transition."""
     ax = jnp.abs(x.astype(jnp.float32)).reshape(-1)
     # != 0 (the update_nnz idiom), NOT > 0: NaN compares false under >
     # and a poisoned round's NaN elements must land in the last bin, not
     # silently vanish from the distribution
     nz = ax != 0
     # log10 of zeros would be -inf; substitute 1.0 (bin of it is discarded
-    # by the nz weight below)
+    # by the nz mask below)
     e = (jnp.log10(jnp.where(nz, ax, 1.0)) - HIST_LO) / HIST_STEP
     idx = jnp.clip(jnp.floor(e), 0, HIST_BINS - 1).astype(jnp.int32)
     # non-finite |x| (a poisoned round): clip/floor of NaN is NaN and its
     # int cast is undefined — pin those elements to the last bin instead
     idx = jnp.where(jnp.isfinite(ax), idx, HIST_BINS - 1)
-    return jnp.zeros(HIST_BINS, jnp.float32).at[idx].add(
-        nz.astype(jnp.float32))
+    # ONE reduction with eight int32 accumulators, not eight jnp.sums: the
+    # TPU compiler fuses sibling sums into one pass by itself, the CPU's
+    # writes idx and eight d-sized masks out first
+    masks = [((idx == i) & nz).astype(jnp.int32) for i in range(HIST_BINS)]
+    counts = jax.lax.reduce(
+        masks, [jnp.int32(0)] * HIST_BINS,
+        lambda acc, m: tuple(a + b for a, b in zip(acc, m)), (0,))
+    return jnp.stack(counts).astype(jnp.float32)
 
 
 @jax.named_scope("fed_telemetry_metrics")

@@ -28,6 +28,7 @@ Pins the acceptance contracts of the continuous-observability PR:
 
 import json
 import os
+import re
 import sys
 import threading
 import time
@@ -103,7 +104,93 @@ def _np_hist(x):
     return counts
 
 
+def _np_hist_int(x):
+    """The same contract vectorised, counted in integers: what a bin past
+    float32's 2**24 must hold."""
+    ax = np.abs(np.asarray(x, np.float32)).ravel()
+    nz = ax != 0
+    e = (np.log10(np.where(nz, ax, np.float32(1))) - HIST_LO) / HIST_STEP
+    with np.errstate(invalid="ignore"):
+        idx = np.clip(np.floor(e), 0, HIST_BINS - 1)
+    idx = np.where(np.isfinite(ax), idx, HIST_BINS - 1).astype(np.int64)
+    return np.bincount(idx[nz], minlength=HIST_BINS)
+
+
+def _scatter_hist(x):
+    """The body this function had before it counted with masked sums
+    (float32 scatter-add): the reference for WHERE an element lands. At an
+    edge the bin is log10's last float32 bit, which numpy's log10 rounds
+    differently (-10.000001 for 1e-10 where XLA reads -10.0), so there
+    ``_np_hist`` is no reference for either body."""
+    ax = jnp.abs(x.astype(jnp.float32)).reshape(-1)
+    nz = ax != 0
+    e = (jnp.log10(jnp.where(nz, ax, 1.0)) - HIST_LO) / HIST_STEP
+    idx = jnp.clip(jnp.floor(e), 0, HIST_BINS - 1).astype(jnp.int32)
+    idx = jnp.where(jnp.isfinite(ax), idx, HIST_BINS - 1)
+    return jnp.zeros(HIST_BINS, jnp.float32).at[idx].add(
+        nz.astype(jnp.float32))
+
+
+def _inner_edge_points():
+    """Each of the seven inner bin edges as float32, and the float32 one
+    ulp below and above it."""
+    edges = np.array([10.0 ** (HIST_LO + i * HIST_STEP)
+                      for i in range(1, HIST_BINS)], np.float32)
+    return np.concatenate([np.nextafter(edges, np.float32(0)), edges,
+                           np.nextafter(edges, np.float32(np.inf))])
+
+
+def _mixed_2d(dtype):
+    rng = np.random.RandomState(3)
+    x = rng.randn(37, 129).astype(np.float32) * 10 ** rng.uniform(
+        -14, 6, (37, 129)).astype(np.float32)
+    x[::5, ::3] = 0.0
+    return jnp.asarray(x).astype(dtype)
+
+
 class TestHistogram:
+    @pytest.mark.parametrize("make,ref", [
+        # 20M elements in ONE bin: a float32 accumulator stops at 2**24
+        pytest.param(lambda: np.full(20_000_000, 1e-3, np.float32),
+                     _np_hist_int, id="past_f32_exact_range"),
+        pytest.param(lambda: _mixed_2d(jnp.bfloat16), _np_hist, id="bf16_2d"),
+        pytest.param(lambda: _mixed_2d(jnp.float32), _np_hist, id="f32_2d"),
+    ])
+    def test_counts_equal_reference(self, make, ref):
+        x = make()
+        got = np.asarray(jax.jit(log_magnitude_histogram)(jnp.asarray(x)))
+        assert got.shape == (HIST_BINS,) and got.dtype == np.float32
+        np.testing.assert_array_equal(got, ref(x).astype(np.float32))
+        assert got.sum() == np.count_nonzero(np.asarray(x, np.float32))
+
+    @pytest.mark.parametrize("jit", [True, False], ids=["jit", "eager"])
+    def test_inner_edges_land_where_they_did(self, jit):
+        """On and one ulp either side of every inner edge: the bin is the
+        scatter-add body's, element for element, and always one of the two
+        bins that meet at that edge (``_np_hist``'s is one of those too)."""
+        wrap = jax.jit if jit else (lambda f: f)
+        new, old = wrap(log_magnitude_histogram), wrap(_scatter_hist)
+        pts = _inner_edge_points()
+        np.testing.assert_array_equal(np.asarray(new(jnp.asarray(pts))),
+                                      np.asarray(old(jnp.asarray(pts))))
+        for j, v in enumerate(pts):
+            one = jnp.full((1,), v)
+            got = np.asarray(new(one))
+            np.testing.assert_array_equal(got, np.asarray(old(one)))
+            upper = j % (HIST_BINS - 1) + 1   # the bin above this edge
+            assert got[upper - 1] + got[upper] == 1, (v, got)
+            assert _np_hist(pts[j:j + 1])[upper - 1:upper + 1].sum() == 1
+
+    def test_one_pass_no_scatter(self):
+        """The counts are one fused reduction: no scatter in the optimised
+        HLO, and no temporary of the operand's size (the old body wrote the
+        bin index AND the weights out, two operands' worth)."""
+        n = 1 << 20
+        compiled = jax.jit(log_magnitude_histogram).lower(
+            jax.ShapeDtypeStruct((n,), jnp.float32)).compile()
+        assert not re.search(r"\bscatter\(", compiled.as_text())
+        assert compiled.memory_analysis().temp_size_in_bytes < 4 * n
+
     def test_matches_numpy_reference(self):
         rng = np.random.RandomState(0)
         x = rng.randn(257).astype(np.float32) * 10 ** rng.uniform(
@@ -234,7 +321,7 @@ class TestHistNonPerturbation:
                         reason="needs the forced-8-device CPU mesh")
     def test_v3_bit_identical_server_shard(self):
         """Same bit-identity on the sharded server plane: the histogram
-        scatter-adds must not perturb the sharded update either."""
+        reductions must not perturb the sharded update either."""
         from commefficient_tpu.parallel.mesh import default_client_mesh
 
         runs = {}
