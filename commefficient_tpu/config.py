@@ -351,6 +351,26 @@ def build_parser(default_lr=None) -> argparse.ArgumentParser:
                              "the effective per-layer weight "
                              "coef/n_moe_layers, so retune rather than "
                              "assuming published values transfer.")
+    # The decoder gpt2_train.py builds (models/joyai.py for the DeepSeek-V3
+    # family) and the cut of it held here: one chip's share of a deployment
+    # in which --layer_chips chips share each layer.
+    parser.add_argument("--arch", choices=["gpt2", "joyai_llm_flash"],
+                        default="gpt2",
+                        help="gpt2_train.py's model: GPT-2 double heads, or "
+                             "JoyAI-LLM-Flash (MLA, sigmoid top-8 routed "
+                             "experts with a shared expert, causal-LM loss).")
+    parser.add_argument("--arch_layers", type=int, default=0,
+                        help="Layers held, leading dense layer included "
+                             "(0 = all the architecture has).")
+    parser.add_argument("--layer_chips", type=int, default=1,
+                        help="Chips that share each layer in the deployment "
+                             "this chip stands for: it holds n_routed_experts"
+                             "/layer_chips experts of every expert layer.")
+    parser.add_argument("--expert_offset", type=int, default=0,
+                        help="First routed expert held here.")
+    parser.add_argument("--vocab_rows", type=int, default=0,
+                        help="Rows of the vocabulary held here: ids, logits "
+                             "and loss are over the slice (0 = all).")
     # TPU-first extension: dropout/DP mask PRNG. threefry (JAX default) is
     # counter-based ALU work; rbg uses the TPU hardware RNG and is much
     # cheaper at GPT-2 mask volumes. unsafe_rbg additionally relaxes
@@ -822,6 +842,24 @@ def validate_args(args):
         assert args.n_experts % args.expert_devices == 0, (
             f"--n_experts {args.n_experts} must divide by "
             f"--expert_devices {args.expert_devices}")
+    if args.arch != "gpt2":
+        assert args.layer_chips >= 1 and args.expert_offset >= 0 \
+            and args.arch_layers >= 0 and args.vocab_rows >= 0
+        # its expert layer routes all clients' tokens of a microbatch as one
+        # axis (losses.make_causal_lm_losses over_clients): the round's
+        # fused-gradient client phase, not a vmap of per-client gradients
+        assert (args.mode in ("sketch", "uncompressed", "true_topk")
+                and args.local_momentum == 0 and args.error_type != "local"
+                and not args.do_dp and not args.do_topk_down
+                and args.max_grad_norm is None and not args.do_test), (
+            f"--arch {args.arch} runs in the fused-gradient client phase "
+            "only: --mode sketch|uncompressed|true_topk without per-client "
+            "momentum, error, clipping, DP or --topk_down")
+        assert (args.seq_parallel == "none" and args.model_devices == 1
+                and args.pipeline_devices == 1 and not args.n_experts
+                and not args.do_bf16), (
+            f"--arch {args.arch} has no seq/model/stage/expert axis, no "
+            "--n_experts and no --bf16 path yet")
     if args.device:
         # --device X sets jax_platforms to X before the backend
         # initializes. After initialization the update has no effect, so

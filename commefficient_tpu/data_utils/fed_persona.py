@@ -22,7 +22,9 @@ fed_persona.py:217-221 re-reads from disk every item — pure overhead).
 
 Zero-egress fallback: with no ``personachat_self_original.json`` under the
 dataset dir, a deterministic synthetic personachat-format dataset is
-generated (``COMMEFFICIENT_SYNTHETIC_CLIENTS`` personalities).
+generated (``COMMEFFICIENT_SYNTHETIC_CLIENTS`` personalities); with
+``COMMEFFICIENT_SYNTHETIC_WORDS`` set, one whose vocabulary, sentence
+lengths and utterance counts the environment names (``_sized_personachat``).
 """
 
 from __future__ import annotations
@@ -45,8 +47,59 @@ MODEL_INPUTS = ["input_ids", "mc_token_ids", "lm_labels", "mc_labels",
 PADDED_INPUTS = ["input_ids", "lm_labels", "token_type_ids"]
 
 
+HISTORY_KEPT = 5   # 2 * max_history + 1 at gpt2_train.py's default
+
+
+def _sized_personachat(n_clients, n_words, seed=0):
+    """Synthetic personachat whose sizes the environment names, for models
+    whose work depends on the tokens (a routed-expert layer over 15 word
+    types and padding is not a workload): words ``w0 .. w<n_words-1>`` drawn
+    Zipf(1.0) (``COMMEFFICIENT_SYNTHETIC_WORDS``), sentences of
+    ``COMMEFFICIENT_SYNTHETIC_SENTENCE`` = ``lo-hi`` words, one dialog of
+    exactly ``COMMEFFICIENT_SYNTHETIC_UTTERANCES`` utterances a client whose
+    history starts ``HISTORY_KEPT`` sentences long (only the last
+    ``HISTORY_KEPT`` are stored), ``COMMEFFICIENT_SYNTHETIC_VALID``
+    validation dialogs. Drawn in bulk with numpy from the seed."""
+    lo, hi = (int(v) for v in os.environ.get(
+        "COMMEFFICIENT_SYNTHETIC_SENTENCE", "3-7").split("-"))
+    n_utt = int(os.environ.get("COMMEFFICIENT_SYNTHETIC_UTTERANCES", 4))
+    n_valid = int(os.environ.get("COMMEFFICIENT_SYNTHETIC_VALID",
+                                 max(2, n_clients // 8)))
+    rng = np.random.RandomState(seed)
+    p = 1.0 / np.arange(1, n_words + 1)
+    names = np.array([f"w{i}" for i in range(n_words)], dtype=object)
+    per_dialog = 4 + HISTORY_KEPT + 4 * n_utt
+
+    def split(n):
+        lens = rng.randint(lo, hi + 1, size=n * per_dialog)
+        ids = rng.choice(n_words, size=int(lens.sum()), p=p / p.sum())
+        ends = np.cumsum(lens)
+        sentences = iter(" ".join(names[ids[e - k:e]])
+                         for e, k in zip(ends, lens))
+
+        def take(k):
+            return [next(sentences) for _ in range(k)]
+
+        out = []
+        for _ in range(n):
+            personality, history, utterances = take(4), take(HISTORY_KEPT), []
+            for _ in range(n_utt):
+                utterances.append({"history": history[-HISTORY_KEPT:],
+                                   "candidates": take(3)})
+                history = history + take(1) \
+                    + [utterances[-1]["candidates"][-1]]
+            out.append({"personality": personality,
+                        "utterances": utterances})
+        return out
+
+    return {"train": split(n_clients), "valid": split(n_valid)}
+
+
 def _synthetic_personachat(seed=0):
     n_clients = int(os.environ.get("COMMEFFICIENT_SYNTHETIC_CLIENTS", 24))
+    n_words = int(os.environ.get("COMMEFFICIENT_SYNTHETIC_WORDS", 0))
+    if n_words:
+        return _sized_personachat(n_clients, n_words, seed)
     rng = random.Random(seed)
     words = ["i", "like", "cats", "dogs", "music", "hiking", "pizza", "code",
              "tpus", "sketches", "running", "tea", "books", "rain", "sun"]

@@ -82,6 +82,42 @@ class ByteTokenizer:
         return tok
 
 
+class WordTokenizer(ByteTokenizer):
+    """One id a whitespace-separated word ``w<i>`` (the sized synthetic
+    PersonaChat's words, data_utils/fed_persona.py), inside a vocabulary of
+    ``rows`` rows whose last rows are the special tokens: ids that fill a
+    slice of a large model's vocabulary. Selected by
+    ``COMMEFFICIENT_WORD_VOCAB=<rows>``."""
+
+    def __init__(self, rows: int):
+        super().__init__()
+        self.rows = int(rows)
+
+    def __len__(self):
+        return self.rows
+
+    def add_special_tokens(self, attr_to_token) -> int:
+        added = super().add_special_tokens(attr_to_token)
+        # the special tokens in the last rows, in the order they were added
+        first = self.rows - len(self.special)
+        self.special = {t: first + i for i, t in enumerate(self.special)}
+        return added
+
+    def tokenize(self, text: str) -> List[str]:
+        return text.split()
+
+    def convert_tokens_to_ids(self, tokens):
+        if isinstance(tokens, str):
+            return self.convert_tokens_to_ids([tokens])[0]
+        return [self.special[t] if t in self.special else int(t[1:])
+                for t in tokens]
+
+    def save_pretrained(self, path: str):
+        os.makedirs(path, exist_ok=True)
+        with open(os.path.join(path, "word_tokenizer.json"), "w") as f:
+            json.dump({"rows": self.rows, "special": self.special}, f)
+
+
 # Vendored byte-level BPE (the 256-token GPT-2 bytes->unicode alphabet,
 # no merges) so the default in-image path runs the reference's real
 # GPT2Tokenizer machinery (reference gpt2_train.py:262-273) instead of the
@@ -94,7 +130,11 @@ VENDORED_BPE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 def get_tokenizer(model_checkpoint: str = "gpt2"):
     """HF GPT2Tokenizer from the checkpoint when available locally, else
-    from the vendored byte-level BPE; ByteTokenizer as a last resort."""
+    from the vendored byte-level BPE; ByteTokenizer as a last resort. With
+    ``COMMEFFICIENT_WORD_VOCAB`` set, the ``WordTokenizer`` of that many
+    rows."""
+    if os.environ.get("COMMEFFICIENT_WORD_VOCAB"):
+        return WordTokenizer(int(os.environ["COMMEFFICIENT_WORD_VOCAB"]))
     try:
         from transformers import GPT2Tokenizer
     except Exception:

@@ -228,6 +228,7 @@ class FedModel:
                  init_params=None, model_state=None):
         self.model = model
         self.args = args
+        self._compute_loss_train = compute_loss_train
         # --device tpu is a hard request: when the flag came too late
         # (backend already initialized on another platform,
         # config.validate_args) fail loudly here — the backend is
@@ -287,6 +288,7 @@ class FedModel:
             model_state = variables.get("batch_stats", {})
         self._model_state = model_state if model_state is not None else {}
         flat, self.unravel = ravel_pytree(init_params)
+        del init_params  # a d-sized tree: the flat vector is the weights now
         self.grad_size = int(flat.size)
         args.grad_size = self.grad_size  # mirrored mutation, fed_aggregator.py:88
         self.ps_weights = flat
@@ -1159,12 +1161,21 @@ class FedModel:
                 # federated/participation.py); obs_report renders the
                 # participation/async sections from these fields
                 cohort.update(handle.cohort)
+            # the loss's named metric sums (a routed-expert model's pair
+            # counts): the worker divided each client's by its example
+            # count, so multiply back and sum over the round's clients
+            names = getattr(self._compute_loss_train, "metric_names", ())
+            model = {n: float(np.sum(m * np.maximum(count, 1.0)))
+                     for n, m in zip(names, ms[1:])}
+            for n, over in getattr(self._compute_loss_train,
+                                   "metric_ratios", {}).items():
+                model[n] /= max(model[over], 1.0)
             self.telemetry.on_metrics(
                 handle.round_no,
                 ({k: float(v) for k, v in zip(METRIC_FIELDS, vals)}
                  if vals is not None else None),
                 loss=loss, guard_ok=guard_ok, cohort=cohort,
-                offload=handle.offload)
+                offload=handle.offload, model=model)
         if guard_ok is not None:
             self._note_guard(guard_ok, round_no=handle.round_no)
         return [m[handle.valid] for m in ms] + [download, handle.upload]

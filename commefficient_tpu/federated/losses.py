@@ -214,3 +214,79 @@ def make_gpt2_losses(model, lm_coef: float = 1.0, mc_coef: float = 1.0,
                 jnp.sum(mask), model_state)
 
     return compute_train, compute_val
+
+
+# the routing counters a causal-LM round leaves in the event log, in the
+# order of the loss's metric sums (telemetry.RunTelemetry "model" record)
+MOE_METRIC_NAMES = ("moe_local_pairs", "moe_absent_pairs",
+                    "moe_load_max_over_mean")
+# ... of which these are summed as numerators and divided by another's sum
+MOE_METRIC_RATIOS = {"moe_load_max_over_mean": "moe_local_pairs"}
+
+
+def make_causal_lm_losses(model):
+    """Next-token cross-entropy for a decoder that returns ``(logits,
+    routing counts)`` (models/joyai.py): no multiple-choice head, no
+    token-type embedding. An example's loss is the mean NLL over its
+    labelled positions (``lm_labels != -1``, all candidates pooled), as in
+    ``make_gpt2_losses``; the vocabulary is the model's slice.
+
+    Train returns the routing counts as metric sums (``MOE_METRIC_NAMES``:
+    the (token, expert) pairs computed here, the pairs routed to absent
+    experts, and the largest held expert's load over the mean load, summed
+    over the expert layers as ``max load x experts held`` and split evenly
+    over the call's examples, so that the round's sum over the round's pairs
+    is the ratio: ``MOE_METRIC_RATIOS``). Val returns (nll, next-token
+    accuracy over the labelled positions).
+
+    ``compute_train.over_clients`` takes a leading clients axis on the batch
+    and returns per-client vectors: the round's fused client phase calls it
+    in place of a ``vmap`` over clients, so that all clients' tokens of a
+    microbatch are routed as one token axis."""
+
+    def per_example(params, batch):
+        ids = batch["input_ids"]
+        lead, T = ids.shape[:-2], ids.shape[-1]            # (..., B), K, T
+        logits, stats = model.apply({"params": params}, ids.reshape(-1, T))
+        labels = batch["lm_labels"].reshape(-1, T)[:, 1:]
+        valid = labels != -1
+        lg = logits[:, :-1].astype(jnp.float32)
+        picked = jnp.take_along_axis(
+            lg, jnp.where(valid, labels, 0)[..., None], axis=-1)[..., 0]
+        tok_nll = (jax.nn.logsumexp(lg, axis=-1) - picked) * valid
+        hit = (jnp.argmax(lg, axis=-1) == labels) & valid
+
+        def by_example(x):      # sum over an example's candidates/positions
+            return x.reshape(lead + (-1,)).sum(axis=-1)
+
+        n_valid = jnp.maximum(by_example(valid), 1)
+        n_seq = logits.shape[0]
+        counts = tuple(
+            jax.lax.stop_gradient(by_example(c.astype(jnp.float32)))
+            for c in (stats["local"], stats["absent"],
+                      jnp.full((n_seq,), stats["max_load"]
+                               * model.cfg.experts_held / n_seq)))
+        return (by_example(tok_nll) / n_valid, by_example(hit) / n_valid,
+                counts)
+
+    def sums(params, batch, train):
+        """(loss sum, metric sums, count) over the last (examples) axis."""
+        nll, acc, counts = per_example(params, batch)
+        mask = batch["mask"]
+        extra = (tuple(jnp.sum(c, axis=-1) for c in counts) if train
+                 else (jnp.sum(acc * mask, axis=-1),))
+        return jnp.sum(nll * mask, axis=-1), extra, jnp.sum(mask, axis=-1)
+
+    def compute_train(params, model_state, batch, rng, train):
+        return sums(params, batch, True) + (model_state,)
+
+    def over_clients(params, model_states, batch, rngs):
+        return sums(params, batch, True) + (model_states,)
+
+    def compute_val(params, model_state, batch, rng, train):
+        return sums(params, batch, False) + (model_state,)
+
+    compute_train.over_clients = over_clients
+    compute_train.metric_names = MOE_METRIC_NAMES
+    compute_train.metric_ratios = MOE_METRIC_RATIOS
+    return compute_train, compute_val

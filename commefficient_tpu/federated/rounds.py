@@ -590,6 +590,17 @@ def build_round_step(
     # Scopes never nest: an operation's path holds exactly one stage.
     scope = jax.named_scope
 
+    def clients_loss(params, mstates, micro, subs):
+        """The loss of every client slot of a microbatch: a vmap over the
+        clients axis, or the loss's own ``over_clients`` where it batches
+        that axis itself (one token axis for an expert layer's routing)."""
+        over = getattr(compute_loss_train, "over_clients", None)
+        if over is not None:
+            return over(params, mstates, micro, subs)
+        return jax.vmap(
+            lambda ms, b, r: compute_loss_train(params, ms, b, r, True))(
+                mstates, micro, subs)
+
     def fused_clients(ps_weights, model_state, batch, rng_keys, worker_mask):
         """One-gradient client phase for a shard's W client slots. Returns
         (local_dense_sum incl. weight decay and seq psum, stacked per-client
@@ -604,13 +615,8 @@ def build_round_step(
             lambda x: jnp.broadcast_to(x[None], (W,) + x.shape), model_state)
 
         def step_loss(w_flat, mstates, micro, subs):
-            params = unravel_res(w_flat)
-
-            def per_client(ms, b, r):
-                return compute_loss_train(params, ms, b, r, True)
-
-            loss_sums, msums, counts, new_ms = jax.vmap(per_client)(
-                mstates, micro, subs)
+            loss_sums, msums, counts, new_ms = clients_loss(
+                unravel_res(w_flat), mstates, micro, subs)
             total = jnp.sum(loss_sums * worker_mask)
             return total, (loss_sums, msums, counts, new_ms)
 
@@ -700,11 +706,8 @@ def build_round_step(
             params = stream_unravel(ps_weights)
 
         def step_loss(p, mstates, micro, subs):
-            def per_client(ms, b, r):
-                return compute_loss_train(p, ms, b, r, True)
-
-            loss_sums, msums, counts, new_ms = jax.vmap(per_client)(
-                mstates, micro, subs)
+            loss_sums, msums, counts, new_ms = clients_loss(
+                p, mstates, micro, subs)
             total = jnp.sum(loss_sums * worker_mask)
             return total, (loss_sums, msums, counts, new_ms)
 

@@ -1,0 +1,188 @@
+"""JoyAI-LLM-Flash (DeepSeek-V3's configuration class), flax: multi-head
+latent attention, one leading dense SwiGLU layer, then layers of 256 routed
+experts (sigmoid scores, 8 a token, one shared expert), RMSNorm, an untied
+head. The equations follow DeepSeek-V3's report (arXiv:2412.19437 section
+2.1); the sizes are the public ``config.json``'s
+(huggingface.co/jdopensource/JoyAI-LLM-Flash).
+
+A block: ``h = x + MLA(N(x)); y = h + FFN(N(h))`` with ``N`` an RMSNorm
+(eps 1e-6). After the last block ``N`` and the head.
+
+MLA: ``c_q = N(x W_qa)``; ``q = c_q W_qb`` gives every head a 128-wide part
+without position and a 64-wide part with; ``[c_kv; k_r] = x W_kva``;
+``c_kv = N(c_kv)``; ``[k_n; v] = c_kv W_kvb`` per head; RoPE (theta 32e6,
+interleaved pairs, no scaling) on each head's ``q_r`` and on the one ``k_r``
+all heads share; causal ``softmax(q k^T / sqrt(192)) v``; the heads
+concatenated through ``W_o``. No biases anywhere.
+
+What is held here is a cut the caller names (``JoyAIConfig``): how many
+layers, which of the routed experts (parallel/moe.py ``RoutedMoE``: the
+router keeps all its columns), how many rows of the vocabulary (ids, logits
+and loss are over the slice). Not held: the multi-token-prediction module.
+Every block is recomputed in the backward pass (``nn.remat``).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Optional
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from commefficient_tpu.parallel.moe import RoutedMoE, SwiGLU
+
+__all__ = ["JoyAIFlash", "JoyAIConfig"]
+
+
+@dataclasses.dataclass(frozen=True)
+class JoyAIConfig:
+    """The published sizes by default; ``layers``, ``experts_held``,
+    ``expert_offset`` and ``vocab_rows`` are the cut."""
+
+    hidden_size: int = 2048
+    num_attention_heads: int = 32
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    intermediate_size: int = 7168
+    moe_intermediate_size: int = 768
+    n_routed_experts: int = 256
+    num_experts_per_tok: int = 8
+    routed_scaling_factor: float = 2.5
+    first_k_dense_replace: int = 1
+    rope_theta: float = 32e6
+    rms_norm_eps: float = 1e-6
+    layers: int = 40
+    experts_held: int = 256
+    expert_offset: int = 0
+    vocab_rows: int = 129280
+    # multiplicands of the routed experts' grouped products (None: as
+    # stored); the entry point sets bfloat16 on the TPU at the default
+    # precision, where every other product's are rounded by the unit
+    expert_operand_dtype: Optional[Any] = None
+
+    @classmethod
+    def tiny(cls, **cut):
+        """Widths for the CPU tests; the same code paths."""
+        return cls(hidden_size=64, num_attention_heads=2, q_lora_rank=32,
+                   kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                   v_head_dim=16, intermediate_size=128,
+                   moe_intermediate_size=32, n_routed_experts=16,
+                   num_experts_per_tok=4, **cut)
+
+
+def _kernel(mod, name, shape):
+    return mod.param(name, nn.initializers.normal(0.02), shape)
+
+
+class RMSNorm(nn.Module):
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
+                                + self.eps)
+        return (y * scale).astype(x.dtype)
+
+
+def rope(x, theta: float):
+    """Rotary position embedding on interleaved pairs: (x[2i], x[2i+1]) of
+    position p turned by ``p * theta**(-2i/n)``. x: (S, T, H, n)."""
+    n = x.shape[-1]
+    freq = theta ** (-jnp.arange(0, n, 2, dtype=jnp.float32) / n)
+    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freq
+    cos, sin = jnp.cos(angle)[None, :, None], jnp.sin(angle)[None, :, None]
+    a, b = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+class MLA(nn.Module):
+    cfg: JoyAIConfig
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.cfg
+        S, T, C = x.shape
+        H, dn, dr, dv = (c.num_attention_heads, c.qk_nope_head_dim,
+                         c.qk_rope_head_dim, c.v_head_dim)
+        q = RMSNorm(c.rms_norm_eps, name="q_norm")(
+            x @ _kernel(self, "q_a", (C, c.q_lora_rank)))
+        q = (q @ _kernel(self, "q_b", (c.q_lora_rank, H * (dn + dr)))
+             ).reshape(S, T, H, dn + dr)
+        kv = x @ _kernel(self, "kv_a", (C, c.kv_lora_rank + dr))
+        c_kv = RMSNorm(c.rms_norm_eps, name="kv_norm")(
+            kv[..., :c.kv_lora_rank])
+        k_r = rope(kv[..., None, c.kv_lora_rank:], c.rope_theta)[:, :, 0]
+        kv = (c_kv @ _kernel(self, "kv_b", (c.kv_lora_rank, H * (dn + dv)))
+              ).reshape(S, T, H, dn + dv)
+        w_o = _kernel(self, "o", (H * dv, C))
+        with jax.named_scope("fed_mla_attn"):
+            q_r = rope(q[..., dn:], c.rope_theta)
+            # k = [k_n ; k_r] with the one k_r for all heads: two products
+            # summed, the shared part never copied per head
+            att = (jnp.einsum("sqhd,skhd->shqk", q[..., :dn], kv[..., :dn])
+                   + jnp.einsum("sqhd,skd->shqk", q_r, k_r)
+                   ) * ((dn + dr) ** -0.5)
+            causal = jnp.tril(jnp.ones((T, T), bool))
+            att = jnp.where(causal, att, jnp.finfo(att.dtype).min)
+            att = jax.nn.softmax(att.astype(jnp.float32), axis=-1)
+            out = jnp.einsum("shqk,skhd->sqhd", att.astype(x.dtype),
+                             kv[..., dn:]).reshape(S, T, H * dv)
+        return out @ w_o
+
+
+class Block(nn.Module):
+    cfg: JoyAIConfig
+    dense: bool
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.cfg
+        h = x + MLA(c, name="attn")(RMSNorm(c.rms_norm_eps,
+                                            name="attn_norm")(x))
+        z = RMSNorm(c.rms_norm_eps, name="ffn_norm")(h)
+        if self.dense:
+            stats = {"local": jnp.zeros(x.shape[:-1], jnp.int32),
+                     "max_load": jnp.int32(0)}
+            return h + SwiGLU(c.intermediate_size, name="mlp")(z), stats
+        y, stats = RoutedMoE(
+            c.n_routed_experts, c.experts_held, c.expert_offset,
+            c.num_experts_per_tok, c.moe_intermediate_size,
+            c.routed_scaling_factor, operand_dtype=c.expert_operand_dtype,
+            name="moe")(z)
+        return h + y, stats
+
+
+class JoyAIFlash(nn.Module):
+    """``input_ids`` (S, T) -> logits (S, T, vocab_rows) and the routing
+    counts of the call: held pairs by sequence (``local``, (S,)), the pairs
+    routed to absent experts (``absent``, (S,)) and the sum over the expert
+    layers of the largest held expert's load (``max_load``)."""
+
+    cfg: JoyAIConfig
+
+    @nn.compact
+    def __call__(self, input_ids):
+        c = self.cfg
+        x = nn.Embed(c.vocab_rows, c.hidden_size, name="embed",
+                     embedding_init=nn.initializers.normal(0.02))(input_ids)
+        local = jnp.zeros(input_ids.shape[:1], jnp.int32)
+        max_load, n_moe = jnp.int32(0), 0
+        for i in range(c.layers):
+            dense = i < c.first_k_dense_replace
+            x, stats = nn.remat(Block)(c, dense, name=f"h{i}")(x)
+            local = local + jnp.sum(stats["local"], axis=-1)
+            max_load = max_load + stats["max_load"]
+            n_moe += not dense
+        x = RMSNorm(c.rms_norm_eps, name="norm_f")(x)
+        logits = x @ _kernel(self, "head", (c.hidden_size, c.vocab_rows))
+        absent = (n_moe * c.num_experts_per_tok * input_ids.shape[1]) - local
+        return logits, {"local": local, "absent": absent,
+                        "max_load": max_load}

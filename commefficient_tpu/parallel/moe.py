@@ -42,17 +42,34 @@ The Switch auxiliary load-balancing loss (E·Σ f·P) is sown into the
 ``losses.make_gpt2_losses`` when ``--moe_aux_coef`` > 0 (under dense
 dispatch imbalance is a routing-quality concern; under sparse dispatch it
 additionally controls the overflow-drop rate, so keep it on there).
+
+``RoutedMoE`` is the layer of the DeepSeek-V3 family (models/joyai.py):
+sigmoid scores, the ``top_k`` experts by ``score + router_bias``, gates
+renormalised over the selected and scaled, a shared expert beside the routed
+ones. It is *told which experts it holds* (``n_held`` from ``expert_offset``
+on): it routes over all ``n_routed`` and returns what its own experts give
+plus the shared expert; what absent experts would add is left out (one
+chip's share of an expert-parallel deployment, without the exchange). No
+token is dropped: ``routed_experts`` groups the (token, expert) pairs whose
+expert is held by expert, runs one grouped matrix product a projection
+(``jax.lax.ragged_dot``) and adds the gated rows back. Its shapes are
+static: a ladder of row counts from the token count doubled up to the
+worst case (every pair held), the smallest rung that holds the call's pairs
+chosen on the device (``lax.switch``); within a rung the grouped products
+skip the row tiles no pair fills, so the work follows the pairs that are
+there.
 """
 
 from __future__ import annotations
 
-from typing import Optional
+import functools
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-__all__ = ["MoEMLP", "ep_sliced_param"]
+__all__ = ["MoEMLP", "RoutedMoE", "routed_experts", "ep_sliced_param"]
 
 
 def ep_sliced_param(path: str) -> bool:
@@ -226,3 +243,219 @@ class MoEMLP(nn.Module):
         gate = jnp.sum(combine, axis=-1).reshape(N, 1)           # (N, 1)
         out = jnp.einsum("enp,epc->nc", d_loc, y) * gate
         return out.reshape(B, T, C)
+
+
+# -- routed experts, a share of them held here (no dropped token) -----------
+
+def _rows_by_expert(e_local, n_held: int, n_rows: int):
+    """The held (token, expert) pairs laid out in ``n_rows`` rows, grouped by
+    expert and in token order within one: ``(token, expert, valid)`` of every
+    row and the groups' sizes. ``e_local`` is (N, k): the local index of each
+    slot's expert, -1 where it is absent. No sort and no scatter: a row's
+    pair is found by bisection in the running count of an (expert, token)
+    mask. Rows past the last pair are not valid."""
+    n_tok = e_local.shape[0]
+    held = (e_local[None] == jnp.arange(n_held)[:, None, None]).any(-1)
+    sizes = jnp.sum(held, axis=1, dtype=jnp.int32)                 # (E,)
+    count = jnp.cumsum(held.reshape(-1).astype(jnp.int32))         # (E*N,)
+    flat = jnp.searchsorted(count, jnp.arange(1, n_rows + 1, dtype=jnp.int32),
+                            method="scan_unrolled")
+    valid = jnp.arange(n_rows) < count[-1]
+    flat = jnp.minimum(flat, n_held * n_tok - 1)
+    return flat % n_tok, flat // n_tok, valid, sizes
+
+
+def _grouped_dot(operand_dtype):
+    """``jax.lax.ragged_dot`` (rows (M, K) grouped by ``sizes`` times
+    (G, K, N)) with both multiplicands of every product, forward and
+    backward, in ``operand_dtype`` and float32 results: what the unit does to
+    a plain float32 product at the default precision. XLA:TPU does not do it
+    to a grouped product's float32 operands by itself and then runs it 2.4x
+    slower (PERF.md, PR 28). ``None`` leaves the operands alone."""
+    if operand_dtype is None:
+        return jax.lax.ragged_dot
+    from jax.lax import RaggedDotDimensionNumbers, ragged_dot_general
+
+    def rnd(a):
+        return a.astype(operand_dtype)
+
+    by_rows = RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((0,), (0,)), ((), ())),
+        lhs_ragged_dimensions=(0,), rhs_group_dimensions=())
+
+    @jax.custom_vjp
+    def dot(rows, w, sizes):
+        return jax.lax.ragged_dot(rnd(rows), rnd(w), sizes,
+                                  preferred_element_type=jnp.float32)
+
+    def fwd(rows, w, sizes):
+        return dot(rows, w, sizes), (rows, w, sizes)
+
+    def bwd(res, ct):
+        rows, w, sizes = res
+        d_rows = jax.lax.ragged_dot(rnd(ct), rnd(w).swapaxes(1, 2), sizes,
+                                    preferred_element_type=jnp.float32)
+        d_w = ragged_dot_general(rnd(rows), rnd(ct), sizes, by_rows,
+                                 preferred_element_type=jnp.float32)
+        return d_rows.astype(rows.dtype), d_w.astype(w.dtype), None
+
+    dot.defvjp(fwd, bwd)
+    return dot
+
+
+def _experts_on_rows(n_rows, x, gate, e_local, w_gate, w_up, w_down,
+                     operand_dtype=None):
+    """One rung of the ladder: the held pairs in ``n_rows`` rows through
+    their experts' SwiGLU, gated and summed back onto their tokens."""
+    with jax.named_scope("fed_moe_route"):
+        tok, exp, valid, sizes = _rows_by_expert(e_local, w_gate.shape[0],
+                                                 n_rows)
+        row_gate = jnp.sum(jnp.where(e_local[tok] == exp[:, None], gate[tok],
+                                     0.0), axis=-1)
+        # a grouped product leaves the rows past its last group unspecified
+        # (and so their cotangents): select on both sides, never multiply
+        rows = jnp.where(valid[:, None], x[tok], 0.0)
+    with jax.named_scope("fed_moe_experts"):
+        dot = _grouped_dot(operand_dtype)
+        h = jax.nn.silu(dot(rows, w_gate, sizes)) * dot(rows, w_up, sizes)
+        y = dot(h, w_down, sizes)
+    with jax.named_scope("fed_moe_route"):
+        y = jnp.where(valid[:, None], y, 0.0) \
+            * row_gate[:, None].astype(y.dtype)
+        return jnp.zeros_like(x).at[tok].add(y)
+
+
+def routed_experts(x, gate, e_local, w_gate, w_up, w_down,
+                   operand_dtype=None):
+    """``sum_s gate[t, s] * SwiGLU_{e_local[t, s]}(x[t])`` over the slots
+    whose expert is held (``e_local >= 0``): x (N, C), gate and e_local
+    (N, k), weights (E, C, F) / (E, F, C). Every pair is computed whatever
+    the imbalance. The row count is the smallest rung of a ladder of static
+    sizes that holds the pairs present: N rows (k * E_held / E_routed of N
+    are expected, so a balanced call has room to spare), doubled up to the
+    worst case N * min(k, E). With rungs below N the gathers' and
+    scatter-adds' rows made a round's time follow the seed's routing by
+    1.3 us a pair; from N up it does so by 0.4 (PERF.md, PR 28).
+    The backward pass picks its rung the same way and recomputes the rung's
+    forward inside it: nothing of a rung's size is kept between the passes,
+    and no rung pays for another's residuals. ``operand_dtype``: see
+    ``_grouped_dot``."""
+    n_tok, k = e_local.shape
+    worst = n_tok * min(k, w_gate.shape[0])
+    ladder = [min(n_tok, worst)]
+    while ladder[-1] < worst:
+        ladder.append(min(2 * ladder[-1], worst))
+
+    def rung(e_local):
+        pairs = jnp.sum(e_local >= 0)
+        return jnp.sum(pairs > jnp.asarray(ladder[:-1], jnp.int32)) \
+            if len(ladder) > 1 else jnp.int32(0)
+
+    @jax.custom_vjp
+    def run(x, gate, w_gate, w_up, w_down, e_local):
+        return jax.lax.switch(
+            rung(e_local),
+            [functools.partial(_experts_on_rows, m,
+                               operand_dtype=operand_dtype) for m in ladder],
+            x, gate, e_local, w_gate, w_up, w_down)
+
+    def fwd(*args):
+        return run(*args), args
+
+    def bwd(args, ct):
+        def back(m):
+            def pull(x, gate, w_gate, w_up, w_down, e_local, ct):
+                _, vjp = jax.vjp(
+                    lambda x, gate, *w: _experts_on_rows(
+                        m, x, gate, e_local, *w,
+                        operand_dtype=operand_dtype),
+                    x, gate, w_gate, w_up, w_down)
+                return vjp(ct)
+            return pull
+
+        return jax.lax.switch(rung(args[-1]), [back(m) for m in ladder],
+                              *args, ct) + (None,)
+
+    run.defvjp(fwd, bwd)
+    return run(x, gate, w_gate, w_up, w_down, e_local)
+
+
+class SwiGLU(nn.Module):
+    """``(silu(x W_gate) * x W_up) W_down``, no biases."""
+
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        init = nn.initializers.normal(0.02)
+        dense = functools.partial(nn.Dense, use_bias=False, kernel_init=init)
+        h = nn.silu(dense(self.width, name="gate")(x)) \
+            * dense(self.width, name="up")(x)
+        return dense(x.shape[-1], name="down")(h)
+
+
+class RoutedMoE(nn.Module):
+    """Sigmoid-scored top-k routed experts, ``n_held`` of ``n_routed`` held
+    here from ``expert_offset`` on, plus one shared expert (module
+    docstring). Returns ``(y, stats)``; ``stats`` are counts for telemetry
+    and carry no gradient: the held pairs of every token (``local``, shaped
+    like x without its last axis) and the largest held expert's load in this
+    call (``max_load``)."""
+
+    n_routed: int
+    n_held: int
+    expert_offset: int
+    top_k: int
+    width: int
+    scale: float
+    # the multiplicands' type in the grouped products (``_grouped_dot``);
+    # whoever builds the model knows the backend and the precision
+    operand_dtype: Optional[Any] = None
+
+    @nn.compact
+    def __call__(self, x):
+        assert 0 <= self.expert_offset \
+            and self.expert_offset + self.n_held <= self.n_routed, \
+            "the held experts must lie inside the routed ones"
+        C, F, E = x.shape[-1], self.width, self.n_held
+        init = nn.initializers.normal(0.02)
+        router = self.param("router", init, (C, self.n_routed))
+        # e_score_correction_bias: takes part in the selection only, so its
+        # gradient is nought; the loss-free balancing update is not run
+        bias = self.param("router_bias", nn.initializers.normal(0.01),
+                          (self.n_routed,))
+        w_gate = self.param("w_gate", init, (E, C, F))
+        w_up = self.param("w_up", init, (E, C, F))
+        w_down = self.param("w_down", init, (E, F, C))
+        xf = x.reshape(-1, C)
+        with jax.named_scope("fed_moe_route"):
+            # float32 products for the scores (six bf16 passes of the unit,
+            # 1/500 of the layer's work): the selection below is discrete,
+            # and a score rounded to bf16 picks other experts
+            score = jax.nn.sigmoid(jnp.dot(
+                xf.astype(jnp.float32), router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+            _, idx = jax.lax.top_k(score + bias, self.top_k)
+            # the selected scores by mask, not by a gather (a gather of 8
+            # of 256 a token and its scatter-add cost 11 ms a round on the
+            # v5e; the masked sum fuses)
+            chosen = jnp.sum(jnp.where(
+                idx[..., None] == jnp.arange(self.n_routed), score[:, None],
+                0.0), axis=-1)
+            # renormalised over all the selected, held here or not
+            gate = self.scale * chosen / jnp.sum(chosen, axis=-1,
+                                                 keepdims=True)
+            e_local = jnp.where(
+                (idx >= self.expert_offset)
+                & (idx < self.expert_offset + E),
+                idx - self.expert_offset, -1)
+        y = routed_experts(xf, gate.astype(x.dtype), e_local, w_gate, w_up,
+                           w_down, operand_dtype=self.operand_dtype)
+        y = y + SwiGLU(F, name="shared")(xf)
+        held = e_local >= 0
+        load = jnp.sum(
+            held[None] & (e_local[None] == jnp.arange(E)[:, None, None]),
+            axis=(1, 2))
+        stats = {"local": jnp.sum(held, axis=-1).reshape(x.shape[:-1]),
+                 "max_load": jnp.max(load)}
+        return y.reshape(x.shape), stats

@@ -46,7 +46,7 @@ import time
 import jax
 
 __all__ = ["annotate", "SPAN_TOTALS", "span_totals", "DEVICE_STAGES",
-           "KERNEL_NAMES", "SyncCounter", "host_sync_monitor",
+           "INNER_SCOPES", "KERNEL_NAMES", "SyncCounter", "host_sync_monitor",
            "materialize", "offpath_fetches", "Heartbeat", "RoundTracer",
            "parse_trace_rounds", "HEARTBEAT_RE", "parse_heartbeat"]
 
@@ -59,6 +59,12 @@ DEVICE_STAGES = ("fed_client_grad", "fed_client_compress",
                  "fed_server_estimate", "fed_server_topk",
                  "fed_server_resketch", "fed_server_apply",
                  "fed_telemetry_metrics", "fed_accounting", "fed_val")
+# Scopes a model opens INSIDE ``fed_client_grad`` (models/joyai.py,
+# parallel/moe.py): the expert layer's routing (scores, top-k, grouping,
+# gather and scatter of the held pairs), its grouped products, and the
+# latent attention's core. Not stages: an operation under one of them still
+# has ``fed_client_grad`` as its one stage.
+INNER_SCOPES = ("fed_moe_route", "fed_moe_experts", "fed_mla_attn")
 # ``name=`` of every pallas_call (ops/sketch.py, ops/topk.py). The sketch
 # kernels keep ``sketch`` / ``estimates`` / ``epilogue`` in theirs and the
 # top-k kernels do not: benchmark/metrics/sketch_kernel_roofline.py tells

@@ -834,7 +834,8 @@ class RunTelemetry:
                    loss: Optional[float] = None,
                    guard_ok: Optional[bool] = None,
                    cohort: Optional[Dict[str, Any]] = None,
-                   offload: Optional[Dict[str, Any]] = None) -> None:
+                   offload: Optional[Dict[str, Any]] = None,
+                   model: Optional[Dict[str, float]] = None) -> None:
         """Called by ``FedModel.finish_round`` with the drained (host)
         metric values; ``cohort`` carries the host-side participation/
         staleness summary (participants, slots, staleness_mean/max when
@@ -842,6 +843,8 @@ class RunTelemetry:
         async buffer record on the ``--async_buffer`` plane);
         ``offload`` the host-offload data-plane record (placement tier,
         gather/scatter ms, prefetch hit/miss — docs/host_offload.md).
+        ``model`` the round's sums of the loss's named metric sums (a
+        routed-expert model's pair counts, losses.MOE_METRIC_NAMES).
         ``metrics`` is None for async BUFFERED dispatches — the server
         phase (whose jitted vector the metrics are) runs only on folds."""
         span = self._spans.setdefault(round_no, {})
@@ -855,6 +858,8 @@ class RunTelemetry:
             span["cohort"] = cohort
         if offload:
             span["offload"] = offload
+        if model:
+            span["model"] = model
 
     def on_drained(self, round_no: int, span) -> None:
         """The round's batched drain finished (``span`` is its closed
@@ -879,7 +884,8 @@ class RunTelemetry:
             rec["window_wait_ms"] = round(buf["window_wait_ms"], 3)
             rec["compute_ms"] = round(buf["compute_ms"], 3)
         rec["drain_fetch_ms"] = round(span.ms, 3)
-        for key in ("loss", "guard_ok", "cohort", "offload", "metrics"):
+        for key in ("loss", "guard_ok", "cohort", "offload", "model",
+                    "metrics"):
             if key in buf:
                 rec[key] = buf[key]
         self._f.write(json.dumps(_json_safe(rec), allow_nan=False) + "\n")

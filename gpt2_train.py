@@ -10,6 +10,7 @@ locally, else training starts from scratch (zero-egress environment).
 
 from __future__ import annotations
 
+import dataclasses
 import math
 import os
 
@@ -43,7 +44,10 @@ from commefficient_tpu.federated.checkpoint import (
     save_round_state,
 )
 from commefficient_tpu.telemetry import attach_run_telemetry
-from commefficient_tpu.federated.losses import make_gpt2_losses
+from commefficient_tpu.federated.losses import (
+    make_causal_lm_losses,
+    make_gpt2_losses,
+)
 from commefficient_tpu.federated.participation import (
     attach_churn,
     attach_participation,
@@ -53,12 +57,14 @@ from commefficient_tpu.models.gpt2 import (
     load_hf_gpt2,
     resize_token_embeddings,
 )
+from commefficient_tpu.models.joyai import JoyAIConfig, JoyAIFlash
 from commefficient_tpu.utils import (
     PiecewiseLinear,
     TableLogger,
     Timer,
     announce_devices,
     configure_compile_cache,
+    is_tpu_backend,
     make_logdir,
 )
 from cv_train import union
@@ -140,7 +146,9 @@ def run_batches(model, opt, lr_scheduler, loader, args, timer, training,
                 return
             interval = timer()
             for res in results:
-                loss, download, upload = res.values
+                # a loss's named metric sums ride between the loss and the
+                # byte counts; the event log keeps them (telemetry "model")
+                loss, *_, download, upload = res.values
                 client_download += download
                 client_upload += upload
                 loss = float(np.mean(loss))
@@ -274,6 +282,32 @@ def train_gpt2(model, opt, scheduler, train_loader, val_loader, args,
     return test_gpt2(model, val_loader, args, timer=timer, writer=writer)
 
 
+def build_joyai(args, tiny):
+    """JoyAI-LLM-Flash and its causal-LM losses, cut as the flags say: the
+    layers held, this chip's experts of --layer_chips that share a layer,
+    the vocabulary's rows (models/joyai.py)."""
+    full = JoyAIConfig.tiny() if tiny else JoyAIConfig()
+    assert full.n_routed_experts % args.layer_chips == 0, \
+        f"--layer_chips must divide {full.n_routed_experts} routed experts"
+    # the unit rounds a float32 product's multiplicands to bfloat16 at the
+    # default precision; XLA:TPU does not do so to a grouped product, so the
+    # expert layer is told to (parallel/moe.py _grouped_dot)
+    rounds = (is_tpu_backend()
+              and jax.config.jax_default_matmul_precision is None)
+    cfg = dataclasses.replace(
+        full, expert_operand_dtype=jnp.bfloat16 if rounds else None,
+        layers=args.arch_layers or full.layers,
+        experts_held=full.n_routed_experts // args.layer_chips,
+        expert_offset=args.expert_offset,
+        vocab_rows=args.vocab_rows or max(full.vocab_rows,
+                                          args.len_tokenizer))
+    assert args.len_tokenizer <= cfg.vocab_rows, (
+        f"the tokenizer's {args.len_tokenizer} ids do not fit the "
+        f"{cfg.vocab_rows} rows of the vocabulary held")
+    model = JoyAIFlash(cfg)
+    return (model,) + make_causal_lm_losses(model)
+
+
 def train(argv=None):
     from commefficient_tpu.parallel.mesh import maybe_init_distributed
 
@@ -359,7 +393,10 @@ def train(argv=None):
             geometry["expert_axis"] = "expert"
 
     # model geometry: tiny when smoke-testing or using the byte fallback
-    if args.do_test or os.environ.get("COMMEFFICIENT_TINY_MODEL"):
+    tiny = args.do_test or os.environ.get("COMMEFFICIENT_TINY_MODEL")
+    if args.arch == "joyai_llm_flash":
+        model, compute_loss_train, compute_loss_val = build_joyai(args, tiny)
+    elif tiny:
         # COMMEFFICIENT_TINY_LAYERS: tests exercising layer-pattern
         # constraints (e.g. MoE pipeline stage alignment) need more depth
         model = GPT2DoubleHeads(vocab_size=max(512, args.len_tokenizer),
@@ -384,7 +421,7 @@ def train(argv=None):
         ne = mesh.shape["expert"]  # realized size, possibly reduced
         assert args.n_experts % ne == 0, \
             f"--expert_devices (realized {ne}) must divide --n_experts"
-    if pp:
+    if args.arch == "gpt2" and pp:
         # pipeline parallelism (--pipeline_devices): the loss callbacks
         # carry the GPipe schedule (parallel/pipeline.py); the model object
         # itself stays the plain dense one
@@ -398,7 +435,7 @@ def train(argv=None):
             lm_coef=args.lm_coef, mc_coef=args.mc_coef,
             compute_dtype=jnp.bfloat16 if args.do_bf16 else None,
             moe_aux_coef=args.moe_aux_coef if args.n_experts else 0.0)
-    else:
+    elif args.arch == "gpt2":
         compute_loss_train, compute_loss_val = make_gpt2_losses(
             model, args.lm_coef, args.mc_coef,
             seq_axis="seq" if sp else None,
@@ -436,12 +473,18 @@ def train(argv=None):
         init_model = init_model.copy(model_axis=None)
     if ep:
         init_model = init_model.copy(expert_axis=None)
-    variables = init_model.init(jax.random.key(args.seed), x0["input_ids"],
-                                token_type_ids=x0["input_ids"],
-                                mc_token_ids=jnp.zeros((1, args.num_candidates),
-                                                       jnp.int32), train=False)
+    if args.arch == "gpt2":
+        variables = init_model.init(
+            jax.random.key(args.seed), x0["input_ids"],
+            token_type_ids=x0["input_ids"],
+            mc_token_ids=jnp.zeros((1, args.num_candidates), jnp.int32),
+            train=False)
+    else:
+        variables = jax.jit(init_model.init)(jax.random.key(args.seed),
+                                             x0["input_ids"][0])
     init_params = variables["params"]
-    pretrained = load_hf_gpt2(init_params, args.model_checkpoint)
+    pretrained = (load_hf_gpt2(init_params, args.model_checkpoint)
+                  if args.arch == "gpt2" else None)
     if pretrained is not None:
         init_params = resize_token_embeddings(pretrained, args.len_tokenizer)
         print("loaded local pretrained GPT-2 weights")
@@ -462,9 +505,14 @@ def train(argv=None):
 
     args.num_results_train = 1
     args.num_results_val = 2
+    # hand the seed's weights over and keep no name on them: from here on
+    # they live in fed_model's flat vector alone, and a caller that puts its
+    # own weights in their place (the benchmark) does not hold both trees
+    handover = [init_params]
+    del variables, init_params, pretrained
     fed_model = FedModel(model, compute_loss_train, args, compute_loss_val,
                          num_clients=train_loader.dataset.num_clients,
-                         init_params=init_params, mesh=mesh)
+                         init_params=handover.pop(), mesh=mesh)
     opt = FedOptimizer(fed_model, args)
     spe = train_loader.steps_per_epoch()
     print("Steps per epoch", spe)

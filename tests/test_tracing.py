@@ -194,6 +194,44 @@ class TestDeviceStages:
             assert "fed_accounting" in low.as_text(debug_info=True)
 
 
+class TestInnerScopes:
+    def test_inner_scopes_nest_under_one_stage(self):
+        """A model's own scopes (``INNER_SCOPES``: the expert layer's
+        routing and grouped products, the latent attention's core) are no
+        stages: an operation under one of them, forward, recomputed or
+        backward, still carries ``fed_client_grad`` as its one stage, so
+        ``stage_of`` finds one stage an operation."""
+        from commefficient_tpu.federated.losses import make_causal_lm_losses
+        from commefficient_tpu.models.joyai import JoyAIConfig, JoyAIFlash
+        from commefficient_tpu.profiling import INNER_SCOPES
+
+        assert not set(INNER_SCOPES) & set(DEVICE_STAGES)
+        model = JoyAIFlash(JoyAIConfig.tiny(layers=2, experts_held=4,
+                                            expert_offset=0, vocab_rows=64))
+        ids = jnp.zeros((2, 1, 8), jnp.int32)
+        params = model.init(jax.random.key(0), ids[:, 0])["params"]
+        train, _ = make_causal_lm_losses(model)
+        batch = {"input_ids": ids, "lm_labels": ids,
+                 "mask": jnp.ones(2, jnp.float32)}
+
+        @jax.jit
+        def step(p):
+            with jax.named_scope("fed_client_grad"):
+                return jax.grad(
+                    lambda p: train(p, {}, batch, None, True)[0])(p)
+
+        names = set(re.findall(r'op_name="([^"]*)"',
+                               step.lower(params).compile().as_text()))
+        for inner in INNER_SCOPES:
+            under = [n for n in names if inner in n]
+            assert under, f"no operation under {inner}"
+            assert any("transpose(" in n for n in under), \
+                f"no backward operation under {inner}"
+            # (a custom_vjp's backward repeats the stack: the one stage twice)
+            assert all(set(_stages_in(n)) == {"fed_client_grad"}
+                       for n in under), under[:3]
+
+
 def _pallas_names(jaxpr, out):
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
