@@ -13,7 +13,9 @@ without position and a 64-wide part with; ``[c_kv; k_r] = x W_kva``;
 ``c_kv = N(c_kv)``; ``[k_n; v] = c_kv W_kvb`` per head; RoPE (theta 32e6,
 interleaved pairs, no scaling) on each head's ``q_r`` and on the one ``k_r``
 all heads share; causal ``softmax(q k^T / sqrt(192)) v``; the heads
-concatenated through ``W_o``. No biases anywhere.
+concatenated through ``W_o``. No biases anywhere. The core (scores, mask,
+softmax, values) lives in ops/attention.py, which also says on which path a
+call runs.
 
 What is held here is a cut the caller names (``JoyAIConfig``): how many
 layers, which of the routed experts (parallel/moe.py ``RoutedMoE``: the
@@ -31,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from commefficient_tpu.ops.attention import mla_attention
 from commefficient_tpu.parallel.moe import RoutedMoE, SwiGLU
 
 __all__ = ["JoyAIFlash", "JoyAIConfig"]
@@ -125,16 +128,7 @@ class MLA(nn.Module):
         w_o = _kernel(self, "o", (H * dv, C))
         with jax.named_scope("fed_mla_attn"):
             q_r = rope(q[..., dn:], c.rope_theta)
-            # k = [k_n ; k_r] with the one k_r for all heads: two products
-            # summed, the shared part never copied per head
-            att = (jnp.einsum("sqhd,skhd->shqk", q[..., :dn], kv[..., :dn])
-                   + jnp.einsum("sqhd,skd->shqk", q_r, k_r)
-                   ) * ((dn + dr) ** -0.5)
-            causal = jnp.tril(jnp.ones((T, T), bool))
-            att = jnp.where(causal, att, jnp.finfo(att.dtype).min)
-            att = jax.nn.softmax(att.astype(jnp.float32), axis=-1)
-            out = jnp.einsum("shqk,skhd->sqhd", att.astype(x.dtype),
-                             kv[..., dn:]).reshape(S, T, H * dv)
+            out = mla_attention(q, q_r, kv, k_r).reshape(S, T, H * dv)
         return out @ w_o
 
 

@@ -62,15 +62,18 @@ DEVICE_STAGES = ("fed_client_grad", "fed_client_compress",
 # Scopes a model opens INSIDE ``fed_client_grad`` (models/joyai.py,
 # parallel/moe.py): the expert layer's routing (scores, top-k, grouping,
 # gather and scatter of the held pairs), its grouped products, and the
-# latent attention's core. Not stages: an operation under one of them still
+# latent attention's core (ops/attention.py opens it again around its
+# backward pass). Not stages: an operation under one of them still
 # has ``fed_client_grad`` as its one stage.
 INNER_SCOPES = ("fed_moe_route", "fed_moe_experts", "fed_mla_attn")
-# ``name=`` of every pallas_call (ops/sketch.py, ops/topk.py). The sketch
-# kernels keep ``sketch`` / ``estimates`` / ``epilogue`` in theirs and the
-# top-k kernels do not: benchmark/metrics/sketch_kernel_roofline.py tells
-# them apart by those words.
+# ``name=`` of every pallas_call (ops/sketch.py, ops/topk.py,
+# ops/attention.py). The sketch kernels keep ``sketch`` / ``estimates`` /
+# ``epilogue`` in theirs and the top-k and attention kernels do not:
+# benchmark/metrics/sketch_kernel_roofline.py tells them apart by those
+# words.
 KERNEL_NAMES = ("fed_sketch_vec", "fed_sketch_accum", "fed_estimates",
-                "fed_epilogue", "fed_topk_count", "fed_topk_descent")
+                "fed_epilogue", "fed_topk_count", "fed_topk_descent",
+                "fed_mla_attn_fwd", "fed_mla_attn_bwd")
 
 
 # THE heartbeat line format, one producer (Heartbeat.round) and one parser

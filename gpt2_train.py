@@ -58,6 +58,7 @@ from commefficient_tpu.models.gpt2 import (
     resize_token_embeddings,
 )
 from commefficient_tpu.models.joyai import JoyAIConfig, JoyAIFlash
+from commefficient_tpu.ops.attention import PATH_CALLS
 from commefficient_tpu.utils import (
     PiecewiseLinear,
     TableLogger,
@@ -104,6 +105,24 @@ def get_data_loaders(args, tokenizer, emit_shifted=False):
 def _wrap(collate):
     # FedLoader hands items as tuples of the post-client-id columns
     return lambda items: collate(items)
+
+
+def report_attention_core(model):
+    """Which path the latent attention's core was traced on in this process
+    and how many calls took it (ops/attention.py ``PATH_CALLS``): printed,
+    and a ``model`` event in the run's log. Said once a run, when its first
+    round has been dispatched, so the round's own programs are among the
+    traces counted, not the initialisation's alone."""
+    if getattr(model, "attention_core_reported", False):
+        return
+    model.attention_core_reported = True
+    attn_path = "fused" if PATH_CALLS["fused"] else "einsum"
+    print(f"attention core: {attn_path} path, "
+          f"{PATH_CALLS[attn_path]} calls traced (ops/attention.py)")
+    rt = getattr(model, "telemetry", None)
+    if rt is not None:
+        rt.event("model", attn_path=attn_path,
+                 attn_calls=PATH_CALLS[attn_path])
 
 
 def run_batches(model, opt, lr_scheduler, loader, args, timer, training,
@@ -177,6 +196,8 @@ def run_batches(model, opt, lr_scheduler, loader, args, timer, training,
             if i0 + batch_idx > spe * epoch_fraction:
                 break
             done = engine.submit(batch)
+            if engine.rounds_submitted == 1 and args.arch == "joyai_llm_flash":
+                report_attention_core(model)
             # the scheduler stepped inside submit(); record this round's
             # batch index and LR so its drained row logs what it ran with
             meta_by_round[engine.rounds_submitted - 1] = (

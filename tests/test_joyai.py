@@ -162,19 +162,12 @@ def test_over_clients_equals_per_client():
         assert float(metrics[1][w]) == float(m1[1])
 
 
-def test_mla_matches_per_head_attention_with_explicit_rope_pairs():
-    """(b): MLA against attention written out head by head, the rotary
-    pairs turned one by one."""
-    cfg = JoyAIConfig.tiny(**CUT)
-    S, C = 2, cfg.hidden_size
-    H, dn, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
-                     cfg.qk_rope_head_dim, cfg.v_head_dim)
-    x = jax.random.normal(jax.random.key(1), (S, T, C))
-    mla = MLA(cfg)
-    p = mla.init(jax.random.key(2), x)["params"]
-    p = jax.tree_util.tree_map(
-        lambda a: a + 0.1 * jax.random.normal(jax.random.key(3), a.shape), p)
-    got = mla.apply({"params": p}, x)
+def per_head_attention(cfg, p, x):
+    """MLA written out head by head, the rotary pairs turned one by one."""
+    S, T = x.shape[:2]
+    H, dn, dr = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                 cfg.qk_rope_head_dim)
+    dv = cfg.v_head_dim
 
     def norm(v, scale):
         return v / jnp.sqrt(jnp.mean(v * v, -1, keepdims=True)
@@ -208,7 +201,26 @@ def test_mla_matches_per_head_attention_with_explicit_rope_pairs():
             att = jnp.where(jnp.tril(jnp.ones((T, T), bool)), att, -jnp.inf)
             heads.append(jax.nn.softmax(att, axis=-1) @ kv[:, h, dn:])
         want.append(jnp.concatenate(heads, axis=-1) @ p["o"])
-    np.testing.assert_allclose(np.asarray(got), np.asarray(jnp.stack(want)),
+    return jnp.stack(want)
+
+
+def mla_case(cfg, S, T):
+    """Seeded input, perturbed weights and the module's output."""
+    x = jax.random.normal(jax.random.key(1), (S, T, cfg.hidden_size))
+    mla = MLA(cfg)
+    p = mla.init(jax.random.key(2), x)["params"]
+    p = jax.tree_util.tree_map(
+        lambda a: a + 0.1 * jax.random.normal(jax.random.key(3), a.shape), p)
+    return x, p, mla.apply({"params": p}, x)
+
+
+def test_mla_matches_per_head_attention_with_explicit_rope_pairs():
+    """(b): MLA against attention written out head by head, the rotary
+    pairs turned one by one."""
+    cfg = JoyAIConfig.tiny(**CUT)
+    x, p, got = mla_case(cfg, 2, T)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(per_head_attention(cfg, p, x)),
                                rtol=2e-4, atol=2e-5)
 
 
@@ -558,8 +570,11 @@ def test_entry_point_trains_through_the_normal_path(mode, monkeypatch,
     data: FedModel / PipelinedRoundEngine / telemetry / validation, one
     short epoch (sketch and uncompressed run in the rounds test above)."""
     import gpt2_train
+    from commefficient_tpu.ops.attention import PATH_CALLS
     from commefficient_tpu.telemetry import read_events
 
+    for path in PATH_CALLS:         # the process's count, from this run on
+        monkeypatch.setitem(PATH_CALLS, path, 0)
     env = dict(SIZED, COMMEFFICIENT_SYNTHETIC_CLIENTS="8",
                COMMEFFICIENT_SYNTHETIC_WORDS="200",
                COMMEFFICIENT_SYNTHETIC_SENTENCE="2-3",
@@ -577,6 +592,10 @@ def test_entry_point_trains_through_the_normal_path(mode, monkeypatch,
         "32", "--local_momentum", "0", "--num_epochs", "1", "--seed", "3",
         "--train_dataloader_workers", "0", "--val_dataloader_workers", "0"])
     assert np.isfinite(stats["val_nll"]) and stats["val_ppl"] > 1.0
-    rounds = [e for e in read_events(str(tmp_path / "run" / "telemetry.jsonl"))
-              if e["ev"] == "round"]
+    events = list(read_events(str(tmp_path / "run" / "telemetry.jsonl")))
+    rounds = [e for e in events if e["ev"] == "round"]
     assert len(rounds) == 4 and all("model" in e for e in rounds)
+    # which attention core ran, said once the first round was traced: more
+    # calls than the initialisation's one a layer
+    (said,) = [e for e in events if e["ev"] == "model"]
+    assert said["attn_path"] == "einsum" and said["attn_calls"] > 2

@@ -27,7 +27,7 @@ from commefficient_tpu.federated.rounds import (
 )
 from commefficient_tpu.federated.server import ServerConfig, init_server_state
 from commefficient_tpu.federated.worker import WorkerConfig
-from commefficient_tpu.ops import sketch as sketch_ops
+from commefficient_tpu.ops import attention, sketch as sketch_ops
 from commefficient_tpu.ops.flat import ravel_pytree
 from commefficient_tpu.parallel.mesh import default_client_mesh
 from commefficient_tpu.telemetry import log_magnitude_histogram
@@ -143,3 +143,41 @@ def test_histogram_is_one_pass_on_v5e():
     _, compiled = _compile_tpu(jax.jit(log_magnitude_histogram), x)
     assert not re.search(r"\bscatter\(", compiled.as_text())
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+@pytest.mark.parametrize("S,T", [(8, 512), (4, attention.MAX_FUSED_T)],
+                         ids=["cell4", "longest"])
+def test_attention_kernels_compile_for_v5e(S, T, backward):
+    """The latent attention's fused core (ops/attention.py) at
+    ``joyai_flash_sketch_1c``'s shape (8 sequences x 512 positions x 32
+    heads of 128 + 64 / 128) and at the longest sequence the path chooser
+    sends to the kernels: Mosaic takes the forward kernel and the backward
+    kernel (their blocks fit VMEM), and nothing of the scores' size (268 MB
+    in float32 in the cell) is left in HBM around them."""
+    H = 32
+    one = SingleDeviceSharding(_v5e_devices()[0])
+    # the projections' outputs: 3-D, viewed (S, T, H, d) without a copy
+    q, q_r, kv, k_r, d_out = (
+        jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+        for shape in ((S, T, H * 192), (S, T, H * 64), (S, T, H * 256),
+                      (S, T, 64), (S, T, H * 128)))
+
+    def fwd(q, q_r, kv, k_r):
+        heads = (lambda x: x.reshape(S, T, H, -1))
+        return attention.mla_attention_fused(
+            heads(q), heads(q_r), heads(kv), k_r).reshape(S, T, -1)
+
+    def bwd(q, q_r, kv, k_r, d_out):
+        return jax.vjp(fwd, q, q_r, kv, k_r)[1](d_out)
+
+    _, compiled = _compile_tpu(jax.jit(bwd if backward else fwd),
+                               *((q, q_r, kv, k_r)
+                                 + ((d_out,) if backward else ())))
+    text = compiled.as_text()
+    assert "fed_mla_attn_fwd" in text
+    assert ("fed_mla_attn_bwd" in text) == backward
+    # nothing of the scores' size, and no copy of an operand either: the
+    # kernels read q and kv as they are
+    assert compiled.memory_analysis().temp_size_in_bytes < S * T * H * 192 * 4

@@ -247,6 +247,7 @@ class TestKernelNames:
     def test_pallas_call_carries_its_name(self, kernel):
         import importlib
 
+        from commefficient_tpu.ops import attention as at
         from commefficient_tpu.ops import sketch as sk
 
         # (``ops.topk`` the attribute is the function, not the module)
@@ -259,6 +260,8 @@ class TestKernelNames:
         kw = dict(S=S, T=T, interpret=True)
         hashes = (cs.shift_q, cs.shift_w, cs.sign_keys, sk._T0)
         raw = jnp.zeros((4, 8, 128), jnp.int32)
+        qkv = [jnp.zeros((1, 128) + w) for w in (
+            (2, 192), (2, 64), (2, 256), (64,))]
         calls = {
             "fed_sketch_vec": lambda: sk._sketch_vec_pallas(v3, *hashes,
                                                             **kw),
@@ -273,17 +276,24 @@ class TestKernelNames:
                 raw, jnp.zeros(16, jnp.int32), T=4, sub=8, interpret=True),
             "fed_topk_descent": lambda: tk._descent_pallas(
                 raw, jnp.ones(1, jnp.int32), T=4, sub=8, interpret=True),
+            "fed_mla_attn_fwd": lambda: at.mla_attention_fused(
+                *qkv, interpret=True),
+            # the backward pass alone, on residuals (output, log-sum-exp)
+            "fed_mla_attn_bwd": lambda: at._fused_bwd(
+                jnp.float32, True,
+                (*qkv, jnp.zeros((1, 128, 2, 128)), jnp.zeros((1, 1, 2, 128))),
+                jnp.zeros((1, 128, 2, 128))),
         }
         names = _pallas_names(jax.make_jaxpr(calls[kernel])().jaxpr, [])
         assert names == [kernel]
 
     def test_sketch_words_tell_the_kernels_apart(self):
         """benchmark/metrics/sketch_kernel_roofline.py finds the sketch's
-        kernels by these words in the operation's head; the top-k kernels
-        must not match."""
+        kernels by these words in the operation's head; the top-k and the
+        attention kernels must not match."""
         word = re.compile(r"sketch|estimates|epilogue")
         assert [bool(word.search(k)) for k in KERNEL_NAMES] \
-            == [True, True, True, True, False, False]
+            == [True, True, True, True, False, False, False, False]
 
 
 def _engine(tmp_path, mode="sketch", window=2, drain_every=4, tracer=None):
