@@ -591,8 +591,8 @@ def build_parser(default_lr=None) -> argparse.ArgumentParser:
     # silently-lying torn write, --inject_io_fault flip/storn) becomes a
     # detected, counted, repaired-or-quarantined event. Verification
     # only reads, so the clean-path fp32 trajectory is bit-identical
-    # checksums on/off (tests/test_integrity.py); overhead gate <= 2%
-    # rounds/sec (bench.py --run-cfg integrity).
+    # checksums on/off (tests/test_integrity.py); overhead on the chip
+    # not measured (no cell runs the disk tier).
     parser.add_argument("--io_checksums", action="store_true",
                         dest="io_checksums", default=True,
                         help="Per-row CRC32 verification of the disk-"

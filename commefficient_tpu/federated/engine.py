@@ -1,6 +1,6 @@
 """Pipelined round engine: host-sync-free steady-state federated rounds.
 
-The GPT-2 per-op profile (docs/measurements/tpu_profile_gpt2.md) measured
+A GPT-2 per-op profile (v5e, 2026-08-01, capture since deleted) measured
 337 ms wall per round against 69 ms of device-busy time — ~80% of every
 round was host dispatch and blocking scalar drains, because the reference
 loop shape (cv_train.py / gpt2_train.py)
@@ -35,7 +35,7 @@ fetched values. This engine restructures the loop around that fact:
 The zero-syncs-per-round invariant is auditable: wrap the submit loop in
 ``profiling.host_sync_monitor`` and assert ``counter.count == 0`` (the
 engine's own drains go through the counted ``profiling.materialize``
-seam). ``bench.py`` reports the measured count per round.
+seam). tests/test_engine.py holds it to zero.
 """
 
 from __future__ import annotations

@@ -396,7 +396,7 @@ class IOFaultSchedule:
     the flip count + row index, NOT an extra RNG draw, so the one-draw-
     per-op stream is untouched). An all-zero schedule is legal on
     purpose: it is the "injection compiled in but idle" overhead probe
-    the bench leg measures."""
+    (not measured on the chip)."""
 
     eio: float = 0.0
     short: float = 0.0

@@ -215,8 +215,8 @@ class RoundConfig:
     # produces it, the seq/model/pp/expert psums ride the small table
     # (sketch linearity), and weight decay folds in as one extra
     # segment-sketch of the resident chunked weights. Kills the client
-    # phase's d-sized concatenate/pad/reshape movement (the 22.6% category
-    # of docs/measurements/tpu_profile_gpt2.md) and shrinks the scan carry
+    # phase's d-sized concatenate/pad/reshape movement (22.6% of device
+    # time in a v5e profile of 2026-08-01) and shrinks the scan carry
     # from O(d) to O(table). Requires the fused-gradient + sketch-after-sum
     # + chunked-resident window; silently composed elsewhere (and under
     # the COMMEFFICIENT_STREAM_SKETCH=0 kill-switch), mirroring the
@@ -367,7 +367,7 @@ def build_round_step(
     # sketch_chunks/estimates_chunks consume and produce PS state directly
     # and the per-round flat↔chunk conversions (the pad/reshape/concatenate
     # data movement measured at ~7 ms/round busy on GPT-2,
-    # docs/measurements/tpu_profile_gpt2.md) drop out of the steady state.
+    # v5e, 2026-08-01, capture deleted) drop out of the steady state.
     # The flat view materializes only inside `unravel_res` at the model
     # (pytree) boundary. topk-down is excluded: its stale-weight
     # reconstruction math lives on (num_clients, d) dense rows.

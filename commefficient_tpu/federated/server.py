@@ -250,7 +250,7 @@ def place_server_state(state: ServerState, mesh, mode: str,
                        server_shard: bool, put=None,
                        axis=None) -> ServerState:
     """THE sharded-server residency rule, in one place (callers: FedModel,
-    bench.py, the multichip dry-run): sketch tables replicated (they are
+    the multichip dry-run): sketch tables replicated (they are
     the already-small transmit), dense velocity/error dim-0-sharded over
     the worker axis, the qres/dres carries always dim-0-sharded. Committing
     fresh state to these shardings up front keeps round 1 on the jit
@@ -712,7 +712,7 @@ def _sketched(sketched_grad, state, cfg, lr, sketch: CountSketch,
         # formulation (unsketch → flat update → sketch_vec) flattened the
         # estimate chunks and then re-padded the SAME flat plane for the
         # re-sketch — the twin d-sized pad/reshape pairs of the GPT-2
-        # profile (~3.1 ms/round, docs/measurements/tpu_profile_gpt2.md).
+        # profile (~3.1 ms/round, v5e, 2026-08-01, capture since deleted).
         # Thresholding the chunked estimates in place and re-sketching the
         # chunked update keeps the one flat materialization at the return
         # boundary; values are identical (pure layout + the same

@@ -826,7 +826,7 @@ def autotune_collective_plan(leg_geoms, error_budget: float = 0.05,
     error 0) the CHEAPEST by ``payload_bytes`` wins, ties broken by lower
     error. Probe timings are reported, not gated — wall-clock per
     candidate is microseconds and the quantize cost rides the round step
-    the bench A/B legs already measure.
+    itself (not measured on the chip: no cell runs a quantized plan).
 
     Returns ``(plan, report)`` where ``report[leg][dtype]`` carries
     ``{"rel_err", "probe_ms", "bytes_per_round"}`` (plus ``"error"`` for

@@ -10,7 +10,7 @@ unravels under jit, where XLA turns the reshape/slice into free views.
 ``ChunkLayout`` is the **chunked resident layout** for sketch-mode rounds:
 the lane-aligned ``(T, S, 128)`` chunk/sublane/lane shape the count-sketch
 kernels consume (ops/sketch.py). The GPT-2 per-op profile
-(docs/measurements/tpu_profile_gpt2.md) showed ~7 ms/round of pure layout
+(v5e, 2026-08-01, capture since deleted) showed ~7 ms/round of pure layout
 churn converting the d=124M flat vector to and from this shape
 (``pad.6``/``reshape.950``/``reshape.2197``) plus the flat ravel concat
 (``concatenate.35``); keeping PS state resident in the chunked shape

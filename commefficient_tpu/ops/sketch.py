@@ -1060,7 +1060,7 @@ def unsketch(cs: CountSketch, table: jax.Array, k: int) -> jax.Array:
     reference fed_aggregator.py:590).
 
     Routed through ONE shared ``(T, S, 128)`` view: the GPT-2 profile
-    (docs/measurements/tpu_profile_gpt2.md) showed the flat formulation —
+    (v5e, 2026-08-01, capture since deleted) showed the flat formulation —
     flatten the estimates, threshold flat, re-pad the flat update for the
     re-sketch — paying twin d-sized ``pad``/``reshape`` pairs
     (~3.1 ms/round) for the SAME plane; thresholding the chunked

@@ -49,16 +49,16 @@ _LANES = 128
 _SUB = 512              # count-kernel block: (512, 128) i32 = 256 KiB VMEM
 
 
-# Measured crossover (scripts/tpu_measure.py ops, v5e, 2026-08-01): the
+# Measured crossover (v5e, 2026-08-01, by a script since deleted): the
 # Pallas count-pass descent wins 37x at the FetchSGD geometry
 # (d=6,568,640: 0.30 ms vs 11.10 ms XLA, outputs bit-equal) but LOSES at
 # the GPT-2 geometry (d=124,444,417: 16.15 ms vs 14.57 ms) — above ~100M
 # the kernel's fixed (512, 128) blocking stops tracking HBM streams (1,900
 # block boundaries per pass leave no pipelining slack). Gate between the
-# two measured points, nearer the win. The blocking is now d-adaptive
-# (``_sub_for``) so the kernel stays armed above the gate for the re-run
-# A/B (scripts/tpu_measure.py topk_ab) to flip; the gate itself only moves
-# on a committed on-chip measurement.
+# two measured points, nearer the win; a cell on each side of it: cell 1
+# below, the GPT-2 and JoyAI cells above. The blocking is d-adaptive
+# (``_sub_for``), never re-measured above the gate; the gate itself only
+# moves on a ledger line (ROADMAP Queue 1 item 13).
 _PALLAS_TOPK_MAX_D = 32 * 1024 * 1024
 
 
@@ -338,8 +338,8 @@ def check_fused_descent_kernel(d: int, k: int,
 def _select_threshold_impl(d: int):
     """Pick the threshold-descent implementation for this geometry.
 
-    The fused whole-descent kernel is default OFF until the on-chip A/B
-    (scripts/tpu_measure.py topk_ab) proves it beats the per-pass kernel —
+    The fused whole-descent kernel is default OFF: never measured on the
+    chip (ROADMAP D2), so nothing says it beats the per-pass kernel —
     the same gate-then-flip playbook as the count-pass kernel. The opt-in
     flag deliberately bypasses the d ≤ 32M crossover gate: the fused
     kernel's large-d blocking is exactly what the A/B needs to test at

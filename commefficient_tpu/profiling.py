@@ -21,7 +21,7 @@ every name with its reader.
 ``host_sync_monitor`` is the pipelined round engine's audit hook
 (federated/engine.py, docs/round_engine.md): it counts blocking
 device→host materializations so the steady-state zero-syncs-per-round
-invariant is assertable in tests and visible in bench output.
+invariant is assertable in tests.
 ``jax.transfer_guard`` is the natural tool but is inert on the CPU backend
 the test suite runs on (measured — "disallow" lets both array and scalar
 fetches through), and ``np.asarray`` on a CPU-backed ``jax.Array`` reads

@@ -3,7 +3,7 @@ event log, and round-lifecycle spans (docs/observability.md).
 
 The engine pipelines, shards, fuses, and quarantines rounds (PRs 1-5), but
 until this module the only windows into a *running* federation were offline
-XLA profile captures and whatever bench.py prints — guard verdicts,
+XLA profile captures and whatever a timing script printed — guard verdicts,
 error-feedback carry norms, compression behavior, and per-collective wire
 bytes were invisible at runtime. That is exactly the gap the FL
 practicality survey (arXiv:2405.20431) flags for real deployments with

@@ -25,6 +25,7 @@ __all__ = [
     "TSVLogger",
     "Timer",
     "make_logdir",
+    "union",
     "is_tpu_backend",
     "configure_compile_cache",
     "announce_devices",
@@ -189,6 +190,14 @@ class Timer:
         if include_in_total:
             self.total_time += dt
         return dt
+
+
+def union(*dicts) -> dict:
+    """One dict of all of them, later ones winning."""
+    out = {}
+    for d in dicts:
+        out.update(d)
+    return out
 
 
 def make_logdir(args) -> str:

@@ -34,23 +34,21 @@ from commefficient_tpu.federated import (
     FedOptimizer,
     LambdaLR,
     PipelinedRoundEngine,
-    cohort_lookahead,
 )
 from commefficient_tpu.federated.checkpoint import (
     load_checkpoint,
     load_matching,
     maybe_save_run_state,
     restore_mid_epoch,
-    resume_run,
     save_checkpoint,
-    save_round_state,
 )
 from commefficient_tpu.federated.losses import make_cv_losses
-from commefficient_tpu.federated.participation import (
-    attach_churn,
-    attach_participation,
+from commefficient_tpu.federated.run import (
+    attach_planes,
+    close_run,
+    population_emptied,
+    run_rounds,
 )
-from commefficient_tpu.telemetry import attach_run_telemetry
 from commefficient_tpu.ops.flat import ravel_pytree
 from commefficient_tpu.utils import (
     PiecewiseLinear,
@@ -59,14 +57,8 @@ from commefficient_tpu.utils import (
     announce_devices,
     configure_compile_cache,
     make_logdir,
+    union,
 )
-
-
-def union(*dicts):
-    out = {}
-    for d in dicts:
-        out.update(d)
-    return out
 
 
 def get_data_loaders(args):
@@ -124,12 +116,6 @@ def run_batches(model, opt, lr_scheduler, loader, training, epoch_fraction,
                                    client_upload)
         losses.extend(np.asarray(ex.get("losses", [])).tolist())
         accs.extend(np.asarray(ex.get("accs", [])).tolist())
-        # Pipelined round engine (federated/engine.py): each loop iteration
-        # dispatches a round without blocking on its results; metrics are
-        # fetched in batches of --metrics_drain_every. The NaN abort
-        # therefore fires at drain time, up to drain_every-1 rounds after
-        # the NaN round — same abort, batched detection
-        # (docs/round_engine.md).
         # the engine owns the liveness heartbeat (global telemetry round
         # index, scripts/crash_matrix.py) and the telemetry spans (the
         # recorder attached to the model by main)
@@ -137,86 +123,33 @@ def run_batches(model, opt, lr_scheduler, loader, training, epoch_fraction,
             model, opt, lr_scheduler,
             window=getattr(args, "round_window", 2),
             drain_every=getattr(args, "metrics_drain_every", 8))
-        nan_loss = False
-        save_every = int(getattr(args, "checkpoint_every_rounds", 0) or 0)
-        # watch plane (telemetry.WatchEngine, docs/observability.md): the
-        # checkpoint reaction is serviced HERE — the engine drains and the
-        # entrypoint owns save_round_state, mirroring the save_every path
-        watch = getattr(getattr(model, "telemetry", None), "watch", None)
 
         def consume(results):
-            nonlocal nan_loss, client_download, client_upload
+            """Fold drained rounds in; true on a NaN loss (the abort)."""
+            nonlocal client_download, client_upload
             for res in results:
                 loss, acc, download, upload = res.values
                 if np.any(np.isnan(loss)):
                     print(f"LOSS OF {np.mean(loss)} IS NAN, "
                           "TERMINATING TRAINING")
-                    nan_loss = True
-                    return
+                    return True
                 client_download += download
                 client_upload += upload
                 losses.extend(loss.tolist())
                 accs.extend(acc.tolist())
+            return False
 
-        # cohort_lookahead peeks batch t+1 AFTER round t submits and
-        # hands its client_ids to the host-offload prefetcher — the
-        # next round's row gather overlaps this round's device compute
-        # (no-op without row streaming; docs/host_offload.md)
-        for i, batch in enumerate(cohort_lookahead(loader, model)):
-            if i0 + i > spe * epoch_fraction:
-                break
-            consume(engine.submit(batch))
-            if nan_loss:
-                return np.nan, np.nan, np.nan, np.nan
-            do_save = bool(save_every
-                           and (i0 + i + 1) % save_every == 0)
-            forced = False
-            if watch is not None and watch.pop_checkpoint():
-                # the watch checkpoint reaction: force a run-state
-                # save at this round boundary (a resumable save needs
-                # the no-prefetch-thread constraint, like
-                # --checkpoint_every_rounds — validate_args noted it)
-                if args.train_dataloader_workers == 0:
-                    do_save = forced = True
-                else:
-                    print("watch: checkpoint reaction skipped (needs "
-                          "--train_dataloader_workers 0 for a "
-                          "resumable save)")
-            if do_save:
-                # drain the in-flight window first: the saved sampler /
-                # RNG position must describe exactly the rounds whose
-                # state AND metrics are folded into the checkpoint
-                consume(engine.drain())
-                if nan_loss:
-                    return np.nan, np.nan, np.nan, np.nan
-                save_round_state(
-                    args, epoch, i0 + i + 1, loader.sampler.get_state(),
-                    model, opt, lr_scheduler, totals,
-                    extras={"download": client_download,
+        finished = run_rounds(
+            engine, loader, args, epoch=epoch, i0=i0, spe=spe,
+            epoch_fraction=epoch_fraction, totals=totals, consume=consume,
+            extras=lambda: {"download": client_download,
                             "upload": client_upload,
                             "losses": np.asarray(losses, np.float64),
-                            "accs": np.asarray(accs, np.float64)})
-                if getattr(model, "telemetry", None) is not None:
-                    # `round` is the GLOBAL round_no the round/guard
-                    # events share (the window just drained, so the
-                    # last dispatched round is the last covered);
-                    # the epoch-local save position rides separately
-                    model.telemetry.event(
-                        "checkpoint", epoch=epoch,
-                        round=model.rounds_dispatched - 1,
-                        round_in_epoch=i0 + i + 1,
-                        **({"forced_by_watch": True} if forced
-                           else {}))
-            if args.do_test:
-                break
-        consume(engine.drain())
-        if nan_loss:
+                            "accs": np.asarray(accs, np.float64)},
+            stop_after_first=args.do_test)
+        if not finished:
             return np.nan, np.nan, np.nan, np.nan
-        if not losses and getattr(model, "_population", None) is not None:
-            # open-world end state (--churn, docs/service.md): the live
-            # population emptied before this epoch produced a single
-            # cohort and no joiner can ever refill it — a clean end of
-            # training, not a NaN trajectory
+        if population_emptied(model, losses):
             return None, None, client_download, client_upload
         return (np.mean(losses), np.mean(accs), client_download,
                 client_upload)
@@ -446,17 +379,6 @@ def main(argv=None):
                          init_params=init_params, model_state=model_state)
     param_groups = build_param_groups(args, fed_model.params)
     opt = FedOptimizer(fed_model, args, param_groups=param_groups)
-    # straggler-/dropout-tolerant participation layer (--participation /
-    # --inject_client_fault, docs/fault_tolerance.md): partial cohorts
-    # through the sampler, seeded client faults, late landing
-    pc = attach_participation(args, fed_model,
-                              sampler=getattr(train_loader, "sampler",
-                                              None))
-    # open-world population churn (--churn, docs/service.md): clients
-    # register/depart mid-run; the sampler draws from the live population
-    # and the disk-tier row store allocates/retires/compacts rows
-    pm = attach_churn(args, fed_model,
-                      sampler=getattr(train_loader, "sampler", None))
 
     lr_schedule = PiecewiseLinear([0, args.pivot_epoch, args.num_epochs],
                                   [0, args.lr_scale, 0])
@@ -480,15 +402,9 @@ def main(argv=None):
             writer = SummaryWriter(log_dir=log_dir)
         except ImportError:
             print("tensorboard unavailable; console logging only")
-    # zero-sync telemetry plane (--telemetry, on by default): per-round
-    # device metrics + the structured run event log under the run dir
-    # (docs/observability.md; render with scripts/obs_report.py)
-    rt = attach_run_telemetry(args, fed_model, log_dir, "cv_train")
-    start_epoch, totals, resume_mid = resume_run(args, fed_model, opt,
-                                                 lr_scheduler)
-    if rt is not None and (start_epoch or resume_mid is not None):
-        rt.event("resume", start_epoch=start_epoch,
-                 mid_epoch=resume_mid is not None)
+    planes, start_epoch, totals, resume_mid = attach_planes(
+        args, fed_model, opt, lr_scheduler, train_loader, log_dir,
+        "cv_train")
     print(f"Finished initializing in {timer():.2f} seconds")
 
     try:
@@ -497,63 +413,7 @@ def main(argv=None):
                         timer=timer, start_epoch=start_epoch, totals=totals,
                         resume_mid=resume_mid)
     finally:
-        if pc is not None:
-            # end-of-run expiry audit (owned HERE, not engine.close() —
-            # cohorts legally land across engine instances): stragglers
-            # whose due round will never dispatch AND async contributions
-            # that landed but never reached a K-fold are counted, never
-            # silent (the obs_report participation/async sections and the
-            # run log both carry the numbers; tests/test_async.py pins
-            # the conservation count)
-            expired = pc.expire_pending()
-            if expired and rt is not None:
-                rt.event("straggler_expired", count=expired)
-            a_expired = pc.expire_buffer() if pc.async_k else 0
-            if a_expired and rt is not None:
-                rt.event("async_expired", count=a_expired)
-        if pm is not None:
-            # open-world conservation audit (docs/service.md): every
-            # client that ever registered is exactly one of active /
-            # departed / quarantined — cross-checked against the live
-            # mask AND the running counters, recorded so the whole churn
-            # story reproduces from the JSONL log alone
-            audit = pm.audit()
-            if rt is not None:
-                # churn records drawn after the last dispatched round
-                # (e.g. the departure that emptied the pool) have no
-                # begin_round left to relay them — flush here so the
-                # event totals match the audit's counters
-                for ev in pm.pop_events():
-                    rt.event(ev.pop("kind"), **ev)
-                rt.event("churn_audit", **audit)
-            if not audit["ok"]:
-                print(f"CHURN AUDIT FAILED: {audit}")
-        tracer = getattr(fed_model, "tracer", None)
-        if tracer is not None:
-            # a capture window left open at run end stops here; its
-            # (partial) record still lands in the event log
-            cap = tracer.close()
-            if cap is not None and rt is not None:
-                rt.event("trace_captured", **cap)
-        store = getattr(fed_model, "_row_store", None)
-        if store is not None and rt is not None:
-            if store.fatal_error is not None:
-                # the storage-fault terminal rung
-                # (docs/fault_tolerance.md §storage faults): the one
-                # actionable error, recorded so the whole ladder
-                # reproduces from the JSONL log alone
-                rt.event("io_fatal", error=str(store.fatal_error))
-            # run-total I/O + integrity counters (incl. the realized
-            # injected-fault counts) — the last word the log needs for
-            # the detected-vs-injected silent-corruption audit
-            rt.event("io_counters", **store.io_counters())
-        if rt is not None:
-            rt.close()
-        # EVERY exit path — including the storage-fault terminal rung —
-        # drains and joins the row store's I/O worker (bounded;
-        # MemmapRowStore.close reports instead of abandoning a daemon
-        # thread mid-write)
-        fed_model.finalize()
+        close_run(planes)
     if args.do_checkpoint:
         os.makedirs(args.checkpoint_path, exist_ok=True)
         save_checkpoint(os.path.join(args.checkpoint_path, args.model),

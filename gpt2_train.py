@@ -33,24 +33,22 @@ from commefficient_tpu.federated import (
     FedOptimizer,
     LambdaLR,
     PipelinedRoundEngine,
-    cohort_lookahead,
 )
 from commefficient_tpu.federated.checkpoint import (
     load_checkpoint,
     load_matching,
     maybe_save_run_state,
     restore_mid_epoch,
-    resume_run,
-    save_round_state,
 )
-from commefficient_tpu.telemetry import attach_run_telemetry
 from commefficient_tpu.federated.losses import (
     make_causal_lm_losses,
     make_gpt2_losses,
 )
-from commefficient_tpu.federated.participation import (
-    attach_churn,
-    attach_participation,
+from commefficient_tpu.federated.run import (
+    attach_planes,
+    close_run,
+    population_emptied,
+    run_rounds,
 )
 from commefficient_tpu.models.gpt2 import (
     GPT2DoubleHeads,
@@ -67,8 +65,8 @@ from commefficient_tpu.utils import (
     configure_compile_cache,
     is_tpu_backend,
     make_logdir,
+    union,
 )
-from cv_train import union
 
 
 def get_data_loaders(args, tokenizer, emit_shifted=False):
@@ -135,17 +133,12 @@ def run_batches(model, opt, lr_scheduler, loader, args, timer, training,
         client_download = np.zeros(num_clients)
         client_upload = np.zeros(num_clients)
         losses = []
-        # round-granular resume (docs/fault_tolerance.md): same contract as
-        # cv_train.run_batches — sampler position replayed, partial epoch
-        # accumulators reloaded, loop indices offset by the rounds done
+        # round-granular resume (docs/fault_tolerance.md): sampler position
+        # replayed, partial epoch accumulators reloaded, loop indices offset
+        # by the rounds done
         i0, ex = restore_mid_epoch(resume_mid, loader, client_download,
                                    client_upload)
         losses.extend(np.asarray(ex.get("losses", [])).tolist())
-        save_every = int(getattr(args, "checkpoint_every_rounds", 0) or 0)
-        # watch plane (telemetry.WatchEngine, docs/observability.md): the
-        # checkpoint reaction is serviced at round boundaries, mirroring
-        # the save_every path (cv_train.run_batches precedent)
-        watch = getattr(getattr(model, "telemetry", None), "watch", None)
         # Pipelined round engine (federated/engine.py): rounds are
         # dispatched sync-free and metrics arrive in batches of
         # --metrics_drain_every, so logger rows are appended at drain time.
@@ -185,64 +178,23 @@ def run_batches(model, opt, lr_scheduler, loader, args, timer, training,
                         union({"batch_idx": row_batch_idx, "lr": row_lr},
                               batch_stats))
 
-        # cohort_lookahead peeks batch t+1 AFTER round t submits and
-        # hands its client_ids to the host-offload prefetcher — the
-        # next round's row gather overlaps this round's device compute
-        # (no-op without row streaming; docs/host_offload.md)
-        for batch_idx, batch in enumerate(cohort_lookahead(loader,
-                                                           model)):
-            if batch_idx > 2 and args.do_test and batch_idx < spe - 10:
-                continue
-            if i0 + batch_idx > spe * epoch_fraction:
-                break
-            done = engine.submit(batch)
+        def submitted(rounds_done):
             if engine.rounds_submitted == 1 and args.arch == "joyai_llm_flash":
                 report_attention_core(model)
             # the scheduler stepped inside submit(); record this round's
             # batch index and LR so its drained row logs what it ran with
             meta_by_round[engine.rounds_submitted - 1] = (
-                i0 + batch_idx + 1, lr_scheduler.get_last_lr()[0])
-            consume(done)
-            do_save = bool(save_every
-                           and (i0 + batch_idx + 1) % save_every == 0)
-            forced = False
-            if watch is not None and watch.pop_checkpoint():
-                # watch checkpoint reaction: force a run-state save
-                # at this round boundary (resumable only without a
-                # prefetch thread — same constraint as save_every)
-                if args.train_dataloader_workers == 0:
-                    do_save = forced = True
-                else:
-                    print("watch: checkpoint reaction skipped (needs "
-                          "--train_dataloader_workers 0 for a "
-                          "resumable save)")
-            if do_save:
-                # drain the in-flight window so the saved sampler/RNG
-                # position matches the rounds folded into the state
-                consume(engine.drain())
-                save_round_state(
-                    args, epoch or 0, i0 + batch_idx + 1,
-                    loader.sampler.get_state(), model, opt,
-                    lr_scheduler, totals,
-                    extras={"download": client_download,
+                rounds_done, lr_scheduler.get_last_lr()[0])
+
+        run_rounds(
+            engine, loader, args, epoch=epoch or 0, i0=i0, spe=spe,
+            epoch_fraction=epoch_fraction, totals=totals, consume=consume,
+            extras=lambda: {"download": client_download,
                             "upload": client_upload,
-                            "losses": np.asarray(losses, np.float64)})
-                if getattr(model, "telemetry", None) is not None:
-                    # `round` is the GLOBAL round_no the round/guard
-                    # events share (the window just drained); the
-                    # epoch-local save position rides separately
-                    model.telemetry.event(
-                        "checkpoint", epoch=epoch or 0,
-                        round=model.rounds_dispatched - 1,
-                        round_in_epoch=i0 + batch_idx + 1,
-                        **({"forced_by_watch": True} if forced
-                           else {}))
-        consume(engine.drain())
-        if not losses and getattr(model, "_population", None) is not None:
-            # open-world end state (--churn, docs/service.md): the live
-            # population emptied before this epoch produced a single
-            # cohort and no joiner can ever refill it — a clean end of
-            # training, not a NaN trajectory
+                            "losses": np.asarray(losses, np.float64)},
+            skip=(lambda i: 2 < i < spe - 10) if args.do_test else None,
+            submitted=submitted)
+        if population_emptied(model, losses):
             return None, client_download, client_upload
         return np.mean(losses), client_download, client_upload
 
@@ -343,7 +295,7 @@ def train(argv=None):
     if args.stream_sketch:
         # the GPT-2 client phase is where the streaming sketch pays off:
         # the d=124M flat-gradient concat/pad/convert churn was 22.6% of
-        # device busy time (docs/measurements/tpu_profile_gpt2.md)
+        # device busy time (v5e profile of 2026-08-01, capture deleted)
         print("stream-sketch client phase requested: gradients stream "
               "leaf-by-leaf into the count-sketch table "
               "(docs/stream_sketch.md; COMMEFFICIENT_STREAM_SKETCH=0 "
@@ -548,25 +500,9 @@ def train(argv=None):
         stats = test_gpt2(fed_model, val_loader, args, logger=TableLogger(),
                           timer=timer)
     else:
-        # straggler-/dropout-tolerant participation layer
-        # (--participation / --inject_client_fault,
-        # docs/fault_tolerance.md): partial cohorts through the sampler,
-        # seeded client faults, staleness-weighted late landing
-        pc = attach_participation(args, fed_model,
-                                  sampler=getattr(train_loader, "sampler",
-                                                  None))
-        # open-world population churn (--churn, docs/service.md)
-        pm = attach_churn(args, fed_model,
-                          sampler=getattr(train_loader, "sampler", None))
-        # zero-sync telemetry plane (--telemetry, on by default): per-round
-        # device metrics + the structured run event log under log_dir
-        # (docs/observability.md; render with scripts/obs_report.py)
-        rt = attach_run_telemetry(args, fed_model, log_dir, "gpt2_train")
-        start_epoch, totals, resume_mid = resume_run(args, fed_model, opt,
-                                                     scheduler)
-        if rt is not None and (start_epoch or resume_mid is not None):
-            rt.event("resume", start_epoch=start_epoch,
-                     mid_epoch=resume_mid is not None)
+        planes, start_epoch, totals, resume_mid = attach_planes(
+            args, fed_model, opt, scheduler, train_loader, log_dir,
+            "gpt2_train")
         try:
             stats = train_gpt2(fed_model, opt, scheduler, train_loader,
                                val_loader, args, log_dir,
@@ -574,57 +510,7 @@ def train(argv=None):
                                start_epoch=start_epoch, totals=totals,
                                resume_mid=resume_mid)
         finally:
-            if pc is not None:
-                # end-of-run expiry audit (owned HERE, not engine.close()
-                # — cohorts legally land across engine instances):
-                # stragglers whose due round will never dispatch AND
-                # async contributions that landed but never reached a
-                # K-fold are counted, never silent (obs_report's
-                # participation/async sections)
-                expired = pc.expire_pending()
-                if expired and rt is not None:
-                    rt.event("straggler_expired", count=expired)
-                a_expired = pc.expire_buffer() if pc.async_k else 0
-                if a_expired and rt is not None:
-                    rt.event("async_expired", count=a_expired)
-            if pm is not None:
-                # open-world conservation audit (docs/service.md):
-                # registered == active + departed + quarantined, from
-                # the masks AND the counters, in the JSONL run log
-                audit = pm.audit()
-                if rt is not None:
-                    # flush churn records drawn after the last dispatched
-                    # round (no begin_round left to relay them), so the
-                    # event totals match the audit's counters
-                    for ev in pm.pop_events():
-                        rt.event(ev.pop("kind"), **ev)
-                    rt.event("churn_audit", **audit)
-                if not audit["ok"]:
-                    print(f"CHURN AUDIT FAILED: {audit}")
-            tracer = getattr(fed_model, "tracer", None)
-            if tracer is not None:
-                # a capture window left open at run end stops here; its
-                # (partial) record still lands in the event log
-                cap = tracer.close()
-                if cap is not None and rt is not None:
-                    rt.event("trace_captured", **cap)
-            store = getattr(fed_model, "_row_store", None)
-            if store is not None and rt is not None:
-                if store.fatal_error is not None:
-                    # the storage-fault terminal rung: the one
-                    # actionable error, recorded so the ladder
-                    # reproduces from the log alone
-                    # (docs/fault_tolerance.md §storage faults)
-                    rt.event("io_fatal", error=str(store.fatal_error))
-                # run-total I/O + integrity counters (incl. realized
-                # injected-fault counts) for the detected-vs-injected
-                # silent-corruption audit from the JSONL alone
-                rt.event("io_counters", **store.io_counters())
-            if rt is not None:
-                rt.close()
-            # EVERY exit path — including the storage-fault terminal
-            # rung — drains and joins the row store's I/O worker
-            fed_model.finalize()
+            close_run(planes)
     if args.do_finetune:
         fed_model.finalize()
     return stats

@@ -126,7 +126,7 @@
 #     finished + gave_up + in_flight) rendered from the log alone by
 #     obs_report --fleet (tests/test_packing.py — the real packed-vs-
 #     sequential cv_train drill with bit-identity is its @slow
-#     TestPackingBench leg / bench.py --run-cfg packing);
+#     TestPackingBench leg);
 #   - the always-on service plane (docs/service.md): the --churn grammar
 #     + RowDirectory lifecycle (allocate/retire/compact with hole reuse
 #     as fresh zero state), the seeded PopulationManager trajectory
@@ -139,8 +139,8 @@
 #     ServingReplica request plane, and the obs_report Churn/Serving
 #     sections rebuilt from the JSONL alone (tests/test_service.py — the
 #     disk-tier churn e2e with mid-churn SIGKILL/resume bit-identity and
-#     the serving-interference bench leg are its @slow TestServiceE2E
-#     legs / bench.py --run-cfg serving).
+#     the live-replica bit-identity leg are its @slow TestServiceE2E
+#     legs).
 # Any extra args are passed through to pytest (e.g. -k bit_identical).
 set -euo pipefail
 cd "$(dirname "$0")/.."

@@ -23,7 +23,7 @@ CPU-mesh fallback (still allocates the full 35 GB in host RAM):
     HOST_OFFLOAD_CPU=1 python scripts/host_offload_fullscale.py
 Smoke mode for the suite harness: HOST_OFFLOAD_TINY=1
 
-Writes docs/measurements/host_offload_fullscale.json.
+Prints one JSON line; it writes no file.
 """
 
 from __future__ import annotations
@@ -74,10 +74,6 @@ ROUNDS = int(os.environ.get("HOST_OFFLOAD_ROUNDS", "6"))
 if TINY:
     NUM_CLIENTS, D, ROWS, COLS, ROUNDS = 48, 9973, 3, 1024, 3
 
-OUT = os.path.join(_REPO, "docs", "measurements",
-                   "host_offload_fullscale.json")
-
-
 def rss_gb() -> float:
     return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024 ** 2
 
@@ -104,8 +100,7 @@ def main() -> int:
     if platform == "cpu" and "COMMEFFICIENT_STATE_HBM_BUDGET" not in os.environ:
         os.environ["COMMEFFICIENT_STATE_HBM_BUDGET"] = "1"
     # this script drives the HOST (in-RAM streaming) tier specifically —
-    # the disk tier has its own legs (bench clients_sweep /
-    # tpu_measure host_offload_scale, docs/host_offload.md) — so pin the
+    # the disk tier is a different placement (docs/host_offload.md) — so pin the
     # host budget above the 35 GB total or a small-RAM host would resolve
     # "disk" and allocate nothing in RAM at all
     plan = plan_client_state_memory(n, D, wcfg, sketch=sketch, mesh=mesh,
@@ -188,13 +183,6 @@ def main() -> int:
         "rss_gb": round(rss_gb(), 2),
         "measured_at": time.strftime("%Y-%m-%d %H:%M:%S"),
     }
-    if not TINY:
-        # the canonical artifact path is reserved for the real TPU run;
-        # the CPU fallback records next to it without clobbering
-        out = OUT if platform != "cpu" else OUT.replace(".json", "_cpu.json")
-        with open(out, "w") as f:
-            json.dump(result, f, indent=1)
-        print(f"[offload] wrote {out}", flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -19,7 +19,7 @@ with ``--telemetry``, the default), print
 
 The LAST line of output is always one machine-readable JSON object
 (``summary_dict``) so bench/CI can consume the numbers without parsing
-prose — same contract as bench.py's one-JSON-line stdout. The tail
+prose. The tail
 carries ``alerts`` (count + worst watch rule) and the schema-v3
 histogram summaries so CI can gate on them without parsing the report
 body.

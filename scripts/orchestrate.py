@@ -33,8 +33,8 @@ tenant runs onto one machine/chip:
   exclusive slot until its first heartbeat (compile done, cache entries
   written) — only then are further tenants admitted, so they compile
   *warm* instead of racing the cold compile N times. This is where the
-  packed-fleet speedup comes from even on a single core (bench.py
-  ``--run-cfg packing`` gates on it); ``--no-warm-admission`` disables.
+  packed-fleet speedup comes from even on a single core (not measured
+  on the chip); ``--no-warm-admission`` disables.
 - **Fair-share interleave.** Admission is bounded (``--max-concurrent``)
   and least-progress-first (heartbeat count, ties by tenant id — the
   admission order is deterministic). Optionally ``--max-lead R``
@@ -103,7 +103,7 @@ def orchestrate(tenants, *, fleet_dir: str, labels=None,
     (module docstring); returns 0 iff every tenant finished, else 1.
     ``tenants`` is a list of argv lists; ``max_concurrent`` 0 means all
     at once (after the warm-admission gate). Programmatic entry for
-    tests and bench.py ``--run-cfg packing``."""
+    tests."""
     out = out if out is not None else sys.stdout
     n = len(tenants)
     if n == 0:

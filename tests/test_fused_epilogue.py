@@ -255,8 +255,8 @@ class TestCountKernelLargeD:
     """ops/topk.py's adaptive blocking: above _PALLAS_TOPK_MAX_D the
     kernels switch to 4x larger (1 MiB) blocks. Both the per-pass count
     kernel and the fused whole-descent kernel must still bit-equal the XLA
-    descent there — the exact path the armed d=124M A/B
-    (scripts/tpu_measure.py topk_ab) measures on-chip."""
+    descent there — the path a d=124M round would take with the gate
+    lifted (never measured on the chip; ROADMAP D2)."""
 
     def test_bit_equal_above_gate(self):
         from commefficient_tpu.ops.topk import (

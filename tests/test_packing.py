@@ -24,14 +24,13 @@ Pins:
   SIGSTOPped until the straggler catches up, then resumed, and both
   still finish;
 - the shared-cache speedup smoke (@heavy): the second identical jax
-  tenant observes a non-empty compile cache at startup — the mechanism
-  the bench packing leg's wall-clock gate rests on.
+  tenant observes a non-empty compile cache at startup.
 
 The unit tests drive the orchestrator over FAKE tenants (tiny scripted
 python children, no jax) so they stay tier-1-fast, per the
 test_supervise.py precedent; the real 3-tenant cv_train packed-vs-
 sequential drill with bit-identity is the @slow ``TestPackingBench``
-leg (bench.py ``--run-cfg packing``).
+leg.
 """
 
 from __future__ import annotations
@@ -399,16 +398,46 @@ def test_second_tenant_compiles_warm(tmp_path, monkeypatch):
 
 @pytest.mark.slow
 class TestPackingBench:
-    def test_packed_speedup_and_bit_identity(self, tmp_path):
-        """The bench leg end-to-end at reduced scale: 2 tiny cv_train
-        tenants packed vs sequential — aggregate wall-clock speedup
-        gated in-leg, per-tenant final fp32 weights bit-identical to
-        the solo baselines."""
-        sys.path.insert(0, os.path.join(
-            os.path.dirname(__file__), ".."))
-        import bench
+    def test_packed_bit_identity(self, tmp_path, monkeypatch):
+        """2 tiny cv_train tenants (the crash_matrix geometry, told apart
+        by seed) run solo, each on its own fresh cache, then packed under
+        the orchestrator on one shared fresh cache with warm admission:
+        every tenant's final fp32 weights are bit-identical to its solo
+        run. Packing may change when a tenant compiles, never what it
+        computes."""
+        cm = _load_script("crash_matrix")
+        orch = _load_script("orchestrate")
+        data = str(tmp_path / "data")
+        os.makedirs(data)
 
-        out = bench.run_packing_measurement(
-            n_tenants=2, workdir=str(tmp_path), gate=1.05)
-        assert out["packing_bit_identical"] is True
-        assert out["packing_speedup"] >= 1.05
+        def tenant_argv(i, ckpt):
+            return cm.train_argv(data, ckpt, shard=False) + [
+                "--num_epochs", "1", "--seed", str(i)]  # last flag wins
+
+        for i in range(2):
+            cache = tmp_path / f"solo{i}" / "cache"
+            cache.mkdir(parents=True)
+            cm.run_to_completion(
+                tenant_argv(i, str(tmp_path / f"solo{i}" / "ckpt")),
+                timeout=1800,
+                env_extra={"JAX_COMPILATION_CACHE_DIR": str(cache)})
+        # the orchestrator spawns from ITS process env: the solo runs'
+        # sanitized child env, and no cache of the caller's
+        for k, v in cm.child_env().items():
+            monkeypatch.setenv(k, v)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        fleet = str(tmp_path / "fleet")
+        rc = orch.orchestrate(
+            [tenant_argv(i, os.path.join(fleet, f"t{i}", "ckpt"))
+             for i in range(2)],
+            fleet_dir=fleet, max_concurrent=min(2, os.cpu_count() or 1),
+            warm_admission=True, share_cache=True,
+            heartbeat_timeout=600.0, startup_grace=1800.0,
+            # a restart would absorb a crash: a tenant that dies fails
+            max_restarts=0, poll=0.05, out=open(os.devnull, "w"))
+        assert rc == 0, f"packed fleet degraded (rc {rc}): see {fleet}"
+        for i in range(2):
+            cm.assert_identical(
+                cm.final_weights(str(tmp_path / f"solo{i}" / "ckpt")),
+                cm.final_weights(os.path.join(fleet, f"t{i}", "ckpt")),
+                f"packed tenant {i} (seed {i}) vs its solo run")

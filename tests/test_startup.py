@@ -114,7 +114,13 @@ class TestNoChipNoNumber:
         assert proc.returncode != 0
         assert _json_lines(proc.stdout) == []
 
-    def test_bench_without_a_tpu(self):
-        proc = _run([sys.executable, "bench.py"], _REPO, JAX_PLATFORMS="cpu")
+    def test_benchmark_without_a_tpu(self):
+        """The yardstick gives no CPU number: a cell run without its chip
+        (and without --rehearse) ends non-zero and prints no result, inside
+        _run's own 120 s limit."""
+        proc = _run([sys.executable, "benchmark/run.py", "--workload",
+                     "resnet9_sketch_1c", "--seed", "1", "--seconds", "1"],
+                    _REPO, JAX_PLATFORMS="cpu")
         assert proc.returncode != 0
-        assert proc.stdout.strip() == ""
+        assert _json_lines(proc.stdout) == []
+        assert "needs 1 TPU chip" in proc.stdout + proc.stderr

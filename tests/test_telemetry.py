@@ -18,9 +18,8 @@ Pins the four contracts of the telemetry PR:
   is reproducible from the JSONL log ALONE (scripts/obs_report.py), and
   its machine-readable tail parses.
 
-Plus the satellite contracts: the engine-owned heartbeat carries the
-global telemetry round index, and profile_diff parses the per-round
-counter registry table generically.
+Plus the satellite contract: the engine-owned heartbeat carries the
+global telemetry round index.
 """
 
 import json
@@ -430,55 +429,3 @@ class TestObsReport:
         assert tail["tripped_rounds"] == [2, 4]
         assert "guard TRIP at round 2" in out
         assert "guard TRIP at round 4" in out
-
-
-class TestProfileDiffCounters:
-    _CAPTURE = """# Per-op profile: test
-
-Wall clock: **3.00 ms/round**. Trace plane `p` line `l`, device busy time
-2.00 ms/round (20.0 ms total).
-
-## By category
-
-| category | spans | total ms | ms/round | % busy |
-|---|---|---|---|---|
-| convolution (MXU) | 100 | 10.00 | {conv} | 50.0% |
-| server epilogue (d-plane sweeps) | 120 | 4.00 | 0.400 | 20.0% |
-
-## Per-round counters
-
-| counter | category | ops/round | ms/round | gate (profile_diff --preset) | doc |
-|---|---|---|---|---|---|
-| epilogue_sweeps | server epilogue (d-plane sweeps) | {ep} | 0.400 | fused-epilogue | docs/fused_epilogue.md |
-| client_movement | client flatten/movement (d-sized) | 5.0 | 0.100 | stream-sketch | docs/stream_sketch.md |
-| transmit_collectives | reduce (transmit collectives) | 2.0 | 0.050 | sharded-server | docs/sharded_server.md |
-"""
-
-    def test_counters_parse_and_diff_as_one_table(self, tmp_path, capsys):
-        import profile_diff
-
-        before = tmp_path / "before.md"
-        after = tmp_path / "after.md"
-        before.write_text(self._CAPTURE.format(conv="1.000", ep="12.0"))
-        after.write_text(self._CAPTURE.format(conv="1.000", ep="1.0"))
-        a = profile_diff.parse_capture(str(before))
-        assert a.counters == {"epilogue_sweeps": (12.0, 0.4),
-                              "client_movement": (5.0, 0.1),
-                              "transmit_collectives": (2.0, 0.05)}
-        rc = profile_diff.main([str(before), str(after)])
-        out = capsys.readouterr().out
-        assert rc == 0
-        assert "| counter (ops/round) | before | after | delta |" in out
-        assert "| epilogue_sweeps | 12.0 | 1.0 |" in out
-
-    def test_legacy_prose_counters_parse(self, tmp_path):
-        import profile_diff
-
-        legacy = (self._CAPTURE.format(conv="1.000", ep="12.0")
-                  .split("## Per-round counters")[0]
-                  + "\nServer epilogue d-plane sweeps: **8.0 ops/round** "
-                    "(0.300 ms/round) — the sweep counter.\n")
-        p = tmp_path / "legacy.md"
-        p.write_text(legacy)
-        cap = profile_diff.parse_capture(str(p))
-        assert cap.counters == {"epilogue_sweeps": (8.0, 0.3)}

@@ -77,7 +77,7 @@ def test_resnet9_sketched_round_compiles_for_v5e(kernels_on, n_devices,
                                                  server_shard):
     """The FetchSGD headline round (d=6,568,640, 8x8, 5x500k, k=50k), as
     cv_train dispatches it: client_step then server_step with the default
-    telemetry vector, plus the fused train_step bench.py times."""
+    telemetry vector, plus the fused train_step."""
     devices = _v5e_devices()[:n_devices]
     model = models.ResNet9()
     shapes = jax.eval_shape(
