@@ -11,7 +11,8 @@ imports jax):
   probe       what JAX sees: platform, device_kind, count, versions, whether
               the native data plane built, where the compile cache lives
   kernels     each of the seven Pallas kernels compiled (never interpreted)
-              at the ResNet9 geometry and bit-compared with its jnp reference
+              at the ResNet9 geometry and bit-compared with its jnp reference;
+              the top-k's pruned descent at GPT-2's d against the whole-plane one
   train-cold  the recipe end to end: finite loss, >= 16 rounds + validation,
               weights moved, telemetry header says tpu, no kernel kill-switch
               flipped, every mesh device used
@@ -65,12 +66,15 @@ RECIPE = [
 # ~20 rounds an epoch (two --metrics_drain_every 8 drains) x the default
 # 24 epochs, each with its validation pass
 PER_CLASS = "128"
-KERNEL_GEOMETRY = {"d": 6_568_640, "c": 500_000, "r": 5, "k": 50_000}
+# big_d: GPT-2's d, above ops/topk's gate, where the top-k prunes to k granules
+KERNEL_GEOMETRY = {"d": 6_568_640, "c": 500_000, "r": 5, "k": 50_000,
+                   "big_d": 124_444_417}
 
 # --rehearse: CPU, tiny model, tiny sketch, interpreted kernels
 REHEARSAL_RECIPE = {"--num_rows": "3", "--num_cols": "2048", "--k": "500",
                     "--device": "cpu"}
-REHEARSAL_GEOMETRY = {"d": 60_000, "c": 20_000, "r": 3, "k": 500}
+REHEARSAL_GEOMETRY = {"d": 60_000, "c": 20_000, "r": 3, "k": 500,
+                      "big_d": 70_001}
 WORKERS = 8
 # the multi-round mesh-parity tolerance (tests/test_rounds.py:147,271)
 LOSS_RTOL = 1e-4
@@ -162,6 +166,9 @@ def phase_kernels(ns) -> dict:
          lambda: tk.check_count_descent_kernel(g["d"], g["k"], interpret)),
         ("topk fused descent",
          lambda: tk.check_fused_descent_kernel(g["d"], g["k"], interpret)),
+        ("topk pruned descent",
+         lambda: tk.check_pruned_descent(g["big_d"], g["k"], cs.sublanes,
+                                         interpret)),
     ]
     how = "INTERPRETED" if interpret else "compiled (not interpreted)"
     failed = []

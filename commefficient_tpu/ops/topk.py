@@ -5,19 +5,19 @@ largest-magnitude coordinates of a vector (or of each row of a matrix), zero
 the rest, returned as a dense masked vector.
 
 TPU-first design: ``jax.lax.top_k`` at FetchSGD scale (k=50k over d≈6.5M) is
-a full sort — ~15 ms/call on a v5e chip and the single hottest op of the
-whole federated round (it sits inside ``unsketch`` on the server). Since the
-callers only ever need the *dense masked* result (never the index list), the
-selection reduces to finding the k-th magnitude as a scalar threshold, found
-exactly by a radix-nibble descent over the **int32 bit patterns** of the
-absolute values (non-negative IEEE-754 floats compare identically as
-integers): 8 passes, each comparing the whole vector against the 15 (7 for
-the top nibble — finite ``|float|`` patterns keep bit 31 clear and top
-nibble ≤ 7) candidate extensions of the resolved prefix and keeping the
-largest whose ≥-count still reaches k. That resolves 4 threshold bits per
-full-vector read with pure int32 compares — no float bisection precision
-cliffs at any dynamic range, no separate max pass, and ``|vec|`` is
-recomputed per pass (2 VPU ops) rather than materialized. Properties:
+a full sort — ~15 ms/call on a v5e chip. The callers only ever need the
+*dense masked* result (never the index list), so the selection reduces to
+the k-th magnitude as a scalar threshold, found exactly by a radix-nibble
+descent over the **int32 bit patterns** of the absolute values (non-negative
+IEEE-754 floats compare identically as integers): 8 passes, each comparing
+every element against the 15 (7 for the top nibble — finite ``|float|``
+patterns keep bit 31 clear) candidate extensions of the resolved prefix and
+keeping the largest whose ≥-count still reaches k: 4 threshold bits per
+read, pure int32 compares, no float bisection precision cliffs at any
+dynamic range, ``|vec|`` recomputed per pass (2 VPU ops). Above
+``_PALLAS_TOPK_MAX_D`` elements the passes do not sweep the plane: only k of
+its d/128 granules can hold the cut, and the descent runs over those
+(``_threshold_descent_pruned``), to the same pattern bit for bit. Properties:
 
   - invariant after every pass: count(m ≥ p) ≥ k with p a prefix of the
     k-th magnitude's bit pattern; at the end ``m ≥ p`` keeps exactly the
@@ -56,18 +56,26 @@ _SUB = 512              # count-kernel block: (512, 128) i32 = 256 KiB VMEM
 # the kernel's fixed (512, 128) blocking stops tracking HBM streams (1,900
 # block boundaries per pass leave no pipelining slack). Gate between the
 # two measured points, nearer the win; a cell on each side of it: cell 1
-# below, the GPT-2 and JoyAI cells above. The blocking is d-adaptive
-# (``_sub_for``), never re-measured above the gate; the gate itself only
-# moves on a ledger line (ROADMAP Queue 1 item 13).
+# below, the GPT-2 and JoyAI cells above, where no whole-plane descent runs
+# on one chip any more: the plane is pruned to k granules first
+# (``_threshold_path``) and the descent left is below-gate sized again.
 _PALLAS_TOPK_MAX_D = 32 * 1024 * 1024
+# Pruning granule: one lane row of the (T, S, 128) chunk view, so a
+# granule's maximum is a minor-axis reduce and its gather a 512-byte row.
+_GRANULE = _LANES
+# Prune only a plane of at least this many granules per kept coordinate:
+# the second descent reads k granules, so at 4 it reads a quarter of the
+# plane at most and the max sweep and gather are paid back; nearer 1 the
+# candidates are the plane again (the cells have 19x and 65x).
+_PRUNE_MIN_GRANULES_PER_K = 4
 
 
 def _sub_for(d: int) -> int:
     """Count/descent-kernel block sublanes chosen from d: (512, 128) i32 =
     256 KiB blocks at FetchSGD scale (the measured 37x-win shape), 4x that
-    (1 MiB blocks, still trivially double-buffered in VMEM) above the 32M
-    gate where the round-5 A/B showed the fixed blocking losing the HBM
-    streams — 4x fewer block boundaries for the same bytes.
+    (1 MiB, still double-buffered in VMEM) above the 32M gate. The default
+    path prunes first there (``_threshold_path``): only the opt-in fused
+    kernel, or the per-pass kernel forced onto a sharded slice, gets the 4x.
 
     Radix width note (the other lever considered for d-scaling): widening
     a pass from 4 to 8 bits would halve the HBM reads but needs 255
@@ -213,6 +221,15 @@ def _blocks3(raw: jax.Array, sub: int = _SUB):
     return jnp.pad(raw, (0, T * block - d)).reshape(T, sub, _LANES), T
 
 
+def _mag(raw: jax.Array) -> jax.Array:
+    """|pattern| as int (abs, not the reference's square, utils.py:246:
+    squares underflow below |v|≈1e-19 and overflow above ≈2e19; bit
+    patterns are exact at every representable magnitude); NaN → 0 so
+    divergence never wins the threshold race."""
+    m = raw & _ABS_MASK
+    return jnp.where(m > _INF_BITS, 0, m)
+
+
 def _apply_threshold(raw: jax.Array, vec: jax.Array, p) -> jax.Array:
     """Dense-masked result from the resolved k-th-magnitude bit pattern:
     keep mag ≥ p (tie-inclusive), re-insert NaNs (module docstring)."""
@@ -335,27 +352,81 @@ def check_fused_descent_kernel(d: int, k: int,
                   _topk_threshold_1d(vec, k), "fused descent")
 
 
-def _select_threshold_impl(d: int):
-    """Pick the threshold-descent implementation for this geometry.
+def check_pruned_descent(d: int, k: int, sublanes: int,
+                         interpret: bool = False) -> None:
+    """Granule-pruned descent == the whole-plane XLA descent, on the flat
+    vector and on its ``(T, sublanes, 128)`` chunk view with a zero tail:
+    chip_smoke.py runs it at the GPT-2 geometry, above the gate, where
+    the CPU tests cannot reach."""
+    vec = _check_vec(d)
+    want = _threshold_descent_xla(vec.view(jnp.int32), k)
+    descend = functools.partial(resolve_threshold, interpret=interpret)
+    require_equal(_threshold_descent_pruned(vec, k, descend), want,
+                  "pruned descent (flat)")
+    chunk = sublanes * _LANES
+    v3 = jnp.pad(vec, (0, -d % chunk)).reshape(-1, sublanes, _LANES)
+    require_equal(_threshold_descent_pruned(v3, k, descend), want,
+                  "pruned descent (chunk view)")
 
-    The fused whole-descent kernel is default OFF: never measured on the
-    chip (ROADMAP D2), so nothing says it beats the per-pass kernel —
-    the same gate-then-flip playbook as the count-pass kernel. The opt-in
-    flag deliberately bypasses the d ≤ 32M crossover gate: the fused
-    kernel's large-d blocking is exactly what the A/B needs to test at
-    GPT-2 scale."""
+
+def _threshold_path(size: int, k: int, interpret: bool = False,
+                    axis_name=None) -> str:
+    """THE rule that picks how the k-th magnitude of ``size`` elements is
+    resolved, from what the call can see (``resolve_threshold``, ``topk``
+    and the run header's ``topk_plan`` all ask here):
+
+      - ``"fused"``: the whole-descent kernel, opt-in only
+        (COMMEFFICIENT_PALLAS_TOPK_FUSED=1 on a TPU): never measured on the
+        chip (ROADMAP D2). It cannot psum between its in-kernel passes, so
+        never on a sharded slice;
+      - ``"pruned"``: above the gate, on one chip's whole plane
+        (``axis_name is None``), with at least
+        ``_PRUNE_MIN_GRANULES_PER_K`` granules per kept coordinate:
+        ``_threshold_descent_pruned``, whose descent over the candidates
+        comes back here at its own, below-gate size. A sharded slice keeps the
+        whole-slice descent with psum'd counts (ROADMAP D3);
+      - ``"pallas"``: the per-pass count kernel, on a TPU below the gate
+        (or forced, or interpreted);
+      - ``"xla"``: everywhere else, and whole under the kill-switch
+        COMMEFFICIENT_PALLAS_TOPK=0, which beats the kernels (pruning is
+        no kernel: it stays, with an XLA descent inside)."""
     import os
 
     from commefficient_tpu.utils import is_tpu_backend
 
-    if os.environ.get("COMMEFFICIENT_PALLAS_TOPK") == "0":
-        return _topk_threshold_1d  # explicit kill-switch beats everything
-    if (os.environ.get("COMMEFFICIENT_PALLAS_TOPK_FUSED") == "1"
-            and is_tpu_backend()):
-        return _topk_threshold_1d_fused
-    if _use_pallas_topk(d):
-        return _topk_threshold_1d_pallas
-    return _topk_threshold_1d
+    killed = os.environ.get("COMMEFFICIENT_PALLAS_TOPK") == "0"
+    if (not killed and axis_name is None and is_tpu_backend()
+            and os.environ.get("COMMEFFICIENT_PALLAS_TOPK_FUSED") == "1"):
+        return "fused"
+    if (axis_name is None and size > _PALLAS_TOPK_MAX_D
+            and -(-size // _GRANULE) >= _PRUNE_MIN_GRANULES_PER_K * k):
+        return "pruned"
+    if not killed and (_use_pallas_topk(size) or interpret):
+        return "pallas"
+    return "xla"
+
+
+def topk_plan(size: int, k: int, sharded: bool = False) -> dict:
+    """How a run resolves its top-k threshold, for the run header
+    (telemetry ``run_start.topk_plan``, docs/observability.md): the path is
+    static for a run, so this line and ``topk_ms`` say how often pruning
+    engages. ``candidates`` is what the descent then reads, ``share``
+    that over the plane."""
+    path = _threshold_path(size, k, axis_name="shard" if sharded else None)
+    plan = {"path": path}
+    if path == "pruned":
+        plan.update(granule=_GRANULE, granules=-(-size // _GRANULE),
+                    candidates=k * _GRANULE, share=k * _GRANULE / size)
+    return plan
+
+
+def _select_threshold_impl(d: int, k: int):
+    """The dense-masked 1-D top-k for this geometry (``_threshold_path``
+    has the rule)."""
+    return {"fused": _topk_threshold_1d_fused,
+            "pruned": topk_dense_nd,
+            "pallas": _topk_threshold_1d_pallas,
+            "xla": _topk_threshold_1d}[_threshold_path(d, k)]
 
 
 def _topk_sort_1d(vec: jax.Array, k: int) -> jax.Array:
@@ -375,14 +446,6 @@ def _threshold_descent_xla(raw: jax.Array, k: int,
     so the threshold matches the unsharded descent's over the
     concatenation of the shards' slices."""
 
-    def mag(r):
-        # |pattern| as int (abs, not the reference's square, utils.py:246:
-        # squares underflow below |v|≈1e-19 and overflow above ≈2e19; bit
-        # patterns are exact at every representable magnitude); NaN → 0 so
-        # divergence never wins the threshold race
-        m = r & _ABS_MASK
-        return jnp.where(m > _INF_BITS, 0, m)
-
     # Radix descent: after each pass p is the resolved high-nibble prefix of
     # the k-th largest magnitude's bit pattern, maintaining
     # count(m ≥ p) ≥ k. Unrolled: 8 static passes, thresholds are ints.
@@ -390,7 +453,7 @@ def _threshold_descent_xla(raw: jax.Array, k: int,
     for shift in range(28, -1, -4):
         hi_nib = 8 if shift == 28 else 16
         ts = p + (jnp.arange(1, hi_nib, dtype=jnp.int32) << shift)
-        m = mag(raw)
+        m = _mag(raw)
         counts = jnp.sum(m[..., None] >= ts, axis=tuple(range(m.ndim)))
         if axis_name is not None:
             counts = jax.lax.psum(counts, axis_name)
@@ -410,6 +473,69 @@ def _topk_threshold_1d(vec: jax.Array, k: int) -> jax.Array:
     return _apply_threshold(raw, vec, p)
 
 
+def _bits(x: jax.Array) -> jax.Array:
+    """The int32 bit patterns of a float32 array (or the array, if it is
+    them already)."""
+    return x if x.dtype == jnp.int32 else x.view(jnp.int32)
+
+
+def _granule_rows(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """Magnitudes of granules ``idx`` of ``x``, ``[len(idx), _GRANULE]``,
+    gathered from the array as given (``_granule_maxima`` numbers them):
+    the float plane itself, not its int32 view, which XLA would write out
+    whole as the gather's operand."""
+    if x.ndim > 1 and x.shape[-1] == _GRANULE:
+        lead = jnp.unravel_index(idx, x.shape[:-1])
+        return _mag(_bits(x[lead]))
+    flat = x.reshape(-1)
+    # dynamic_slice clamps the last, partial granule's window back inside
+    # the vector: blank what it then repeats of the granule before it
+    start = jnp.minimum(idx * _GRANULE, flat.shape[0] - _GRANULE)
+    rows = jax.vmap(lambda s: jax.lax.dynamic_slice(
+        flat, (s,), (_GRANULE,)))(start)
+    pos = start[:, None] + jnp.arange(_GRANULE)
+    return jnp.where(pos >= idx[:, None] * _GRANULE, _mag(_bits(rows)), 0)
+
+
+def _granule_maxima(x: jax.Array) -> jax.Array:
+    """Largest magnitude of every granule of ``_GRANULE`` consecutive
+    elements, flat ``(n,)``, reduced from the view as given: a chunk
+    view's lane rows, or a flat vector's full granules and its tail — no
+    pad and no copy of the plane."""
+    if x.ndim > 1 and x.shape[-1] == _GRANULE:
+        return jnp.max(_mag(_bits(x)), axis=-1).reshape(-1)
+    flat = _bits(x).reshape(-1)
+    full = flat.shape[0] // _GRANULE
+    gmax = jnp.max(_mag(flat[:full * _GRANULE]).reshape(full, _GRANULE),
+                   axis=-1)
+    if full * _GRANULE == flat.shape[0]:
+        return gmax
+    return jnp.append(gmax, jnp.max(_mag(flat[full * _GRANULE:])))
+
+
+def _threshold_descent_pruned(x: jax.Array, k: int, descend) -> jax.Array:
+    """The k-th-largest-magnitude bit pattern of ``x`` (any shape; float32
+    or its int32 view), equal to ``_threshold_descent_xla``'s, from the k
+    granules that can hold it. ``descend(raw, k)`` resolves the gathered
+    candidates, a below-gate array.
+
+    Let q be the k-th largest granule maximum and p the pattern sought.
+    The pick is **every granule whose maximum is above q, the remaining
+    slots filled with granules whose maximum equals q** (``lax.top_k`` of
+    the maxima is exactly that). Proof that the k-th largest of the picked
+    elements is p: k maxima are ≥ q, so p ≥ q. A granule not picked holds
+    nothing above q, so for every threshold above q the picked elements
+    count what the plane counts: if p > q they resolve p. If p = q no
+    threshold above q reaches k on either, and each of the k slots holds
+    an element ≥ q: they resolve q. Ties, NaNs (magnitude 0) and fewer
+    than k nonzeros (p = 0) are cases of the same two lines."""
+    gmax = _granule_maxima(x)
+    if gmax.shape[0] < k:
+        raise ValueError(f"{gmax.shape[0]} granules cannot hold k={k}")
+    _, idx = jax.lax.top_k(gmax, k)
+    return descend(_granule_rows(x, idx), k)
+
+
 def resolve_threshold(vec: jax.Array, k: int, interpret: bool = False,
                       axis_name=None) -> jax.Array:
     """THE k-th-largest-magnitude bit-pattern resolver (scalar int32 p) for
@@ -418,32 +544,23 @@ def resolve_threshold(vec: jax.Array, k: int, interpret: bool = False,
     ``topk_dense_nd`` below, and the fused server epilogue
     (ops/sketch.fused_epilogue_chunks, docs/fused_epilogue.md), whose
     megakernel takes p precomputed so its single sweep can mask, emit the
-    update, and re-sketch in one pass.
-
-    Precedence (mirrors ``_select_threshold_impl``): kill-switch
-    (COMMEFFICIENT_PALLAS_TOPK=0) beats everything, then the fused
-    whole-descent kernel A/B opt-in (COMMEFFICIENT_PALLAS_TOPK_FUSED=1 —
-    deliberately bypasses the crossover gate: GPT-2-scale d is what the
-    A/B tests), then the per-pass kernel below the measured gate, then
-    pure XLA. Every implementation resolves exact integer counts, so they
-    agree bit-for-bit.
+    update, and re-sketch in one pass. ``_threshold_path`` picks the
+    implementation; every one resolves exact integer counts, so they agree
+    bit-for-bit.
 
     ``axis_name`` (sharded server, docs/sharded_server.md): ``vec`` is one
     shard's slice inside a ``shard_map``; the per-pass counts psum over
-    the axis so p is the GLOBAL k-th magnitude. The fused whole-descent
-    kernel cannot psum between its in-kernel passes, so the sharded path
-    always uses the per-pass kernel or pure XLA."""
-    import os
-
-    from commefficient_tpu.utils import is_tpu_backend
-
-    raw = vec.view(jnp.int32)
-    if os.environ.get("COMMEFFICIENT_PALLAS_TOPK") == "0":
-        return _threshold_descent_xla(raw, k, axis_name=axis_name)
-    if (os.environ.get("COMMEFFICIENT_PALLAS_TOPK_FUSED") == "1"
-            and is_tpu_backend() and axis_name is None):
+    the axis so p is the GLOBAL k-th magnitude."""
+    path = _threshold_path(vec.size, k, interpret, axis_name)
+    if path == "pruned":
+        # magnitudes are their own bit patterns: the candidates come back
+        # here, below the gate
+        return _threshold_descent_pruned(
+            vec, k, functools.partial(resolve_threshold, interpret=interpret))
+    raw = _bits(vec)
+    if path == "fused":
         return _threshold_descent_fused(raw, k, interpret=interpret)
-    if _use_pallas_topk(vec.size) or interpret:
+    if path == "pallas":
         return _threshold_descent_pallas(raw, k, interpret=interpret,
                                          axis_name=axis_name)
     return _threshold_descent_xla(raw, k, axis_name=axis_name)
@@ -466,8 +583,8 @@ def topk_dense_nd(vec: jax.Array, k: int, interpret: bool = False,
     the measured Pallas crossover the count passes run through the fused
     count kernel on a blocked flat view (the one remaining reshape rides
     the same path the flat round always paid; above the crossover the
-    descent is reshape-free). Threshold dispatch precedence lives in
-    ``resolve_threshold``."""
+    plane is read in place, once for the granule maxima and once for the
+    mask). ``_threshold_path`` has the dispatch rule."""
     raw = vec.view(jnp.int32)
     p = resolve_threshold(vec, k, interpret=interpret, axis_name=axis_name)
     return _apply_threshold(raw, vec, p)
@@ -480,7 +597,7 @@ def topk(vec: jax.Array, k: int, method: str = "threshold") -> jax.Array:
     reference utils.py:246-252.
     """
     if method == "threshold":
-        f = _select_threshold_impl(vec.shape[-1])
+        f = _select_threshold_impl(vec.shape[-1], k)
     elif method == "sort":
         f = _topk_sort_1d
     else:

@@ -1014,6 +1014,17 @@ def attach_run_telemetry(args, fed_model, log_dir: str,
             "axes": [{"name": n, "size": int(mesh.shape[n]),
                       "placement": placement[n]}
                      for n in mesh.axis_names]}
+    if args.mode in ("sketch", "true_topk", "local_topk"):
+        # how this run resolves its top-k threshold (static for a run:
+        # ops/topk.topk_plan has the rule). Sketch mode thresholds the
+        # chunk view of the estimates, the others the flat vector.
+        from commefficient_tpu.ops.topk import topk_plan
+
+        cs = fed_model.sketch
+        run_info["topk_plan"] = topk_plan(
+            cs.chunk_layout.padded_size if cs is not None
+            else fed_model.grad_size,
+            args.k, sharded=bool(fed_model._n_shard))
     # Participation-layer config (--participation / --inject_client_fault,
     # federated/participation.py): recorded in the run header so a logged
     # run is reproducible from the log alone — the fault schedule is

@@ -417,6 +417,170 @@ class TestTopkPallasCounts:
         self._both(v, 1000)
 
 
+def _pruned_cases():
+    """(name, array, k): the planes ``_threshold_descent_pruned`` must
+    resolve to ``_threshold_descent_xla``'s pattern, bit for bit."""
+    rng = np.random.RandomState(31)
+    d = 40_003  # 312 full granules of 128 and a tail of 67
+    cubed = (rng.randn(d) ** 3).astype(np.float32)
+    chunks = np.zeros((6, 13, 128), np.float32)  # S = 13: no multiple of 8
+    chunks.reshape(-1)[:9_000] = rng.randn(9_000)  # zero tail
+    # the nonzero(max >= q, size=k) trap: sixty granules that only TIE the
+    # cut come first in index order, the five that lie above it last
+    ties = np.zeros(d, np.float32)
+    ties[:60 * 128] = 1.0
+    for g in range(200, 205):
+        ties[g * 128:g * 128 + 3] = -2.0
+    ties[-1] = 2.0  # and one in the partial tail granule
+    few = np.zeros(d, np.float32)
+    few[[5, 4_000, d - 1]] = [2.0, -3.0, 1.0]
+    odd = rng.randn(d).astype(np.float32)
+    odd[::97] = np.nan
+    odd[[3, 130]] = [np.inf, -np.inf]
+    odd[300:600] = 1e-42  # subnormals
+    odd[-5:] = np.nan
+    return [
+        ("cubed-k1", cubed, 1),
+        ("cubed-k50", cubed, 50),
+        ("cubed-k-at-factor", cubed, 313 // 4),
+        ("chunks-S13-zero-tail", chunks, 15),
+        ("ties-before-strict-p-above-q", ties, 10),
+        ("ties-before-strict-p-is-q", ties, 40),
+        ("ties-tail-granule", ties, 16),
+        ("all-equal", np.full(d, -0.5, np.float32), 77),
+        ("fewer-than-k-nonzeros", few, 20),
+        ("nan-inf-subnormal-k10", odd, 10),
+        ("nan-inf-subnormal-k78", odd, 78),
+    ]
+
+
+class TestTopkPrunedDescent:
+    """Above ``_PALLAS_TOPK_MAX_D`` the threshold is resolved on the k
+    granules that can hold it (``_threshold_descent_pruned``): the same
+    pattern as the whole-plane descent on every plane, and taken exactly
+    where ``_threshold_path`` says."""
+
+    GATE = 4096  # a gate the CPU can stand above
+
+    @pytest.fixture
+    def tk(self):
+        import sys
+
+        return sys.modules["commefficient_tpu.ops.topk"]
+
+    @pytest.mark.parametrize("v,k", [pytest.param(v, k, id=name)
+                                     for name, v, k in _pruned_cases()])
+    def test_pattern_equals_whole_plane_descent(self, tk, v, k):
+        x = jnp.asarray(v)
+        want = int(tk._threshold_descent_xla(x.view(jnp.int32), k))
+        assert int(tk._threshold_descent_pruned(
+            x, k, tk._threshold_descent_xla)) == want
+        # the int32 view resolves like the floats it views
+        assert int(tk._threshold_descent_pruned(
+            x.view(jnp.int32), k, tk._threshold_descent_xla)) == want
+
+    def test_vmapped_rows(self, tk):
+        # as topk() batches a 2-D input (per-client top-k)
+        rng = np.random.RandomState(32)
+        rows = jnp.asarray((rng.randn(3, 20_001) ** 3).astype(np.float32))
+        got = jax.vmap(lambda r: tk._threshold_descent_pruned(
+            r, 30, tk._threshold_descent_xla))(rows)
+        want = [int(tk._threshold_descent_xla(r.view(jnp.int32), 30))
+                for r in rows]
+        assert [int(p) for p in got] == want
+
+    @pytest.mark.parametrize("shape", [(40_003,), (6, 13, 128)])
+    def test_interpreted_count_kernel_serves_the_inner_descent(
+            self, tk, monkeypatch, shape):
+        monkeypatch.setattr(tk, "_PALLAS_TOPK_MAX_D", self.GATE)
+        rng = np.random.RandomState(33)
+        x = jnp.asarray((rng.randn(*shape) ** 3).astype(np.float32))
+        calls = []
+        real = tk._threshold_descent_pallas
+        monkeypatch.setattr(
+            tk, "_threshold_descent_pallas",
+            lambda raw, k, **kw: calls.append(raw.shape) or real(
+                raw, k, **kw))
+        got = int(tk.resolve_threshold(x, 12, interpret=True))
+        assert calls == [(12, 128)]
+        assert got == int(tk._threshold_descent_xla(x.view(jnp.int32), 12))
+
+    def test_too_few_granules_raise(self, tk):
+        with pytest.raises(ValueError, match="granules"):
+            tk._threshold_descent_pruned(jnp.ones(1000), 9,
+                                         tk._threshold_descent_xla)
+
+    @pytest.mark.parametrize("entry", ["resolve_threshold", "topk",
+                                       "topk_rows", "topk_dense_nd"])
+    def test_above_the_gate_takes_the_pruned_path(self, tk, monkeypatch,
+                                                  entry):
+        monkeypatch.setattr(tk, "_PALLAS_TOPK_MAX_D", self.GATE)
+        hits = []
+        real = tk._threshold_descent_pruned
+        monkeypatch.setattr(
+            tk, "_threshold_descent_pruned",
+            lambda x, k, descend: hits.append(x.shape) or real(
+                x, k, descend))
+        rng = np.random.RandomState(34)
+        v = jnp.asarray((rng.randn(6, 13, 128) ** 3).astype(np.float32))
+        flat, k = v.reshape(-1), 15
+        raw = flat.view(jnp.int32)
+        p = tk._threshold_descent_xla(raw, k)
+        parent = np.asarray(tk._apply_threshold(raw, flat, p))
+        if entry == "resolve_threshold":
+            assert int(tk.resolve_threshold(v, k)) == int(p)
+        elif entry == "topk":
+            np.testing.assert_array_equal(np.asarray(tk.topk(flat, k)),
+                                          parent)
+        elif entry == "topk_rows":
+            np.testing.assert_array_equal(
+                np.asarray(tk.topk(jnp.stack([flat, flat]), k)),
+                np.stack([parent, parent]))
+        else:
+            np.testing.assert_array_equal(
+                np.asarray(tk.topk_dense_nd(v, k)),
+                parent.reshape(v.shape))
+        assert len(hits) == 1  # the candidates resolve below the gate
+
+    @pytest.mark.parametrize("why", ["below_gate", "too_few_granules",
+                                     "axis_name"])
+    def test_elsewhere_the_program_is_the_whole_plane_descent(
+            self, tk, monkeypatch, why):
+        """Lowered text, not values: these callers must compile to what
+        they compiled to before pruning existed."""
+        k = 15
+        x = jnp.zeros((6, 13, 128), jnp.float32)
+        if why != "below_gate":
+            monkeypatch.setattr(tk, "_PALLAS_TOPK_MAX_D", self.GATE)
+        if why == "too_few_granules":
+            k = 78 // tk._PRUNE_MIN_GRANULES_PER_K + 1
+        axis = "s" if why == "axis_name" else None
+
+        def lowered(f):
+            if axis is not None:
+                f = jax.vmap(f, axis_name=axis)
+                return jax.jit(f).lower(x[None]).as_text()
+            return jax.jit(f).lower(x).as_text()
+
+        assert tk._threshold_path(x.size, k, axis_name=axis) == "xla"
+        assert lowered(
+            lambda v: tk.resolve_threshold(v, k, axis_name=axis)
+        ) == lowered(
+            lambda v: tk._threshold_descent_xla(v.view(jnp.int32), k,
+                                                axis_name=axis))
+
+    def test_plan_states_the_rule_once(self, tk, monkeypatch):
+        d, k = 124_523_904, 50_000  # gpt2_sketch_1c's chunk view
+        assert tk.topk_plan(d, k) == {
+            "path": "pruned", "granule": 128, "granules": 972_843,
+            "candidates": 6_400_000, "share": 6_400_000 / d}
+        assert tk.topk_plan(d, k, sharded=True) == {"path": "xla"}
+        assert tk.topk_plan(6_568_640, k) == {"path": "xla"}  # no TPU here
+        assert tk.topk_plan(d, 972_843 // 4 + 1) == {"path": "xla"}
+        monkeypatch.setenv("COMMEFFICIENT_PALLAS_TOPK", "0")
+        assert tk.topk_plan(d, k)["path"] == "pruned"  # pruning is no kernel
+
+
 class TestTopkFusedDescent:
     """The single-kernel fused descent (grid (8, T), SMEM-carried prefix)
     must reproduce the XLA radix descent bit-for-bit in interpret mode —

@@ -998,6 +998,23 @@ class TestMidEpochResumeWithFaults:
 # ---------------------------------------------------------------------------
 
 class TestTelemetryIntegration:
+    @pytest.mark.parametrize("mode,error_type,plan", [
+        ("sketch", "virtual", {"path": "xla"}),
+        ("true_topk", "virtual", {"path": "xla"}),
+        ("uncompressed", "none", None)])
+    def test_run_start_records_topk_plan(self, tmp_path, mode, error_type,
+                                         plan):
+        """The run header says how the run resolves its top-k threshold
+        (ops/topk.topk_plan has the rule; tests/test_ops.py holds it to
+        the cells' sizes); a mode with no top-k has no plan."""
+        from commefficient_tpu.telemetry import attach_run_telemetry
+
+        args = _args(telemetry=True, mode=mode, error_type=error_type)
+        fm = FedModel(TinyModel(), _loss, args, input_shape=(3,))
+        attach_run_telemetry(args, fm, str(tmp_path), "test").close()
+        start = next(read_events(str(tmp_path / "telemetry.jsonl")))
+        assert start.get("topk_plan") == plan
+
     def test_run_start_records_participation_config(self, tmp_path):
         """The satellite bugfix: the run header carries the participation
         config (fraction, sampling, decay, fault schedule incl. seed) so
