@@ -12,7 +12,9 @@ imports jax):
               the native data plane built, where the compile cache lives
   kernels     each of the seven Pallas kernels compiled (never interpreted)
               at the ResNet9 geometry and bit-compared with its jnp reference;
-              the top-k's pruned descent at GPT-2's d against the whole-plane one
+              the top-k's pruned descent at GPT-2's d against the whole-plane
+              one; the grouped-query attention kernels at 4,096 positions
+              (48 / 64 heads over 8) against their einsum oracle, to rounding
   train-cold  the recipe end to end: finite loss, >= 16 rounds + validation,
               weights moved, telemetry header says tpu, no kernel kill-switch
               flipped, every mesh device used
@@ -74,7 +76,9 @@ KERNEL_GEOMETRY = {"d": 6_568_640, "c": 500_000, "r": 5, "k": 50_000,
 REHEARSAL_RECIPE = {"--num_rows": "3", "--num_cols": "2048", "--k": "500",
                     "--device": "cpu"}
 REHEARSAL_GEOMETRY = {"d": 60_000, "c": 20_000, "r": 3, "k": 500,
-                      "big_d": 70_001}
+                      "big_d": 70_001,
+                      "gqa": {"heads": (6, 8), "kv_heads": 1, "d": 16,
+                              "T": 48, "window": 20, "tile": 16}}
 WORKERS = 8
 # the multi-round mesh-parity tolerance (tests/test_rounds.py:147,271)
 LOSS_RTOL = 1e-4
@@ -133,6 +137,7 @@ def phase_probe(ns) -> dict:
 def phase_kernels(ns) -> dict:
     import importlib
 
+    from commefficient_tpu.ops import attention as at
     from commefficient_tpu.ops import sketch as sk
     from commefficient_tpu.utils import configure_compile_cache
 
@@ -169,19 +174,27 @@ def phase_kernels(ns) -> dict:
         ("topk pruned descent",
          lambda: tk.check_pruned_descent(g["big_d"], g["k"], cs.sublanes,
                                          interpret)),
+        # the grouped-query attention core at models/laguna.py's published
+        # shape; within rounding of its oracle, not bit-equal (at.GQA_CHECK_TOL)
+        ("gqa attention fwd + bwd",
+         lambda: at.check_gqa_kernels(**g.get("gqa", {}),
+                                      interpret=interpret)),
     ]
     how = "INTERPRETED" if interpret else "compiled (not interpreted)"
     failed = []
     for name, check in checks:
         t0 = time.monotonic()
         try:
-            check()
+            gaps = check()
         except Exception as e:  # noqa: BLE001 — report every kernel, then fail
             failed.append(name)
             _say(f"kernel {name}: FAILED {type(e).__name__}: {e}")
         else:
-            _say(f"kernel {name}: {how}, bit-equal to reference "
-                 f"(T={cs.T} S={cs.sublanes}, "
+            verdict = (f"within {at.GQA_CHECK_TOL:g} of the oracle's "
+                       f"largest entry (worst {max(gaps.values()):.2e}"
+                       if gaps else
+                       f"bit-equal to reference (T={cs.T} S={cs.sublanes}")
+            _say(f"kernel {name}: {how}, {verdict}, "
                  f"{time.monotonic() - t0:.1f} s)")
     return {"ok": not failed, "failed": failed}
 

@@ -352,13 +352,19 @@ def build_parser(default_lr=None) -> argparse.ArgumentParser:
                              "coef/n_moe_layers, so retune rather than "
                              "assuming published values transfer.")
     # The decoder gpt2_train.py builds (models/joyai.py for the DeepSeek-V3
-    # family) and the cut of it held here: one chip's share of a deployment
-    # in which --layer_chips chips share each layer.
-    parser.add_argument("--arch", choices=["gpt2", "joyai_llm_flash"],
+    # family, models/laguna.py) and the cut of it held here: one chip's share
+    # of a deployment in which --layer_chips chips share each layer.
+    parser.add_argument("--arch",
+                        choices=["gpt2", "joyai_llm_flash", "laguna_xs2"],
                         default="gpt2",
-                        help="gpt2_train.py's model: GPT-2 double heads, or "
+                        help="gpt2_train.py's model: GPT-2 double heads, "
                              "JoyAI-LLM-Flash (MLA, sigmoid top-8 routed "
-                             "experts with a shared expert, causal-LM loss).")
+                             "experts with a shared expert, causal-LM "
+                             "loss), or Laguna-XS.2 (window-512 and full "
+                             "grouped-query layers with 64 / 48 heads over "
+                             "8, two RoPEs, gated head outputs, the same "
+                             "kind of expert layer without its selection "
+                             "bias).")
     parser.add_argument("--arch_layers", type=int, default=0,
                         help="Layers held, leading dense layer included "
                              "(0 = all the architecture has).")

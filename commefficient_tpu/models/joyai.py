@@ -36,7 +36,7 @@ from flax import linen as nn
 from commefficient_tpu.ops.attention import mla_attention
 from commefficient_tpu.parallel.moe import RoutedMoE, SwiGLU
 
-__all__ = ["JoyAIFlash", "JoyAIConfig"]
+__all__ = ["JoyAIFlash", "JoyAIConfig", "Decoder", "Block", "RMSNorm"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,6 +76,22 @@ class JoyAIConfig:
                    v_head_dim=16, intermediate_size=128,
                    moe_intermediate_size=32, n_routed_experts=16,
                    num_experts_per_tok=4, **cut)
+
+    # what ``Block`` and the entry point ask of a configuration
+    routed = property(lambda self: self.n_routed_experts)
+
+    def attention(self, layer: int):
+        return MLA(self, name="attn")
+
+    def is_dense(self, layer: int) -> bool:
+        return layer < self.first_k_dense_replace
+
+    def experts(self):
+        return RoutedMoE(
+            self.n_routed_experts, self.experts_held, self.expert_offset,
+            self.num_experts_per_tok, self.moe_intermediate_size,
+            self.routed_scaling_factor,
+            operand_dtype=self.expert_operand_dtype, name="moe")
 
 
 def _kernel(mod, name, shape):
@@ -133,34 +149,35 @@ class MLA(nn.Module):
 
 
 class Block(nn.Module):
-    cfg: JoyAIConfig
-    dense: bool
+    """One decoder block of a configuration (``JoyAIConfig``, or
+    models/laguna.py's): the configuration says which attention module layer
+    ``layer`` has, whether its feed-forward part is dense, and builds its
+    expert layer."""
+
+    cfg: Any
+    layer: int
 
     @nn.compact
     def __call__(self, x):
         c = self.cfg
-        h = x + MLA(c, name="attn")(RMSNorm(c.rms_norm_eps,
-                                            name="attn_norm")(x))
+        h = x + c.attention(self.layer)(RMSNorm(c.rms_norm_eps,
+                                                name="attn_norm")(x))
         z = RMSNorm(c.rms_norm_eps, name="ffn_norm")(h)
-        if self.dense:
+        if c.is_dense(self.layer):
             stats = {"local": jnp.zeros(x.shape[:-1], jnp.int32),
                      "max_load": jnp.int32(0)}
             return h + SwiGLU(c.intermediate_size, name="mlp")(z), stats
-        y, stats = RoutedMoE(
-            c.n_routed_experts, c.experts_held, c.expert_offset,
-            c.num_experts_per_tok, c.moe_intermediate_size,
-            c.routed_scaling_factor, operand_dtype=c.expert_operand_dtype,
-            name="moe")(z)
+        y, stats = c.experts()(z)
         return h + y, stats
 
 
-class JoyAIFlash(nn.Module):
+class Decoder(nn.Module):
     """``input_ids`` (S, T) -> logits (S, T, vocab_rows) and the routing
     counts of the call: held pairs by sequence (``local``, (S,)), the pairs
     routed to absent experts (``absent``, (S,)) and the sum over the expert
     layers of the largest held expert's load (``max_load``)."""
 
-    cfg: JoyAIConfig
+    cfg: Any
 
     @nn.compact
     def __call__(self, input_ids):
@@ -170,13 +187,16 @@ class JoyAIFlash(nn.Module):
         local = jnp.zeros(input_ids.shape[:1], jnp.int32)
         max_load, n_moe = jnp.int32(0), 0
         for i in range(c.layers):
-            dense = i < c.first_k_dense_replace
-            x, stats = nn.remat(Block)(c, dense, name=f"h{i}")(x)
+            x, stats = nn.remat(Block)(c, i, name=f"h{i}")(x)
             local = local + jnp.sum(stats["local"], axis=-1)
             max_load = max_load + stats["max_load"]
-            n_moe += not dense
+            n_moe += not c.is_dense(i)
         x = RMSNorm(c.rms_norm_eps, name="norm_f")(x)
         logits = x @ _kernel(self, "head", (c.hidden_size, c.vocab_rows))
         absent = (n_moe * c.num_experts_per_tok * input_ids.shape[1]) - local
         return logits, {"local": local, "absent": absent,
                         "max_load": max_load}
+
+
+class JoyAIFlash(Decoder):
+    """The decoder of a ``JoyAIConfig``."""

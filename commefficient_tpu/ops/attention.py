@@ -35,6 +35,27 @@ holds and that the kernels take the shape (TPU backend, no matmul precision
 set, T a multiple of 128 up to ``MAX_FUSED_T``, head widths that make
 lane-aligned groups); everything else runs the ``einsum``s. Which one a
 call was traced on is counted in ``PATH_CALLS``.
+
+The same module holds the causal core of grouped-query attention with an
+optional window (models/laguna.py ``GQA``): ``softmax(q k^T / sqrt(d) +
+mask) v`` with ``q`` (S, T, Hq, d), ``k`` and ``v`` (S, T, Hkv, d), query
+head ``h`` reading key/value head ``h // (Hq / Hkv)``, and query ``i`` seeing
+the keys ``j`` with ``0 <= i - j < window`` (no window: every ``j <= i``).
+``gqa_attention_einsum`` is the oracle; ``gqa_attention_fused`` is two Pallas
+kernels under one ``custom_vjp`` that, unlike the latent core's, tile the
+keys: the grid walks (sequence, key/value head, query tile, key tile) with a
+running max and sum, and of a query tile's row only the key tiles its mask
+can reach are visited (``_visits``: up to the diagonal, or the
+``ceil((window - 1) / tile) + 1`` that end on it), so the sequence length is
+bounded by the backward kernel's whole-sequence ``dk`` / ``dv`` blocks alone
+(``MAX_GQA_T``). A key/value tile is loaded once for the query heads that
+share it. The forward kernel writes the output and one float32 log-sum-exp
+a row; the backward kernel rebuilds the probabilities from it and makes all
+three gradients in one pass (``dq`` accumulates over a query tile's key
+tiles in its output block, ``dk`` and ``dv`` over the whole sequence in
+theirs). Arrays, rounding and the rule that picks the path
+(``gqa_attention_path``) are the latent core's; ``GQA_PLAN`` keeps the tile
+walk of the fused calls traced, by kind of layer.
 """
 
 from __future__ import annotations
@@ -50,14 +71,17 @@ from commefficient_tpu.utils import is_tpu_backend
 
 __all__ = ["mla_attention", "mla_attention_einsum", "mla_attention_fused",
            "fused_shape_ok", "attention_path", "PATH_CALLS", "TILE",
-           "MAX_FUSED_T"]
+           "MAX_FUSED_T", "gqa_attention", "gqa_attention_einsum",
+           "gqa_attention_fused", "gqa_shape_ok", "gqa_attention_path",
+           "GQA_TILE", "MAX_GQA_T", "GQA_PLAN"]
 
 TILE = 128          # query tile, and the lane width head groups align to
 # a group's keys, values and a query tile's scores (TILE x T float32) are
 # held in VMEM whole; longer sequences take the einsum path. The longest
 # the kernels were compiled for (tests/test_tpu_aot.py) and run at on a v5e
 MAX_FUSED_T = 1024
-# calls of ``mla_attention`` traced on each path, this process
+# calls of ``mla_attention`` / ``gqa_attention`` traced on each path, this
+# process
 PATH_CALLS = {"fused": 0, "einsum": 0}
 
 _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -74,7 +98,7 @@ def _dims(q, q_r, kv):
 
 def _rounds_to_bfloat16() -> bool:
     """Whether a float32 product's multiplicands are rounded to bfloat16 on
-    the chip: at the default matmul precision (gpt2_train.build_joyai asks
+    the chip: at the default matmul precision (gpt2_train.build_decoder asks
     the same of the expert layer's operands)."""
     return jax.config.jax_default_matmul_precision is None
 
@@ -340,3 +364,368 @@ def mla_attention(q, q_r, kv, k_r, interpret=False):
     if path == "einsum":
         return mla_attention_einsum(q, q_r, kv, k_r)
     return mla_attention_fused(q, q_r, kv, k_r, interpret)
+
+
+# -- grouped queries, an optional window, the keys tiled ----------------------
+
+GQA_TILE = 512      # query tile = key tile
+# the backward kernel keeps one key/value head's dk and dv of a whole
+# sequence in VMEM (T x d float32 each, twice for the pipeline). The longest
+# the kernels were compiled for (tests/test_tpu_aot.py) and run at on a v5e
+MAX_GQA_T = 4096
+# the tile walk of the fused calls traced in this process, by kind of layer
+# ("full" / "window"): tile, key tiles visited over one sequence's query
+# tiles, and how many a causal mask alone would visit
+GQA_PLAN: dict = {}
+# a running max starts above a masked score, so that a row whose keys in a
+# visited tile are all masked adds exp(masked - start) = 0, not exp(0)
+_M_START = -1e30
+
+
+def _gqa_dims(q, k):
+    """(S, T, Hq, Hkv, d) of a call's operands."""
+    S, T, Hq, d = q.shape
+    return S, T, Hq, k.shape[2], d
+
+
+def _sees(query, key, window):
+    """The mask: ``key <= query`` and, with a window, ``query - key <
+    window``."""
+    ok = key <= query
+    return ok if window is None else ok & (query - key < window)
+
+
+def gqa_attention_einsum(q, k, v, window=None):
+    S, T, Hq, Hkv, d = _gqa_dims(q, k)
+    qg = q.reshape(S, T, Hkv, Hq // Hkv, d)
+    att = jnp.einsum("sqhgd,skhd->shgqk", qg, k) * (d ** -0.5)
+    pos = jnp.arange(T)
+    att = jnp.where(_sees(pos[:, None], pos[None, :], window), att,
+                    jnp.finfo(att.dtype).min)
+    att = jax.nn.softmax(att.astype(jnp.float32), axis=-1)
+    return jnp.einsum("shgqk,skhd->sqhgd", att.astype(v.dtype),
+                      v).reshape(q.shape)
+
+
+def _visits(T: int, tile: int, window) -> int:
+    """Key tiles a query tile's grid row visits: those that end on its
+    diagonal tile. Without a window all that a causal mask can reach (the
+    ones before the sequence's start are skipped without a load)."""
+    n = T // tile
+    return n if window is None else min(n, -(-(window - 1) // tile) + 1)
+
+
+def gqa_shape_ok(T: int, Hq: int, Hkv: int, d: int, tile=GQA_TILE) -> bool:
+    """Whether the kernels take this shape."""
+    return (T % tile == 0 and 0 < T <= MAX_GQA_T and d % TILE == 0
+            and Hkv > 0 and Hq % Hkv == 0)
+
+
+def _walk(tile, n_visits, window):
+    """Where a grid step stands: (query tile, key tile, whether the key
+    tile exists, whether every score of the pair is seen)."""
+    from jax.experimental import pallas as pl
+
+    i, step = pl.program_id(2), pl.program_id(3)
+    j = i - (n_visits - 1) + step
+    whole = j < i
+    if window is not None:
+        whole = whole & ((i - j + 1) * tile <= window)
+    return i, j, j >= 0, whole
+
+
+def _scores(q, k, i, j, tile, window, scale, masked):
+    """(queries, keys) float32 scores of one head's tile pair."""
+    s = _dot(q, k, _NT) * scale
+    if not masked:
+        return s
+    query = i * tile + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    key = j * tile + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(_sees(query, key, window), s, _MASKED)
+
+
+def _both(exists, whole, step):
+    """Run ``step(masked)`` once: without the mask where the whole tile pair
+    is seen, with it on the pairs the mask's edge crosses."""
+    from jax.experimental import pallas as pl
+
+    pl.when(exists & whole)(functools.partial(step, False))
+    pl.when(exists & jnp.logical_not(whole))(functools.partial(step, True))
+
+
+def _lanes(col):
+    """(n, 1) -> (n, LANES): a row statistic on every lane, the shape the
+    kernels keep them in (a one-lane column costs a vector register a
+    sublane group all the same, and every use of it a lane broadcast: 7 of
+    the forward kernel's 15.6 ms at the published shape, PERF.md PR 32)."""
+    return jnp.broadcast_to(col, (col.shape[0], TILE))
+
+
+def _lanes_to(x, d):
+    """A (n, LANES) row statistic against (n, d) columns of a head."""
+    return x[:, :d] if d <= TILE else jnp.tile(x, (1, d // TILE))
+
+
+def _gqa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qb, m_s, l_s, acc,
+                    *, group, d, tile, n_visits, window, scale, mul):
+    from jax.experimental import pallas as pl
+
+    i, j, exists, whole = _walk(tile, n_visits, window)
+    first = pl.program_id(3) == 0
+    last = pl.program_id(3) == n_visits - 1
+
+    @pl.when(first)
+    def _():
+        qb[...] = q_ref[0].astype(mul)
+        m_s[...] = jnp.full_like(m_s, _M_START)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc[...] = jnp.zeros_like(acc)
+
+    def step(masked):
+        k, v = k_ref[0].astype(mul), v_ref[0].astype(mul)
+        for h in range(group):
+            cols = slice(h * d, (h + 1) * d)
+            s = _scores(qb[:, cols], k, i, j, tile, window, scale, masked)
+            m_old = m_s[h]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new[:, :1])
+            turn = jnp.exp(m_old - m_new)
+            l_s[h] = turn * l_s[h] + jnp.sum(p, axis=1, keepdims=True)
+            acc[:, cols] = _lanes_to(turn, d) * acc[:, cols] \
+                + _dot(p.astype(mul), v, _NN)
+            m_s[h] = m_new
+
+    _both(exists, whole, step)
+
+    @pl.when(last)
+    def _():
+        lane = lax.broadcasted_iota(jnp.int32, (tile, group), 1)
+        lse = jnp.zeros((tile, group), jnp.float32)
+        for h in range(group):
+            cols = slice(h * d, (h + 1) * d)
+            o_ref[0, :, cols] = (acc[:, cols] / _lanes_to(l_s[h], d)
+                                 ).astype(o_ref.dtype)
+            lse = jnp.where(lane == h,
+                            (m_s[h] + jnp.log(l_s[h]))[:, :group], lse)
+        lse_ref[0, 0] = lse
+
+
+def _gqa_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                    dq_ref, dk_ref, dv_ref, qb, dob, lse_s, delta,
+                    *, group, d, tile, n_visits, window, scale, mul):
+    from jax.experimental import pallas as pl
+
+    i, j, exists, whole = _walk(tile, n_visits, window)
+    first = pl.program_id(3) == 0
+
+    @pl.when(first & (i == 0))
+    def _():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    @pl.when(first)
+    def _():
+        qb[...] = q_ref[0].astype(mul)
+        dob[...] = do_ref[0].astype(mul)
+        dq_ref[...] = jnp.zeros_like(dq_ref)
+        lane = lax.broadcasted_iota(jnp.int32, (tile, group), 1)
+        for h in range(group):
+            cols = slice(h * d, (h + 1) * d)
+            lse_s[h] = _lanes(jnp.sum(
+                jnp.where(lane == h, lse_ref[0, 0], 0.0), axis=1,
+                keepdims=True))
+            # sum_k p dp of a row: d_out . out
+            delta[h] = _lanes(jnp.sum(
+                do_ref[0, :, cols] * o_ref[0, :, cols], axis=1,
+                keepdims=True))
+
+    def step(masked):
+        k, v = k_ref[0].astype(mul), v_ref[0].astype(mul)
+        d_k = jnp.zeros((tile, d), jnp.float32)
+        d_v = jnp.zeros((tile, d), jnp.float32)
+        for h in range(group):
+            cols = slice(h * d, (h + 1) * d)
+            q, do = qb[:, cols], dob[:, cols]
+            s = _scores(q, k, i, j, tile, window, scale, masked)
+            p = jnp.exp(s - lse_s[h][:, :1])
+            dp = _dot(do, v, _NT)
+            ds = p * (dp - delta[h][:, :1]) * scale
+            d_v = d_v + _dot(p.T.astype(mul), do, _NN)
+            d_k = d_k + _dot(ds.T.astype(mul), q, _NN)
+            dq_ref[0, :, cols] += _dot(ds.astype(mul), k, _NN)
+        keys = pl.ds(pl.multiple_of(j * tile, tile), tile)
+        dk_ref[0, keys, :] += d_k
+        dv_ref[0, keys, :] += d_v
+
+    _both(exists, whole, step)
+
+
+def _gqa_call(kernel, name, dims, tile, window, in_specs, out_specs,
+              out_shape, scratch, operand_dtype, interpret):
+    """One ``pallas_call`` over (sequences, key/value heads, query tiles,
+    the key tiles a query tile visits)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, T, Hq, Hkv, d = dims
+    G = Hq // Hkv
+    n_visits = _visits(T, tile, window)
+
+    def key_tile(s, g, i, step):
+        # a tile before the sequence's start is not loaded: the index stays
+        # on tile 0, which the next existing step wants anyway
+        return (s, jnp.maximum(i - (n_visits - 1) + step, 0), g)
+
+    specs = {"q": pl.BlockSpec((1, tile, G * d), lambda s, g, i, _: (s, i, g)),
+             "kv": pl.BlockSpec((1, tile, d), key_tile),
+             "whole": pl.BlockSpec((1, T, d), lambda s, g, i, _: (s, 0, g)),
+             "stat": pl.BlockSpec((1, 1, tile, G),
+                                  lambda s, g, i, _: (s, g, i, 0))}
+    shapes = {"q": (tile, G * d), "stat": (G, tile, TILE)}
+    return pl.pallas_call(
+        functools.partial(kernel, group=G, d=d, tile=tile, n_visits=n_visits,
+                          window=window, scale=d ** -0.5, mul=operand_dtype),
+        grid=(S, Hkv, T // tile, n_visits),
+        in_specs=[specs[k] for k in in_specs],
+        out_specs=[specs[k] for k in out_specs],
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM(shapes[k], dt) for k, dt in scratch],
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=48 * 1024 * 1024),
+        interpret=interpret,
+        name=name)
+
+
+def _gqa_forward(q, k, v, window, tile, operand_dtype, interpret):
+    S, T, Hq, Hkv, d = dims = _gqa_dims(q, k)
+    out, lse = _gqa_call(
+        _gqa_fwd_kernel, "fed_gqa_attn_fwd", dims, tile, window,
+        ("q", "kv", "kv"), ("q", "stat"),
+        [jax.ShapeDtypeStruct((S, T, Hq * d), q.dtype),
+         jax.ShapeDtypeStruct((S, Hkv, T, Hq // Hkv), jnp.float32)],
+        (("q", operand_dtype), ("stat", jnp.float32), ("stat", jnp.float32),
+         ("q", jnp.float32)),
+        operand_dtype, interpret)(_flat(q), _flat(k), _flat(v))
+    return out.reshape(q.shape), lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _gqa_fused(q, k, v, window, tile, operand_dtype, interpret):
+    return _gqa_forward(q, k, v, window, tile, operand_dtype, interpret)[0]
+
+
+def _gqa_fused_fwd(q, k, v, window, tile, operand_dtype, interpret):
+    out, lse = _gqa_forward(q, k, v, window, tile, operand_dtype, interpret)
+    return out, (q, k, v, out, lse)
+
+
+def gqa_scope(window) -> str:
+    """The inner scope of a layer's kind, under ``fed_gqa_attn``."""
+    return "fed_gqa_attn_full" if window is None else "fed_gqa_attn_window"
+
+
+def _gqa_fused_bwd(window, tile, operand_dtype, interpret, res, d_out):
+    q, k, v, out, lse = res
+    S, T, Hq, Hkv, d = dims = _gqa_dims(q, k)
+    # traced here, not where the forward call was: the scopes again
+    with jax.named_scope("fed_gqa_attn"), jax.named_scope(gqa_scope(window)):
+        dq, dk, dv = _gqa_call(
+            _gqa_bwd_kernel, "fed_gqa_attn_bwd", dims, tile, window,
+            ("q", "kv", "kv", "q", "q", "stat"), ("q", "whole", "whole"),
+            [jax.ShapeDtypeStruct((S, T, Hq * d), jnp.float32),
+             jax.ShapeDtypeStruct((S, T, Hkv * d), jnp.float32),
+             jax.ShapeDtypeStruct((S, T, Hkv * d), jnp.float32)],
+            (("q", operand_dtype), ("q", operand_dtype),
+             ("stat", jnp.float32), ("stat", jnp.float32)),
+            operand_dtype, interpret,
+        )(_flat(q), _flat(k), _flat(v), _flat(out), _flat(d_out), lse)
+        return (dq.reshape(q.shape).astype(q.dtype),
+                dk.reshape(k.shape).astype(k.dtype),
+                dv.reshape(v.shape).astype(v.dtype))
+
+
+_gqa_fused.defvjp(_gqa_fused_fwd, _gqa_fused_bwd)
+
+
+def gqa_attention_fused(q, k, v, window=None, interpret=False,
+                        tile=GQA_TILE):
+    """The fused kernels; multiplicands rounded as ``mla_attention_fused``
+    rounds them. ``tile`` is for the interpreted tests' small shapes."""
+    T = q.shape[1]
+    n = T // tile
+    GQA_PLAN["full" if window is None else "window"] = {
+        "tile": tile,
+        "key_tiles_visited": sum(min(i + 1, _visits(T, tile, window))
+                                 for i in range(n)),
+        "key_tiles_causal": n * (n + 1) // 2}
+    return _gqa_fused(q, k, v, window, tile,
+                      jnp.bfloat16 if _rounds_to_bfloat16() else jnp.float32,
+                      interpret)
+
+
+def gqa_attention_path(T, Hq, Hkv, d, interpret=False) -> str:
+    """``attention_path``'s twin for the grouped-query core."""
+    on_chip = is_tpu_backend() and _rounds_to_bfloat16()
+    if (on_chip or interpret) and gqa_shape_ok(T, Hq, Hkv, d):
+        return "fused"
+    return "einsum"
+
+
+def gqa_attention(q, k, v, window=None, interpret=False):
+    """The grouped-query core on the path this call's shape and process
+    take (``gqa_attention_path``); counts the call in ``PATH_CALLS``."""
+    path = gqa_attention_path(*_gqa_dims(q, k)[1:], interpret)
+    PATH_CALLS[path] += 1
+    if path == "einsum":
+        return gqa_attention_einsum(q, k, v, window)
+    return gqa_attention_fused(q, k, v, window, interpret)
+
+
+# the kernels against the oracle on the chip: both round multiplicands to
+# bfloat16 (2^-9 relative), the kernels round the unnormalised probabilities
+# of a key tile where the oracle rounds the normalised ones, and the sums
+# over keys run in another order; so not bit for bit, but within rounding of
+# the largest entry
+GQA_CHECK_TOL = 2e-2
+
+
+def check_gqa_kernels(heads=(48, 64), kv_heads=8, d=128, T=MAX_GQA_T,
+                      window=512, interpret=False, tile=GQA_TILE) -> dict:
+    """``gqa_attention_fused`` against ``gqa_attention_einsum`` at the
+    published shape of models/laguna.py (one sequence; 48 query heads with
+    no window, 64 with it): output and all three gradients within
+    ``GQA_CHECK_TOL`` of the oracle's largest entry. The oracle's scores do
+    not fit a chip whole at 4,096 positions, so it runs one key/value head
+    (and its query heads) at a time. Returns the largest gaps seen."""
+    worst = {}
+    for n, (Hq, win) in enumerate(zip(heads, (None, window))):
+        G = Hq // kv_heads
+        keys = jax.random.split(jax.random.key(n), 4)
+        q, k, v, w = (jax.random.normal(key, (1, T, h, d), jnp.float32)
+                      for key, h in zip(keys, (Hq, kv_heads, kv_heads, Hq)))
+
+        def both(fn):
+            def run(q, k, v, w):
+                return (fn(q, k, v),) + jax.grad(
+                    lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2))(q, k, v)
+            return jax.jit(run)
+
+        got = both(functools.partial(gqa_attention_fused, window=win,
+                                     interpret=interpret, tile=tile))(
+                                         q, k, v, w)
+        one = both(functools.partial(gqa_attention_einsum, window=win))
+        for g in range(kv_heads):
+            hq, hk = slice(g * G, (g + 1) * G), slice(g, g + 1)
+            want = one(q[:, :, hq], k[:, :, hk], v[:, :, hk], w[:, :, hq])
+            for name, a, b, cut in zip(("out", "dq", "dk", "dv"), got, want,
+                                       (hq, hq, hk, hk)):
+                gap = float(jnp.max(jnp.abs(a[:, :, cut] - b))
+                            / jnp.max(jnp.abs(b)))
+                key = f"{name}_{'window' if win else 'full'}"
+                worst[key] = max(worst.get(key, 0.0), gap)
+    bad = {k: v for k, v in worst.items() if not v <= GQA_CHECK_TOL}
+    if bad:
+        raise AssertionError(f"gqa kernels off the oracle: {bad}")
+    return worst

@@ -43,7 +43,8 @@ The Switch auxiliary load-balancing loss (E·Σ f·P) is sown into the
 dispatch imbalance is a routing-quality concern; under sparse dispatch it
 additionally controls the overflow-drop rate, so keep it on there).
 
-``RoutedMoE`` is the layer of the DeepSeek-V3 family (models/joyai.py):
+``RoutedMoE`` is the layer of the DeepSeek-V3 family (models/joyai.py, and
+models/laguna.py without the selection bias):
 sigmoid scores, the ``top_k`` experts by ``score + router_bias``, gates
 renormalised over the selected and scaled, a shared expert beside the routed
 ones. It is *told which experts it holds* (``n_held`` from ``expert_offset``
@@ -411,6 +412,9 @@ class RoutedMoE(nn.Module):
     # the multiplicands' type in the grouped products (``_grouped_dot``);
     # whoever builds the model knows the backend and the precision
     operand_dtype: Optional[Any] = None
+    # DeepSeek-V3's ``router_bias`` leaf, added to the scores in the
+    # selection; a model without one (models/laguna.py) has no such leaf
+    selection_bias: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -423,7 +427,7 @@ class RoutedMoE(nn.Module):
         # e_score_correction_bias: takes part in the selection only, so its
         # gradient is nought; the loss-free balancing update is not run
         bias = self.param("router_bias", nn.initializers.normal(0.01),
-                          (self.n_routed,))
+                          (self.n_routed,)) if self.selection_bias else None
         w_gate = self.param("w_gate", init, (E, C, F))
         w_up = self.param("w_up", init, (E, C, F))
         w_down = self.param("w_down", init, (E, F, C))
@@ -435,7 +439,8 @@ class RoutedMoE(nn.Module):
             score = jax.nn.sigmoid(jnp.dot(
                 xf.astype(jnp.float32), router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST))
-            _, idx = jax.lax.top_k(score + bias, self.top_k)
+            _, idx = jax.lax.top_k(
+                score if bias is None else score + bias, self.top_k)
             # the selected scores by mask, not by a gather (a gather of 8
             # of 256 a token and its scatter-add cost 11 ms a round on the
             # v5e; the masked sum fuses)

@@ -60,12 +60,15 @@ DEVICE_STAGES = ("fed_client_grad", "fed_client_compress",
                  "fed_server_resketch", "fed_server_apply",
                  "fed_telemetry_metrics", "fed_accounting", "fed_val")
 # Scopes a model opens INSIDE ``fed_client_grad`` (models/joyai.py,
-# parallel/moe.py): the expert layer's routing (scores, top-k, grouping,
-# gather and scatter of the held pairs), its grouped products, and the
-# latent attention's core (ops/attention.py opens it again around its
-# backward pass). Not stages: an operation under one of them still
-# has ``fed_client_grad`` as its one stage.
-INNER_SCOPES = ("fed_moe_route", "fed_moe_experts", "fed_mla_attn")
+# models/laguna.py, parallel/moe.py): the expert layer's routing (scores,
+# top-k, grouping, gather and scatter of the held pairs), its grouped
+# products, the latent attention's core, and the grouped-query attention's
+# (RoPE, the core, the head gate) with the kind of its layer nested in it
+# (ops/attention.py opens an attention scope again around its backward
+# pass). Not stages: an operation under one of them still has
+# ``fed_client_grad`` as its one stage.
+INNER_SCOPES = ("fed_moe_route", "fed_moe_experts", "fed_mla_attn",
+                "fed_gqa_attn", "fed_gqa_attn_full", "fed_gqa_attn_window")
 # ``name=`` of every pallas_call (ops/sketch.py, ops/topk.py,
 # ops/attention.py). The sketch kernels keep ``sketch`` / ``estimates`` /
 # ``epilogue`` in theirs and the top-k and attention kernels do not:
@@ -73,7 +76,8 @@ INNER_SCOPES = ("fed_moe_route", "fed_moe_experts", "fed_mla_attn")
 # words.
 KERNEL_NAMES = ("fed_sketch_vec", "fed_sketch_accum", "fed_estimates",
                 "fed_epilogue", "fed_topk_count", "fed_topk_descent",
-                "fed_mla_attn_fwd", "fed_mla_attn_bwd")
+                "fed_mla_attn_fwd", "fed_mla_attn_bwd",
+                "fed_gqa_attn_fwd", "fed_gqa_attn_bwd")
 
 
 # THE heartbeat line format, one producer (Heartbeat.round) and one parser

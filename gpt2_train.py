@@ -56,7 +56,8 @@ from commefficient_tpu.models.gpt2 import (
     resize_token_embeddings,
 )
 from commefficient_tpu.models.joyai import JoyAIConfig, JoyAIFlash
-from commefficient_tpu.ops.attention import PATH_CALLS
+from commefficient_tpu.models.laguna import LagunaConfig, LagunaXS2
+from commefficient_tpu.ops.attention import GQA_PLAN, PATH_CALLS
 from commefficient_tpu.utils import (
     PiecewiseLinear,
     TableLogger,
@@ -106,21 +107,27 @@ def _wrap(collate):
 
 
 def report_attention_core(model):
-    """Which path the latent attention's core was traced on in this process
-    and how many calls took it (ops/attention.py ``PATH_CALLS``): printed,
-    and a ``model`` event in the run's log. Said once a run, when its first
-    round has been dispatched, so the round's own programs are among the
-    traces counted, not the initialisation's alone."""
+    """Which path the attention's core (latent or grouped-query) was traced
+    on in this process and how many calls took it (ops/attention.py
+    ``PATH_CALLS``), and the grouped-query kernels' tile walk by kind of
+    layer (``GQA_PLAN``): printed, and a ``model`` event in the run's log.
+    Said once a run, when its first round has been dispatched, so the
+    round's own programs are among the traces counted, not the
+    initialisation's alone."""
     if getattr(model, "attention_core_reported", False):
         return
     model.attention_core_reported = True
     attn_path = "fused" if PATH_CALLS["fused"] else "einsum"
     print(f"attention core: {attn_path} path, "
-          f"{PATH_CALLS[attn_path]} calls traced (ops/attention.py)")
+          f"{PATH_CALLS[attn_path]} calls traced (ops/attention.py)"
+          + "".join(f"; {kind} layers visit {p['key_tiles_visited']} of "
+                    f"{p['key_tiles_causal']} causal key tiles of {p['tile']}"
+                    for kind, p in sorted(GQA_PLAN.items())))
     rt = getattr(model, "telemetry", None)
     if rt is not None:
         rt.event("model", attn_path=attn_path,
-                 attn_calls=PATH_CALLS[attn_path])
+                 attn_calls=PATH_CALLS[attn_path],
+                 **({"attn_plan": dict(GQA_PLAN)} if GQA_PLAN else {}))
 
 
 def run_batches(model, opt, lr_scheduler, loader, args, timer, training,
@@ -179,7 +186,7 @@ def run_batches(model, opt, lr_scheduler, loader, args, timer, training,
                               batch_stats))
 
         def submitted(rounds_done):
-            if engine.rounds_submitted == 1 and args.arch == "joyai_llm_flash":
+            if engine.rounds_submitted == 1 and args.arch in DECODERS:
                 report_attention_core(model)
             # the scheduler stepped inside submit(); record this round's
             # batch index and LR so its drained row logs what it ran with
@@ -255,13 +262,19 @@ def train_gpt2(model, opt, scheduler, train_loader, val_loader, args,
     return test_gpt2(model, val_loader, args, timer=timer, writer=writer)
 
 
-def build_joyai(args, tiny):
-    """JoyAI-LLM-Flash and its causal-LM losses, cut as the flags say: the
-    layers held, this chip's experts of --layer_chips that share a layer,
-    the vocabulary's rows (models/joyai.py)."""
-    full = JoyAIConfig.tiny() if tiny else JoyAIConfig()
-    assert full.n_routed_experts % args.layer_chips == 0, \
-        f"--layer_chips must divide {full.n_routed_experts} routed experts"
+# --arch: the configuration and its decoder (models/joyai.py, models/laguna.py)
+DECODERS = {"joyai_llm_flash": (JoyAIConfig, JoyAIFlash),
+            "laguna_xs2": (LagunaConfig, LagunaXS2)}
+
+
+def build_decoder(args, tiny):
+    """The --arch decoder and its causal-LM losses, cut as the flags say:
+    the layers held, this chip's experts of --layer_chips that share a
+    layer, the vocabulary's rows."""
+    config, decoder = DECODERS[args.arch]
+    full = config.tiny() if tiny else config()
+    assert full.routed % args.layer_chips == 0, \
+        f"--layer_chips must divide {full.routed} routed experts"
     # the unit rounds a float32 product's multiplicands to bfloat16 at the
     # default precision; XLA:TPU does not do so to a grouped product, so the
     # expert layer is told to (parallel/moe.py _grouped_dot)
@@ -270,14 +283,14 @@ def build_joyai(args, tiny):
     cfg = dataclasses.replace(
         full, expert_operand_dtype=jnp.bfloat16 if rounds else None,
         layers=args.arch_layers or full.layers,
-        experts_held=full.n_routed_experts // args.layer_chips,
+        experts_held=full.routed // args.layer_chips,
         expert_offset=args.expert_offset,
         vocab_rows=args.vocab_rows or max(full.vocab_rows,
                                           args.len_tokenizer))
     assert args.len_tokenizer <= cfg.vocab_rows, (
         f"the tokenizer's {args.len_tokenizer} ids do not fit the "
         f"{cfg.vocab_rows} rows of the vocabulary held")
-    model = JoyAIFlash(cfg)
+    model = decoder(cfg)
     return (model,) + make_causal_lm_losses(model)
 
 
@@ -367,8 +380,9 @@ def train(argv=None):
 
     # model geometry: tiny when smoke-testing or using the byte fallback
     tiny = args.do_test or os.environ.get("COMMEFFICIENT_TINY_MODEL")
-    if args.arch == "joyai_llm_flash":
-        model, compute_loss_train, compute_loss_val = build_joyai(args, tiny)
+    if args.arch in DECODERS:
+        model, compute_loss_train, compute_loss_val = build_decoder(args,
+                                                                    tiny)
     elif tiny:
         # COMMEFFICIENT_TINY_LAYERS: tests exercising layer-pattern
         # constraints (e.g. MoE pipeline stage alignment) need more depth

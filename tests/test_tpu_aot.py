@@ -181,3 +181,43 @@ def test_attention_kernels_compile_for_v5e(S, T, backward):
     # nothing of the scores' size, and no copy of an operand either: the
     # kernels read q and kv as they are
     assert compiled.memory_analysis().temp_size_in_bytes < S * T * H * 192 * 4
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+@pytest.mark.parametrize("Hq,window", [(48, None), (64, 512)],
+                         ids=["full48", "window64"])
+def test_gqa_attention_kernels_compile_for_v5e(Hq, window, backward):
+    """The grouped-query core (ops/attention.py) at
+    ``laguna_xs2_sketch_1c``'s shape: 4 sequences x 4,096 positions (the
+    longest the path chooser sends to the kernels), 48 query heads with no
+    window or 64 with a window of 512 over 8 key/value heads of 128. Mosaic
+    takes both kernels (their blocks fit VMEM) and nothing of the scores'
+    size (4.3 GB a sequence of a 64-head layer in float32) is left in HBM
+    around them: beside the output only the log-sum-exp, a lane-padded
+    (4, 8, 4096, G) float32 array of 64 MiB."""
+    S, T, Hkv, d = 4, attention.MAX_GQA_T, 8, 128
+    one = SingleDeviceSharding(_v5e_devices()[0])
+    q, k, v, d_out = (
+        jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+        for shape in ((S, T, Hq * d), (S, T, Hkv * d), (S, T, Hkv * d),
+                      (S, T, Hq * d)))
+
+    def fwd(q, k, v):
+        heads = (lambda x: x.reshape(S, T, -1, d))
+        return attention.gqa_attention_fused(
+            heads(q), heads(k), heads(v), window).reshape(S, T, -1)
+
+    def bwd(q, k, v, d_out):
+        return jax.vjp(fwd, q, k, v)[1](d_out)
+
+    _, compiled = _compile_tpu(jax.jit(bwd if backward else fwd),
+                               *((q, k, v) + ((d_out,) if backward else ())))
+    text = compiled.as_text()
+    assert "fed_gqa_attn_fwd" in text
+    assert ("fed_gqa_attn_bwd" in text) == backward
+    # forward: the log-sum-exp alone; backward: the forward's output too
+    # (the residual), never a (T, T) array
+    out_bytes = S * T * Hq * d * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < (out_bytes if backward else 0) + (65 << 20)

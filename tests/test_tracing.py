@@ -203,25 +203,29 @@ class TestInnerScopes:
         ``stage_of`` finds one stage an operation."""
         from commefficient_tpu.federated.losses import make_causal_lm_losses
         from commefficient_tpu.models.joyai import JoyAIConfig, JoyAIFlash
+        from commefficient_tpu.models.laguna import LagunaConfig, LagunaXS2
         from commefficient_tpu.profiling import INNER_SCOPES
 
         assert not set(INNER_SCOPES) & set(DEVICE_STAGES)
-        model = JoyAIFlash(JoyAIConfig.tiny(layers=2, experts_held=4,
-                                            expert_offset=0, vocab_rows=64))
-        ids = jnp.zeros((2, 1, 8), jnp.int32)
-        params = model.init(jax.random.key(0), ids[:, 0])["params"]
-        train, _ = make_causal_lm_losses(model)
-        batch = {"input_ids": ids, "lm_labels": ids,
-                 "mask": jnp.ones(2, jnp.float32)}
+        cut = dict(layers=2, experts_held=4, expert_offset=0, vocab_rows=64)
+        names = set()
+        # (a full and a sliding layer: LagunaConfig.tiny's first two)
+        for model in (JoyAIFlash(JoyAIConfig.tiny(**cut)),
+                      LagunaXS2(LagunaConfig.tiny(**cut))):
+            ids = jnp.zeros((2, 1, 8), jnp.int32)
+            params = model.init(jax.random.key(0), ids[:, 0])["params"]
+            train, _ = make_causal_lm_losses(model)
+            batch = {"input_ids": ids, "lm_labels": ids,
+                     "mask": jnp.ones(2, jnp.float32)}
 
-        @jax.jit
-        def step(p):
-            with jax.named_scope("fed_client_grad"):
-                return jax.grad(
-                    lambda p: train(p, {}, batch, None, True)[0])(p)
+            @jax.jit
+            def step(p):
+                with jax.named_scope("fed_client_grad"):
+                    return jax.grad(
+                        lambda p: train(p, {}, batch, None, True)[0])(p)
 
-        names = set(re.findall(r'op_name="([^"]*)"',
-                               step.lower(params).compile().as_text()))
+            names |= set(re.findall(r'op_name="([^"]*)"',
+                                    step.lower(params).compile().as_text()))
         for inner in INNER_SCOPES:
             under = [n for n in names if inner in n]
             assert under, f"no operation under {inner}"
@@ -262,6 +266,7 @@ class TestKernelNames:
         raw = jnp.zeros((4, 8, 128), jnp.int32)
         qkv = [jnp.zeros((1, 128) + w) for w in (
             (2, 192), (2, 64), (2, 256), (64,))]
+        gqa = [jnp.zeros((1, 32, h, 16)) for h in (2, 1, 1)]
         calls = {
             "fed_sketch_vec": lambda: sk._sketch_vec_pallas(v3, *hashes,
                                                             **kw),
@@ -283,6 +288,11 @@ class TestKernelNames:
                 jnp.float32, True,
                 (*qkv, jnp.zeros((1, 128, 2, 128)), jnp.zeros((1, 1, 2, 128))),
                 jnp.zeros((1, 128, 2, 128))),
+            "fed_gqa_attn_fwd": lambda: at.gqa_attention_fused(
+                *gqa, window=16, interpret=True, tile=16),
+            "fed_gqa_attn_bwd": lambda: at._gqa_fused_bwd(
+                16, 16, jnp.float32, True,
+                (*gqa, gqa[0], jnp.zeros((1, 1, 32, 2))), gqa[0]),
         }
         names = _pallas_names(jax.make_jaxpr(calls[kernel])().jaxpr, [])
         assert names == [kernel]
@@ -293,7 +303,7 @@ class TestKernelNames:
         attention kernels must not match."""
         word = re.compile(r"sketch|estimates|epilogue")
         assert [bool(word.search(k)) for k in KERNEL_NAMES] \
-            == [True, True, True, True, False, False, False, False]
+            == [True] * 4 + [False] * 6
 
 
 def _engine(tmp_path, mode="sketch", window=2, drain_every=4, tracer=None):
