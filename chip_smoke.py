@@ -174,8 +174,10 @@ def phase_kernels(ns) -> dict:
         ("topk pruned descent",
          lambda: tk.check_pruned_descent(g["big_d"], g["k"], cs.sublanes,
                                          interpret)),
-        # the grouped-query attention core at models/laguna.py's published
-        # shape; within rounding of its oracle, not bit-equal (at.GQA_CHECK_TOL)
+        # the grouped-query attention at models/laguna.py's published shape,
+        # the turn of q and k and the heads' gates inside the kernels: gated
+        # output and four gradients, full and window layer; within rounding
+        # of its oracle, not bit-equal (at.GQA_CHECK_TOL)
         ("gqa attention fwd + bwd",
          lambda: at.check_gqa_kernels(**g.get("gqa", {}),
                                       interpret=interpret)),
@@ -191,7 +193,8 @@ def phase_kernels(ns) -> dict:
             _say(f"kernel {name}: FAILED {type(e).__name__}: {e}")
         else:
             verdict = (f"within {at.GQA_CHECK_TOL:g} of the oracle's "
-                       f"largest entry (worst {max(gaps.values()):.2e}"
+                       f"largest entry (worst {max(gaps.values()):.2e}: "
+                       + ", ".join(f"{k} {v:.1e}" for k, v in gaps.items())
                        if gaps else
                        f"bit-equal to reference (T={cs.T} S={cs.sublanes}")
             _say(f"kernel {name}: {how}, {verdict}, "

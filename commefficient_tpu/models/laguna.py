@@ -16,8 +16,10 @@ this configuration supplies): ``h = x + W_o (g * Core_l(q, k, v))``,
 scalar a head, RoPE on q and k by the layer's kind, and ``Core_l`` =
 ``softmax(q k^T / sqrt(128) + mask_l) v`` where query head h reads key/value
 head ``h // (H_l / 8)`` and a sliding layer's query i sees the keys j with
-``0 <= i - j < 512``. The core lives in ops/attention.py
-(``gqa_attention``), which also says on which path a call runs.
+``0 <= i - j < 512``. The core with the rotation and the gate lives in
+ops/attention.py (``gqa_attention``: q, k, v go in as the products wrote
+them and ``W_o``'s operand comes out), which also says on which path a call
+runs.
 
 What is held here is a cut the caller names, as in models/joyai.py: the
 first ``layers`` layers of the published lists, ``experts_held`` of the
@@ -166,21 +168,6 @@ def rope_frequencies(cfg: LagunaConfig, kind: str):
         cfg.yarn_attention_factor
 
 
-def _turn(x, cos, sin):
-    """Half-split RoPE on the first ``2 * cos.shape[-1]`` columns of every
-    head: the pair (x_i, x_{i+n/2}) of position p turned by p's angle, by a
-    roll of the rotary columns (no strided slice, no stack); the columns
-    past them pass. x (S, T, H, d), cos and sin (T, n/2)."""
-    half = cos.shape[-1]
-    cos2 = jnp.concatenate([cos, cos], axis=-1)[None, :, None]
-    sin2 = jnp.concatenate([-sin, sin], axis=-1)[None, :, None]
-    rot = x[..., :2 * half]
-    out = rot * cos2 + jnp.roll(rot, half, axis=-1) * sin2
-    if 2 * half == x.shape[-1]:
-        return out.astype(x.dtype)
-    return jnp.concatenate([out.astype(x.dtype), x[..., 2 * half:]], axis=-1)
-
-
 class GQA(nn.Module):
     cfg: LagunaConfig
     layer: int
@@ -188,14 +175,15 @@ class GQA(nn.Module):
     @nn.compact
     def __call__(self, x):
         c = self.cfg
-        S, T, C = x.shape
+        _, T, C = x.shape
         H, Hkv, d = (c.num_attention_heads_per_layer[self.layer],
                      c.num_key_value_heads, c.head_dim)
         kind = c.layer_types[self.layer]
         window = c.sliding_window if kind == "sliding_attention" else None
-        q = (x @ _kernel(self, "q", (C, H * d))).reshape(S, T, H, d)
-        k = (x @ _kernel(self, "k", (C, Hkv * d))).reshape(S, T, Hkv, d)
-        v = (x @ _kernel(self, "v", (C, Hkv * d))).reshape(S, T, Hkv, d)
+        # as the products write them, (S, T, H * d): the core takes them so
+        q = x @ _kernel(self, "q", (C, H * d))
+        k = x @ _kernel(self, "k", (C, Hkv * d))
+        v = x @ _kernel(self, "v", (C, Hkv * d))
         gate = x @ _kernel(self, "gate", (C, H))
         w_o = _kernel(self, "o", (H * d, C))
         with jax.named_scope("fed_gqa_attn"), \
@@ -203,11 +191,9 @@ class GQA(nn.Module):
             freq, factor = rope_frequencies(c, kind)
             angle = jnp.arange(T, dtype=jnp.float32)[:, None] \
                 * jnp.asarray(freq, jnp.float32)
-            cos, sin = jnp.cos(angle) * factor, jnp.sin(angle) * factor
-            out = gqa_attention(_turn(q, cos, sin), _turn(k, cos, sin), v,
-                                window)
-            out = out * jax.nn.sigmoid(gate)[..., None].astype(out.dtype)
-        return out.reshape(S, T, H * d) @ w_o
+            rope = jnp.cos(angle) * factor, jnp.sin(angle) * factor
+            out = gqa_attention(q, k, v, jax.nn.sigmoid(gate), rope, window)
+        return out @ w_o
 
 
 class LagunaXS2(Decoder):

@@ -36,26 +36,26 @@ set, T a multiple of 128 up to ``MAX_FUSED_T``, head widths that make
 lane-aligned groups); everything else runs the ``einsum``s. Which one a
 call was traced on is counted in ``PATH_CALLS``.
 
-The same module holds the causal core of grouped-query attention with an
-optional window (models/laguna.py ``GQA``): ``softmax(q k^T / sqrt(d) +
-mask) v`` with ``q`` (S, T, Hq, d), ``k`` and ``v`` (S, T, Hkv, d), query
-head ``h`` reading key/value head ``h // (Hq / Hkv)``, and query ``i`` seeing
-the keys ``j`` with ``0 <= i - j < window`` (no window: every ``j <= i``).
-``gqa_attention_einsum`` is the oracle; ``gqa_attention_fused`` is two Pallas
-kernels under one ``custom_vjp`` that, unlike the latent core's, tile the
-keys: the grid walks (sequence, key/value head, query tile, key tile) with a
-running max and sum, and of a query tile's row only the key tiles its mask
-can reach are visited (``_visits``: up to the diagonal, or the
-``ceil((window - 1) / tile) + 1`` that end on it), so the sequence length is
-bounded by the backward kernel's whole-sequence ``dk`` / ``dv`` blocks alone
-(``MAX_GQA_T``). A key/value tile is loaded once for the query heads that
-share it. The forward kernel writes the output and one float32 log-sum-exp
-a row; the backward kernel rebuilds the probabilities from it and makes all
-three gradients in one pass (``dq`` accumulates over a query tile's key
-tiles in its output block, ``dk`` and ``dv`` over the whole sequence in
-theirs). Arrays, rounding and the rule that picks the path
-(``gqa_attention_path``) are the latent core's; ``GQA_PLAN`` keeps the tile
-walk of the fused calls traced, by kind of layer.
+The same module holds the grouped-query attention of models/laguna.py ``GQA``
+from the projections' outputs to ``W_o``'s operand: ``g * softmax(R(q) R(k)^T
+/ sqrt(d) + mask) v`` on ``q`` (S, T, Hq*d), ``k``, ``v`` (S, T, Hkv*d), flat
+as ``x @ W`` wrote them, the gates ``g`` (S, T, Hq) and ``R`` the half-split
+RoPE of the layer's ``(cos, sin)``; query head ``h`` reads key/value head ``h
+// (Hq / Hkv)``, query ``i`` sees the keys ``j`` with ``0 <= i - j < window``
+(no window: every ``j <= i``). ``gqa_attention_einsum`` is the oracle, in
+``jnp``; ``gqa_attention_fused`` is two Pallas kernels under one
+``custom_vjp`` that, unlike the latent core's, tile the keys: the grid walks
+(sequence, key/value head, query tile, key tile) with a running max and sum
+and visits only the key tiles the mask can reach (``_visits``), so the
+sequence length is bounded by the backward kernel's whole-sequence ``dk`` /
+``dv`` blocks alone (``MAX_GQA_T``). A head is a 128-lane column slice of a
+tile in VMEM: there q and k are turned in float32 (lane rotations against a
+table of cos and sin, ``_rope_table``) before they are rounded, the output is
+multiplied by its head's gate, and the backward kernel runs the gate's and
+the turn's transposes on the same tiles, so no (S, T, H, d) array exists.
+The forward writes the gated output, the ungated one and a log-sum-exp a row
+(the backward's residuals: it divides by no gate). Rounding and the path's
+rule (``gqa_attention_path``) are the latent core's; ``GQA_PLAN`` is the plan.
 """
 
 from __future__ import annotations
@@ -373,19 +373,22 @@ GQA_TILE = 512      # query tile = key tile
 # sequence in VMEM (T x d float32 each, twice for the pipeline). The longest
 # the kernels were compiled for (tests/test_tpu_aot.py) and run at on a v5e
 MAX_GQA_T = 4096
-# the tile walk of the fused calls traced in this process, by kind of layer
-# ("full" / "window"): tile, key tiles visited over one sequence's query
-# tiles, and how many a causal mask alone would visit
+# the fused calls traced in this process, by kind of layer ("full" /
+# "window"): tile, key tiles visited over one sequence's query tiles, how
+# many a causal mask alone would visit; and for every call, fused or not,
+# where q and k were turned and the heads gated ("kernel" / "xla")
 GQA_PLAN: dict = {}
 # a running max starts above a masked score, so that a row whose keys in a
 # visited tile are all masked adds exp(masked - start) = 0, not exp(0)
 _M_START = -1e30
 
 
-def _gqa_dims(q, k):
+def _gqa_dims(q, k, gate):
     """(S, T, Hq, Hkv, d) of a call's operands."""
-    S, T, Hq, d = q.shape
-    return S, T, Hq, k.shape[2], d
+    S, T, width = q.shape
+    Hq = gate.shape[-1]
+    d = width // Hq
+    return S, T, Hq, k.shape[-1] // d, d
 
 
 def _sees(query, key, window):
@@ -395,16 +398,36 @@ def _sees(query, key, window):
     return ok if window is None else ok & (query - key < window)
 
 
-def gqa_attention_einsum(q, k, v, window=None):
-    S, T, Hq, Hkv, d = _gqa_dims(q, k)
-    qg = q.reshape(S, T, Hkv, Hq // Hkv, d)
+def _turn(x, cos, sin):
+    """Half-split RoPE on the first ``2 * cos.shape[-1]`` columns of every
+    head: the pair (x_i, x_{i+n/2}) of position p turned by p's angle, by a
+    roll of the rotary columns (no strided slice, no stack); the columns
+    past them pass. x (S, T, H, d), cos and sin (T, n/2)."""
+    half = cos.shape[-1]
+    cos2 = jnp.concatenate([cos, cos], axis=-1)[None, :, None]
+    sin2 = jnp.concatenate([-sin, sin], axis=-1)[None, :, None]
+    rot = x[..., :2 * half]
+    out = rot * cos2 + jnp.roll(rot, half, axis=-1) * sin2
+    if 2 * half == x.shape[-1]:
+        return out.astype(x.dtype)
+    return jnp.concatenate([out.astype(x.dtype), x[..., 2 * half:]], axis=-1)
+
+
+def gqa_attention_einsum(q, k, v, gate, rope, window=None):
+    """The oracle, in ``jnp``: the heads viewed (S, T, H, d), q and k turned
+    (``_turn``), two ``einsum``s around a float32 softmax, the gate."""
+    S, T, Hq, Hkv, d = _gqa_dims(q, k, gate)
+    q, k, v = (x.reshape(S, T, -1, d) for x in (q, k, v))
+    qg = _turn(q, *rope).reshape(S, T, Hkv, Hq // Hkv, d)
+    k = _turn(k, *rope)
     att = jnp.einsum("sqhgd,skhd->shgqk", qg, k) * (d ** -0.5)
     pos = jnp.arange(T)
     att = jnp.where(_sees(pos[:, None], pos[None, :], window), att,
                     jnp.finfo(att.dtype).min)
     att = jax.nn.softmax(att.astype(jnp.float32), axis=-1)
-    return jnp.einsum("shgqk,skhd->sqhgd", att.astype(v.dtype),
-                      v).reshape(q.shape)
+    out = jnp.einsum("shgqk,skhd->sqhgd", att.astype(v.dtype),
+                     v).reshape(S, T, Hq, d)
+    return (out * gate[..., None].astype(out.dtype)).reshape(S, T, Hq * d)
 
 
 def _visits(T: int, tile: int, window) -> int:
@@ -466,8 +489,59 @@ def _lanes_to(x, d):
     return x[:, :d] if d <= TILE else jnp.tile(x, (1, d // TILE))
 
 
-def _gqa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qb, m_s, l_s, acc,
-                    *, group, d, tile, n_visits, window, scale, mul):
+def _head_stat(block, h):
+    """Head ``h``'s column of a (tile, G) block of row statistics, on every
+    lane."""
+    lane = lax.broadcasted_iota(jnp.int32, block.shape, 1)
+    return _lanes(jnp.sum(jnp.where(lane == h, block, 0.0), axis=1,
+                          keepdims=True))
+
+
+def _stat_block(cols):
+    """G row statistics on every lane -> their (tile, G) block."""
+    group = len(cols)
+    lane = lax.broadcasted_iota(jnp.int32, (cols[0].shape[0], group), 1)
+    block = jnp.zeros(lane.shape, jnp.float32)
+    for h, col in enumerate(cols):
+        block = jnp.where(lane == h, col[:, :group], block)
+    return block
+
+
+def _rope_table(cos, sin, d):
+    """A layer's (T, n/2) cos and sin as lane tables a head wide, side by
+    side: ``C`` (cos on the rotary columns, 1 past them), ``A`` (-sin on
+    ``[0, n/2)``) and ``B`` (+sin on ``[n/2, n)``), zero elsewhere, so that
+    a head's turn is ``x C + roll(x, -n/2) A + roll(x, +n/2) B``. Where the
+    rotary columns are the whole head the two rolls are one, and the table
+    is ``C`` and ``A + B``."""
+    T, half = cos.shape
+    zero = functools.partial(jnp.zeros, dtype=cos.dtype)
+    c = jnp.concatenate([cos, cos, jnp.ones((T, d - 2 * half), cos.dtype)], 1)
+    a = jnp.concatenate([-sin, zero((T, d - half))], 1)
+    b = jnp.concatenate([zero((T, half)), sin, zero((T, d - 2 * half))], 1)
+    return jnp.concatenate([c, a + b] if 2 * half == d else [c, a, b], 1)
+
+
+def _rotate(x, table, half, back=False):
+    """A head's (tile, d) float32 columns turned by the positions of the
+    rows of ``table`` (a block of ``_rope_table``'s, in VMEM); ``back`` is
+    the transpose, which takes a gradient of the turned columns to the raw
+    ones (the turn by the opposite angle, times the same factor)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    d = x.shape[1]
+    if 2 * half == d:
+        swapped = pltpu.roll(x, half, 1) * table[:, d:]
+    else:
+        swapped = pltpu.roll(x, d - half, 1) * table[:, d:2 * d] \
+            + pltpu.roll(x, half, 1) * table[:, 2 * d:]
+    straight = x * table[:, :d]
+    return straight - swapped if back else straight + swapped
+
+
+def _gqa_fwd_kernel(q_ref, k_ref, v_ref, g_ref, tq_ref, tk_ref,
+                    og_ref, o_ref, lse_ref, qb, m_s, l_s, acc,
+                    *, group, d, half, tile, n_visits, window, scale, mul):
     from jax.experimental import pallas as pl
 
     i, j, exists, whole = _walk(tile, n_visits, window)
@@ -476,13 +550,17 @@ def _gqa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qb, m_s, l_s, acc,
 
     @pl.when(first)
     def _():
-        qb[...] = q_ref[0].astype(mul)
+        for h in range(group):
+            cols = slice(h * d, (h + 1) * d)
+            qb[:, cols] = _rotate(q_ref[0, :, cols], tq_ref,
+                                  half).astype(mul)
         m_s[...] = jnp.full_like(m_s, _M_START)
         l_s[...] = jnp.zeros_like(l_s)
         acc[...] = jnp.zeros_like(acc)
 
     def step(masked):
-        k, v = k_ref[0].astype(mul), v_ref[0].astype(mul)
+        k = _rotate(k_ref[0], tk_ref, half).astype(mul)
+        v = v_ref[0].astype(mul)
         for h in range(group):
             cols = slice(h * d, (h + 1) * d)
             s = _scores(qb[:, cols], k, i, j, tile, window, scale, masked)
@@ -499,24 +577,25 @@ def _gqa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qb, m_s, l_s, acc,
 
     @pl.when(last)
     def _():
-        lane = lax.broadcasted_iota(jnp.int32, (tile, group), 1)
-        lse = jnp.zeros((tile, group), jnp.float32)
         for h in range(group):
             cols = slice(h * d, (h + 1) * d)
-            o_ref[0, :, cols] = (acc[:, cols] / _lanes_to(l_s[h], d)
-                                 ).astype(o_ref.dtype)
-            lse = jnp.where(lane == h,
-                            (m_s[h] + jnp.log(l_s[h]))[:, :group], lse)
-        lse_ref[0, 0] = lse
+            o = acc[:, cols] / _lanes_to(l_s[h], d)
+            gate = _lanes_to(_head_stat(g_ref[0, 0], h), d)
+            o_ref[0, :, cols] = o.astype(o_ref.dtype)
+            og_ref[0, :, cols] = (o * gate).astype(og_ref.dtype)
+        lse_ref[0, 0] = _stat_block(
+            [m_s[h] + jnp.log(l_s[h]) for h in range(group)])
 
 
-def _gqa_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                    dq_ref, dk_ref, dv_ref, qb, dob, lse_s, delta,
-                    *, group, d, tile, n_visits, window, scale, mul):
+def _gqa_bwd_kernel(q_ref, k_ref, v_ref, g_ref, tq_ref, tk_ref, o_ref,
+                    dog_ref, lse_ref, dq_ref, dk_ref, dv_ref, dg_ref,
+                    qb, dob, lse_s, delta,
+                    *, group, d, half, tile, n_visits, window, scale, mul):
     from jax.experimental import pallas as pl
 
     i, j, exists, whole = _walk(tile, n_visits, window)
     first = pl.program_id(3) == 0
+    last = pl.program_id(3) == n_visits - 1
 
     @pl.when(first & (i == 0))
     def _():
@@ -525,22 +604,27 @@ def _gqa_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
     @pl.when(first)
     def _():
-        qb[...] = q_ref[0].astype(mul)
-        dob[...] = do_ref[0].astype(mul)
         dq_ref[...] = jnp.zeros_like(dq_ref)
-        lane = lax.broadcasted_iota(jnp.int32, (tile, group), 1)
+        d_gate = []
         for h in range(group):
             cols = slice(h * d, (h + 1) * d)
-            lse_s[h] = _lanes(jnp.sum(
-                jnp.where(lane == h, lse_ref[0, 0], 0.0), axis=1,
-                keepdims=True))
-            # sum_k p dp of a row: d_out . out
-            delta[h] = _lanes(jnp.sum(
-                do_ref[0, :, cols] * o_ref[0, :, cols], axis=1,
-                keepdims=True))
+            qb[:, cols] = _rotate(q_ref[0, :, cols], tq_ref,
+                                  half).astype(mul)
+            gate = _head_stat(g_ref[0, 0], h)
+            d_gated = dog_ref[0, :, cols]
+            # the ungated output's gradient, formed in float32
+            dob[:, cols] = (d_gated * _lanes_to(gate, d)).astype(mul)
+            # the gate's gradient: d_gated . out of a row; times the gate
+            # it is sum_k p dp of that row (d_out . out)
+            d_gate.append(_lanes(jnp.sum(d_gated * o_ref[0, :, cols],
+                                         axis=1, keepdims=True)))
+            delta[h] = gate * d_gate[h]
+            lse_s[h] = _head_stat(lse_ref[0, 0], h)
+        dg_ref[0, 0] = _stat_block(d_gate)
 
     def step(masked):
-        k, v = k_ref[0].astype(mul), v_ref[0].astype(mul)
+        k = _rotate(k_ref[0], tk_ref, half).astype(mul)
+        v = v_ref[0].astype(mul)
         d_k = jnp.zeros((tile, d), jnp.float32)
         d_v = jnp.zeros((tile, d), jnp.float32)
         for h in range(group):
@@ -554,13 +638,22 @@ def _gqa_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             d_k = d_k + _dot(ds.T.astype(mul), q, _NN)
             dq_ref[0, :, cols] += _dot(ds.astype(mul), k, _NN)
         keys = pl.ds(pl.multiple_of(j * tile, tile), tile)
-        dk_ref[0, keys, :] += d_k
+        # the turn is linear: each tile pair's part of dk goes back through
+        # it by the key tile's own rows of the table
+        dk_ref[0, keys, :] += _rotate(d_k, tk_ref, half, back=True)
         dv_ref[0, keys, :] += d_v
 
     _both(exists, whole, step)
 
+    @pl.when(last)
+    def _():
+        for h in range(group):
+            cols = slice(h * d, (h + 1) * d)
+            dq_ref[0, :, cols] = _rotate(dq_ref[0, :, cols], tq_ref,
+                                         half, back=True)
 
-def _gqa_call(kernel, name, dims, tile, window, in_specs, out_specs,
+
+def _gqa_call(kernel, name, dims, half, tile, window, in_specs, out_specs,
               out_shape, scratch, operand_dtype, interpret):
     """One ``pallas_call`` over (sequences, key/value heads, query tiles,
     the key tiles a query tile visits)."""
@@ -570,21 +663,28 @@ def _gqa_call(kernel, name, dims, tile, window, in_specs, out_specs,
     S, T, Hq, Hkv, d = dims
     G = Hq // Hkv
     n_visits = _visits(T, tile, window)
+    table = (2 if 2 * half == d else 3) * d
 
-    def key_tile(s, g, i, step):
+    def key_tile(i, step):
         # a tile before the sequence's start is not loaded: the index stays
         # on tile 0, which the next existing step wants anyway
-        return (s, jnp.maximum(i - (n_visits - 1) + step, 0), g)
+        return jnp.maximum(i - (n_visits - 1) + step, 0)
 
     specs = {"q": pl.BlockSpec((1, tile, G * d), lambda s, g, i, _: (s, i, g)),
-             "kv": pl.BlockSpec((1, tile, d), key_tile),
+             "kv": pl.BlockSpec((1, tile, d), lambda s, g, i, step:
+                                (s, key_tile(i, step), g)),
              "whole": pl.BlockSpec((1, T, d), lambda s, g, i, _: (s, 0, g)),
              "stat": pl.BlockSpec((1, 1, tile, G),
-                                  lambda s, g, i, _: (s, g, i, 0))}
+                                  lambda s, g, i, _: (s, g, i, 0)),
+             # the rope table's rows of the query tile, of the key tile
+             "rope_q": pl.BlockSpec((tile, table), lambda s, g, i, _: (i, 0)),
+             "rope_k": pl.BlockSpec((tile, table), lambda s, g, i, step:
+                                    (key_tile(i, step), 0))}
     shapes = {"q": (tile, G * d), "stat": (G, tile, TILE)}
     return pl.pallas_call(
-        functools.partial(kernel, group=G, d=d, tile=tile, n_visits=n_visits,
-                          window=window, scale=d ** -0.5, mul=operand_dtype),
+        functools.partial(kernel, group=G, d=d, half=half, tile=tile,
+                          n_visits=n_visits, window=window, scale=d ** -0.5,
+                          mul=operand_dtype),
         grid=(S, Hkv, T // tile, n_visits),
         in_specs=[specs[k] for k in in_specs],
         out_specs=[specs[k] for k in out_specs],
@@ -598,71 +698,92 @@ def _gqa_call(kernel, name, dims, tile, window, in_specs, out_specs,
         name=name)
 
 
-def _gqa_forward(q, k, v, window, tile, operand_dtype, interpret):
-    S, T, Hq, Hkv, d = dims = _gqa_dims(q, k)
-    out, lse = _gqa_call(
-        _gqa_fwd_kernel, "fed_gqa_attn_fwd", dims, tile, window,
-        ("q", "kv", "kv"), ("q", "stat"),
-        [jax.ShapeDtypeStruct((S, T, Hq * d), q.dtype),
+def _by_kv_head(x, Hkv):
+    """A row statistic (S, T, Hq) in the kernels' layout (S, Hkv, T, G)."""
+    S, T, Hq = x.shape
+    return x.reshape(S, T, Hkv, Hq // Hkv).transpose(0, 2, 1, 3)
+
+
+def _gqa_forward(q, k, v, gate, table, static):
+    """(gated output, output, log-sum-exp); ``static`` is (rotary pairs,
+    window, tile, the multiplicands' dtype, interpret)."""
+    half, window, tile, operand_dtype, interpret = static
+    S, T, Hq, Hkv, d = dims = _gqa_dims(q, k, gate)
+    wide = jax.ShapeDtypeStruct((S, T, Hq * d), q.dtype)
+    return _gqa_call(
+        _gqa_fwd_kernel, "fed_gqa_attn_fwd", dims, half, tile, window,
+        ("q", "kv", "kv", "stat", "rope_q", "rope_k"), ("q", "q", "stat"),
+        [wide, wide,
          jax.ShapeDtypeStruct((S, Hkv, T, Hq // Hkv), jnp.float32)],
         (("q", operand_dtype), ("stat", jnp.float32), ("stat", jnp.float32),
          ("q", jnp.float32)),
-        operand_dtype, interpret)(_flat(q), _flat(k), _flat(v))
-    return out.reshape(q.shape), lse
+        operand_dtype, interpret)(q, k, v, _by_kv_head(gate, Hkv), table,
+                                  table)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _gqa_fused(q, k, v, window, tile, operand_dtype, interpret):
-    return _gqa_forward(q, k, v, window, tile, operand_dtype, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _gqa_fused(q, k, v, gate, table, static):
+    return _gqa_forward(q, k, v, gate, table, static)[0]
 
 
-def _gqa_fused_fwd(q, k, v, window, tile, operand_dtype, interpret):
-    out, lse = _gqa_forward(q, k, v, window, tile, operand_dtype, interpret)
-    return out, (q, k, v, out, lse)
+def _gqa_fused_fwd(q, k, v, gate, table, static):
+    gated, out, lse = _gqa_forward(q, k, v, gate, table, static)
+    return gated, (q, k, v, gate, table, out, lse)
+
+
+def _kind(window) -> str:
+    return "full" if window is None else "window"
 
 
 def gqa_scope(window) -> str:
     """The inner scope of a layer's kind, under ``fed_gqa_attn``."""
-    return "fed_gqa_attn_full" if window is None else "fed_gqa_attn_window"
+    return "fed_gqa_attn_" + _kind(window)
 
 
-def _gqa_fused_bwd(window, tile, operand_dtype, interpret, res, d_out):
-    q, k, v, out, lse = res
-    S, T, Hq, Hkv, d = dims = _gqa_dims(q, k)
+def _gqa_fused_bwd(static, res, d_gated):
+    half, window, tile, operand_dtype, interpret = static
+    q, k, v, gate, table, out, lse = res
+    S, T, Hq, Hkv, d = dims = _gqa_dims(q, k, gate)
     # traced here, not where the forward call was: the scopes again
     with jax.named_scope("fed_gqa_attn"), jax.named_scope(gqa_scope(window)):
-        dq, dk, dv = _gqa_call(
-            _gqa_bwd_kernel, "fed_gqa_attn_bwd", dims, tile, window,
-            ("q", "kv", "kv", "q", "q", "stat"), ("q", "whole", "whole"),
+        dq, dk, dv, dg = _gqa_call(
+            _gqa_bwd_kernel, "fed_gqa_attn_bwd", dims, half, tile, window,
+            ("q", "kv", "kv", "stat", "rope_q", "rope_k", "q", "q", "stat"),
+            ("q", "whole", "whole", "stat"),
             [jax.ShapeDtypeStruct((S, T, Hq * d), jnp.float32),
              jax.ShapeDtypeStruct((S, T, Hkv * d), jnp.float32),
-             jax.ShapeDtypeStruct((S, T, Hkv * d), jnp.float32)],
+             jax.ShapeDtypeStruct((S, T, Hkv * d), jnp.float32),
+             jax.ShapeDtypeStruct((S, Hkv, T, Hq // Hkv), jnp.float32)],
             (("q", operand_dtype), ("q", operand_dtype),
              ("stat", jnp.float32), ("stat", jnp.float32)),
             operand_dtype, interpret,
-        )(_flat(q), _flat(k), _flat(v), _flat(out), _flat(d_out), lse)
-        return (dq.reshape(q.shape).astype(q.dtype),
-                dk.reshape(k.shape).astype(k.dtype),
-                dv.reshape(v.shape).astype(v.dtype))
+        )(q, k, v, _by_kv_head(gate, Hkv), table, table, out, d_gated, lse)
+        dg = dg.transpose(0, 2, 1, 3).reshape(gate.shape)
+        # positions are no parameter: the table takes no gradient
+        return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
+                dg.astype(gate.dtype), jnp.zeros_like(table))
 
 
 _gqa_fused.defvjp(_gqa_fused_fwd, _gqa_fused_bwd)
 
 
-def gqa_attention_fused(q, k, v, window=None, interpret=False,
+def gqa_attention_fused(q, k, v, gate, rope, window=None, interpret=False,
                         tile=GQA_TILE):
     """The fused kernels; multiplicands rounded as ``mla_attention_fused``
     rounds them. ``tile`` is for the interpreted tests' small shapes."""
     T = q.shape[1]
     n = T // tile
-    GQA_PLAN["full" if window is None else "window"] = {
+    GQA_PLAN[_kind(window)] = {
         "tile": tile,
         "key_tiles_visited": sum(min(i + 1, _visits(T, tile, window))
                                  for i in range(n)),
-        "key_tiles_causal": n * (n + 1) // 2}
-    return _gqa_fused(q, k, v, window, tile,
-                      jnp.bfloat16 if _rounds_to_bfloat16() else jnp.float32,
-                      interpret)
+        "key_tiles_causal": n * (n + 1) // 2,
+        "turn_and_gate": "kernel"}
+    cos, sin = rope
+    return _gqa_fused(
+        q, k, v, gate, _rope_table(cos, sin, _gqa_dims(q, k, gate)[-1]),
+        (cos.shape[-1], window, tile,
+         jnp.bfloat16 if _rounds_to_bfloat16() else jnp.float32, interpret))
 
 
 def gqa_attention_path(T, Hq, Hkv, d, interpret=False) -> str:
@@ -673,14 +794,16 @@ def gqa_attention_path(T, Hq, Hkv, d, interpret=False) -> str:
     return "einsum"
 
 
-def gqa_attention(q, k, v, window=None, interpret=False):
-    """The grouped-query core on the path this call's shape and process
-    take (``gqa_attention_path``); counts the call in ``PATH_CALLS``."""
-    path = gqa_attention_path(*_gqa_dims(q, k)[1:], interpret)
+def gqa_attention(q, k, v, gate, rope, window=None, interpret=False):
+    """The grouped-query core, from the projections' outputs to ``W_o``'s
+    operand, on the path this call's shape and process take
+    (``gqa_attention_path``); counts the call in ``PATH_CALLS``."""
+    path = gqa_attention_path(*_gqa_dims(q, k, gate)[1:], interpret)
     PATH_CALLS[path] += 1
     if path == "einsum":
-        return gqa_attention_einsum(q, k, v, window)
-    return gqa_attention_fused(q, k, v, window, interpret)
+        GQA_PLAN[_kind(window)] = {"turn_and_gate": "xla"}
+        return gqa_attention_einsum(q, k, v, gate, rope, window)
+    return gqa_attention_fused(q, k, v, gate, rope, window, interpret)
 
 
 # the kernels against the oracle on the chip: both round multiplicands to
@@ -695,35 +818,47 @@ def check_gqa_kernels(heads=(48, 64), kv_heads=8, d=128, T=MAX_GQA_T,
                       window=512, interpret=False, tile=GQA_TILE) -> dict:
     """``gqa_attention_fused`` against ``gqa_attention_einsum`` at the
     published shape of models/laguna.py (one sequence; 48 query heads with
-    no window, 64 with it): output and all three gradients within
-    ``GQA_CHECK_TOL`` of the oracle's largest entry. The oracle's scores do
-    not fit a chip whole at 4,096 positions, so it runs one key/value head
-    (and its query heads) at a time. Returns the largest gaps seen."""
+    no window, half of a head's columns turned, a factor on cos and sin; 64
+    with the window, every column turned): the gated output and all four
+    gradients within ``GQA_CHECK_TOL`` of the oracle's largest entry. The
+    oracle's scores do not fit a chip whole at 4,096 positions, so it runs
+    one key/value head (and its query heads) at a time. Returns the largest
+    gaps seen."""
     worst = {}
+    pos = jnp.arange(T, dtype=jnp.float32)[:, None]
     for n, (Hq, win) in enumerate(zip(heads, (None, window))):
         G = Hq // kv_heads
-        keys = jax.random.split(jax.random.key(n), 4)
-        q, k, v, w = (jax.random.normal(key, (1, T, h, d), jnp.float32)
+        rotary, factor = (d // 2, 1.4) if win is None else (d, 1.0)
+        angle = pos * 10000.0 ** (-jnp.arange(0, rotary, 2) / rotary)
+        rope = (jnp.cos(angle) * factor, jnp.sin(angle) * factor)
+        keys = jax.random.split(jax.random.key(n), 5)
+        q, k, v, w = (jax.random.normal(key, (1, T, h * d), jnp.float32)
                       for key, h in zip(keys, (Hq, kv_heads, kv_heads, Hq)))
+        gate = jax.nn.sigmoid(jax.random.normal(keys[4], (1, T, Hq)))
 
         def both(fn):
-            def run(q, k, v, w):
-                return (fn(q, k, v),) + jax.grad(
-                    lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2))(q, k, v)
+            def run(q, k, v, gate, w):
+                return (fn(q, k, v, gate),) + jax.grad(
+                    lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2, 3))(
+                        q, k, v, gate)
             return jax.jit(run)
 
-        got = both(functools.partial(gqa_attention_fused, window=win,
-                                     interpret=interpret, tile=tile))(
-                                         q, k, v, w)
-        one = both(functools.partial(gqa_attention_einsum, window=win))
+        got = both(functools.partial(gqa_attention_fused, rope=rope,
+                                     window=win, interpret=interpret,
+                                     tile=tile))(q, k, v, gate, w)
+        one = both(functools.partial(gqa_attention_einsum, rope=rope,
+                                     window=win))
         for g in range(kv_heads):
-            hq, hk = slice(g * G, (g + 1) * G), slice(g, g + 1)
-            want = one(q[:, :, hq], k[:, :, hk], v[:, :, hk], w[:, :, hq])
-            for name, a, b, cut in zip(("out", "dq", "dk", "dv"), got, want,
-                                       (hq, hq, hk, hk)):
-                gap = float(jnp.max(jnp.abs(a[:, :, cut] - b))
+            hq = slice(g * G, (g + 1) * G)
+            cq = slice(g * G * d, (g + 1) * G * d)
+            ck = slice(g * d, (g + 1) * d)
+            want = one(q[..., cq], k[..., ck], v[..., ck], gate[..., hq],
+                       w[..., cq])
+            for name, a, b, cut in zip(("out", "dq", "dk", "dv", "dg"), got,
+                                       want, (cq, cq, ck, ck, hq)):
+                gap = float(jnp.max(jnp.abs(a[..., cut] - b))
                             / jnp.max(jnp.abs(b)))
-                key = f"{name}_{'window' if win else 'full'}"
+                key = f"{name}_{_kind(win)}"
                 worst[key] = max(worst.get(key, 0.0), gap)
     bad = {k: v for k, v in worst.items() if not v <= GQA_CHECK_TOL}
     if bad:

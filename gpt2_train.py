@@ -109,8 +109,9 @@ def _wrap(collate):
 def report_attention_core(model):
     """Which path the attention's core (latent or grouped-query) was traced
     on in this process and how many calls took it (ops/attention.py
-    ``PATH_CALLS``), and the grouped-query kernels' tile walk by kind of
-    layer (``GQA_PLAN``): printed, and a ``model`` event in the run's log.
+    ``PATH_CALLS``), and by kind of grouped-query layer the kernels' tile
+    walk and where q and k were turned and the heads gated (``GQA_PLAN``):
+    printed, and a ``model`` event in the run's log.
     Said once a run, when its first round has been dispatched, so the
     round's own programs are among the traces counted, not the
     initialisation's alone."""
@@ -120,9 +121,13 @@ def report_attention_core(model):
     attn_path = "fused" if PATH_CALLS["fused"] else "einsum"
     print(f"attention core: {attn_path} path, "
           f"{PATH_CALLS[attn_path]} calls traced (ops/attention.py)"
-          + "".join(f"; {kind} layers visit {p['key_tiles_visited']} of "
-                    f"{p['key_tiles_causal']} causal key tiles of {p['tile']}"
-                    for kind, p in sorted(GQA_PLAN.items())))
+          + "".join(
+              f"; {kind} layers"
+              + (f" visit {p['key_tiles_visited']} of "
+                 f"{p['key_tiles_causal']} causal key tiles of {p['tile']}, "
+                 if "tile" in p else " ")
+              + f"turn and gate: {p['turn_and_gate']}"
+              for kind, p in sorted(GQA_PLAN.items())))
     rt = getattr(model, "telemetry", None)
     if rt is not None:
         rt.event("model", attn_path=attn_path,

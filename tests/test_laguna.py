@@ -9,9 +9,11 @@ CPU, float32 ``highest``, seeded random weights:
     ignored fails the same comparison), grouped queries read the right
     key/value head, partial rotary leaves the upper half of a full layer's
     head unturned, YaRN's 32 frequencies equal a table written by hand;
-(c) the fused kernels, interpreted, equal the ``einsum`` oracle: forward and
-    three gradients, groups of 6 and 8 query heads, with a window and
-    without, T not a multiple of the window;
+(c) the fused kernels, interpreted, with the turn of q and k and the heads'
+    gates inside, equal the ``jnp`` oracle: the gated output and four
+    gradients, groups of 6 and 8 query heads, a full layer (half of a head
+    turned, YaRN's factor) and a window layer, T not a multiple of the
+    window, a closed gate;
 (d) the share: all 32 shares of a block, the shared expert and the attention
     counted once, add up to the uncut block;
 (e) federated rounds through ``FedModel`` equal benchmark/reference.py's,
@@ -22,6 +24,7 @@ CPU, float32 ``highest``, seeded random weights:
 """
 
 import dataclasses
+import functools
 import hashlib
 import os
 import sys
@@ -44,7 +47,6 @@ from commefficient_tpu.federated.losses import (  # noqa: E402
     MOE_METRIC_NAMES,
     make_causal_lm_losses,
 )
-from commefficient_tpu.models import laguna  # noqa: E402
 from commefficient_tpu.models.joyai import Block  # noqa: E402
 from commefficient_tpu.models.laguna import (  # noqa: E402
     GQA,
@@ -217,21 +219,66 @@ def test_a_layer_with_the_window_ignored_fails_the_same_comparison():
 
 
 def core_inputs(S, T_, Hq, Hkv, d, seed=0):
+    """q, k, v and a weight on the output, flat as the projections write
+    them: (S, T, H * d)."""
     keys = jax.random.split(jax.random.key(seed), 4)
-    return [jax.random.normal(k, (S, T_, h, d))
+    return [jax.random.normal(k, (S, T_, h * d))
             for k, h in zip(keys, (Hq, Hkv, Hkv, Hq))]
 
 
-def interpreted(window, tile):
-    def fn(q, k, v):
-        return at.gqa_attention_fused(q, k, v, window, interpret=True,
-                                      tile=tile)
+def rope_of(T_, rotary, factor=1.0, theta=100.0):
+    """(cos, sin) over ``rotary`` columns of a head, times ``factor``."""
+    angle = jnp.arange(T_, dtype=jnp.float32)[:, None] \
+        * theta ** (-jnp.arange(0, rotary, 2) / rotary)
+    return jnp.cos(angle) * factor, jnp.sin(angle) * factor
+
+
+def no_turn(T_, d):
+    """A rope that turns nothing (angle 0 everywhere), and with ``ones`` a
+    gate that passes everything: the bare core of the mask's tests."""
+    return jnp.ones((T_, d // 2)), jnp.zeros((T_, d // 2))
+
+
+def ones(q, d):
+    return jnp.ones(q.shape[:2] + (q.shape[-1] // d,))
+
+
+def interpreted(window, tile, d=16):
+    def fn(q, k, v, gate=None, rope=None):
+        return at.gqa_attention_fused(
+            q, k, v, ones(q, d) if gate is None else gate,
+            rope or no_turn(q.shape[1], d), window, interpret=True,
+            tile=tile)
     return fn
 
 
-CORES = {"einsum": lambda window: (
-    lambda q, k, v: at.gqa_attention_einsum(q, k, v, window)),
-         "fused": lambda window: interpreted(window, 16)}
+def oracle(window, d=16):
+    def fn(q, k, v, gate=None, rope=None):
+        return at.gqa_attention_einsum(
+            q, k, v, ones(q, d) if gate is None else gate,
+            rope or no_turn(q.shape[1], d), window)
+    return fn
+
+
+def by_hand(q, k, v, gate, rope, window, d):
+    """The oracle written out apart from ops/attention.py's: heads viewed
+    (S, T, H, d), ``_turn``, scores, mask, softmax, values, gate."""
+    S, T_ = q.shape[:2]
+    q, k, v = (x.reshape(S, T_, -1, d) for x in (q, k, v))
+    q, k = at._turn(q, *rope), at._turn(k, *rope)
+    G = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x, G, axis=2) for x in (k, v))
+    att = jnp.einsum("sqhd,skhd->shqk", q, k) * d ** -0.5
+    pos = jnp.arange(T_)
+    seen = pos[None, :] <= pos[:, None]
+    if window is not None:
+        seen &= pos[:, None] - pos[None, :] < window
+    att = jax.nn.softmax(jnp.where(seen, att, -jnp.inf), axis=-1)
+    out = jnp.einsum("shqk,skhd->sqhd", att, v) * gate[..., None]
+    return out.reshape(S, T_, -1)
+
+
+CORES = {"einsum": oracle, "fused": lambda window: interpreted(window, 16)}
 
 
 @pytest.mark.parametrize("core", sorted(CORES))
@@ -244,7 +291,7 @@ def test_the_windows_edge_is_exact(core):
     fn = CORES[core](W)
     a = fn(q, k, v)
     b = fn(q, k.at[:, j].add(1.0), v.at[:, j].add(1.0))
-    moved = np.asarray(jnp.max(jnp.abs(a - b), axis=(0, 2, 3)))
+    moved = np.asarray(jnp.max(jnp.abs(a - b), axis=(0, 2)))
     assert np.all(moved[:j] == 0.0) and np.all(moved[j + W:] == 0.0)
     assert np.all(moved[j:j + W] > 1e-4)
 
@@ -256,8 +303,9 @@ def test_grouped_queries_read_their_own_key_value_head(core):
     q, k, v, _ = core_inputs(1, 32, 6, 2, 16, seed=1)
     fn = CORES[core](None)
     a = fn(q, k, v)
-    b = fn(q, k.at[:, :, 1].add(0.5), v.at[:, :, 1].add(0.5))
-    moved = np.asarray(jnp.max(jnp.abs(a - b), axis=(0, 1, 3)))
+    b = fn(q, k.at[..., 16:].add(0.5), v.at[..., 16:].add(0.5))
+    moved = np.asarray(jnp.max(jnp.abs(a - b).reshape(1, 32, 6, 16),
+                               axis=(0, 1, 3)))
     assert np.all(moved[:3] == 0.0) and np.all(moved[3:] > 1e-4)
 
 
@@ -269,8 +317,8 @@ def test_partial_rotary_leaves_the_upper_half_of_a_full_head_unturned():
     for kind in ("full_attention", "sliding_attention"):
         freq, factor = rope_frequencies(cfg, kind)
         angle = pos * jnp.asarray(freq, jnp.float32)
-        out[kind] = laguna._turn(x, jnp.cos(angle) * factor,
-                                 jnp.sin(angle) * factor)
+        out[kind] = at._turn(x, jnp.cos(angle) * factor,
+                             jnp.sin(angle) * factor)
     full, sliding = out["full_attention"], out["sliding_attention"]
     np.testing.assert_array_equal(np.asarray(full[..., 64:]),
                                   np.asarray(x[..., 64:]))
@@ -318,54 +366,122 @@ def test_yarn_frequencies_equal_the_hand_written_table():
 # -- (c) the fused kernels, interpreted ----------------------------------------
 
 # (query heads, key/value heads): 48 / 8 and 64 / 8 scaled to groups of 6
-# and 8; T = 48 in tiles of 16; the window 20 divides neither
-KERNEL_CASES = [(Hq, Hkv, window) for Hq, Hkv in ((6, 1), (16, 2))
-                for window in (None, 20)]
+# and 8; T = 48 in tiles of 16; the window 20 divides neither. A full layer
+# turns half of a head's columns and carries a factor on cos and sin (YaRN's),
+# a window layer turns them all; "bare" is the core alone (no turn, gates 1)
+KERNEL_CASES = [(Hq, Hkv, window, turned)
+                for Hq, Hkv in ((6, 1), (16, 2)) for window in (None, 20)
+                for turned in (True, False)]
 
 
-@pytest.mark.parametrize("Hq,Hkv,window", KERNEL_CASES)
-def test_fused_kernels_match_the_einsum_oracle(Hq, Hkv, window):
-    q, k, v, w = core_inputs(2, 48, Hq, Hkv, 16, seed=2)
-    fused, oracle = interpreted(window, 16), CORES["einsum"](window)
+def assert_close(got, want, what, tol=2e-5):
+    scale = max(float(jnp.max(jnp.abs(want))), 1.0)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=tol * scale, err_msg=what)
 
-    def assert_close(got, want, what):
-        scale = max(float(jnp.max(jnp.abs(want))), 1.0)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
-                                   atol=2e-5 * scale, err_msg=what)
 
-    assert_close(fused(q, k, v), oracle(q, k, v), "forward")
-    got = jax.grad(lambda *a: jnp.sum(fused(*a) * w), argnums=(0, 1, 2))(
-        q, k, v)
+def layer_operands(S, T_, Hq, Hkv, d, window, seed=2):
+    """A layer's operands by its kind: (q, k, v, gate), the rope, and a
+    weight on the output."""
+    q, k, v, w = core_inputs(S, T_, Hq, Hkv, d, seed=seed)
+    gate = jax.nn.sigmoid(2.0 * jax.random.normal(jax.random.key(seed + 50),
+                                                  (S, T_, Hq)))
+    rope = rope_of(T_, d // 2, 1.4) if window is None else rope_of(T_, d)
+    return (q, k, v, gate), rope, w
+
+
+@pytest.mark.parametrize("Hq,Hkv,window,turned", KERNEL_CASES)
+def test_fused_kernels_match_the_einsum_oracle(Hq, Hkv, window, turned):
+    """The interpreted kernels, with the turn of q and k and the heads'
+    gates inside, against the ``jnp`` oracle: the gated output and all four
+    gradients, also under recomputation."""
+    d = 16
+    args, rope, w = layer_operands(2, 48, Hq, Hkv, d, window)
+    if not turned:
+        args, rope = args[:3] + (ones(args[0], d),), no_turn(48, d)
+    fused = functools.partial(interpreted(window, 16), rope=rope)
+    want_fn = functools.partial(oracle(window), rope=rope)
+    want = want_fn(*args)
+    assert_close(fused(*args), want, "forward")
+    assert_close(by_hand(*args, rope, window, d), want, "the oracle itself")
+    every = (0, 1, 2, 3)
+    got = jax.grad(lambda *a: jnp.sum(fused(*a) * w), argnums=every)(*args)
     # under recomputation too (``nn.remat`` is ``jax.checkpoint``)
     again = jax.grad(lambda *a: jnp.sum(jax.checkpoint(fused)(*a) * w),
-                     argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(oracle(*a) * w), argnums=(0, 1, 2))(
-        q, k, v)
-    for name, g, g2, e in zip(("dq", "dk", "dv"), got, again, want):
+                     argnums=every)(*args)
+    want = jax.grad(lambda *a: jnp.sum(want_fn(*a) * w), argnums=every)(*args)
+    for name, g, g2, e in zip(("dq", "dk", "dv", "dg"), got, again, want):
         assert g.shape == e.shape, name
         assert_close(g, e, name)
         assert_close(g2, e, name + " recomputed")
 
 
-def test_fused_kernels_at_the_real_tile_and_head_width():
-    """One case at head width 128 and the kernels' own tile (``GQA_TILE``),
-    two query tiles, a window that is no multiple of it; and the
-    bfloat16 multiplicands of the chip against the oracle on rounded
-    operands."""
-    Tq = 2 * at.GQA_TILE
-    window = at.GQA_TILE + 44
-    q, k, v, w = core_inputs(1, Tq, 2, 1, 128, seed=3)
-    oracle = CORES["einsum"](window)
-    got = at.gqa_attention_fused(q, k, v, window, interpret=True)
-    want = oracle(q, k, v)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
-                               atol=2e-5 * float(jnp.max(jnp.abs(want))))
+@pytest.mark.parametrize("gate_logit", [-np.inf, -100.0],
+                         ids=["exactly_zero", "underflows"])
+def test_a_closed_gate_gives_finite_gradients(gate_logit):
+    """Head 1's gate at some positions is 0 (or ``sigmoid(-100)``, 4e-44):
+    the backward kernel takes the ungated output from the forward call and
+    divides by no gate, so every gradient is finite and the oracle's; the
+    closed head's q takes none there."""
+    d, window = 16, 20
+    (q, k, v, gate), rope, w = layer_operands(1, 48, 6, 1, d, window, seed=5)
+    gate = gate.at[:, 8:40, 1].set(jax.nn.sigmoid(jnp.float32(gate_logit)))
+    assert float(gate[0, 8, 1]) < 1e-40
+    fused = functools.partial(interpreted(window, 16), rope=rope)
+    want_fn = functools.partial(oracle(window), rope=rope)
+    every = (0, 1, 2, 3)
+    got = jax.grad(lambda *a: jnp.sum(fused(*a) * w), argnums=every)(
+        q, k, v, gate)
+    want = jax.grad(lambda *a: jnp.sum(want_fn(*a) * w), argnums=every)(
+        q, k, v, gate)
+    for name, g, e in zip(("dq", "dk", "dv", "dg"), got, want):
+        assert bool(jnp.all(jnp.isfinite(g))), name
+        assert_close(g, e, name)
+    closed = got[0].reshape(48, 6, d)[8:40, 1]
+    assert float(jnp.max(jnp.abs(closed))) < 1e-30
+    assert float(jnp.max(jnp.abs(got[3][0, 8:40, 1]))) > 1e-2
+
+
+@pytest.mark.parametrize("window", [None, at.GQA_TILE + 44],
+                         ids=["full", "window"])
+def test_fused_kernels_at_the_real_tile_and_head_width(window):
+    """Head width 128 and the kernels' own tile (``GQA_TILE``), two query
+    tiles, a window that is no multiple of it, the turn by lane rotation of
+    a 128-lane head (64 of 128 columns on the full layer, all on the window
+    layer) and the gate; and the bfloat16 multiplicands of the chip against
+    the oracle on operands rounded after the turn, as the kernels round."""
+    d, Tq = 128, 2 * at.GQA_TILE
+    args, rope, w = layer_operands(1, Tq, 2, 1, d, window, seed=3)
+    want_fn = functools.partial(oracle(window, d), rope=rope)
+
+    def fused(*a):
+        return at.gqa_attention_fused(*a, rope, window, interpret=True)
+
+    want = want_fn(*args)
+    got = fused(*args)
+    assert_close(got, want, "forward")
+    every = (0, 1, 2, 3)
+    grads = jax.grad(lambda *a: jnp.sum(fused(*a) * w), argnums=every)(*args)
+    wants = jax.grad(lambda *a: jnp.sum(want_fn(*a) * w), argnums=every)(
+        *args)
+    for name, g, e in zip(("dq", "dk", "dv", "dg"), grads, wants):
+        assert_close(g, e, name)
     with jax.default_matmul_precision(None):
-        low = at.gqa_attention_fused(q, k, v, window, interpret=True)
-    rounded = [a.astype(jnp.bfloat16).astype(jnp.float32) for a in (q, k, v)]
+        low = fused(*args)
     assert low.dtype == jnp.float32
+    # the oracle on multiplicands rounded where the kernels round them:
+    # q and k after their turn, v as it is
+    q, k, v, gate = args
+
+    def rounded(x, turned):
+        x4 = x.reshape(1, Tq, -1, d)
+        x4 = at._turn(x4, *rope) if turned else x4
+        return x4.astype(jnp.bfloat16).astype(jnp.float32).reshape(x.shape)
+
+    want_low = oracle(window, d)(rounded(q, True), rounded(k, True),
+                                 rounded(v, False), gate)
     scale = float(jnp.max(jnp.abs(want)))
-    assert float(jnp.max(jnp.abs(low - oracle(*rounded)))) <= 1e-2 * scale
+    assert float(jnp.max(jnp.abs(low - want_low))) <= 1e-2 * scale
     assert float(jnp.max(jnp.abs(low - got))) > 1e-4
 
 
@@ -396,20 +512,26 @@ def test_gqa_path_chooser(case, monkeypatch):
 
 
 def test_counter_and_plan_name_the_path_taken(monkeypatch):
-    q, k, v, _ = core_inputs(1, 2 * at.GQA_TILE, 2, 1, 128, seed=4)
+    (q, k, v, gate), rope, _ = layer_operands(1, 2 * at.GQA_TILE, 2, 1, 128,
+                                              at.GQA_TILE, seed=4)
     monkeypatch.setattr(at, "GQA_PLAN", {})
     before = dict(at.PATH_CALLS)
-    out = at.gqa_attention(q, k, v, at.GQA_TILE, interpret=True)
+    out = at.gqa_attention(q, k, v, gate, rope, at.GQA_TILE, interpret=True)
     assert at.PATH_CALLS["fused"] == before["fused"] + 1
-    # a window of one tile: the diagonal tile and the one before it
+    # a window of one tile: the diagonal tile and the one before it; the
+    # turn and the gate ran where the scores did
     assert at.GQA_PLAN == {"window": {"tile": at.GQA_TILE,
                                       "key_tiles_visited": 3,
-                                      "key_tiles_causal": 3}}
-    at.gqa_attention(q[:, :32], k[:, :32], v[:, :32], 5, interpret=True)
+                                      "key_tiles_causal": 3,
+                                      "turn_and_gate": "kernel"}}
+    # a shape the kernels refuse: the einsum path, turn and gate in XLA
+    at.gqa_attention(q[:, :32], k[:, :32], v[:, :32], gate[:, :32],
+                     tuple(r[:32] for r in rope), None, interpret=True)
     assert at.PATH_CALLS["einsum"] == before["einsum"] + 1
+    assert at.GQA_PLAN["full"] == {"turn_and_gate": "xla"}
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(at.gqa_attention_einsum(
-            q, k, v, at.GQA_TILE)), rtol=0, atol=1e-4)
+            q, k, v, gate, rope, at.GQA_TILE)), rtol=0, atol=1e-4)
 
 
 # -- (d) the share -------------------------------------------------------------
@@ -586,6 +708,9 @@ def test_entry_point_trains_through_the_normal_path(monkeypatch, tmp_path):
     assert len(rounds) == 4 and all("model" in e for e in rounds)
     (said,) = [e for e in events if e["ev"] == "model"]
     assert said["attn_path"] == "einsum" and said["attn_calls"] > 5
+    # the einsum path: q and k turned and the heads gated by XLA's code
+    assert said["attn_plan"] == {kind: {"turn_and_gate": "xla"}
+                                 for kind in ("full", "window")}
 
 
 # -- (f) JoyAI-LLM-Flash's round is the program it was -------------------------

@@ -191,33 +191,112 @@ def test_gqa_attention_kernels_compile_for_v5e(Hq, window, backward):
     """The grouped-query core (ops/attention.py) at
     ``laguna_xs2_sketch_1c``'s shape: 4 sequences x 4,096 positions (the
     longest the path chooser sends to the kernels), 48 query heads with no
-    window or 64 with a window of 512 over 8 key/value heads of 128. Mosaic
-    takes both kernels (their blocks fit VMEM) and nothing of the scores'
-    size (4.3 GB a sequence of a 64-head layer in float32) is left in HBM
-    around them: beside the output only the log-sum-exp, a lane-padded
-    (4, 8, 4096, G) float32 array of 64 MiB."""
+    window (32 rotary pairs: two lane rotations a head) or 64 with a window
+    of 512 (64 pairs: one) over 8 key/value heads of 128, operands flat as
+    the projections write them. Mosaic takes both kernels with the turn and
+    the gate inside (their blocks fit VMEM, the rotations lower) and nothing
+    of the scores' size (4.3 GB a sequence of a 64-head layer in float32) is
+    left in HBM around them: beside the gated output only the ungated one
+    (the backward's residual), and the log-sum-exp and the gates by
+    key/value head, lane-padded (4, 8, 4096, G) float32 arrays of 64 MiB."""
     S, T, Hkv, d = 4, attention.MAX_GQA_T, 8, 128
+    half = d // 4 if window is None else d // 2
     one = SingleDeviceSharding(_v5e_devices()[0])
-    q, k, v, d_out = (
+    q, k, v, gate, cos, sin, d_out = (
         jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
         for shape in ((S, T, Hq * d), (S, T, Hkv * d), (S, T, Hkv * d),
-                      (S, T, Hq * d)))
+                      (S, T, Hq), (T, half), (T, half), (S, T, Hq * d)))
 
-    def fwd(q, k, v):
-        heads = (lambda x: x.reshape(S, T, -1, d))
-        return attention.gqa_attention_fused(
-            heads(q), heads(k), heads(v), window).reshape(S, T, -1)
+    def fwd(q, k, v, gate, cos, sin):
+        return attention.gqa_attention_fused(q, k, v, gate, (cos, sin),
+                                             window)
 
-    def bwd(q, k, v, d_out):
-        return jax.vjp(fwd, q, k, v)[1](d_out)
+    def bwd(q, k, v, gate, cos, sin, d_out):
+        return jax.vjp(lambda *a: fwd(*a, cos, sin), q, k, v, gate)[1](d_out)
 
     _, compiled = _compile_tpu(jax.jit(bwd if backward else fwd),
-                               *((q, k, v) + ((d_out,) if backward else ())))
+                               *((q, k, v, gate, cos, sin)
+                                 + ((d_out,) if backward else ())))
     text = compiled.as_text()
     assert "fed_gqa_attn_fwd" in text
     assert ("fed_gqa_attn_bwd" in text) == backward
-    # forward: the log-sum-exp alone; backward: the forward's output too
-    # (the residual), never a (T, T) array
+    # the ungated output, the log-sum-exp, the gates (and the backward's
+    # gate gradient before its way back), the rope's table: never a (T, T)
+    # array, and no second array of q's size
     out_bytes = S * T * Hq * d * 4
     assert compiled.memory_analysis().temp_size_in_bytes \
-        < (out_bytes if backward else 0) + (65 << 20)
+        < out_bytes + ((3 * 64 + 8) << 20)
+
+
+def _entry_ops(text):
+    """(element count of the first result, operation, line) of the entry
+    computation's instructions of a compiled module's text."""
+    entry = text[text.index("ENTRY "):]
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \(?\w+\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if m:
+            count = 1
+            for n in filter(None, m.group(1).split(",")):
+                count *= int(n)
+            yield count, m.group(2), line
+
+
+# temp_size_in_bytes of the same module (forward + backward, S = 4, T =
+# 4,096) at the parent of PR 33, where ``GQA.__call__`` viewed q, k, the
+# output and their gradients (S, T, H, 128) around the kernels
+GQA_MODULE_TEMP_AT_PARENT = {0: 2_493_432_320, 1: 2_898_553_344}
+
+
+@pytest.mark.parametrize("layer", [0, 1], ids=["full48", "window64"])
+def test_gqa_module_leaves_no_relayout_of_q_for_v5e(layer, monkeypatch):
+    """One whole ``GQA`` module of models/laguna.py, projections to ``W_o``,
+    forward and backward, at ``laguna_xs2_sketch_1c``'s shape: what is left
+    of q's size (384 MiB at 48 heads, 512 MiB at 64) in the compiled
+    program is what the projections' products (q, and ``W_o``'s backward:
+    the gated output's gradient) and the two kernels write. No ``copy``,
+    ``reshape`` or ``transpose`` and no other fusion's output: the turn, the
+    gate and their backward work on the kernels' tiles, and no
+    (S, T, H, 128) view of q exists between the projection and ``W_o``."""
+    from commefficient_tpu.models.laguna import GQA, LagunaConfig
+
+    cfg = LagunaConfig()
+    S, T = 4, attention.MAX_GQA_T
+    Hq = cfg.num_attention_heads_per_layer[layer]
+    one = SingleDeviceSharding(_v5e_devices()[0])
+    mod = GQA(cfg, layer)
+    x = jax.ShapeDtypeStruct((S, T, cfg.hidden_size), jnp.float32,
+                             sharding=one)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(mod.init, jax.random.key(0),
+                       jnp.zeros((1, 8, cfg.hidden_size)))["params"])
+
+    def both(p, x, d_out):
+        out, vjp = jax.vjp(lambda p, x: mod.apply({"params": p}, x), p, x)
+        return out, vjp(d_out)
+
+    monkeypatch.setattr(attention, "is_tpu_backend", lambda: True)
+    _, compiled = _compile_tpu(jax.jit(both), params, x, x)
+    text = compiled.as_text()
+    assert "fed_gqa_attn_fwd" in text and "fed_gqa_attn_bwd" in text
+    q_size = S * T * Hq * cfg.head_dim
+    left = [(op, line) for count, op, line in _entry_ops(text)
+            if count >= q_size and op not in ("parameter", "tuple")]
+    for op, line in left:
+        assert (op == "get-tuple-element" and "fed_gqa_attn" in line) \
+            or (op == "fusion" and _is_product(text, line)), line[:300]
+    # the kernels' three (gated and ungated output, dq) and the products'
+    # two (q, the gated output's gradient): the reader reads something
+    assert sorted(op for op, _ in left) == ["fusion"] * 2 \
+        + ["get-tuple-element"] * 3
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < GQA_MODULE_TEMP_AT_PARENT[layer]
+
+
+def _is_product(text, line):
+    """Whether the computation a fusion instruction calls holds a matrix
+    product."""
+    name = re.search(r"calls=(%[\w.\-]+)", line).group(1)
+    start = text.index("\n" + name + " ")
+    return " convolution(" in text[start:text.index("\n}", start)]

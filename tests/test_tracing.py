@@ -266,7 +266,9 @@ class TestKernelNames:
         raw = jnp.zeros((4, 8, 128), jnp.int32)
         qkv = [jnp.zeros((1, 128) + w) for w in (
             (2, 192), (2, 64), (2, 256), (64,))]
-        gqa = [jnp.zeros((1, 32, h, 16)) for h in (2, 1, 1)]
+        # q, k, v flat; the gates; the rope's (cos, sin) and its lane table
+        gqa = [jnp.zeros((1, 32, w)) for w in (32, 16, 16, 2)]
+        rope = (jnp.ones((32, 8)), jnp.zeros((32, 8)))
         calls = {
             "fed_sketch_vec": lambda: sk._sketch_vec_pallas(v3, *hashes,
                                                             **kw),
@@ -289,10 +291,12 @@ class TestKernelNames:
                 (*qkv, jnp.zeros((1, 128, 2, 128)), jnp.zeros((1, 1, 2, 128))),
                 jnp.zeros((1, 128, 2, 128))),
             "fed_gqa_attn_fwd": lambda: at.gqa_attention_fused(
-                *gqa, window=16, interpret=True, tile=16),
+                *gqa, rope, window=16, interpret=True, tile=16),
+            # residuals: the operands, the table, the output, log-sum-exp
             "fed_gqa_attn_bwd": lambda: at._gqa_fused_bwd(
-                16, 16, jnp.float32, True,
-                (*gqa, gqa[0], jnp.zeros((1, 1, 32, 2))), gqa[0]),
+                (8, 16, 16, jnp.float32, True),
+                (*gqa, at._rope_table(*rope, 16), gqa[0],
+                 jnp.zeros((1, 1, 32, 2))), gqa[0]),
         }
         names = _pallas_names(jax.make_jaxpr(calls[kernel])().jaxpr, [])
         assert names == [kernel]
