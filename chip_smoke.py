@@ -78,7 +78,8 @@ REHEARSAL_RECIPE = {"--num_rows": "3", "--num_cols": "2048", "--k": "500",
 REHEARSAL_GEOMETRY = {"d": 60_000, "c": 20_000, "r": 3, "k": 500,
                       "big_d": 70_001,
                       "gqa": {"heads": (6, 8), "kv_heads": 1, "d": 16,
-                              "T": 48, "window": 20, "tile": 16}}
+                              "T": 48, "window": 20, "tile": 16,
+                              "ungated": ((4, 4, 48),)}}
 WORKERS = 8
 # the multi-round mesh-parity tolerance (tests/test_rounds.py:147,271)
 LOSS_RTOL = 1e-4
@@ -176,8 +177,10 @@ def phase_kernels(ns) -> dict:
                                          interpret)),
         # the grouped-query attention at models/laguna.py's published shape,
         # the turn of q and k and the heads' gates inside the kernels: gated
-        # output and four gradients, full and window layer; within rounding
-        # of its oracle, not bit-equal (at.GQA_CHECK_TOL)
+        # output and four gradients, full and window layer; and at
+        # models/ouro.py's (16 heads over 16, no gate, 1,024 and 4,096
+        # positions: output and three gradients); within rounding of its
+        # oracle, not bit-equal (at.GQA_CHECK_TOL)
         ("gqa attention fwd + bwd",
          lambda: at.check_gqa_kernels(**g.get("gqa", {}),
                                       interpret=interpret)),

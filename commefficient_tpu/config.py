@@ -352,19 +352,25 @@ def build_parser(default_lr=None) -> argparse.ArgumentParser:
                              "coef/n_moe_layers, so retune rather than "
                              "assuming published values transfer.")
     # The decoder gpt2_train.py builds (models/joyai.py for the DeepSeek-V3
-    # family, models/laguna.py) and the cut of it held here: one chip's share
-    # of a deployment in which --layer_chips chips share each layer.
+    # family, models/laguna.py, models/ouro.py) and the cut of it held here:
+    # one chip's share of a deployment in which --layer_chips chips share
+    # each layer.
     parser.add_argument("--arch",
-                        choices=["gpt2", "joyai_llm_flash", "laguna_xs2"],
+                        choices=["gpt2", "joyai_llm_flash", "laguna_xs2",
+                                 "ouro_2p6b"],
                         default="gpt2",
                         help="gpt2_train.py's model: GPT-2 double heads, "
                              "JoyAI-LLM-Flash (MLA, sigmoid top-8 routed "
                              "experts with a shared expert, causal-LM "
-                             "loss), or Laguna-XS.2 (window-512 and full "
+                             "loss), Laguna-XS.2 (window-512 and full "
                              "grouped-query layers with 64 / 48 heads over "
                              "8, two RoPEs, gated head outputs, the same "
                              "kind of expert layer without its selection "
-                             "bias).")
+                             "bias), or Ouro-2.6B (a dense stack of "
+                             "sandwich-norm layers run four times on shared "
+                             "weights, a head and an exit gate after every "
+                             "pass, the expected-exit loss; no routed "
+                             "experts: --layer_chips stays 1).")
     parser.add_argument("--arch_layers", type=int, default=0,
                         help="Layers held, leading dense layer included "
                              "(0 = all the architecture has).")

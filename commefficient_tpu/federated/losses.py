@@ -216,37 +216,54 @@ def make_gpt2_losses(model, lm_coef: float = 1.0, mc_coef: float = 1.0,
     return compute_train, compute_val
 
 
-# the routing counters a causal-LM round leaves in the event log, in the
-# order of the loss's metric sums (telemetry.RunTelemetry "model" record)
-MOE_METRIC_NAMES = ("moe_local_pairs", "moe_absent_pairs",
-                    "moe_load_max_over_mean")
-# ... of which these are summed as numerators and divided by another's sum
-MOE_METRIC_RATIOS = {"moe_load_max_over_mean": "moe_local_pairs"}
-
-
 def make_causal_lm_losses(model):
-    """Next-token cross-entropy for a decoder that returns ``(logits,
-    routing counts)`` (models/joyai.py): no multiple-choice head, no
-    token-type embedding. An example's loss is the mean NLL over its
-    labelled positions (``lm_labels != -1``, all candidates pooled), as in
-    ``make_gpt2_losses``; the vocabulary is the model's slice.
+    """The losses of a decoder without a multiple-choice head or token-type
+    embedding (models/joyai.py, models/laguna.py, models/ouro.py). An
+    example's loss is a mean over its labelled positions (``lm_labels !=
+    -1``, all candidates pooled), as in ``make_gpt2_losses``; the vocabulary
+    is the model's slice. The batch is read here; what a position's loss is,
+    and which metric sums the train path returns beside the loss
+    (``metric_names``, of which ``metric_ratios`` names those the event log
+    divides by another's sum), is the model's configuration's to say, in one
+    of two forms (``model.cfg.reads_labels``):
 
-    Train returns the routing counts as metric sums (``MOE_METRIC_NAMES``:
-    the (token, expert) pairs computed here, the pairs routed to absent
-    experts, and the largest held expert's load over the mean load, summed
-    over the expert layers as ``max load x experts held`` and split evenly
-    over the call's examples, so that the round's sum over the round's pairs
-    is the ratio: ``MOE_METRIC_RATIOS``). Val returns (nll, next-token
-    accuracy over the labelled positions).
+    - the model returns ``(logits, counts)``: next-token cross-entropy, and
+      ``cfg.metric_sums(counts, sequences)`` gives the metric sums a
+      sequence;
+    - the model takes the labels and returns per-position terms (so that it
+      never holds more than one pass's logits: models/ouro.py):
+      ``cfg.position_terms(*terms, train)`` gives a position's loss, whether
+      its prediction was the label, and its counters, which are summed here
+      over the labelled positions with no gradient.
+
+    Train returns the metric sums, val (loss, next-token accuracy over the
+    labelled positions).
 
     ``compute_train.over_clients`` takes a leading clients axis on the batch
     and returns per-client vectors: the round's fused client phase calls it
-    in place of a ``vmap`` over clients, so that all clients' tokens of a
-    microbatch are routed as one token axis."""
+    in place of a ``vmap`` over clients, so that all clients' sequences of a
+    microbatch are one batch axis (one token axis for an expert layer's
+    routing)."""
+    cfg = model.cfg
 
-    def per_example(params, batch):
+    def per_example(params, batch, train):
         ids = batch["input_ids"]
         lead, T = ids.shape[:-2], ids.shape[-1]            # (..., B), K, T
+
+        def by_example(x):      # sum over an example's candidates/positions
+            return x.reshape(lead + (-1,)).sum(axis=-1)
+
+        if cfg.reads_labels:
+            labels = batch["lm_labels"].reshape(-1, T)[:, 1:]
+            valid = labels != -1
+            tok_loss, hit, counters = cfg.position_terms(
+                *model.apply({"params": params}, ids.reshape(-1, T), labels),
+                train)
+            n_valid = jnp.maximum(by_example(valid), 1)
+            counts = tuple(jax.lax.stop_gradient(by_example(c * valid))
+                           for c in counters)
+            return (by_example(tok_loss * valid) / n_valid,
+                    by_example(hit & valid) / n_valid, counts)
         logits, stats = model.apply({"params": params}, ids.reshape(-1, T))
         labels = batch["lm_labels"].reshape(-1, T)[:, 1:]
         valid = labels != -1
@@ -255,27 +272,20 @@ def make_causal_lm_losses(model):
             lg, jnp.where(valid, labels, 0)[..., None], axis=-1)[..., 0]
         tok_nll = (jax.nn.logsumexp(lg, axis=-1) - picked) * valid
         hit = (jnp.argmax(lg, axis=-1) == labels) & valid
-
-        def by_example(x):      # sum over an example's candidates/positions
-            return x.reshape(lead + (-1,)).sum(axis=-1)
-
         n_valid = jnp.maximum(by_example(valid), 1)
-        n_seq = logits.shape[0]
         counts = tuple(
             jax.lax.stop_gradient(by_example(c.astype(jnp.float32)))
-            for c in (stats["local"], stats["absent"],
-                      jnp.full((n_seq,), stats["max_load"]
-                               * model.cfg.experts_held / n_seq)))
+            for c in cfg.metric_sums(stats, logits.shape[0]))
         return (by_example(tok_nll) / n_valid, by_example(hit) / n_valid,
                 counts)
 
     def sums(params, batch, train):
         """(loss sum, metric sums, count) over the last (examples) axis."""
-        nll, acc, counts = per_example(params, batch)
+        loss, acc, counts = per_example(params, batch, train)
         mask = batch["mask"]
         extra = (tuple(jnp.sum(c, axis=-1) for c in counts) if train
                  else (jnp.sum(acc * mask, axis=-1),))
-        return jnp.sum(nll * mask, axis=-1), extra, jnp.sum(mask, axis=-1)
+        return jnp.sum(loss * mask, axis=-1), extra, jnp.sum(mask, axis=-1)
 
     def compute_train(params, model_state, batch, rng, train):
         return sums(params, batch, True) + (model_state,)
@@ -287,6 +297,6 @@ def make_causal_lm_losses(model):
         return sums(params, batch, False) + (model_state,)
 
     compute_train.over_clients = over_clients
-    compute_train.metric_names = MOE_METRIC_NAMES
-    compute_train.metric_ratios = MOE_METRIC_RATIOS
+    compute_train.metric_names = cfg.metric_names
+    compute_train.metric_ratios = cfg.metric_ratios
     return compute_train, compute_val

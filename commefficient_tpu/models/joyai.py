@@ -36,7 +36,28 @@ from flax import linen as nn
 from commefficient_tpu.ops.attention import mla_attention
 from commefficient_tpu.parallel.moe import RoutedMoE, SwiGLU
 
-__all__ = ["JoyAIFlash", "JoyAIConfig", "Decoder", "Block", "RMSNorm"]
+__all__ = ["JoyAIFlash", "JoyAIConfig", "Decoder", "Block", "RMSNorm",
+           "MOE_METRIC_NAMES", "MOE_METRIC_RATIOS", "routing_sums"]
+
+# the routing counters a round of a ``Decoder`` leaves in the event log, in
+# the order of the loss's metric sums (telemetry.RunTelemetry "model" record)
+MOE_METRIC_NAMES = ("moe_local_pairs", "moe_absent_pairs",
+                    "moe_load_max_over_mean")
+# ... of which these are summed as numerators and divided by another's sum
+MOE_METRIC_RATIOS = {"moe_load_max_over_mean": "moe_local_pairs"}
+
+
+def routing_sums(cfg, stats, n_seq):
+    """What losses.make_causal_lm_losses asks of a configuration whose model
+    returns ``(logits, routing counts)``: the counts as metric sums a
+    sequence (``MOE_METRIC_NAMES``: the (token, expert) pairs computed here,
+    the pairs routed to absent experts, and the largest held expert's load
+    over the mean load, summed over the expert layers as ``max load x
+    experts held`` and split evenly over the call's ``n_seq`` sequences, so
+    that the round's sum over the round's pairs is the ratio:
+    ``MOE_METRIC_RATIOS``)."""
+    return (stats["local"], stats["absent"],
+            jnp.full((n_seq,), stats["max_load"] * cfg.experts_held / n_seq))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,6 +100,11 @@ class JoyAIConfig:
 
     # what ``Block`` and the entry point ask of a configuration
     routed = property(lambda self: self.n_routed_experts)
+    # ... and losses.make_causal_lm_losses
+    reads_labels = False
+    metric_names = MOE_METRIC_NAMES
+    metric_ratios = MOE_METRIC_RATIOS
+    metric_sums = routing_sums
 
     def attention(self, layer: int):
         return MLA(self, name="attn")

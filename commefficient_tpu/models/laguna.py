@@ -38,7 +38,13 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
-from commefficient_tpu.models.joyai import Decoder, _kernel
+from commefficient_tpu.models.joyai import (
+    MOE_METRIC_NAMES,
+    MOE_METRIC_RATIOS,
+    Decoder,
+    _kernel,
+    routing_sums,
+)
 from commefficient_tpu.ops.attention import gqa_attention, gqa_scope
 from commefficient_tpu.parallel.moe import RoutedMoE
 
@@ -125,6 +131,11 @@ class LagunaConfig:
 
     # what models/joyai.py ``Block`` and the entry point ask of a configuration
     routed = property(lambda self: self.num_experts)
+    # ... and losses.make_causal_lm_losses
+    reads_labels = False
+    metric_names = MOE_METRIC_NAMES
+    metric_ratios = MOE_METRIC_RATIOS
+    metric_sums = routing_sums
 
     def attention(self, layer: int):
         return GQA(self, layer, name="attn")

@@ -56,6 +56,10 @@ the turn's transposes on the same tiles, so no (S, T, H, d) array exists.
 The forward writes the gated output, the ungated one and a log-sum-exp a row
 (the backward's residuals: it divides by no gate). Rounding and the path's
 rule (``gqa_attention_path``) are the latent core's; ``GQA_PLAN`` is the plan.
+A call without a gate (``gate=None``, the head count in ``heads``:
+models/ouro.py, one query head a key/value head) runs the same kernels
+without the gate's operand: one output and the log-sum-exp forward, ``dq``,
+``dk``, ``dv`` and no ``d_g`` backward.
 """
 
 from __future__ import annotations
@@ -383,10 +387,11 @@ GQA_PLAN: dict = {}
 _M_START = -1e30
 
 
-def _gqa_dims(q, k, gate):
-    """(S, T, Hq, Hkv, d) of a call's operands."""
+def _gqa_dims(q, k, gate, heads=None):
+    """(S, T, Hq, Hkv, d) of a call's operands; the query heads are the
+    gate's last axis, or ``heads`` where there is no gate."""
     S, T, width = q.shape
-    Hq = gate.shape[-1]
+    Hq = heads if gate is None else gate.shape[-1]
     d = width // Hq
     return S, T, Hq, k.shape[-1] // d, d
 
@@ -413,10 +418,11 @@ def _turn(x, cos, sin):
     return jnp.concatenate([out.astype(x.dtype), x[..., 2 * half:]], axis=-1)
 
 
-def gqa_attention_einsum(q, k, v, gate, rope, window=None):
+def gqa_attention_einsum(q, k, v, gate, rope, window=None, heads=None):
     """The oracle, in ``jnp``: the heads viewed (S, T, H, d), q and k turned
-    (``_turn``), two ``einsum``s around a float32 softmax, the gate."""
-    S, T, Hq, Hkv, d = _gqa_dims(q, k, gate)
+    (``_turn``), two ``einsum``s around a float32 softmax, the gate where
+    there is one."""
+    S, T, Hq, Hkv, d = _gqa_dims(q, k, gate, heads)
     q, k, v = (x.reshape(S, T, -1, d) for x in (q, k, v))
     qg = _turn(q, *rope).reshape(S, T, Hkv, Hq // Hkv, d)
     k = _turn(k, *rope)
@@ -427,6 +433,8 @@ def gqa_attention_einsum(q, k, v, gate, rope, window=None):
     att = jax.nn.softmax(att.astype(jnp.float32), axis=-1)
     out = jnp.einsum("shgqk,skhd->sqhgd", att.astype(v.dtype),
                      v).reshape(S, T, Hq, d)
+    if gate is None:
+        return out.reshape(S, T, Hq * d)
     return (out * gate[..., None].astype(out.dtype)).reshape(S, T, Hq * d)
 
 
@@ -539,11 +547,16 @@ def _rotate(x, table, half, back=False):
     return straight - swapped if back else straight + swapped
 
 
-def _gqa_fwd_kernel(q_ref, k_ref, v_ref, g_ref, tq_ref, tk_ref,
-                    og_ref, o_ref, lse_ref, qb, m_s, l_s, acc,
-                    *, group, d, half, tile, n_visits, window, scale, mul):
+def _gqa_fwd_kernel(*refs, gated, group, d, half, tile, n_visits, window,
+                    scale, mul):
     from jax.experimental import pallas as pl
 
+    if gated:
+        (q_ref, k_ref, v_ref, g_ref, tq_ref, tk_ref, og_ref, o_ref, lse_ref,
+         qb, m_s, l_s, acc) = refs
+    else:
+        (q_ref, k_ref, v_ref, tq_ref, tk_ref, o_ref, lse_ref,
+         qb, m_s, l_s, acc) = refs
     i, j, exists, whole = _walk(tile, n_visits, window)
     first = pl.program_id(3) == 0
     last = pl.program_id(3) == n_visits - 1
@@ -580,19 +593,26 @@ def _gqa_fwd_kernel(q_ref, k_ref, v_ref, g_ref, tq_ref, tk_ref,
         for h in range(group):
             cols = slice(h * d, (h + 1) * d)
             o = acc[:, cols] / _lanes_to(l_s[h], d)
-            gate = _lanes_to(_head_stat(g_ref[0, 0], h), d)
+            if gated:
+                gate = _lanes_to(_head_stat(g_ref[0, 0], h), d)
             o_ref[0, :, cols] = o.astype(o_ref.dtype)
-            og_ref[0, :, cols] = (o * gate).astype(og_ref.dtype)
+            if gated:
+                og_ref[0, :, cols] = (o * gate).astype(og_ref.dtype)
         lse_ref[0, 0] = _stat_block(
             [m_s[h] + jnp.log(l_s[h]) for h in range(group)])
 
 
-def _gqa_bwd_kernel(q_ref, k_ref, v_ref, g_ref, tq_ref, tk_ref, o_ref,
-                    dog_ref, lse_ref, dq_ref, dk_ref, dv_ref, dg_ref,
-                    qb, dob, lse_s, delta,
-                    *, group, d, half, tile, n_visits, window, scale, mul):
+def _gqa_bwd_kernel(*refs, gated, group, d, half, tile, n_visits, window,
+                    scale, mul):
     from jax.experimental import pallas as pl
 
+    if gated:
+        (q_ref, k_ref, v_ref, g_ref, tq_ref, tk_ref, o_ref, dog_ref, lse_ref,
+         dq_ref, dk_ref, dv_ref, dg_ref, qb, dob, lse_s, delta) = refs
+    else:
+        # ``dog_ref`` is then the output's own gradient
+        (q_ref, k_ref, v_ref, tq_ref, tk_ref, o_ref, dog_ref, lse_ref,
+         dq_ref, dk_ref, dv_ref, qb, dob, lse_s, delta) = refs
     i, j, exists, whole = _walk(tile, n_visits, window)
     first = pl.program_id(3) == 0
     last = pl.program_id(3) == n_visits - 1
@@ -610,17 +630,20 @@ def _gqa_bwd_kernel(q_ref, k_ref, v_ref, g_ref, tq_ref, tk_ref, o_ref,
             cols = slice(h * d, (h + 1) * d)
             qb[:, cols] = _rotate(q_ref[0, :, cols], tq_ref,
                                   half).astype(mul)
-            gate = _head_stat(g_ref[0, 0], h)
+            if gated:
+                gate = _head_stat(g_ref[0, 0], h)
             d_gated = dog_ref[0, :, cols]
             # the ungated output's gradient, formed in float32
-            dob[:, cols] = (d_gated * _lanes_to(gate, d)).astype(mul)
+            dob[:, cols] = (d_gated * _lanes_to(gate, d) if gated
+                            else d_gated).astype(mul)
             # the gate's gradient: d_gated . out of a row; times the gate
             # it is sum_k p dp of that row (d_out . out)
             d_gate.append(_lanes(jnp.sum(d_gated * o_ref[0, :, cols],
                                          axis=1, keepdims=True)))
-            delta[h] = gate * d_gate[h]
+            delta[h] = gate * d_gate[h] if gated else d_gate[h]
             lse_s[h] = _head_stat(lse_ref[0, 0], h)
-        dg_ref[0, 0] = _stat_block(d_gate)
+        if gated:
+            dg_ref[0, 0] = _stat_block(d_gate)
 
     def step(masked):
         k = _rotate(k_ref[0], tk_ref, half).astype(mul)
@@ -653,10 +676,12 @@ def _gqa_bwd_kernel(q_ref, k_ref, v_ref, g_ref, tq_ref, tk_ref, o_ref,
                                          half, back=True)
 
 
-def _gqa_call(kernel, name, dims, half, tile, window, in_specs, out_specs,
-              out_shape, scratch, operand_dtype, interpret):
+def _gqa_call(kernel, name, dims, half, tile, window, gated, in_specs,
+              out_specs, out_shape, scratch, operand_dtype, interpret):
     """One ``pallas_call`` over (sequences, key/value heads, query tiles,
-    the key tiles a query tile visits)."""
+    the key tiles a query tile visits). Without a gate (``gated`` false) the
+    gate's operands and results (``"gate"``, a row statistic; ``"gated"``,
+    the gated output) are left out of the lists."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -680,15 +705,21 @@ def _gqa_call(kernel, name, dims, half, tile, window, in_specs, out_specs,
              "rope_q": pl.BlockSpec((tile, table), lambda s, g, i, _: (i, 0)),
              "rope_k": pl.BlockSpec((tile, table), lambda s, g, i, step:
                                     (key_tile(i, step), 0))}
+    specs.update(gate=specs["stat"], gated=specs["q"])
     shapes = {"q": (tile, G * d), "stat": (G, tile, TILE)}
+
+    def kept(keys, of):
+        return [x for k, x in zip(keys, of)
+                if gated or k not in ("gate", "gated")]
+
     return pl.pallas_call(
-        functools.partial(kernel, group=G, d=d, half=half, tile=tile,
-                          n_visits=n_visits, window=window, scale=d ** -0.5,
-                          mul=operand_dtype),
+        functools.partial(kernel, gated=gated, group=G, d=d, half=half,
+                          tile=tile, n_visits=n_visits, window=window,
+                          scale=d ** -0.5, mul=operand_dtype),
         grid=(S, Hkv, T // tile, n_visits),
-        in_specs=[specs[k] for k in in_specs],
-        out_specs=[specs[k] for k in out_specs],
-        out_shape=out_shape,
+        in_specs=kept(in_specs, [specs[k] for k in in_specs]),
+        out_specs=kept(out_specs, [specs[k] for k in out_specs]),
+        out_shape=kept(out_specs, out_shape),
         scratch_shapes=[pltpu.VMEM(shapes[k], dt) for k, dt in scratch],
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
@@ -705,20 +736,23 @@ def _by_kv_head(x, Hkv):
 
 
 def _gqa_forward(q, k, v, gate, table, static):
-    """(gated output, output, log-sum-exp); ``static`` is (rotary pairs,
-    window, tile, the multiplicands' dtype, interpret)."""
-    half, window, tile, operand_dtype, interpret = static
-    S, T, Hq, Hkv, d = dims = _gqa_dims(q, k, gate)
+    """(gated output, output, log-sum-exp), without a gate (output,
+    log-sum-exp); ``static`` is (rotary pairs, window, tile, the
+    multiplicands' dtype, interpret, the query heads of a call without a
+    gate)."""
+    half, window, tile, operand_dtype, interpret, heads = static
+    S, T, Hq, Hkv, d = dims = _gqa_dims(q, k, gate, heads)
     wide = jax.ShapeDtypeStruct((S, T, Hq * d), q.dtype)
+    gates = () if gate is None else (_by_kv_head(gate, Hkv),)
     return _gqa_call(
         _gqa_fwd_kernel, "fed_gqa_attn_fwd", dims, half, tile, window,
-        ("q", "kv", "kv", "stat", "rope_q", "rope_k"), ("q", "q", "stat"),
+        gate is not None,
+        ("q", "kv", "kv", "gate", "rope_q", "rope_k"), ("gated", "q", "stat"),
         [wide, wide,
          jax.ShapeDtypeStruct((S, Hkv, T, Hq // Hkv), jnp.float32)],
         (("q", operand_dtype), ("stat", jnp.float32), ("stat", jnp.float32),
          ("q", jnp.float32)),
-        operand_dtype, interpret)(q, k, v, _by_kv_head(gate, Hkv), table,
-                                  table)
+        operand_dtype, interpret)(q, k, v, *gates, table, table)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -727,8 +761,10 @@ def _gqa_fused(q, k, v, gate, table, static):
 
 
 def _gqa_fused_fwd(q, k, v, gate, table, static):
-    gated, out, lse = _gqa_forward(q, k, v, gate, table, static)
-    return gated, (q, k, v, gate, table, out, lse)
+    *outs, lse = _gqa_forward(q, k, v, gate, table, static)
+    # the ungated output is the backward's residual: the only one of a call
+    # without a gate
+    return outs[0], (q, k, v, gate, table, outs[-1], lse)
 
 
 def _kind(window) -> str:
@@ -741,15 +777,17 @@ def gqa_scope(window) -> str:
 
 
 def _gqa_fused_bwd(static, res, d_gated):
-    half, window, tile, operand_dtype, interpret = static
+    half, window, tile, operand_dtype, interpret, heads = static
     q, k, v, gate, table, out, lse = res
-    S, T, Hq, Hkv, d = dims = _gqa_dims(q, k, gate)
+    S, T, Hq, Hkv, d = dims = _gqa_dims(q, k, gate, heads)
+    gates = () if gate is None else (_by_kv_head(gate, Hkv),)
     # traced here, not where the forward call was: the scopes again
     with jax.named_scope("fed_gqa_attn"), jax.named_scope(gqa_scope(window)):
-        dq, dk, dv, dg = _gqa_call(
+        dq, dk, dv, *dg = _gqa_call(
             _gqa_bwd_kernel, "fed_gqa_attn_bwd", dims, half, tile, window,
-            ("q", "kv", "kv", "stat", "rope_q", "rope_k", "q", "q", "stat"),
-            ("q", "whole", "whole", "stat"),
+            gate is not None,
+            ("q", "kv", "kv", "gate", "rope_q", "rope_k", "q", "q", "stat"),
+            ("q", "whole", "whole", "gate"),
             [jax.ShapeDtypeStruct((S, T, Hq * d), jnp.float32),
              jax.ShapeDtypeStruct((S, T, Hkv * d), jnp.float32),
              jax.ShapeDtypeStruct((S, T, Hkv * d), jnp.float32),
@@ -757,20 +795,31 @@ def _gqa_fused_bwd(static, res, d_gated):
             (("q", operand_dtype), ("q", operand_dtype),
              ("stat", jnp.float32), ("stat", jnp.float32)),
             operand_dtype, interpret,
-        )(q, k, v, _by_kv_head(gate, Hkv), table, table, out, d_gated, lse)
-        dg = dg.transpose(0, 2, 1, 3).reshape(gate.shape)
+        )(q, k, v, *gates, table, table, out, d_gated, lse)
+        if gate is not None:
+            dg = dg[0].transpose(0, 2, 1, 3).reshape(gate.shape).astype(
+                gate.dtype)
         # positions are no parameter: the table takes no gradient
         return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
-                dg.astype(gate.dtype), jnp.zeros_like(table))
+                dg if gate is not None else None, jnp.zeros_like(table))
 
 
 _gqa_fused.defvjp(_gqa_fused_fwd, _gqa_fused_bwd)
 
 
+def _plan(gate, where: str) -> dict:
+    """``GQA_PLAN``'s entry for where q and k were turned (and the heads
+    gated, where the call has a gate)."""
+    if gate is None:
+        return {"turn": where, "gate": "none"}
+    return {"turn_and_gate": where}
+
+
 def gqa_attention_fused(q, k, v, gate, rope, window=None, interpret=False,
-                        tile=GQA_TILE):
+                        tile=GQA_TILE, heads=None):
     """The fused kernels; multiplicands rounded as ``mla_attention_fused``
-    rounds them. ``tile`` is for the interpreted tests' small shapes."""
+    rounds them. ``tile`` is for the interpreted tests' small shapes;
+    ``gate=None`` is a core without a gate, of ``heads`` query heads."""
     T = q.shape[1]
     n = T // tile
     GQA_PLAN[_kind(window)] = {
@@ -778,12 +827,14 @@ def gqa_attention_fused(q, k, v, gate, rope, window=None, interpret=False,
         "key_tiles_visited": sum(min(i + 1, _visits(T, tile, window))
                                  for i in range(n)),
         "key_tiles_causal": n * (n + 1) // 2,
-        "turn_and_gate": "kernel"}
+        **_plan(gate, "kernel")}
     cos, sin = rope
     return _gqa_fused(
-        q, k, v, gate, _rope_table(cos, sin, _gqa_dims(q, k, gate)[-1]),
+        q, k, v, gate,
+        _rope_table(cos, sin, _gqa_dims(q, k, gate, heads)[-1]),
         (cos.shape[-1], window, tile,
-         jnp.bfloat16 if _rounds_to_bfloat16() else jnp.float32, interpret))
+         jnp.bfloat16 if _rounds_to_bfloat16() else jnp.float32, interpret,
+         heads))
 
 
 def gqa_attention_path(T, Hq, Hkv, d, interpret=False) -> str:
@@ -794,16 +845,20 @@ def gqa_attention_path(T, Hq, Hkv, d, interpret=False) -> str:
     return "einsum"
 
 
-def gqa_attention(q, k, v, gate, rope, window=None, interpret=False):
+def gqa_attention(q, k, v, gate, rope, window=None, interpret=False,
+                  heads=None):
     """The grouped-query core, from the projections' outputs to ``W_o``'s
     operand, on the path this call's shape and process take
-    (``gqa_attention_path``); counts the call in ``PATH_CALLS``."""
-    path = gqa_attention_path(*_gqa_dims(q, k, gate)[1:], interpret)
+    (``gqa_attention_path``); counts the call in ``PATH_CALLS``. ``gate`` is
+    (S, T, Hq), or ``None`` for a core without one: ``heads`` then says how
+    many query heads q holds."""
+    path = gqa_attention_path(*_gqa_dims(q, k, gate, heads)[1:], interpret)
     PATH_CALLS[path] += 1
     if path == "einsum":
-        GQA_PLAN[_kind(window)] = {"turn_and_gate": "xla"}
-        return gqa_attention_einsum(q, k, v, gate, rope, window)
-    return gqa_attention_fused(q, k, v, gate, rope, window, interpret)
+        GQA_PLAN[_kind(window)] = _plan(gate, "xla")
+        return gqa_attention_einsum(q, k, v, gate, rope, window, heads)
+    return gqa_attention_fused(q, k, v, gate, rope, window, interpret,
+                               heads=heads)
 
 
 # the kernels against the oracle on the chip: both round multiplicands to
@@ -815,50 +870,61 @@ GQA_CHECK_TOL = 2e-2
 
 
 def check_gqa_kernels(heads=(48, 64), kv_heads=8, d=128, T=MAX_GQA_T,
-                      window=512, interpret=False, tile=GQA_TILE) -> dict:
+                      window=512, interpret=False, tile=GQA_TILE,
+                      ungated=((16, 16, 1024), (16, 16, MAX_GQA_T))) -> dict:
     """``gqa_attention_fused`` against ``gqa_attention_einsum`` at the
-    published shape of models/laguna.py (one sequence; 48 query heads with
+    published shapes (one sequence). models/laguna.py: 48 query heads with
     no window, half of a head's columns turned, a factor on cos and sin; 64
-    with the window, every column turned): the gated output and all four
-    gradients within ``GQA_CHECK_TOL`` of the oracle's largest entry. The
-    oracle's scores do not fit a chip whole at 4,096 positions, so it runs
-    one key/value head (and its query heads) at a time. Returns the largest
-    gaps seen."""
+    with the window, every column turned. models/ouro.py (``ungated``:
+    query heads, key/value heads, positions): 16 over 16, no gate, no
+    window, every column turned. The output and every gradient within
+    ``GQA_CHECK_TOL`` of the oracle's largest entry. The oracle's scores do
+    not fit a chip whole at 4,096 positions, so it runs one key/value head
+    (and its query heads) at a time. Returns the largest gaps seen."""
     worst = {}
-    pos = jnp.arange(T, dtype=jnp.float32)[:, None]
-    for n, (Hq, win) in enumerate(zip(heads, (None, window))):
-        G = Hq // kv_heads
-        rotary, factor = (d // 2, 1.4) if win is None else (d, 1.0)
+    cases = [(Hq, kv_heads, T, win, True)
+             for Hq, win in zip(heads, (None, window))]
+    cases += [(Hq, Hkv, T_, None, False) for Hq, Hkv, T_ in ungated]
+    for n, (Hq, Hkv, T_, win, gated) in enumerate(cases):
+        G = Hq // Hkv
+        rotary, factor = (d // 2, 1.4) if gated and win is None else (d, 1.0)
+        pos = jnp.arange(T_, dtype=jnp.float32)[:, None]
         angle = pos * 10000.0 ** (-jnp.arange(0, rotary, 2) / rotary)
         rope = (jnp.cos(angle) * factor, jnp.sin(angle) * factor)
         keys = jax.random.split(jax.random.key(n), 5)
-        q, k, v, w = (jax.random.normal(key, (1, T, h * d), jnp.float32)
-                      for key, h in zip(keys, (Hq, kv_heads, kv_heads, Hq)))
-        gate = jax.nn.sigmoid(jax.random.normal(keys[4], (1, T, Hq)))
+        q, k, v, w = (jax.random.normal(key, (1, T_, h * d), jnp.float32)
+                      for key, h in zip(keys, (Hq, Hkv, Hkv, Hq)))
+        gate = (jax.nn.sigmoid(jax.random.normal(keys[4], (1, T_, Hq)))
+                if gated else None)
 
         def both(fn):
             def run(q, k, v, gate, w):
-                return (fn(q, k, v, gate),) + jax.grad(
-                    lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2, 3))(
-                        q, k, v, gate)
+                args = (q, k, v) if gate is None else (q, k, v, gate)
+
+                def f(*a):
+                    return fn(*a, None) if gate is None else fn(*a)
+                return (f(*args),) + jax.grad(
+                    lambda *a: jnp.sum(f(*a) * w),
+                    argnums=tuple(range(len(args))))(*args)
             return jax.jit(run)
 
-        got = both(functools.partial(gqa_attention_fused, rope=rope,
-                                     window=win, interpret=interpret,
-                                     tile=tile))(q, k, v, gate, w)
+        got = both(functools.partial(
+            gqa_attention_fused, rope=rope, window=win, interpret=interpret,
+            tile=tile, heads=Hq))(q, k, v, gate, w)
         one = both(functools.partial(gqa_attention_einsum, rope=rope,
-                                     window=win))
-        for g in range(kv_heads):
+                                     window=win, heads=G))
+        label = _kind(win) if gated else f"ungated_T{T_}"
+        for g in range(Hkv):
             hq = slice(g * G, (g + 1) * G)
             cq = slice(g * G * d, (g + 1) * G * d)
             ck = slice(g * d, (g + 1) * d)
-            want = one(q[..., cq], k[..., ck], v[..., ck], gate[..., hq],
-                       w[..., cq])
+            want = one(q[..., cq], k[..., ck], v[..., ck],
+                       gate[..., hq] if gated else None, w[..., cq])
             for name, a, b, cut in zip(("out", "dq", "dk", "dv", "dg"), got,
                                        want, (cq, cq, ck, ck, hq)):
                 gap = float(jnp.max(jnp.abs(a[..., cut] - b))
                             / jnp.max(jnp.abs(b)))
-                key = f"{name}_{_kind(win)}"
+                key = f"{name}_{label}"
                 worst[key] = max(worst.get(key, 0.0), gap)
     bad = {k: v for k, v in worst.items() if not v <= GQA_CHECK_TOL}
     if bad:

@@ -60,15 +60,19 @@ DEVICE_STAGES = ("fed_client_grad", "fed_client_compress",
                  "fed_server_resketch", "fed_server_apply",
                  "fed_telemetry_metrics", "fed_accounting", "fed_val")
 # Scopes a model opens INSIDE ``fed_client_grad`` (models/joyai.py,
-# models/laguna.py, parallel/moe.py): the expert layer's routing (scores,
-# top-k, grouping, gather and scatter of the held pairs), its grouped
-# products, the latent attention's core, and the grouped-query attention's
-# (RoPE, the core, the head gate) with the kind of its layer nested in it
-# (ops/attention.py opens an attention scope again around its backward
-# pass). Not stages: an operation under one of them still has
+# models/laguna.py, models/ouro.py, parallel/moe.py): the expert layer's
+# routing (scores, top-k, grouping, gather and scatter of the held pairs),
+# its grouped products, the latent attention's core, and the grouped-query
+# attention's (RoPE, the core, the head gate) with the kind of its layer
+# nested in it (ops/attention.py opens an attention scope again around its
+# backward pass); of a stack run as a recurrence (models/ouro.py) the
+# blocks of a pass (``fed_loop_body``, the attention's scopes nested in it)
+# and what is read after a pass (``fed_loop_head``: final norm, head, NLL,
+# exit gate). Not stages: an operation under one of them still has
 # ``fed_client_grad`` as its one stage.
 INNER_SCOPES = ("fed_moe_route", "fed_moe_experts", "fed_mla_attn",
-                "fed_gqa_attn", "fed_gqa_attn_full", "fed_gqa_attn_window")
+                "fed_gqa_attn", "fed_gqa_attn_full", "fed_gqa_attn_window",
+                "fed_loop_body", "fed_loop_head")
 # ``name=`` of every pallas_call (ops/sketch.py, ops/topk.py,
 # ops/attention.py). The sketch kernels keep ``sketch`` / ``estimates`` /
 # ``epilogue`` in theirs and the top-k and attention kernels do not:

@@ -844,7 +844,8 @@ class RunTelemetry:
         ``offload`` the host-offload data-plane record (placement tier,
         gather/scatter ms, prefetch hit/miss — docs/host_offload.md).
         ``model`` the round's sums of the loss's named metric sums (a
-        routed-expert model's pair counts, losses.MOE_METRIC_NAMES).
+        routed-expert model's pair counts, the model configuration's
+        ``metric_names``).
         ``metrics`` is None for async BUFFERED dispatches — the server
         phase (whose jitted vector the metrics are) runs only on folds."""
         span = self._spans.setdefault(round_no, {})

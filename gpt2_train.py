@@ -57,6 +57,7 @@ from commefficient_tpu.models.gpt2 import (
 )
 from commefficient_tpu.models.joyai import JoyAIConfig, JoyAIFlash
 from commefficient_tpu.models.laguna import LagunaConfig, LagunaXS2
+from commefficient_tpu.models.ouro import Ouro, OuroConfig
 from commefficient_tpu.ops.attention import GQA_PLAN, PATH_CALLS
 from commefficient_tpu.utils import (
     PiecewiseLinear,
@@ -111,7 +112,8 @@ def report_attention_core(model):
     on in this process and how many calls took it (ops/attention.py
     ``PATH_CALLS``), and by kind of grouped-query layer the kernels' tile
     walk and where q and k were turned and the heads gated (``GQA_PLAN``):
-    printed, and a ``model`` event in the run's log.
+    printed, and a ``model`` event in the run's log; a recurrent stack's
+    passes, layers and block applications a step beside it (``loop``).
     Said once a run, when its first round has been dispatched, so the
     round's own programs are among the traces counted, not the
     initialisation's alone."""
@@ -126,13 +128,22 @@ def report_attention_core(model):
               + (f" visit {p['key_tiles_visited']} of "
                  f"{p['key_tiles_causal']} causal key tiles of {p['tile']}, "
                  if "tile" in p else " ")
-              + f"turn and gate: {p['turn_and_gate']}"
+              + (f"turn and gate: {p['turn_and_gate']}"
+                 if "turn_and_gate" in p
+                 else f"turn: {p['turn']}, gate: {p['gate']}")
               for kind, p in sorted(GQA_PLAN.items())))
+    # a decoder whose stack is a recurrence says how it was traced
+    # (models/ouro.py ``loop_plan``): its own event, ``loop``
+    loop = getattr(model.model.cfg, "loop_plan", None)
+    if loop:
+        print("recurrence: " + ", ".join(f"{k} {v}" for k, v in loop.items()))
     rt = getattr(model, "telemetry", None)
     if rt is not None:
         rt.event("model", attn_path=attn_path,
                  attn_calls=PATH_CALLS[attn_path],
                  **({"attn_plan": dict(GQA_PLAN)} if GQA_PLAN else {}))
+        if loop:
+            rt.event("loop", **loop)
 
 
 def run_batches(model, opt, lr_scheduler, loader, args, timer, training,
@@ -267,31 +278,39 @@ def train_gpt2(model, opt, scheduler, train_loader, val_loader, args,
     return test_gpt2(model, val_loader, args, timer=timer, writer=writer)
 
 
-# --arch: the configuration and its decoder (models/joyai.py, models/laguna.py)
+# --arch: the configuration and its decoder (models/joyai.py,
+# models/laguna.py, models/ouro.py)
 DECODERS = {"joyai_llm_flash": (JoyAIConfig, JoyAIFlash),
-            "laguna_xs2": (LagunaConfig, LagunaXS2)}
+            "laguna_xs2": (LagunaConfig, LagunaXS2),
+            "ouro_2p6b": (OuroConfig, Ouro)}
 
 
 def build_decoder(args, tiny):
     """The --arch decoder and its causal-LM losses, cut as the flags say:
-    the layers held, this chip's experts of --layer_chips that share a
-    layer, the vocabulary's rows."""
+    the layers held, the vocabulary's rows and, where the configuration has
+    routed experts, this chip's of --layer_chips that share a layer."""
     config, decoder = DECODERS[args.arch]
     full = config.tiny() if tiny else config()
-    assert full.routed % args.layer_chips == 0, \
-        f"--layer_chips must divide {full.routed} routed experts"
-    # the unit rounds a float32 product's multiplicands to bfloat16 at the
-    # default precision; XLA:TPU does not do so to a grouped product, so the
-    # expert layer is told to (parallel/moe.py _grouped_dot)
-    rounds = (is_tpu_backend()
-              and jax.config.jax_default_matmul_precision is None)
-    cfg = dataclasses.replace(
-        full, expert_operand_dtype=jnp.bfloat16 if rounds else None,
-        layers=args.arch_layers or full.layers,
-        experts_held=full.routed // args.layer_chips,
-        expert_offset=args.expert_offset,
-        vocab_rows=args.vocab_rows or max(full.vocab_rows,
-                                          args.len_tokenizer))
+    cut = dict(layers=args.arch_layers or full.layers,
+               vocab_rows=args.vocab_rows or max(full.vocab_rows,
+                                                 args.len_tokenizer))
+    if full.routed:
+        assert full.routed % args.layer_chips == 0, \
+            f"--layer_chips must divide {full.routed} routed experts"
+        # the unit rounds a float32 product's multiplicands to bfloat16 at
+        # the default precision; XLA:TPU does not do so to a grouped
+        # product, so the expert layer is told to (parallel/moe.py
+        # _grouped_dot)
+        rounds = (is_tpu_backend()
+                  and jax.config.jax_default_matmul_precision is None)
+        cut.update(expert_operand_dtype=jnp.bfloat16 if rounds else None,
+                   experts_held=full.routed // args.layer_chips,
+                   expert_offset=args.expert_offset)
+    else:
+        assert args.layer_chips == 1 and args.expert_offset == 0, (
+            f"--arch {args.arch} has no routed experts to share out: its "
+            "cut is in depth alone (--layer_chips 1, --expert_offset 0)")
+    cfg = dataclasses.replace(full, **cut)
     assert args.len_tokenizer <= cfg.vocab_rows, (
         f"the tokenizer's {args.len_tokenizer} ids do not fit the "
         f"{cfg.vocab_rows} rows of the vocabulary held")

@@ -32,11 +32,11 @@ import joyai_flash_ep32_ref as ref_file  # noqa: E402
 import reference  # noqa: E402
 
 from commefficient_tpu.federated.losses import (  # noqa: E402
-    MOE_METRIC_NAMES,
     make_causal_lm_losses,
 )
 from commefficient_tpu.models.joyai import (  # noqa: E402
     MLA,
+    MOE_METRIC_NAMES,
     JoyAIConfig,
     JoyAIFlash,
 )

@@ -44,10 +44,12 @@ import laguna_xs2_ep32_ref as ref_file  # noqa: E402
 import reference  # noqa: E402
 
 from commefficient_tpu.federated.losses import (  # noqa: E402
-    MOE_METRIC_NAMES,
     make_causal_lm_losses,
 )
-from commefficient_tpu.models.joyai import Block  # noqa: E402
+from commefficient_tpu.models.joyai import (  # noqa: E402
+    MOE_METRIC_NAMES,
+    Block,
+)
 from commefficient_tpu.models.laguna import (  # noqa: E402
     GQA,
     LagunaConfig,

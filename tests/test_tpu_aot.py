@@ -228,6 +228,52 @@ def test_gqa_attention_kernels_compile_for_v5e(Hq, window, backward):
         < out_bytes + ((3 * 64 + 8) << 20)
 
 
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+@pytest.mark.parametrize("T", [1024, attention.MAX_GQA_T])
+def test_gqa_attention_kernels_without_a_gate_compile_for_v5e(T, backward):
+    """The same kernels without the gate's operand (``gate=None``) at
+    models/ouro.py's shape: 16 query heads over 16 key/value heads of 128 (a
+    group of ONE query head a grid step: q's block is one head wide, the row
+    statistics' blocks one column), every column turned, 4 sequences of
+    1,024 positions (``ouro_2p6b_sketch_1c``'s scan step) and of 4,096 (the
+    longest the path chooser sends them). Forward: one output and the
+    log-sum-exp. Backward: ``dq``, ``dk``, ``dv`` and no gate gradient.
+    Nothing of the scores' size and no second array of q's size is left in
+    HBM around them: the log-sum-exp by key/value head is a lane-padded
+    (4, 16, T, 1) float32 array (128 MiB at 4,096 positions)."""
+    S, H, d = 4, 16, 128
+    one = SingleDeviceSharding(_v5e_devices()[0])
+    q, cos, sin = (jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+                   for shape in ((S, T, H * d), (T, d // 2), (T, d // 2)))
+
+    def fwd(q, k, v, cos, sin):
+        return attention.gqa_attention_fused(q, k, v, None, (cos, sin),
+                                             heads=H)
+
+    def bwd(q, k, v, cos, sin, d_out):
+        return jax.vjp(lambda *a: fwd(*a, cos, sin), q, k, v)[1](d_out)
+
+    _, compiled = _compile_tpu(jax.jit(bwd if backward else fwd),
+                               *((q, q, q, cos, sin)
+                                 + ((q,) if backward else ())))
+    text = compiled.as_text()
+    assert "fed_gqa_attn_fwd" in text
+    assert ("fed_gqa_attn_bwd" in text) == backward
+    if backward:
+        (call,) = [line for line in text.splitlines()
+                   if "fed_gqa_attn_bwd" in line and "custom-call(" in line]
+        # three results (dq, dk, dv): no fourth for a gate
+        assert call.count("f32[4,%d,2048]" % T) >= 3
+        assert "f32[4,16,%d,1]" % T not in call.split(" custom-call(")[0]
+    # the output (the backward's residual), the log-sum-exp, the rope's
+    # table: never a (T, T) array, and no second array of q's size
+    out_bytes = S * T * H * d * 4
+    lse_bytes = S * H * T * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < out_bytes + lse_bytes + (8 << 20)
+
+
 def _entry_ops(text):
     """(element count of the first result, operation, line) of the entry
     computation's instructions of a compiled module's text."""

@@ -197,21 +197,25 @@ class TestDeviceStages:
 class TestInnerScopes:
     def test_inner_scopes_nest_under_one_stage(self):
         """A model's own scopes (``INNER_SCOPES``: the expert layer's
-        routing and grouped products, the latent attention's core) are no
-        stages: an operation under one of them, forward, recomputed or
-        backward, still carries ``fed_client_grad`` as its one stage, so
-        ``stage_of`` finds one stage an operation."""
+        routing and grouped products, the attention cores, a recurrent
+        stack's body and head) are no stages: an operation under one of
+        them, forward, recomputed or backward, still carries
+        ``fed_client_grad`` as its one stage, so ``stage_of`` finds one
+        stage an operation."""
         from commefficient_tpu.federated.losses import make_causal_lm_losses
         from commefficient_tpu.models.joyai import JoyAIConfig, JoyAIFlash
         from commefficient_tpu.models.laguna import LagunaConfig, LagunaXS2
+        from commefficient_tpu.models.ouro import Ouro, OuroConfig
         from commefficient_tpu.profiling import INNER_SCOPES
 
         assert not set(INNER_SCOPES) & set(DEVICE_STAGES)
         cut = dict(layers=2, experts_held=4, expert_offset=0, vocab_rows=64)
-        names = set()
-        # (a full and a sliding layer: LagunaConfig.tiny's first two)
+        # (a full and a sliding layer: LagunaConfig.tiny's first two; the
+        # recurrence's body and head: Ouro's)
+        names = {}
         for model in (JoyAIFlash(JoyAIConfig.tiny(**cut)),
-                      LagunaXS2(LagunaConfig.tiny(**cut))):
+                      LagunaXS2(LagunaConfig.tiny(**cut)),
+                      Ouro(OuroConfig.tiny(layers=2, vocab_rows=64))):
             ids = jnp.zeros((2, 1, 8), jnp.int32)
             params = model.init(jax.random.key(0), ids[:, 0])["params"]
             train, _ = make_causal_lm_losses(model)
@@ -224,16 +228,33 @@ class TestInnerScopes:
                     return jax.grad(
                         lambda p: train(p, {}, batch, None, True)[0])(p)
 
-            names |= set(re.findall(r'op_name="([^"]*)"',
-                                    step.lower(params).compile().as_text()))
+            names[type(model).__name__] = set(re.findall(
+                r'op_name="([^"]*)"', step.lower(params).compile().as_text()))
+        looped = names.pop("Ouro")
+        plain = set().union(*names.values())
         for inner in INNER_SCOPES:
-            under = [n for n in names if inner in n]
-            assert under, f"no operation under {inner}"
-            assert any("transpose(" in n for n in under), \
-                f"no backward operation under {inner}"
+            under = [n for n in plain if inner in n]
+            loop_under = [n for n in looped if inner in n]
+            assert loop_under if inner.startswith("fed_loop_") else under, \
+                f"no operation under {inner}"
+            for found in filter(None, (under, loop_under)):
+                assert any("transpose(" in n for n in found), \
+                    f"no backward operation under {inner}"
             # (a custom_vjp's backward repeats the stack: the one stage twice)
             assert all(set(_stages_in(n)) == {"fed_client_grad"}
                        for n in under), under[:3]
+            # a stack run as a ``scan`` over its passes, and that alone:
+            # differentiating the loop moves what does not depend on the
+            # pass (the turn's table, the oracle's mask, the labels' index)
+            # out of it, and the CPU's reducers inside a loop are named by
+            # their own path: those carry no stage. Never a product, never a
+            # kernel, and no other stage
+            stray = [n for n in loop_under if not _stages_in(n)]
+            assert not any("dot_general" in n or "pallas_call" in n
+                           for n in stray), stray[:3]
+            assert all(set(_stages_in(n)) == {"fed_client_grad"}
+                       for n in loop_under if n not in stray), loop_under[:3]
+            assert len(stray) <= len(loop_under) / 2
 
 
 def _pallas_names(jaxpr, out):
@@ -294,7 +315,7 @@ class TestKernelNames:
                 *gqa, rope, window=16, interpret=True, tile=16),
             # residuals: the operands, the table, the output, log-sum-exp
             "fed_gqa_attn_bwd": lambda: at._gqa_fused_bwd(
-                (8, 16, 16, jnp.float32, True),
+                (8, 16, 16, jnp.float32, True, None),
                 (*gqa, at._rope_table(*rope, 16), gqa[0],
                  jnp.zeros((1, 1, 32, 2))), gqa[0]),
         }
