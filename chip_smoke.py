@@ -162,9 +162,7 @@ def phase_kernels(ns) -> dict:
     checks = [
         ("sketch_vec", lambda: sk.check_sketch_vec_kernel(cs, interpret)),
         ("estimates", lambda: sk.check_estimates_kernel(cs, interpret)),
-        ("sketch_accum (--stream_sketch)",
-         lambda: sk.check_sketch_accum_kernel(cs, interpret)),
-        ("sketch_segments (--sketch_coalesce)",
+        ("sketch_segments (the client phase's leaf groups)",
          lambda: sk.check_sketch_segments_kernel(cs, interpret)),
         ("fused_epilogue",
          lambda: sk.check_fused_epilogue_kernel(cs, g["k"], interpret)),
@@ -311,6 +309,10 @@ def phase_train(ns) -> dict:
     _say(f"{ns.phase}: backend compile {compiled['s']:.1f} s, persistent "
          f"cache hits {compiled['hits']}, entries written "
          f"{compiled['writes']}")
+    _say(f"{ns.phase}: client sketch path "
+         f"{header.get('client_sketch_path')}, "
+         f"{header.get('client_sketch_launches')} accumulate launches a "
+         f"round")
     _say(f"{ns.phase}: weights {seen['init']} -> {final}")
     for p in problems:
         _say(f"{ns.phase}: PROBLEM {p}")

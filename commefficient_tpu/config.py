@@ -241,37 +241,6 @@ def build_parser(default_lr=None) -> argparse.ArgumentParser:
                              "re-sketch) into one kernel pass over the "
                              "d-plane (sketch mode only; composed path "
                              "stays the default and the reference).")
-    # Streaming client-phase sketch (docs/stream_sketch.md): the fused
-    # client phase sketches each gradient leaf at its flat offset as the
-    # backward pass produces it — the d-sized concatenate/pad/reshape
-    # movement of the client phase disappears and the microbatch scan's
-    # carry shrinks from O(d) to O(sketch table). Composed stays the
-    # default and the bit-exact reference; env kill-switch
-    # COMMEFFICIENT_STREAM_SKETCH=0 restores composed without a flag
-    # change (the fused-epilogue rollout pattern).
-    parser.add_argument("--stream_sketch", action="store_true",
-                        dest="stream_sketch",
-                        help="Stream the client phase's gradient into the "
-                             "count-sketch table leaf-by-leaf instead of "
-                             "materializing the flat d-vector (sketch mode "
-                             "with the fused client phase only; composed "
-                             "path stays the default).")
-    # Coalesced client-phase sketch megakernel (docs/stream_sketch.md):
-    # refines --stream_sketch by batching adjacent gradient leaves into
-    # covering chunk-range groups, each accumulated with ONE kernel
-    # launch that keeps the table row block VMEM-resident across the
-    # group — one table read+write per group instead of per leaf (~150
-    # per-leaf launches/microbatch at GPT-2 geometry). Bit-identical to
-    # the per-leaf streaming path; env kill-switch
-    # COMMEFFICIENT_SKETCH_COALESCE=0 restores per-leaf without a flag
-    # change.
-    parser.add_argument("--sketch_coalesce", action="store_true",
-                        dest="sketch_coalesce",
-                        help="Coalesce the streamed client-phase sketch's "
-                             "per-leaf accumulate launches into one "
-                             "multi-segment kernel per group of adjacent "
-                             "leaves (requires --stream_sketch; per-leaf "
-                             "path stays the reference).")
     parser.add_argument("--metrics_drain_every", type=int, default=8,
                         help="Fetch per-round metrics in batches of N "
                              "rounds; 1 restores per-round (blocking) "
@@ -778,27 +747,6 @@ def validate_args(args):
             print("NOTE: --inject_fault without --guards will poison the "
                   "run with nothing to catch it (intentional only for "
                   "demonstrating the failure mode)")
-    if args.stream_sketch:
-        # rounds.build_round_step silently composes outside the legal
-        # window (mirroring --fused_epilogue); say so up front for the
-        # obviously-ineligible configs instead of quietly ignoring the flag
-        if args.mode != "sketch":
-            print(f"NOTE: --stream_sketch is sketch-mode only; mode="
-                  f"{args.mode} runs the composed path")
-        elif (args.local_momentum > 0 or args.error_type == "local"
-              or args.do_dp or args.max_grad_norm is not None
-              or args.do_topk_down):
-            print("NOTE: --stream_sketch needs the fused client phase "
-                  "(no per-client sketch-space state — set "
-                  "--local_momentum 0 / --error_type virtual — and no "
-                  "clip, DP, or topk-down); this config runs the "
-                  "composed path")
-    if getattr(args, "sketch_coalesce", False) and not args.stream_sketch:
-        # the coalescer refines the leaf-streamed accumulate; without
-        # --stream_sketch there are no per-leaf launches to coalesce
-        print("NOTE: --sketch_coalesce refines the streaming client "
-              "phase; without --stream_sketch it has nothing to coalesce "
-              "and this config runs the composed path")
     if args.reduce_dtype == "int8":
         assert args.server_shard, (
             "--reduce_dtype int8 quantizes the transmit reduce of the "

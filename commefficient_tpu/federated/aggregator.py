@@ -349,19 +349,6 @@ class FedModel:
         self._guards = bool(getattr(args, "guards", False))
         self._guard_max_abs = float(getattr(args, "guard_max_abs", 0.0)
                                     or 0.0)
-        # Streaming client-phase sketch (--stream_sketch,
-        # docs/stream_sketch.md): the fused client phase sketches each
-        # gradient leaf at its flat offset instead of materializing the
-        # d-vector; rounds.build_round_step composes silently when the
-        # config is outside the legal window (the fused-epilogue pattern).
-        self._stream_sketch = bool(getattr(args, "stream_sketch", False))
-        # Coalesced client-phase sketch (--sketch_coalesce,
-        # docs/stream_sketch.md): adjacent leaves batch into one
-        # multi-segment accumulate launch per covering chunk-range group;
-        # only active inside the streaming window (build_round_step
-        # ignores it otherwise, like the flags above).
-        self._sketch_coalesce = bool(getattr(args, "sketch_coalesce",
-                                             False))
         # Zero-sync telemetry plane (--telemetry, docs/observability.md):
         # the jitted server phase returns one extra fixed-schema device
         # metrics vector per round; it rides the round handle to the
@@ -391,8 +378,6 @@ class FedModel:
                           server_shard=self._server_shard,
                           reduce_dtype=self._reduce_dtype,
                           collective_plan=self.collective_plan,
-                          stream_sketch=self._stream_sketch,
-                          sketch_coalesce=self._sketch_coalesce,
                           guards=self._guards,
                           guard_max_abs=self._guard_max_abs,
                           telemetry=self._telemetry_cfg,

@@ -75,7 +75,6 @@ from commefficient_tpu.ops.sketch import (
     CountSketch,
     coalesce_vmem_budget,
     sketch_chunks,
-    sketch_chunks_accum,
     sketch_vec,
 )
 
@@ -207,40 +206,21 @@ class RoundConfig:
     # the pre-plan code paths (pinned in
     # tests/test_compressed_collectives.py).
     collective_plan: Optional[Any] = None
-    # Streaming client-phase sketch (--stream_sketch,
-    # docs/stream_sketch.md): the fused client phase's microbatch scan
-    # carries the (r, c_pad) count-sketch TABLE instead of the d-sized
-    # gradient accumulator — each gradient leaf is sketched at its flat
-    # offset (ops/flat.leaf_segments) right after the backward pass
-    # produces it, the seq/model/pp/expert psums ride the small table
-    # (sketch linearity), and weight decay folds in as one extra
-    # segment-sketch of the resident chunked weights. Kills the client
-    # phase's d-sized concatenate/pad/reshape movement (22.6% of device
-    # time in a v5e profile of 2026-08-01) and shrinks the scan carry
-    # from O(d) to O(table). Requires the fused-gradient + sketch-after-sum
-    # + chunked-resident window; silently composed elsewhere (and under
-    # the COMMEFFICIENT_STREAM_SKETCH=0 kill-switch), mirroring the
-    # fused-epilogue rollout. The composed path stays the default and the
-    # bit-exact reference.
-    stream_sketch: bool = False
-    # Coalesced client-phase sketch megakernel (--sketch_coalesce,
-    # docs/stream_sketch.md): refines --stream_sketch by grouping
-    # adjacent gradient leaves into covering chunk-range groups
-    # (ops/flat.coalesce_segments) and accumulating each group with ONE
-    # multi-segment kernel launch (ops/sketch.sketch_segments_accum) that
-    # keeps the table row block VMEM-resident across every leaf of the
-    # group — one table row-block read + write per GROUP instead of per
-    # leaf (the per-leaf path re-reads 2·r·c_pad·4 bytes per leaf, ~150
-    # launches/microbatch ≈ 3 GB/round of table churn at GPT-2 geometry).
-    # The per-cell f32 add order replays the per-leaf streaming fold
-    # (±0.0 caveat unchanged), so fp32 trajectories are bit-identical to
-    # the per-leaf --stream_sketch path. Only active inside the streaming
-    # window (requires stream_sketch); COMMEFFICIENT_SKETCH_COALESCE=0
-    # kill-switch restores per-leaf. The per-leaf and composed paths are
-    # kept as the always-available references.
-    sketch_coalesce: bool = False
-    # Coalescer group-sizing budget in bytes (the covering chunk-range
-    # staging buffer per group); 0 = auto from the sketch geometry
+    # The sketch cells' client phase (docs/stream_sketch.md): inside the
+    # fused-gradient + sketch-after-sum + chunked-resident window the
+    # gradient never becomes a flat vector. The parameter tree is sliced
+    # out of the resident plane once a round, the backward pass
+    # differentiates by that tree, the microbatch scan adds the leaves'
+    # gradients leaf by leaf, and after the scan the leaves are sketched
+    # once at their flat offsets, one accumulate launch per
+    # ``ops/flat.coalesce_segments`` group (weight decay added in the
+    # group's staging pass, read from the plane's own rows). The
+    # table equals the flat route's under ``==`` (zero cells may differ in
+    # sign). None = decide (on in that window, the only rule), False pins
+    # the flat route (tests build both sides), True asserts the window.
+    sketch_leaf_groups: Optional[bool] = None
+    # Group-sizing budget in bytes (the covering chunk-range staging
+    # buffer of one launch); 0 = auto from the sketch geometry
     # (ops/sketch.coalesce_vmem_budget).
     sketch_coalesce_budget: int = 0
     # On-device health guards (--guards, docs/fault_tolerance.md): the
@@ -293,6 +273,12 @@ class FederatedSteps(NamedTuple):
     # ops/flat.ChunkLayout of the resident ps_weights when the chunked data
     # plane is on, else None (callers convert flat vectors at this boundary)
     layout: Optional[Any] = None
+    # the gradient's route to the sketch table in the client phase
+    # ("leaf_groups" | "flat"; docs/stream_sketch.md) and the accumulate
+    # launches of its group plan (0 on the flat route) — the run's start
+    # event carries both (docs/observability.md §Names)
+    client_sketch_path: str = "flat"
+    client_sketch_launches: int = 0
 
 
 def build_round_step(
@@ -455,18 +441,16 @@ def build_round_step(
     # fused sketch mode only ever rides the sketch-after-sum path
     assert not (fused_grad and wcfg.mode == "sketch" and not sketch_after_sum)
 
-    # Streaming client-phase sketch (--stream_sketch, docs/stream_sketch.md):
-    # legal only inside the fused-gradient + sketch-after-sum +
-    # chunked-resident window (one gradient per shard, nothing nonlinear
-    # between the backward pass and the table). Silently composed elsewhere
-    # and under the COMMEFFICIENT_STREAM_SKETCH=0 kill-switch — the
-    # fused-epilogue rollout pattern; the composed path stays the default
-    # and the bit-exact reference.
-    import os as _os
-
-    stream = (bool(cfg.stream_sketch)
-              and fused_grad and sketch_after_sum and chunked
-              and _os.environ.get("COMMEFFICIENT_STREAM_SKETCH", "1") != "0")
+    # The gradient's route to the table (docs/stream_sketch.md): inside the
+    # fused-gradient + sketch-after-sum + chunked-resident window (one
+    # gradient per shard, nothing nonlinear between the backward pass and
+    # the table) the leaves are sketched in groups at their flat offsets;
+    # everywhere else the flat gradient itself is needed.
+    leaf_groups = fused_grad and sketch_after_sum and chunked
+    if cfg.sketch_leaf_groups is not None:
+        assert not (cfg.sketch_leaf_groups and not leaf_groups), \
+            "sketch_leaf_groups=True forced on a config outside its window"
+        leaf_groups = cfg.sketch_leaf_groups
 
     # Tensor/expert parallelism: flat grad-rescale masks built once,
     # host-side — 1.0 on segments whose weights the model computes
@@ -475,8 +459,8 @@ def build_round_step(
     # .expert_axis).
     # the template pytree of the flat layout (eval_shape: no device
     # allocation at GPT-2 scale) and its per-leaf offset map — computed
-    # once per build, shared by the tp/ep rescale masks and the streaming
-    # sketch's per-leaf scales and offsets, so the layouts cannot drift
+    # once per build, shared by the tp/ep rescale masks and the leaf
+    # groups' per-leaf scales and offsets, so the layouts cannot drift
     # (ops/flat.leaf_segments)
     _layout_cache = {}
 
@@ -512,15 +496,14 @@ def build_round_step(
             f"{pred_attr} scale layout does not match the flat vector"
         return scale
 
-    # A streaming build never touches the d-sized masks (its per-leaf
+    # The leaf-group route never touches the d-sized masks (its per-leaf
     # constants come from _leaf_scale_vals below) — materializing them
-    # anyway would park ~2×d f32 of dead mask in HBM at GPT-2 scale,
-    # eroding the O(d)→O(table) memory win the flag exists for.
+    # anyway would park ~2×d f32 of dead mask in HBM at GPT-2 scale.
     tp_scale = None
-    if wcfg.model_axis is not None and not stream:
+    if wcfg.model_axis is not None and not leaf_groups:
         tp_scale = _flat_scale(wcfg.model_axis, cfg.tp_sliced, "tp_sliced")
     ep_scale = None
-    if wcfg.expert_axis is not None and not stream:
+    if wcfg.expert_axis is not None and not leaf_groups:
         # composes with every other axis, each on its own mesh dimension:
         # seq (token-partial grads, scale 1), model (orthogonal param
         # sets: each axis's scale mask marks the other's params
@@ -537,20 +520,20 @@ def build_round_step(
     ep_scale_res = layout.chunk(ep_scale) if (chunked and ep_scale is not None) \
         else ep_scale
 
-    # Streaming-path machinery: the leaf offset map of the flat layout,
-    # a model-boundary unravel that reads leaves straight out of the
-    # (T, S, 128) resident plane (no d-sized flatten — the last d-sized
-    # movement op of the composed client phase), and the per-leaf tp×ep
-    # rescale constants applied BEFORE sketching (the flat masks are
-    # per-leaf constants; the reorder past the psum is exact for
-    # power-of-two mesh axes — docs/stream_sketch.md).
-    stream_segs = stream_unravel = stream_scales = stream_groups = None
-    if stream:
-        stream_segs = _segs()
-        assert stream_segs[-1].offset + stream_segs[-1].size \
+    # Leaf-group machinery, built once, host-side: the leaf offset map of
+    # the flat layout, a model-boundary unravel that reads the leaves
+    # straight out of the (T, S, 128) resident plane (no d-sized flatten),
+    # the per-leaf tp×ep rescale constants (the flat masks are per-leaf
+    # constants; the reorder past the psum is exact for power-of-two mesh
+    # axes — docs/stream_sketch.md) and the group plan, one accumulate
+    # launch a group, sized from the sketch's geometry.
+    leaf_segs = leaf_unravel = leaf_scales = leaf_plan = None
+    if leaf_groups:
+        leaf_segs = _segs()
+        assert leaf_segs[-1].offset + leaf_segs[-1].size \
             == cfg.grad_size, "leaf layout does not cover the flat vector"
-        stream_unravel = chunked_unravel(layout, _template())
-        vals = [1.0] * len(stream_segs)
+        leaf_unravel = chunked_unravel(layout, _template())
+        vals = [1.0] * len(leaf_segs)
         if wcfg.model_axis is not None:
             tp_vals = _leaf_scale_vals(wcfg.model_axis, cfg.tp_sliced,
                                        "tp_sliced")
@@ -559,21 +542,11 @@ def build_round_step(
             ep_vals = _leaf_scale_vals(wcfg.expert_axis, cfg.ep_sliced,
                                        "ep_sliced")
             vals = [a * b for a, b in zip(vals, ep_vals)]
-        stream_scales = tuple(vals) if any(v != 1.0 for v in vals) else None
-        # Coalesced client-phase sketch (--sketch_coalesce,
-        # docs/stream_sketch.md): the group plan is computed ONCE per
-        # build, host-side, from the same leaf offset map the per-leaf
-        # path streams — the two paths share the layout by construction.
-        # Only meaningful inside the streaming window (it refines the
-        # leaf-streamed accumulate); the env kill-switch mirrors
-        # COMMEFFICIENT_STREAM_SKETCH's rollout pattern.
-        if (bool(cfg.sketch_coalesce)
-                and _os.environ.get("COMMEFFICIENT_SKETCH_COALESCE",
-                                    "1") != "0"):
-            budget = int(cfg.sketch_coalesce_budget) \
-                or coalesce_vmem_budget(sketch)
-            stream_groups = coalesce_segments(stream_segs, budget,
-                                              chunk_elems=sketch.c_pad)
+        leaf_scales = tuple(vals) if any(v != 1.0 for v in vals) else None
+        leaf_plan = coalesce_segments(
+            leaf_segs,
+            int(cfg.sketch_coalesce_budget) or coalesce_vmem_budget(sketch),
+            chunk_elems=sketch.c_pad)
 
     # Pipeline parallelism (parallel/pipeline.py): the loss callbacks carry
     # the GPipe schedule; the round only needs the one-gradient psum over
@@ -603,9 +576,24 @@ def build_round_step(
 
     def fused_clients(ps_weights, model_state, batch, rng_keys, worker_mask):
         """One-gradient client phase for a shard's W client slots. Returns
-        (local_dense_sum incl. weight decay and seq psum, stacked per-client
-        model_state, per-client metrics) — drop-in for the vmap path's
-        (Σ transmit, new_ms, metrics)."""
+        (the shard's transmit, stacked per-client model_state, per-client
+        metrics) — drop-in for the vmap path's (Σ transmit, new_ms,
+        metrics). The transmit is the dense gradient sum incl. weight decay
+        and the seq/model/pp/expert psums, or — on the leaf-group route
+        (docs/stream_sketch.md) — already the shard's (r, c_pad) table.
+
+        The leaf-group route differentiates by the parameter TREE, sliced
+        out of the resident plane once a round, so the backward pass's
+        transpose never writes a (d,) vector and the scan carries a tree
+        of float32 leaves; after the scan the leaves are rescaled, staged
+        in groups (the decay read from the plane's own rows there) and
+        sketched once at their flat offsets (worker.sketch_grad_tree).
+        The microbatches and the decay are added before the sketch, in the
+        flat route's own elementwise order, so on the clients axis alone
+        the table equals ``sketch_chunks`` of the flat route's sum under
+        ``==`` for any count of scan steps and any weight decay (zero
+        cells may differ in sign); seq/model/pp/expert psums ride the
+        small table (sketch linearity) and reorder float32 sums."""
         W = worker_mask.shape[0]
         B = batch["mask"].shape[1]
         mb, n_iters, pad = microbatch_plan(B, wcfg.microbatch_size)
@@ -614,16 +602,25 @@ def build_round_step(
         mstates0 = jax.tree_util.tree_map(
             lambda x: jnp.broadcast_to(x[None], (W,) + x.shape), model_state)
 
-        def step_loss(w_flat, mstates, micro, subs):
+        if leaf_groups:
+            # the ONE model boundary of the round: leaves sliced straight
+            # from the resident plane, every op smaller than d
+            with scope("fed_client_grad"):
+                wrt = leaf_unravel(ps_weights)
+            to_params = lambda p: p  # noqa: E731
+        else:
+            wrt, to_params = ps_weights, unravel_res
+
+        def step_loss(w, mstates, micro, subs):
             loss_sums, msums, counts, new_ms = clients_loss(
-                unravel_res(w_flat), mstates, micro, subs)
+                to_params(w), mstates, micro, subs)
             total = jnp.sum(loss_sums * worker_mask)
             return total, (loss_sums, msums, counts, new_ms)
 
         grad_fn = jax.value_and_grad(step_loss, has_aux=True)
 
         n_metrics = probe_n_metrics(
-            compute_loss_train, unravel_res(ps_weights), model_state,
+            compute_loss_train, to_params(wrt), model_state,
             jax.tree_util.tree_map(lambda x: x[0, 0], stacked))
 
         def body(carry, micro):
@@ -631,20 +628,53 @@ def build_round_step(
             # the per-client scan's rng protocol, one lane per client
             keys2, subs = jax.vmap(next_rng)(keys)
             (_, (loss_sums, msums, counts, new_ms)), g = grad_fn(
-                ps_weights, mstates, micro, subs)
+                wrt, mstates, micro, subs)
             m_acc = tuple(a + m for a, m in zip(m_acc, msums))
-            return (g_acc + g, loss_acc + loss_sums, m_acc, n_acc + counts,
+            # one array on the flat route, the tree's leaves otherwise
+            return (jax.tree_util.tree_map(jnp.add, g_acc, g),
+                    loss_acc + loss_sums, m_acc, n_acc + counts,
                     new_ms, keys2), None
 
-        init = (jnp.zeros_like(ps_weights), jnp.zeros(W),
-                tuple(jnp.zeros(W) for _ in range(n_metrics)), jnp.zeros(W),
-                mstates0, rng_keys)
         with scope("fed_client_grad"):
+            init = (jax.tree_util.tree_map(
+                        lambda x: jnp.zeros(x.shape, jnp.float32), wrt),
+                    jnp.zeros(W),
+                    tuple(jnp.zeros(W) for _ in range(n_metrics)),
+                    jnp.zeros(W), mstates0, rng_keys)
             (g_sum, loss_sums, m_sums, counts, new_ms, _), _ = jax.lax.scan(
                 body, init, stacked)
             denom = jnp.maximum(counts, 1.0)
             metrics = (loss_sums / denom,) \
                 + tuple(m / denom for m in m_sums) + (counts,)
+
+        def decay_coef():
+            """Per-client (wd/num_workers)·w scaled by the client's datum
+            count (worker.forward_grad + local_step ×count)."""
+            return (wcfg.weight_decay / wcfg.num_workers) * \
+                jnp.sum(worker_mask * counts)
+
+        if leaf_groups:
+            axes = [ax for ax in (wcfg.seq_axis, wcfg.model_axis,
+                                  wcfg.pp_axis, wcfg.expert_axis)
+                    if ax is not None]
+            with scope("fed_client_compress"):
+                decay = None
+                if wcfg.weight_decay != 0:
+                    coef = decay_coef()
+                    # the weights are replicated over the axes whose psums
+                    # ride the table below: one shard of each adds the
+                    # decay (g + 0·w is g on the others)
+                    for ax in axes:
+                        coef = jnp.where(jax.lax.axis_index(ax) == 0,
+                                         coef, 0.0)
+                    decay = (coef, ps_weights)
+                table = sketch_grad_tree(
+                    sketch, jnp.zeros(sketch.table_shape, jnp.float32),
+                    g_sum, leaf_segs, leaf_plan, scales=leaf_scales,
+                    decay=decay)
+                for ax in axes:
+                    table = jax.lax.psum(table, ax)
+            return table, new_ms, metrics
 
         with scope("fed_client_compress"):
             if wcfg.seq_axis is not None:
@@ -662,107 +692,8 @@ def build_round_step(
                 # (see worker.forward_grad)
                 g_sum = jax.lax.psum(g_sum, wcfg.expert_axis) * ep_scale_res
             if wcfg.weight_decay != 0:
-                # per-client (wd/num_workers)·w scaled by the client's
-                # datum count (worker.forward_grad + local_step ×count)
-                wd_scale = jnp.sum(worker_mask * counts)
-                g_sum = g_sum + (wcfg.weight_decay / wcfg.num_workers) * \
-                    wd_scale * ps_weights
+                g_sum = g_sum + decay_coef() * ps_weights
         return g_sum, new_ms, metrics
-
-    def fused_clients_stream(ps_weights, model_state, batch, rng_keys,
-                             worker_mask):
-        """Streaming client phase (--stream_sketch, docs/stream_sketch.md):
-        like ``fused_clients``, but the microbatch scan's carry holds the
-        shard's (r, c_pad) count-sketch TABLE instead of the d-sized
-        gradient accumulator. The backward pass differentiates w.r.t. the
-        parameter PYTREE (not the flat vector), so its transpose never
-        concatenates the d-vector; each leaf gradient is sketched at its
-        flat offset as soon as ``grad_fn`` returns (worker.sketch_grad_tree
-        — leaves in offset order continue the composed fold's per-cell add
-        order), the seq/model/pp/expert psums ride the small table (sketch
-        linearity), and weight decay folds in as one extra segment-sketch
-        of the resident chunked weights. Returns (local TABLE, stacked
-        per-client model_state, per-client metrics) — the table slots into
-        ``clients_shard`` where the composed path's
-        ``sketch_chunks(local_sum)`` result would.
-
-        Bit-compatibility with the composed path (pinned in
-        tests/test_stream_sketch.py): with a single microbatch, zero
-        weight decay, and client-axis-only parallelism the table — and
-        therefore the whole fp32 trajectory — matches ``fused_clients`` +
-        ``sketch_chunks`` up to the sign of all-zero cells. Multiple
-        microbatches, wd ≠ 0, or seq/model/pp/expert axes reorder f32
-        summation (documented in docs/stream_sketch.md), exactly the class
-        of deviation the sharded server plane already documents."""
-        W = worker_mask.shape[0]
-        B = batch["mask"].shape[1]
-        mb, n_iters, pad = microbatch_plan(B, wcfg.microbatch_size)
-        stacked = split_microbatches(batch, mb, n_iters, pad, example_dim=1)
-        mstates0 = jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(x[None], (W,) + x.shape), model_state)
-        # the ONE model boundary: leaves sliced straight from the resident
-        # chunk plane (ops/flat.chunked_unravel — every op < d-sized)
-        with scope("fed_client_grad"):
-            params = stream_unravel(ps_weights)
-
-        def step_loss(p, mstates, micro, subs):
-            loss_sums, msums, counts, new_ms = clients_loss(
-                p, mstates, micro, subs)
-            total = jnp.sum(loss_sums * worker_mask)
-            return total, (loss_sums, msums, counts, new_ms)
-
-        grad_fn = jax.value_and_grad(step_loss, has_aux=True)
-
-        n_metrics = probe_n_metrics(
-            compute_loss_train, params, model_state,
-            jax.tree_util.tree_map(lambda x: x[0, 0], stacked))
-
-        def body(carry, micro):
-            table, loss_acc, m_acc, n_acc, mstates, keys = carry
-            with scope("fed_client_grad"):
-                keys2, subs = jax.vmap(next_rng)(keys)
-                (_, (loss_sums, msums, counts, new_ms)), g_tree = grad_fn(
-                    params, mstates, micro, subs)
-                m_acc = tuple(a + m for a, m in zip(m_acc, msums))
-            # leaf gradients -> table, right where the backward made them
-            # (one accumulate per leaf, or per coalesced group when the
-            # --sketch_coalesce plan is set)
-            with scope("fed_client_compress"):
-                table = sketch_grad_tree(sketch, table, g_tree, stream_segs,
-                                         scales=stream_scales,
-                                         groups=stream_groups)
-            return (table, loss_acc + loss_sums, m_acc, n_acc + counts,
-                    new_ms, keys2), None
-
-        init = (jnp.zeros(sketch.table_shape, jnp.float32), jnp.zeros(W),
-                tuple(jnp.zeros(W) for _ in range(n_metrics)), jnp.zeros(W),
-                mstates0, rng_keys)
-        (table, loss_sums, m_sums, counts, new_ms, _), _ = jax.lax.scan(
-            body, init, stacked)
-
-        # the composed path's post-scan psums, riding the table: sketches
-        # are linear, so psum(sketch(g)) == sketch(psum(g)); the tp/ep
-        # rescales already happened per leaf above
-        with scope("fed_client_compress"):
-            for ax in (wcfg.seq_axis, wcfg.model_axis, wcfg.pp_axis,
-                       wcfg.expert_axis):
-                if ax is not None:
-                    table = jax.lax.psum(table, ax)
-            if wcfg.weight_decay != 0:
-                # (wd/num_workers)·Σ_i mask_i·count_i · w, as one extra
-                # full-range segment-sketch of the resident chunked
-                # weights — AFTER the axis psums (w is replicated across
-                # them, exactly like the composed path adds wd after its
-                # psums)
-                wd_scale = jnp.sum(worker_mask * counts)
-                coef = (wcfg.weight_decay / wcfg.num_workers) * wd_scale
-                table = sketch_chunks_accum(sketch, table, ps_weights * coef)
-
-        with scope("fed_client_grad"):
-            denom = jnp.maximum(counts, 1.0)
-            metrics = (loss_sums / denom,) \
-                + tuple(m / denom for m in m_sums) + (counts,)
-        return table, new_ms, metrics
 
     def one_client(ps_weights, vel_row, err_row, stale_row, model_state,
                    batch_row, lr, rng, slot_mask):
@@ -819,13 +750,9 @@ def build_round_step(
                       batch, lr, rng_keys, worker_mask):
         """Runs on one device over its W/n client slots; psums the transmit."""
         if fused_grad:
-            if stream:
-                # streaming path: local_sum IS already the shard's table
-                local_sum, new_ms, metrics = fused_clients_stream(
-                    ps_weights, model_state, batch, rng_keys, worker_mask)
-            else:
-                local_sum, new_ms, metrics = fused_clients(
-                    ps_weights, model_state, batch, rng_keys, worker_mask)
+            # on the leaf-group route local_sum IS already the shard's table
+            local_sum, new_ms, metrics = fused_clients(
+                ps_weights, model_state, batch, rng_keys, worker_mask)
             # no per-client state on any fused-eligible config: the inert
             # placeholder rows pass through untouched
             new_vel, new_err = vel_rows, err_rows
@@ -852,12 +779,12 @@ def build_round_step(
 
     def _reduce_transmit(local_sum):
         """The shard's transmit sum -> the round's (fed_client_compress)."""
-        if sketch_after_sum and not stream:
+        if sketch_after_sum and not leaf_groups:
             # one sketch of the shard's dense gradient sum (see fusion note
             # above); the psum then rides the small (r, c_pad) table exactly
             # as the per-client path would. The fused chunked gradient is
             # already in the kernel's (T, S, 128) layout — no pad/reshape.
-            # (The streaming path already produced the table.)
+            # (The leaf-group route already produced the table.)
             if chunked and fused_grad:
                 local_sum = sketch_chunks(sketch, local_sum)
             else:
@@ -1262,4 +1189,6 @@ def build_round_step(
         server_step=jax.jit(server_step, donate_argnums=server_donate),
         val_step=jax.jit(val_step),
         layout=layout,
+        client_sketch_path="leaf_groups" if leaf_groups else "flat",
+        client_sketch_launches=len(leaf_plan) if leaf_groups else 0,
     )

@@ -59,7 +59,6 @@ from commefficient_tpu.ops.clip import clip_by_l2
 from commefficient_tpu.ops.sketch import (
     CountSketch,
     l2estimate,
-    sketch_segment_accum,
     sketch_segments_accum,
     sketch_vec,
 )
@@ -182,31 +181,30 @@ def probe_n_metrics(compute_loss, params, model_state, example_batch) -> int:
     return len(probe[1])
 
 
-def sketch_grad_tree(sketch: CountSketch, table, grad_tree, segments,
-                     scales=None, groups=None, interpret: bool = False):
-    """Stream a gradient PYTREE into a running count-sketch table —
-    the streaming client phase's replacement for
-    ``sketch_vec(sketch, ravel(grad_tree))`` (docs/stream_sketch.md):
-    every leaf is accumulated at its global flat offset
-    (ops/flat.leaf_segments) right where the backward pass produced it, so
-    the concatenated d-vector is never materialized. Leaves stream in
-    offset order, so per table cell the f32 adds continue the composed
-    path's chunk-ordered fold — bit-identical up to the sign of all-zero
-    cells (ops/sketch.sketch_segment_accum). ``scales`` (optional, one
-    float per leaf) is the tp/ep grad-rescale value applied per leaf
-    BEFORE sketching — a per-leaf constant of the flat rescale masks, and
-    exact under the psum reorder for power-of-two mesh axes
-    (docs/stream_sketch.md). bf16 leaves are cast to f32 per element
-    (exact), matching the composed path's pad/convert.
+def sketch_grad_tree(sketch: CountSketch, table, grad_tree, segments, groups,
+                     scales=None, decay=None):
+    """Accumulate a gradient PYTREE into a running count-sketch table —
+    the sketch cells' replacement for ``sketch_vec(sketch,
+    ravel(grad_tree))`` (docs/stream_sketch.md): every leaf lands at its
+    global flat offset (ops/flat.leaf_segments), so the concatenated
+    d-vector is never materialized. ``groups`` (an
+    ``ops/flat.coalesce_segments`` plan partitioning the leaves) makes each
+    run of adjacent leaves ONE accumulate launch
+    (ops/sketch.sketch_segments_accum): one table row-block read + write
+    per group, staging per group. Groups run in offset order, so per table
+    cell the f32 adds continue the flat route's chunk-ordered fold —
+    equal under ``==``, up to the sign of all-zero cells.
 
-    ``groups`` (optional, an ``ops/flat.coalesce_segments`` plan
-    partitioning the leaves — --sketch_coalesce, docs/stream_sketch.md)
-    coalesces each group of adjacent leaves into ONE multi-segment
-    accumulate launch (ops/sketch.sketch_segments_accum): one table
-    row-block read + write per GROUP instead of per leaf, with the
-    per-leaf scales applied identically before the group concatenate —
-    the per-cell f32 add order replays the per-leaf fold (fewer boundary
-    ±0.0 terms is the one deviation, tests/test_sketch_coalesce.py)."""
+    Per leaf, before the group's staging: the cast to float32 (exact for
+    bf16 leaves, matching the flat route's pad/convert), then ``scales``
+    (optional, one float per leaf), the tp/ep grad-rescale value, a
+    per-leaf constant of the flat rescale masks and exact under the psum
+    reorder for power-of-two mesh axes. ``decay`` (optional, ``(coef,
+    plane)`` with the resident ``(T, S, 128)`` weights) is added in each
+    group's staging pass, ``g + coef · w`` read from the plane where it
+    lies (ops/sketch.sketch_segments_accum) — the flat route's ``g_sum +
+    coef · ps_weights`` element for element, so weight decay costs no
+    pass of its own and keeps no leaf of the weights alive."""
     leaves = jax.tree_util.tree_leaves(grad_tree)
     assert len(leaves) == len(segments), (len(leaves), len(segments))
     assert scales is None or len(scales) == len(segments)
@@ -219,18 +217,13 @@ def sketch_grad_tree(sketch: CountSketch, table, grad_tree, segments,
             x = x * jnp.float32(scales[i])
         return x
 
-    if groups is None:
-        for i, seg in enumerate(segments):
-            table = sketch_segment_accum(sketch, table, leaf_flat(i),
-                                         seg.offset, interpret=interpret)
-        return table
     assert groups[0].start == 0 and groups[-1].stop == len(segments) \
         and all(a.stop == b.start for a, b in zip(groups[:-1], groups[1:])), \
         "groups must partition the leaf segments in order"
     for grp in groups:
         table = sketch_segments_accum(
             sketch, table, [leaf_flat(i) for i in range(grp.start, grp.stop)],
-            grp.offset, interpret=interpret)
+            grp.offset, decay=decay)
     return table
 
 

@@ -54,7 +54,7 @@ class LeafSegment(NamedTuple):
 def leaf_segments(tree: Any) -> Tuple[LeafSegment, ...]:
     """Per-leaf ``(path, offset, size)`` of ``ravel_pytree``'s flat layout:
     leaves in ``tree_flatten`` order, each raveled C-order, offsets the
-    running cumulative size — THE offset map the streaming client phase
+    running cumulative size — THE offset map the sketch cells' client phase
     (docs/stream_sketch.md) uses to sketch each gradient leaf at its global
     coordinate base instead of materializing the concatenated d-vector,
     and the one the tp/ep flat grad-rescale masks are built from
@@ -74,8 +74,7 @@ def leaf_segments(tree: Any) -> Tuple[LeafSegment, ...]:
 
 class SegmentGroup(NamedTuple):
     """A contiguous run of ``leaf_segments`` leaves coalesced into ONE
-    multi-segment sketch-accumulate launch (--sketch_coalesce,
-    docs/stream_sketch.md). Because ``leaf_segments`` offsets are the
+    sketch-accumulate launch (docs/stream_sketch.md). Because ``leaf_segments`` offsets are the
     running cumulative size, the run covers one contiguous flat span
     ``[offset, offset + size)`` whose covering chunk range is
     ``[t_a, t_b)`` — the range the kernel keeps the table row block
@@ -93,10 +92,10 @@ def coalesce_segments(segs: Sequence[LeafSegment], vmem_budget: int, *,
                       chunk_elems: int) -> Tuple[SegmentGroup, ...]:
     """Greedy in-order grouping of adjacent ``leaf_segments`` leaves into
     covering chunk-range groups under a static byte budget — the planner
-    of the coalesced client-phase sketch (docs/stream_sketch.md). A group
-    is extended while its covering chunk range ``[t_a, t_b)`` stays within
+    of the client phase's sketch (docs/stream_sketch.md). A group is
+    extended while its covering chunk range ``[t_a, t_b)`` stays within
     ``vmem_budget`` bytes of f32 chunks (``chunk_elems`` = the sketch's
-    ``c_pad``); the multi-segment kernel then pays ONE table row-block
+    ``c_pad``); the accumulate kernel then pays ONE table row-block
     read + write per group instead of per leaf.
 
     Rules (pinned in tests/test_sketch_coalesce.py):
@@ -107,11 +106,10 @@ def coalesce_segments(segs: Sequence[LeafSegment], vmem_budget: int, *,
       ride whichever group is current (their covering range is empty);
     - a single leaf whose covering range alone exceeds the budget cannot
       be split (splitting would only ADD launches): it forms its own
-      group — one launch, exactly the per-leaf path for that leaf, and
-      already optimal (a GPT-2-scale embedding leaf under the auto
+      group — one launch for that leaf, already optimal (a GPT-2-scale embedding leaf under the auto
       budget is the normal case, so an oversized leaf alone is silent);
     - when the budget is smaller than EVERY adjacency — no multi-leaf
-      group forms at all and the plan degenerates to the per-leaf path
+      group forms at all and the plan degenerates to a launch per leaf
       (e.g. a budget below one chunk) — ONE warning per plan says so.
 
     Host-side and deterministic; called once per round-step build, never
@@ -167,7 +165,7 @@ def coalesce_segments(segs: Sequence[LeafSegment], vmem_budget: int, *,
     if n_nonzero > 1 and not multi:
         # there WAS something to coalesce (>= 2 nonzero leaves) and the
         # plan coalesced nothing — every adjacency (and possibly every
-        # single leaf) exceeds the budget, so --sketch_coalesce buys
+        # single leaf) exceeds the budget, so grouping buys
         # zero benefit: the degenerate misconfiguration worth one
         # warning. (An oversized leaf INSIDE an otherwise-coalesced plan
         # is normal — GPT-2's embedding under the auto budget — and its
@@ -193,9 +191,8 @@ def chunked_unravel(layout: "ChunkLayout",
     (a pure slice), flattens that block (≤ leaf size + 2 chunks), and
     reshapes to the leaf shape. Bitwise the same values as
     ``unravel(layout.unchunk(c3))`` for the matching ``ravel_pytree``
-    layout — the streaming client phase's model boundary
-    (docs/stream_sketch.md), where the composed path's single
-    padded-size reshape is the last d-sized movement op standing.
+    layout — the leaf-group client phase's model boundary
+    (docs/stream_sketch.md), run once a round.
     ``template`` may be real arrays or ``jax.eval_shape`` structs."""
     segs = leaf_segments(template)
     flat_leaves, treedef = jax.tree_util.tree_flatten(template)

@@ -367,7 +367,7 @@ def _use_pallas_sketch() -> bool:
 
 def _sketch_interpret_forced() -> bool:
     """COMMEFFICIENT_PALLAS_SKETCH=interpret forces the running-table
-    accumulate kernels through the Pallas interpreter even off-TPU — the
+    accumulate kernel through the Pallas interpreter even off-TPU — the
     CPU-mesh test hook (mirroring COMMEFFICIENT_FUSED_EPILOGUE=interpret)
     that lets the structural launch-count asserts of
     tests/test_sketch_coalesce.py see real ``pallas_call`` eqns in the
@@ -458,28 +458,13 @@ def _check_segment(cs: CountSketch):
             min(cs.d, cs.c_pad + 50_011))
 
 
-def check_sketch_accum_kernel(cs: CountSketch,
-                              interpret: bool = False) -> None:
-    """``_sketch_accum_pallas`` (--stream_sketch, docs/stream_sketch.md):
-    the running-table kernel must bit-continue the pure fold at an
-    unaligned element offset spanning a chunk boundary."""
-    v, tbl0, a, b = _check_segment(cs)
-    seg3, t_a = _segment_chunks(cs, v[a:b], a)
-    got = _sketch_accum_pallas(
-        tbl0.reshape(cs.r, cs.sublanes, _LANES), seg3,
-        cs.shift_q[:, t_a:t_a + seg3.shape[0]],
-        cs.shift_w[:, t_a:t_a + seg3.shape[0]], cs.sign_keys,
-        np.full(1, t_a, np.int32), S=cs.sublanes, T=seg3.shape[0],
-        interpret=interpret).reshape(cs.r, cs.c_pad)
-    require_equal(got, _sketch_accum_chunks_jax(cs, tbl0, seg3, t_a),
-                  "sketch_accum")
-
-
 def check_sketch_segments_kernel(cs: CountSketch,
                                  interpret: bool = False) -> None:
-    """``_sketch_segments_pallas`` (--sketch_coalesce): ONE launch over a
-    group of contiguous segments == the same span's single-segment fold
-    (through the dispatcher, which assembles the group)."""
+    """``_sketch_segments_pallas`` (docs/stream_sketch.md): ONE launch over
+    a group of contiguous segments, at an unaligned element offset spanning
+    a chunk boundary, must bit-continue the pure fold of the same span
+    onto a running table (through the dispatcher, which assembles the
+    group)."""
     v, tbl0, a, b = _check_segment(cs)
     cuts = (a, a + 11_003, a + 11_004, b)
     got = sketch_segments_accum(
@@ -515,8 +500,8 @@ _SKETCH_KERNEL_CHECKED = False
 
 
 def _check_sketch_kernel_once(eager: bool = False) -> None:
-    """One-time on-TPU self-check of the accumulate kernels (zero-init,
-    running-table, multi-segment), mirroring
+    """One-time on-TPU self-check of the accumulate kernels (zero-init and
+    running-table), mirroring
     ``_check_estimates_kernel_once``. Primary trigger is ``make_sketch``;
     the accumulate entry points also trigger it when called eagerly,
     covering CountSketch objects that bypassed ``make_sketch`` (e.g.
@@ -531,7 +516,6 @@ def _check_sketch_kernel_once(eager: bool = False) -> None:
     _SKETCH_KERNEL_CHECKED = True
     cs = _check_geometry()
     check_sketch_vec_kernel(cs)
-    check_sketch_accum_kernel(cs)
     check_sketch_segments_kernel(cs)
 
 
@@ -570,13 +554,21 @@ def sketch_chunks(cs: CountSketch, v3: jax.Array) -> jax.Array:
     return _sketch_chunks_jax(cs, v3)
 
 
-def _accum_pallas_call(tbl3, v3, shift_q, shift_w, sign_keys, t0, S, T,
-                       interpret):
-    """Shared lowering of the RUNNING-TABLE accumulate kernels
-    (``_sketch_accum_pallas`` / ``_sketch_segments_pallas`` — one body so
-    the per-leaf and coalesced client phases cannot drift bit-wise; on
-    the device both are the kernel ``fed_sketch_accum``, and the two jit
-    wrappers keep each path's own name in the operation's scope path)."""
+@functools.partial(jax.jit, static_argnames=("S", "T", "interpret"))
+def _sketch_segments_pallas(tbl3, v3, shift_q, shift_w, sign_keys, t0, *, S,
+                            T, interpret=False):
+    """``_sketch_vec_pallas`` with a RUNNING-TABLE init (on the device the
+    kernel ``fed_sketch_accum``; docs/stream_sketch.md): the output row
+    starts from ``tbl3``'s row instead of zeros, then accumulates the T
+    chunks exactly like the zero-init kernel. Per (row, cell) the f32 adds
+    are ``tbl + c_0 + c_1 + ...`` in chunk order — bit-continuing the pure
+    scan's left fold, which is what lets the client phase sketch a gradient
+    group by group and still match the flat ``sketch_chunks`` route's
+    per-cell add order. ``v3`` holds a whole group's covering chunk range
+    (many leaves, one launch), so the table row block is read and written
+    once per group. ``t0`` is the chunks' global index offset as in
+    ``_sketch_vec_pallas`` (shift arrays arrive pre-sliced to the local
+    chunk range)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -615,44 +607,13 @@ def _accum_pallas_call(tbl3, v3, shift_q, shift_w, sign_keys, t0, S, T,
         out_specs=pl.BlockSpec((1, S, _LANES), lambda row, t, *_: (row, 0, 0)),
         scratch_shapes=[pltpu.VMEM((2 * S, _LANES), jnp.float32)],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, S, _LANES), jnp.float32),
         interpret=interpret,
         name="fed_sketch_accum",
     )(shift_q, shift_w, sign_keys, t0, tbl3, v3)
-    return out
-
-
-@functools.partial(jax.jit, static_argnames=("S", "T", "interpret"))
-def _sketch_accum_pallas(tbl3, v3, shift_q, shift_w, sign_keys, t0, *, S, T,
-                         interpret=False):
-    """``_sketch_vec_pallas`` with a RUNNING-TABLE init: the output row
-    starts from ``tbl3``'s row instead of zeros, then accumulates the T
-    chunks exactly like the zero-init kernel. Per (row, cell) the f32 adds
-    are ``tbl + c_0 + c_1 + ...`` in chunk order — bit-continuing the pure
-    scan's left fold, which is what lets the streaming client phase
-    (docs/stream_sketch.md) sketch a gradient leaf-by-leaf and still match
-    the composed ravel-then-``sketch_vec`` path's per-cell add order.
-    ``t0`` is the chunks' global index offset as in ``_sketch_vec_pallas``
-    (shift arrays arrive pre-sliced to the local chunk range)."""
-    return _accum_pallas_call(tbl3, v3, shift_q, shift_w, sign_keys, t0,
-                              S, T, interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("S", "T", "interpret"))
-def _sketch_segments_pallas(tbl3, v3, shift_q, shift_w, sign_keys, t0, *, S,
-                            T, interpret=False):
-    """The multi-segment (coalesced-group) accumulate kernel
-    (--sketch_coalesce, docs/stream_sketch.md): bit-for-bit the SAME
-    lowering as ``_sketch_accum_pallas`` (shared ``_accum_pallas_call``),
-    under its own jit name so client-phase launch counts are attributable
-    per path in traces — ``v3`` here holds a whole GROUP's covering chunk
-    range (many leaves, one launch), so the table row block is read and
-    written once per group instead of once per leaf."""
-    return _accum_pallas_call(tbl3, v3, shift_q, shift_w, sign_keys, t0,
-                              S, T, interpret)
 
 
 def _sketch_accum_chunks_jax(cs: CountSketch, table: jax.Array,
@@ -683,10 +644,10 @@ def _segment_chunks(cs: CountSketch, seg: jax.Array, e0: int):
     """STATIC-offset segment prep: zero-pad the 1-D segment out to the
     chunk boundaries it touches and reshape to the ``(Tn, S, 128)`` chunk
     layout of chunks ``[t_a, t_a + Tn)``. Pads are segment-sized (+ < 2
-    chunks), never d-sized — the point of the streaming path. Zero-padded
-    positions contribute sign·0 = ±0.0 to their cells, the one documented
-    deviation from the composed path (cells whose every contribution is a
-    signed zero can differ in the SIGN of their zero; never in ``==``)."""
+    chunks), never d-sized. Zero-padded positions contribute sign·0 =
+    ±0.0 to their cells, the one deviation from the flat route (cells
+    whose every contribution is a signed zero can differ in the SIGN of
+    their zero; never in ``==``)."""
     n = int(seg.size)
     ce = cs.c_pad
     t_a = e0 // ce
@@ -697,42 +658,6 @@ def _segment_chunks(cs: CountSketch, seg: jax.Array, e0: int):
     return v.reshape(Tn, cs.sublanes, _LANES), t_a
 
 
-def sketch_segment_accum(cs: CountSketch, table: jax.Array, seg: jax.Array,
-                         e0: int, interpret: bool = False) -> jax.Array:
-    """Accumulate a contiguous flat-coordinate segment — ``seg`` holds
-    coordinates ``[e0, e0 + seg.size)`` of the conceptual d-vector — into
-    a RUNNING ``(r, c_pad)`` table. ``e0`` is a STATIC int (leaf offsets
-    of a pytree layout are trace-time constants, ops/flat.leaf_segments),
-    which is what generalizes the sharded-server ``t0`` chunk offset down
-    to element granularity: the segment is padded to its covering chunk
-    range (small, static pads) and the chunk-offset kernels do the rest.
-
-    Streaming a d-vector through consecutive segments in offset order is
-    bit-identical to ``sketch_vec`` of the whole vector up to the sign of
-    all-zero cells (see ``_segment_chunks``): per cell exactly one
-    coordinate per chunk contributes, the fold visits chunks in the same
-    order, and boundary chunks only add extra ±0.0 terms."""
-    e0 = int(e0)
-    n = int(seg.size)
-    assert 0 <= e0 and e0 + n <= cs.d, (e0, n, cs.d)
-    assert table.shape == cs.table_shape, (table.shape, cs.table_shape)
-    if n == 0:
-        return table
-    v3, t_a = _segment_chunks(cs, seg, e0)
-    if _trace_state_clean():
-        _check_sketch_kernel_once(eager=True)
-    interpret = interpret or _sketch_interpret_forced()
-    if _use_pallas_sketch() or interpret:
-        out = _sketch_accum_pallas(
-            table.reshape(cs.r, cs.sublanes, _LANES), v3,
-            cs.shift_q[:, t_a:t_a + v3.shape[0]],
-            cs.shift_w[:, t_a:t_a + v3.shape[0]], cs.sign_keys,
-            np.full(1, t_a, np.int32), S=cs.sublanes, T=v3.shape[0],
-            interpret=interpret)
-        return out.reshape(cs.r, cs.c_pad)
-    return _sketch_accum_chunks_jax(cs, table, v3, t_a)
-
-
 # staging ceiling for the segment coalescer's auto budget: far above any
 # single covering chunk range worth coalescing, far below the d-plane
 _COALESCE_MAX_BUDGET = 32 * 1024 * 1024
@@ -740,16 +665,15 @@ _COALESCE_MAX_BUDGET = 32 * 1024 * 1024
 
 def coalesce_vmem_budget(cs: CountSketch) -> int:
     """Auto group-sizing budget (bytes) for ``ops/flat.coalesce_segments``
-    (--sketch_coalesce, docs/stream_sketch.md). The multi-segment kernel
-    streams a group's chunks through VMEM one ``(S, 128)`` block at a time
-    while the table row block stays resident, so its per-step VMEM is
-    group-size-INDEPENDENT; what the budget actually bounds is the group's
-    covering chunk-range STAGING buffer — the trace-time concatenate+pad
-    of the group's leaves — which must stay well under d or the
-    O(d)→O(table) memory story --stream_sketch exists for quietly erodes
-    through the coalescer. ``min(32 MiB, max(one chunk, padded/4))``:
-    GPT-2 124M (c_pad≈500k, T=249) gets 32 MiB ≈ 16-chunk groups — ~150
-    per-leaf launches collapse to ~16 — while the CIFAR FetchSGD geometry
+    (docs/stream_sketch.md). The accumulate kernel streams a group's
+    chunks through VMEM one ``(S, 128)`` block at a time while the table
+    row block stays resident, so its per-step VMEM is
+    group-size-INDEPENDENT; what the budget bounds is the group's covering
+    chunk-range STAGING buffer — the concatenate+pad of the group's
+    leaves — which must stay well under d, or the client phase holds a
+    second copy of the gradient after all. ``min(32 MiB, max(one chunk,
+    padded/4))``: GPT-2 124M (c_pad≈500k, T=249) gets 32 MiB ≈ 16-chunk
+    groups, ~150 leaves in ~16 launches, while the CIFAR FetchSGD geometry
     (T=14) gets ~7 MiB ≈ 3-chunk groups, and no geometry ever stages more
     than max(one chunk, a quarter of its padded plane) — the one-chunk
     floor means a T<4 geometry can stage up to its whole (tiny) plane,
@@ -760,27 +684,39 @@ def coalesce_vmem_budget(cs: CountSketch) -> int:
 
 
 def sketch_segments_accum(cs: CountSketch, table: jax.Array, segs,
-                          e0: int, interpret: bool = False) -> jax.Array:
+                          e0: int, decay=None,
+                          interpret: bool = False) -> jax.Array:
     """ONE kernel launch for a GROUP of contiguous flat segments
-    (--sketch_coalesce, docs/stream_sketch.md): ``segs`` is a sequence of
-    1-D arrays where segment ``i`` starts exactly where ``i-1`` ends and
-    the first starts at STATIC flat offset ``e0`` (adjacent leaves of the
-    ``ops/flat.leaf_segments`` layout are contiguous by construction —
-    ``ops/flat.coalesce_segments`` plans the groups). The group's covering
-    chunk-range buffer is assembled at trace time (concatenate + the same
-    chunk-boundary pads ``_segment_chunks`` makes — group-sized, never
-    d-sized) and handed to the multi-segment kernel, which keeps each
-    table row block VMEM-resident across EVERY chunk of the group: one
-    table read + one table write per group instead of per leaf.
+    (docs/stream_sketch.md), accumulated onto a RUNNING ``(r, c_pad)``
+    table: ``segs`` is a sequence of 1-D arrays where segment ``i`` starts
+    exactly where ``i-1`` ends and the first starts at STATIC flat offset
+    ``e0`` (leaf offsets of a pytree layout are trace-time constants, and
+    adjacent leaves of the ``ops/flat.leaf_segments`` layout are
+    contiguous by construction — ``ops/flat.coalesce_segments`` plans the
+    groups). The group's covering chunk-range buffer is assembled at trace
+    time (concatenate + the chunk-boundary pads of ``_segment_chunks`` —
+    group-sized, never d-sized) and handed to the running-table kernel,
+    which keeps each table row block VMEM-resident across EVERY chunk of
+    the group: one table read + one table write per group.
 
-    Bit-compatibility (pinned in tests/test_sketch_coalesce.py): per table
-    cell and chunk exactly one coordinate contributes and the fold visits
-    chunks in the same order as folding ``sketch_segment_accum`` over the
-    segments one by one, so the per-cell f32 add order replays the
-    per-leaf streaming fold — the only deviation is FEWER boundary-chunk
-    ``±0.0`` terms (per-leaf processes a straddled chunk once per leaf,
-    coalesced once per group), i.e. the sign of all-zero cells; never a
-    value under ``==``. Zero-size segments are skipped."""
+    Bit-compatibility (pinned in tests/test_sketch_coalesce.py and
+    tests/test_stream_sketch.py): per table cell and chunk exactly one
+    coordinate contributes and consecutive groups visit the chunks in the
+    order ``sketch_vec`` does, so sketching a d-vector group by group in
+    offset order equals ``sketch_vec`` of the whole vector — the only
+    deviation is the boundary chunks' extra ``±0.0`` terms (a chunk two
+    groups straddle is visited once by each), i.e. the sign of all-zero
+    cells; never a value under ``==``. Zero-size segments are skipped.
+
+    ``decay`` (optional, ``(coef, plane)``) adds ``coef · plane`` over the
+    group's coordinates, ``plane`` being a ``(T, S, 128)`` resident vector
+    (the weights): the covering chunk range of the plane IS the staging
+    buffer's layout, so weight decay is one multiply-add inside the
+    staging pass (``g + coef · w``, the flat route's arithmetic element for
+    element) and reads the plane where it lies: no leaf of the weights is
+    sliced out or kept alive for it. Positions of the boundary chunks
+    outside ``[e0, e0 + n)`` belong to the neighbouring groups and are
+    masked to zero."""
     e0 = int(e0)
     xs = [s.reshape(-1).astype(jnp.float32) for s in segs if int(s.size)]
     n = sum(int(x.size) for x in xs)
@@ -790,6 +726,16 @@ def sketch_segments_accum(cs: CountSketch, table: jax.Array, segs,
     assert 0 <= e0 and e0 + n <= cs.d, (e0, n, cs.d)
     v = xs[0] if len(xs) == 1 else jnp.concatenate(xs)
     v3, t_a = _segment_chunks(cs, v, e0)
+    if decay is not None:
+        coef, plane = decay
+        assert plane.shape == (cs.T, cs.sublanes, _LANES), plane.shape
+        w3 = plane[t_a:t_a + v3.shape[0]]
+        lo = e0 - t_a * cs.c_pad
+        if lo or lo + n != v3.size:
+            pos = sum(jax.lax.broadcasted_iota(jnp.int32, v3.shape, ax) * m
+                      for ax, m in enumerate((cs.c_pad, _LANES, 1)))
+            w3 = jnp.where((pos >= lo) & (pos < lo + n), w3, 0.0)
+        v3 = v3 + coef * w3
     if _trace_state_clean():
         _check_sketch_kernel_once(eager=True)
     interpret = interpret or _sketch_interpret_forced()
@@ -802,27 +748,6 @@ def sketch_segments_accum(cs: CountSketch, table: jax.Array, segs,
             interpret=interpret)
         return out.reshape(cs.r, cs.c_pad)
     return _sketch_accum_chunks_jax(cs, table, v3, t_a)
-
-
-def sketch_chunks_accum(cs: CountSketch, table: jax.Array, v3: jax.Array,
-                        interpret: bool = False) -> jax.Array:
-    """Full-range running-table accumulate: ``table`` plus the sketch of a
-    vector already in the ``(T, S, 128)`` resident chunk layout, with the
-    per-cell adds bit-continuing the incoming table's fold (the streaming
-    client phase's weight-decay term rides this — one extra segment-sketch
-    of the resident chunked weights, docs/stream_sketch.md)."""
-    assert v3.shape == (cs.T, cs.sublanes, _LANES), v3.shape
-    assert table.shape == cs.table_shape, (table.shape, cs.table_shape)
-    if _trace_state_clean():
-        _check_sketch_kernel_once(eager=True)
-    interpret = interpret or _sketch_interpret_forced()
-    if _use_pallas_sketch() or interpret:
-        out = _sketch_accum_pallas(
-            table.reshape(cs.r, cs.sublanes, _LANES), v3, cs.shift_q,
-            cs.shift_w, cs.sign_keys, _T0, S=cs.sublanes, T=cs.T,
-            interpret=interpret)
-        return out.reshape(cs.r, cs.c_pad)
-    return _sketch_accum_chunks_jax(cs, table, v3, 0)
 
 
 def sketch_chunks_local(cs: CountSketch, v3: jax.Array, t0,

@@ -1026,6 +1026,13 @@ def attach_run_telemetry(args, fed_model, log_dir: str,
             cs.chunk_layout.padded_size if cs is not None
             else fed_model.grad_size,
             args.k, sharded=bool(fed_model._n_shard))
+    if args.mode == "sketch":
+        # the gradient's route to the table in the client phase (static
+        # for a run: rounds.build_round_step has the rule) and the
+        # accumulate launches of its group plan (docs/stream_sketch.md)
+        run_info["client_sketch_path"] = fed_model.steps.client_sketch_path
+        run_info["client_sketch_launches"] = \
+            fed_model.steps.client_sketch_launches
     # Participation-layer config (--participation / --inject_client_fault,
     # federated/participation.py): recorded in the run header so a logged
     # run is reproducible from the log alone — the fault schedule is

@@ -339,16 +339,6 @@ def main(argv=None):
         "models have no expert axis — use gpt2_train.py")
     if args.lr_scale is None:
         args.lr_scale = 0.4  # cifar10-fast default peak LR
-    if args.stream_sketch:
-        print("stream-sketch client phase requested: gradients stream "
-              "leaf-by-leaf into the count-sketch table "
-              "(docs/stream_sketch.md; COMMEFFICIENT_STREAM_SKETCH=0 "
-              "restores the composed path)")
-    if args.sketch_coalesce:
-        print("sketch-coalesce requested: adjacent gradient leaves batch "
-              "into one accumulate launch per chunk-range group "
-              "(docs/stream_sketch.md; COMMEFFICIENT_SKETCH_COALESCE=0 "
-              "restores the per-leaf streaming path)")
     print(args)
     timer = Timer()
     np.random.seed(args.seed)

@@ -329,23 +329,6 @@ def train(argv=None):
     announce_devices()
     if not args.dataset_name:
         args.dataset_name = "PERSONA"
-    if args.stream_sketch:
-        # the GPT-2 client phase is where the streaming sketch pays off:
-        # the d=124M flat-gradient concat/pad/convert churn was 22.6% of
-        # device busy time (v5e profile of 2026-08-01, capture deleted)
-        print("stream-sketch client phase requested: gradients stream "
-              "leaf-by-leaf into the count-sketch table "
-              "(docs/stream_sketch.md; COMMEFFICIENT_STREAM_SKETCH=0 "
-              "restores the composed path)")
-    if args.sketch_coalesce:
-        # the ~150 per-leaf accumulate launches of the GPT-2 streaming
-        # client phase re-read the table row block per leaf (~3 GB/round
-        # of table churn, docs/stream_sketch.md honest ledger) — the
-        # coalesced plan is where that churn drops to per-group
-        print("sketch-coalesce requested: adjacent gradient leaves batch "
-              "into one accumulate launch per chunk-range group "
-              "(docs/stream_sketch.md; COMMEFFICIENT_SKETCH_COALESCE=0 "
-              "restores the per-leaf streaming path)")
     print(args)
     timer = Timer()
 

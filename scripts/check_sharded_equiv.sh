@@ -10,15 +10,16 @@
 #   - the fused server epilogue's bit-identity to the composed path on
 #     both planes (tests/test_fused_epilogue.py, docs/fused_epilogue.md —
 #     megakernel through the Pallas interpreter);
-#   - the streaming client-phase sketch's bit-identity to the composed
-#     ravel+sketch path, replicated/--server_shard × composed/
-#     --fused_epilogue, plus the no-d-sized-movement and table-sized-carry
-#     structural asserts (tests/test_stream_sketch.py,
+#   - the sketch cells' client phase (the leaves sketched in groups at
+#     their flat offsets): its table == the flat ravel+sketch route's for
+#     1, 2 and 4 scan steps x weight decay, replicated/--server_shard x
+#     composed/--fused_epilogue trajectories, plus the no-d-sized-movement
+#     and tree-shaped-carry structural asserts (tests/test_stream_sketch.py,
 #     docs/stream_sketch.md);
-#   - the coalesced client-phase megakernel's bit-identity to the
-#     per-leaf streaming path across the same matrix, the coalescer's
-#     planner contracts, and the launch-count == group-count structural
-#     assert (tests/test_sketch_coalesce.py, docs/stream_sketch.md);
+#   - the group plan: the planner's contracts, bit-identity under any
+#     plan across the same matrix, and the launch-count == group-count,
+#     once a round, structural assert (tests/test_sketch_coalesce.py,
+#     docs/stream_sketch.md);
 #   - the telemetry plane's non-perturbation (fp32 bit-identity with
 #     --telemetry on/off on BOTH planes) and its strict zero-host-sync
 #     audit with guards+telemetry through the engine

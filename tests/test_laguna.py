@@ -749,11 +749,12 @@ def joyai_client_step_text():
     return seen[0]
 
 
-# sha256 of that text at the parent of PR 32 (commit 5013640), where
-# models/joyai.py's Block took ``dense`` and built MLA and RoutedMoE itself.
-# A PR that changes JoyAI's round on purpose pins its own.
+# sha256 of that text. A PR that changes JoyAI's round on purpose pins its
+# own: PR 35 did (sketch mode's client phase differentiates by the parameter
+# tree and sketches the leaves in groups, docs/stream_sketch.md); before it
+# the digest was 523235a1..., unchanged since the parent of PR 32.
 JOYAI_CLIENT_STEP = \
-    "523235a1422cf3f63d2c42c341100df15b46a9fad17034cd8b4ff0768b97fa97"
+    "d4b13deb774b2060e4cfdec32f12f8889905e4a1ef092f48a04a8689ca700955"
 
 
 def test_joyai_client_step_is_byte_equal_to_the_parents():

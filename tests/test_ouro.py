@@ -673,15 +673,69 @@ def laguna_client_step_text():
     return seen[0]
 
 
-# sha256 of the lowered ``client_step`` (StableHLO, no locations) at the
-# parent of PR 34 (commit 2abe947), where losses.make_causal_lm_losses held
-# the routed decoders' loss itself and the grouped-query core took a gate
-# always. A PR that changes a round on purpose pins its own.
+def gpt2_uncompressed_client_step_text():
+    """The lowered ``client_step`` (StableHLO, no locations) of GPT-2 at
+    test size under ``--mode uncompressed``, as FedModel dispatches it:
+    ``gpt2_uncompressed_1c``'s program, which bypasses the sketch."""
+    from commefficient_tpu.config import parse_args
+    from commefficient_tpu.federated import FedModel
+    from commefficient_tpu.federated.losses import make_gpt2_losses
+    from commefficient_tpu.models.gpt2 import GPT2DoubleHeads
+    from commefficient_tpu.parallel.mesh import default_client_mesh
+
+    W, B, C, T = 2, 2, 2, 16
+    model = GPT2DoubleHeads(vocab_size=64, n_positions=T, n_embd=16,
+                            n_layer=2, n_head=2)
+    args = parse_args(default_lr=4e-2, argv=[
+        "--dataset_name", "PERSONA", "--mode", "uncompressed",
+        "--error_type", "none", "--num_workers", str(W), "--num_devices",
+        "1", "--local_batch_size", str(B), "--local_momentum", "0",
+        "--virtual_momentum", "0.9", "--seed", "21"])
+    train, val = make_gpt2_losses(model, args.lm_coef, args.mc_coef)
+    ids0 = jnp.zeros((1, C, T), jnp.int32)
+    params = jax.jit(lambda k: model.init(
+        k, ids0, token_type_ids=ids0,
+        mc_token_ids=jnp.zeros((1, C), jnp.int32), train=False))(
+            jax.random.key(0))["params"]
+    fm = FedModel(model, train, args, val, num_clients=8, init_params=params,
+                  mesh=default_client_mesh(W, 1))
+    r = np.random.RandomState(30)
+    batch = {
+        "input_ids": r.randint(0, 64, (W, B, C, T)).astype(np.int32),
+        "token_type_ids": r.randint(0, 64, (W, B, C, T)).astype(np.int32),
+        "lm_labels": r.randint(0, 64, (W, B, C, T)).astype(np.int32),
+        "mc_token_ids": r.randint(0, T, (W, B, C)).astype(np.int32),
+        "mc_labels": r.randint(0, C, (W, B)).astype(np.int32),
+        "mask": np.ones((W, B), np.float32),
+        "client_ids": np.arange(W, dtype=np.int32),
+        "worker_mask": np.ones(W, np.float32),
+    }
+    seen = []
+    real = fm.steps.client_step
+    fm.steps = fm.steps._replace(
+        client_step=lambda *a: (seen.append(real.lower(*a).as_text()),
+                                real(*a))[1])
+    fm.finish_round(fm.begin_round(batch))
+    fm.finalize()
+    return seen[0]
+
+
+# sha256 of the lowered ``client_step`` (StableHLO, no locations). A PR that
+# changes a round on purpose pins its own: PR 35 re-pinned the two routed
+# decoders (sketch mode's client phase differentiates by the parameter tree
+# and sketches the leaves in groups, docs/stream_sketch.md) and added the
+# pin that must NOT move with such a PR: GPT-2 under ``--mode uncompressed``
+# keeps the flat route, and its digest was taken at PR 35's parent (commit
+# 9c48bab), by this function in this module (whose imports configure jax:
+# alone in a file the same program's text hashes to 2997d8c0... on both).
 CLIENT_STEPS = {
     "joyai_llm_flash": (joyai_client_step_text, JOYAI_CLIENT_STEP),
     "laguna_xs2": (
         laguna_client_step_text,
-        "b4309b3423ff01dbbf7eb15bc720bd0cee6ad21683cab89ca39a5fea456ddce1"),
+        "b31cbc2d724346b9efe10a9550ac3692a987215d5223064e3dd53bbdbbf3a360"),
+    "gpt2_uncompressed": (
+        gpt2_uncompressed_client_step_text,
+        "d79bb4f6b0448f417fb94a0c3c3cae56fc5b282c084f53431091e13ac0f1df36"),
 }
 
 

@@ -1,5 +1,4 @@
-"""Coalesced client-phase sketch megakernel (--sketch_coalesce,
-docs/stream_sketch.md).
+"""The group plan of the client phase's sketch (docs/stream_sketch.md).
 
 Contracts pinned on the forced-8-device CPU mesh:
 
@@ -7,26 +6,23 @@ Contracts pinned on the forced-8-device CPU mesh:
    in order under the byte budget — zero-size leaves ride their
    neighbors, a leaf straddling many chunk boundaries coalesces or falls
    back cleanly, a budget covering the padded plane yields ONE group,
-   and a budget smaller than one leaf falls back to per-leaf with ONE
-   warning;
+   and a budget smaller than one leaf falls back to a launch per leaf
+   with ONE warning;
 2. op level: ``ops/sketch.sketch_segments_accum`` (one launch per group)
-   equals the per-leaf ``sketch_segment_accum`` fold and the composed
-   ``sketch_vec`` (``==``: all-zero cells may differ in zero sign), on
-   the pure path and the Pallas kernel through the interpreter;
-3. tree level: ``worker.sketch_grad_tree(groups=...)`` equals the
-   per-leaf call bit-for-bit, per-leaf tp/ep scales included;
-4. round level: fp32 ``--sketch_coalesce`` trajectories are
-   BIT-IDENTICAL to the per-leaf ``--stream_sketch`` path across
-   replicated/``--server_shard`` × composed/``--fused_epilogue`` —
-   coalescing replays the per-leaf fold's add order, so unlike
-   stream-vs-composed there is NO microbatch/wd window caveat;
+   equals the leaf-by-leaf fold and the flat ``sketch_vec`` (``==``:
+   all-zero cells may differ in zero sign), on the pure path and the
+   Pallas kernel through the interpreter;
+3. tree level: ``worker.sketch_grad_tree`` under a coarse plan equals
+   the one-launch-per-leaf plan bit-for-bit, per-leaf tp/ep scales
+   included;
+4. round level: fp32 trajectories are BIT-IDENTICAL whatever the plan
+   (a launch per leaf, two groups, one group) across
+   replicated/``--server_shard`` × composed/``--fused_epilogue``;
 5. structure: with COMMEFFICIENT_PALLAS_SKETCH=interpret the jitted
    client phase's sketch-accumulate ``pallas_call`` count EQUALS the
-   coalesce plan's group count — strictly fewer than the per-leaf
-   build's launch count (shown to trip the detector) — and
-   COMMEFFICIENT_SKETCH_COALESCE=0 restores the per-leaf counts;
-6. rollout: --sketch_coalesce without --stream_sketch runs the composed
-   client phase (d-sized scan carry), not a half-enabled stream.
+   plan's group count, ONCE a round (none inside the microbatch scan),
+   whatever the count of scan steps;
+6. a build pinned to the flat route ignores the budget.
 """
 
 import warnings
@@ -36,18 +32,8 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
 
-from commefficient_tpu.federated.rounds import (
-    RoundConfig,
-    build_round_step,
-    init_client_states,
-)
-from commefficient_tpu.federated.server import (
-    ServerConfig,
-    init_server_state,
-)
-from commefficient_tpu.federated.worker import WorkerConfig, sketch_grad_tree
+from commefficient_tpu.federated.worker import sketch_grad_tree
 from commefficient_tpu.ops.flat import (
     LeafSegment,
     SegmentGroup,
@@ -55,20 +41,21 @@ from commefficient_tpu.ops.flat import (
     leaf_segments,
     ravel_pytree,
 )
+from commefficient_tpu.ops import sketch as sk
 from commefficient_tpu.ops.sketch import (
     coalesce_vmem_budget,
     make_sketch,
-    sketch_segment_accum,
     sketch_segments_accum,
     sketch_vec,
 )
-from tests.test_sharded_server import N, _mesh
 from tests.test_stream_sketch import (
     _batch,
+    _build,
     _max_scan_carry,
-    _mlp_loss,
     _mlp_params,
     _run_rounds,
+    _tree,
+    _walk_eqns,
 )
 
 CE = 512  # chunk elements used by the planner-only tests
@@ -250,11 +237,11 @@ class TestSegmentsAccum:
         cs = make_sketch(d, c, r, seed=7, num_blocks=2)
         v = jnp.asarray(np.random.RandomState(3).randn(d), jnp.float32)
         cuts = self._cuts(bounds)
-        # per-leaf reference fold
+        # leaf-by-leaf reference fold
         ref = jnp.zeros(cs.table_shape, jnp.float32)
         for a, b in cuts:
-            ref = sketch_segment_accum(cs, ref, v[a:b], a,
-                                       interpret=interpret)
+            ref = sketch_segments_accum(cs, ref, [v[a:b]], a,
+                                        interpret=interpret)
         # grouped: split the leaves into two groups at an arbitrary point
         mid = max(1, len(cuts) // 2)
         tbl = jnp.zeros(cs.table_shape, jnp.float32)
@@ -277,13 +264,18 @@ class TestSegmentsAccum:
         np.testing.assert_array_equal(np.asarray(got),
                                       np.asarray(sketch_vec(cs, v)))
 
-    def test_single_segment_group_equals_segment_accum(self):
+    @pytest.mark.parametrize("interpret", [False, True],
+                             ids=["pure", "interpret"])
+    def test_single_segment_onto_running_table(self, interpret):
+        """One unaligned segment onto a running table == the pure fold of
+        its covering chunks (what the kernel's on-TPU self-check holds)."""
         cs = make_sketch(2000, 256, 3, seed=4, num_blocks=2)
         v = jnp.asarray(np.random.RandomState(1).randn(900), jnp.float32)
         base = jnp.asarray(
             np.random.RandomState(2).randn(*cs.table_shape), jnp.float32)
-        got = sketch_segments_accum(cs, base, [v], 613)
-        want = sketch_segment_accum(cs, base, v, 613)
+        got = sketch_segments_accum(cs, base, [v], 613, interpret=interpret)
+        seg3, t_a = sk._segment_chunks(cs, v, 613)
+        want = sk._sketch_accum_chunks_jax(cs, base, seg3, t_a)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     def test_empty_group_and_bounds(self):
@@ -298,15 +290,11 @@ class TestSegmentsAccum:
 
 # ---- 3. tree level: sketch_grad_tree(groups=) == per-leaf ----------------
 
-def _tree(dtype=jnp.float32, seed=0):
-    r = np.random.RandomState(seed)
-    return {
-        "block": {"w": jnp.asarray(r.randn(13, 31), dtype),
-                  "b": jnp.asarray(r.randn(31), dtype)},
-        "head": [jnp.asarray(r.randn(31, 7), dtype),
-                 jnp.asarray(r.randn(1), dtype)],
-        "scalar": jnp.asarray(r.randn(), dtype),
-    }
+def _per_leaf(segs, cs):
+    """The plan of one launch a nonzero leaf (a budget under one chunk;
+    the planner says so once)."""
+    with pytest.warns(RuntimeWarning, match="no adjacent leaves"):
+        return coalesce_segments(segs, 1, chunk_elems=cs.c_pad)
 
 
 class TestGradTreeCoalesced:
@@ -322,8 +310,8 @@ class TestGradTreeCoalesced:
                                    chunk_elems=cs.c_pad)
         assert 1 < len(groups) < len(segs)
         zero = jnp.zeros(cs.table_shape, jnp.float32)
-        got = sketch_grad_tree(cs, zero, tree, segs, groups=groups)
-        want = sketch_grad_tree(cs, zero, tree, segs)
+        got = sketch_grad_tree(cs, zero, tree, segs, groups)
+        want = sketch_grad_tree(cs, zero, tree, segs, _per_leaf(segs, cs))
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
         np.testing.assert_array_equal(
             np.asarray(got),
@@ -339,9 +327,9 @@ class TestGradTreeCoalesced:
         groups = coalesce_segments(segs, 4 * 128 * 4,
                                    chunk_elems=cs.c_pad)
         zero = jnp.zeros(cs.table_shape, jnp.float32)
-        got = sketch_grad_tree(cs, zero, tree, segs, scales=scales,
-                               groups=groups)
-        want = sketch_grad_tree(cs, zero, tree, segs, scales=scales)
+        got = sketch_grad_tree(cs, zero, tree, segs, groups, scales=scales)
+        want = sketch_grad_tree(cs, zero, tree, segs, _per_leaf(segs, cs),
+                                scales=scales)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     def test_groups_must_partition(self):
@@ -354,7 +342,7 @@ class TestGradTreeCoalesced:
         assert len(groups) >= 2
         zero = jnp.zeros(cs.table_shape, jnp.float32)
         with pytest.raises(AssertionError, match="partition"):
-            sketch_grad_tree(cs, zero, tree, segs, groups=groups[:-1])
+            sketch_grad_tree(cs, zero, tree, segs, groups[:-1])
 
 
 # ---- 4./5./6. round level on the 8-device mesh ---------------------------
@@ -362,57 +350,29 @@ class TestGradTreeCoalesced:
 # a budget that coalesces the MLP's 6 leaves (d=4141, c_pad=128, T=33)
 # into 2 groups — fewer launches than leaves, more than one group
 BUDGET = 32 * 128 * 4
+WHOLE = 33 * 128 * 4  # the padded plane: one group
+PER_LEAF = 1          # under one chunk: a launch per leaf (one warning)
 
 
-def _build(stream, coalesce, server_shard=False, fused=False,
-           budget=BUDGET):
-    """The tests/test_stream_sketch.py MLP round on the 8-device mesh,
-    with the coalesced client phase opt-in on top."""
-    mesh = _mesh()
-    rep = NamedSharding(mesh, P())
-    params = _mlp_params()
-    flat, unravel = ravel_pytree(params)
-    d = int(flat.size)
-
-    def ravel(tree):
-        return ravel_pytree(tree)[0]
-
-    wcfg = WorkerConfig(mode="sketch", error_type="virtual", k=5,
-                        num_workers=N)
-    scfg = ServerConfig(mode="sketch", error_type="virtual", k=5,
-                        grad_size=d, virtual_momentum=0.9,
-                        fused_epilogue=fused)
-    cs_geo = make_sketch(d, 16, 3, seed=0, num_blocks=1)
-    cfg = RoundConfig(worker=wcfg, server=scfg, grad_size=d,
-                      server_shard=server_shard, stream_sketch=stream,
-                      sketch_coalesce=coalesce,
-                      sketch_coalesce_budget=budget)
-    steps = build_round_step(_mlp_loss, _mlp_loss, unravel, ravel, cfg,
-                             sketch=cs_geo, mesh=mesh)
-    ss = init_server_state(scfg, cs_geo)
-    ss = ss._replace(velocity=jax.device_put(ss.velocity, rep),
-                     error=jax.device_put(ss.error, rep))
-    ps = jax.device_put(steps.layout.chunk(flat), rep)
-    cstates = jax.tree_util.tree_map(
-        lambda a: jax.device_put(a, rep),
-        init_client_states(16, d, wcfg, init_weights=flat, sketch=cs_geo))
-    return steps, ps, ss, cstates, d
-
-
-def _plan(d=4141):
-    """The coalesce plan the BUDGET builds use (same inputs as
+def _plan(budget=BUDGET, d=4141):
+    """The plan a ``budget`` build uses (same inputs as
     build_round_step's: the leaf offset map + the sketch's c_pad)."""
     tpl = jax.eval_shape(_mlp_params)
     segs = leaf_segments(tpl)
     cs_geo = make_sketch(d, 16, 3, seed=0, num_blocks=1)
-    return segs, coalesce_segments(segs, BUDGET, chunk_elems=cs_geo.c_pad)
+    return segs, coalesce_segments(segs, budget, chunk_elems=cs_geo.c_pad)
 
 
-class TestCoalesceRoundBitIdentity:
-    """Acceptance criterion: fp32 --sketch_coalesce trajectories are
-    bit-identical to the per-leaf --stream_sketch path's across both
-    server planes and both epilogues. No wd/microbatch caveat: the
-    coalesced fold replays the per-leaf add order exactly."""
+@pytest.mark.filterwarnings("ignore:coalesce_segments:RuntimeWarning")
+class TestPlanRoundBitIdentity:
+    """Acceptance criterion: fp32 trajectories are bit-identical whatever
+    the group plan, across both server planes and both epilogues: a
+    coarser plan replays the finer plan's per-cell add order exactly.
+    (One scan step and no decay: XLA:CPU contracts ``g + coef · w`` into
+    a fused multiply-add in some plans' staging fusions and not in
+    others', and compiles the MLP's backward pass differently from
+    program to program; tests/test_stream_sketch.py holds both to ``==``
+    on ``_int_loss``.)"""
 
     @pytest.mark.parametrize("shard", [False, True],
                              ids=["replicated", "server_shard"])
@@ -421,94 +381,92 @@ class TestCoalesceRoundBitIdentity:
     def test_trajectory_bit_identical(self, shard, fused, monkeypatch):
         if fused:
             monkeypatch.setenv("COMMEFFICIENT_FUSED_EPILOGUE", "interpret")
-        a, ssa, csa = _run_rounds(*_build(True, False, shard, fused)[:4])
-        b, ssb, csb = _run_rounds(*_build(True, True, shard, fused)[:4])
-        for rnd, (x, y) in enumerate(zip(a, b)):
-            np.testing.assert_array_equal(
-                x, y,
-                err_msg=f"shard={shard} fused={fused} round {rnd} ps")
-        for name in ("velocity", "error"):
-            np.testing.assert_array_equal(
-                np.asarray(getattr(ssa, name)),
-                np.asarray(getattr(ssb, name)), err_msg=name)
+        kw = dict(server_shard=shard, fused=fused)
+        runs = [_run_rounds(*_build(budget=b, **kw)[:4])
+                for b in (PER_LEAF, BUDGET, WHOLE)]
+        a, ssa, _ = runs[0]
+        for b, ssb, _ in runs[1:]:
+            for rnd, (x, y) in enumerate(zip(a, b)):
+                np.testing.assert_array_equal(
+                    x, y,
+                    err_msg=f"shard={shard} fused={fused} round {rnd} ps")
+            for name in ("velocity", "error"):
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(ssa, name)),
+                    np.asarray(getattr(ssb, name)), err_msg=name)
 
-    def test_coalesce_without_stream_runs_composed(self):
-        """--sketch_coalesce outside the streaming window must not
-        half-enable anything: the client phase is the composed one (scan
-        carry is d-sized), and the trajectory matches the composed
-        build's bit-for-bit."""
-        steps_c, ps_c, ss_c, cs_c, d = _build(False, True)
+    def test_flat_build_ignores_the_budget(self):
+        """A build pinned to the flat route must not half-enable anything:
+        its client phase is the flat one (scan carry is d-sized, no plan),
+        and the trajectory matches the budget-less flat build's
+        bit-for-bit."""
+        steps_c, ps_c, ss_c, cs_c, d = _build(False, budget=BUDGET)
+        assert (steps_c.client_sketch_path,
+                steps_c.client_sketch_launches) == ("flat", 0)
         args = (ps_c, cs_c, {}, _batch(0), 0.1, jax.random.key(0))
         carry = _max_scan_carry(steps_c.client_step, *args)
         assert carry >= d, \
-            f"composed carry {carry} should be d-sized (d={d})"
-        a, _, _ = _run_rounds(*_build(False, False)[:4])
-        b, _, _ = _run_rounds(*_build(False, True)[:4])
+            f"flat carry {carry} should be d-sized (d={d})"
+        a, _, _ = _run_rounds(*_build(False)[:4])
+        b, _, _ = _run_rounds(*_build(False, budget=BUDGET)[:4])
         for rnd, (x, y) in enumerate(zip(a, b)):
             np.testing.assert_array_equal(x, y, err_msg=f"round {rnd}")
 
 
-# ---- structural assert: launch count == group count ----------------------
+# ---- structural assert: launch count == group count, once a round --------
 
 def _count_accum_launches(fn, *args):
-    """Number of ``pallas_call`` eqns anywhere in the jaxpr — with
-    COMMEFFICIENT_PALLAS_SKETCH=interpret the streaming client phase's
-    only Pallas calls are the sketch-accumulate launches, so this IS the
-    client phase's kernel-launch count per microbatch."""
-    count = 0
+    """``pallas_call`` eqns in the jaxpr, (outside, inside) any scan —
+    with COMMEFFICIENT_PALLAS_SKETCH=interpret the client phase's only
+    Pallas calls are the sketch-accumulate launches, so ``outside`` IS the
+    client phase's launch count a round and ``inside`` what would run once
+    a scan step."""
+    count = [0, 0]
 
-    def walk(jx):
-        nonlocal count
-        for eqn in jx.eqns:
-            if eqn.primitive.name == "pallas_call":
-                count += 1
-            for val in eqn.params.values():
-                for j in (val if isinstance(val, (list, tuple)) else [val]):
-                    if hasattr(j, "eqns"):
-                        walk(j)
-                    elif hasattr(j, "jaxpr") and hasattr(j.jaxpr, "eqns"):
-                        walk(j.jaxpr)
+    def visit(eqn, in_scan):
+        if eqn.primitive.name == "pallas_call":
+            count[in_scan] += 1
 
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return count
+    _walk_eqns(fn, args, visit)
+    return tuple(count)
 
 
-class TestCoalesceStructure:
-    """Acceptance criterion: the coalesced client phase launches exactly
-    ONE sketch-accumulate kernel per plan group — strictly fewer than the
-    per-leaf build's one-per-leaf, which is shown to trip the detector."""
+@pytest.mark.filterwarnings("ignore:coalesce_segments:RuntimeWarning")
+class TestPlanStructure:
+    """Acceptance criterion: the client phase launches exactly ONE
+    sketch-accumulate kernel per plan group, once a round: none of them
+    inside the microbatch scan, whatever the count of scan steps."""
 
-    def _launches(self, steps, ps, cstates):
-        return _count_accum_launches(
+    def _launches(self, **kw):
+        steps, ps, _, cstates, _ = _build(**kw)
+        return steps.client_sketch_launches, _count_accum_launches(
             steps.client_step, ps, cstates, {}, _batch(0), 0.1,
             jax.random.key(0))
 
-    def test_launches_equal_group_count(self, monkeypatch):
+    @pytest.mark.parametrize("micro", [-1, 2, 1],
+                             ids=["scan1", "scan2", "scan4"])
+    def test_launches_equal_group_count(self, micro, monkeypatch):
         monkeypatch.setenv("COMMEFFICIENT_PALLAS_SKETCH", "interpret")
         segs, groups = _plan()
         n_leaves = sum(1 for s in segs if s.size)
         assert 1 < len(groups) < n_leaves, \
             "test layout must coalesce to fewer groups than leaves"
+        said, (outside, inside) = self._launches(budget=BUDGET, micro=micro,
+                                                 wd=5e-4)
+        assert said == outside == len(groups), (said, outside, len(groups))
+        assert inside == 0, f"{inside} accumulate launches a scan step"
 
-        steps_p, ps_p, _, cs_p, _ = _build(True, False)
-        per_leaf = self._launches(steps_p, ps_p, cs_p)
-        assert per_leaf == n_leaves, \
-            f"per-leaf build launches {per_leaf} != leaf count {n_leaves}"
-
-        steps_c, ps_c, _, cs_c, _ = _build(True, True)
-        coalesced = self._launches(steps_c, ps_c, cs_c)
-        assert coalesced == len(groups), \
-            f"coalesced build launches {coalesced} != " \
-            f"group count {len(groups)}"
-        assert coalesced < per_leaf
-
-    def test_kill_switch_restores_per_leaf(self, monkeypatch):
-        """COMMEFFICIENT_SKETCH_COALESCE=0 must restore one launch per
-        leaf even with the flag on — structural evidence, not just equal
-        numbers."""
+    def test_plan_under_one_chunk_launches_per_leaf(self, monkeypatch):
+        """The detector counts what runs: the degenerate plan shows one
+        launch a leaf, the one-group plan one."""
         monkeypatch.setenv("COMMEFFICIENT_PALLAS_SKETCH", "interpret")
-        monkeypatch.setenv("COMMEFFICIENT_SKETCH_COALESCE", "0")
         segs, groups = _plan()
         n_leaves = sum(1 for s in segs if s.size)
-        steps, ps, _, cstates, _ = _build(True, True)
-        assert self._launches(steps, ps, cstates) == n_leaves > len(groups)
+        said, (outside, inside) = self._launches(budget=PER_LEAF, micro=2)
+        assert said == outside == n_leaves > len(groups) and inside == 0
+        said, (outside, inside) = self._launches(budget=WHOLE, micro=2)
+        assert said == outside == 1 and inside == 0
+
+    def test_flat_route_has_no_accumulate_launch(self, monkeypatch):
+        monkeypatch.setenv("COMMEFFICIENT_PALLAS_SKETCH", "interpret")
+        assert self._launches(leaf=False, micro=2) == (0, (0, 0))

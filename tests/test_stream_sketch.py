@@ -1,31 +1,34 @@
-"""Streaming client-phase sketch (--stream_sketch, docs/stream_sketch.md).
+"""The sketch cells' client phase: leaf groups (docs/stream_sketch.md).
 
 Contracts pinned on the forced-8-device CPU mesh:
 
-1. op level: streaming a vector through ``sketch_segment_accum`` calls in
-   offset order — any segmentation, any (mis)alignment, bf16 or f32
-   segments — equals the composed ``sketch_vec`` of the whole vector
-   (``==``: all-zero cells may differ in zero sign), on both the pure
-   path and the Pallas accumulate kernel through the interpreter;
+1. op level: accumulating a vector through ``sketch_segments_accum`` calls
+   in offset order — any segmentation, any (mis)alignment, bf16 or f32
+   segments — equals the flat ``sketch_vec`` of the whole vector (``==``:
+   all-zero cells may differ in zero sign), on both the pure path and the
+   Pallas accumulate kernel through the interpreter;
 2. tree level: ``worker.sketch_grad_tree`` over a gradient pytree with
    the ``ops/flat.leaf_segments`` offset map equals
    ``sketch_vec(ravel_pytree(tree))`` across leaf-count/dtype mixes
-   (bf16 grads, fp32 table), and ``ops/flat.chunked_unravel`` rebuilds
-   the pytree from the resident chunk plane bit-exactly;
-3. round level: fp32 ``--stream_sketch`` trajectories and server/client
-   state are BIT-IDENTICAL to the composed fused path's across
-   replicated/``--server_shard`` × composed/``--fused_epilogue``
-   (megakernel through the Pallas interpreter), single microbatch and
-   wd=0 — the exact-equality window docs/stream_sketch.md documents;
-4. structure: the jitted streaming client phase contains NO d-sized
-   concatenate/pad/reshape (HLO inspection) and its scan carry is
-   table-sized, not d-sized (jaxpr walk) — while the composed build
-   demonstrably trips both detectors, so the asserts are not vacuous;
-5. rollout: COMMEFFICIENT_STREAM_SKETCH=0 restores the composed client
-   phase even with the flag on.
+   (bf16 grads, fp32 table), per-leaf scales and the weight decay read
+   from the plane in the staging pass included, and ``ops/flat.chunked_unravel`` rebuilds the pytree
+   from the resident chunk plane bit-exactly;
+3. round level: the leaf-group route's table ``==`` the flat route's for
+   scan steps ∈ {1, 2, 4} × weight decay ∈ {0, 5e-4} × replicated /
+   ``--server_shard``, and fp32 trajectories and server state are
+   BIT-IDENTICAL across replicated/``--server_shard`` × composed /
+   ``--fused_epilogue`` epilogues;
+4. structure: the jitted leaf-group client phase contains NO d-sized
+   concatenate/pad/reshape (HLO inspection) and its scan carries a tree
+   of leaf shapes (jaxpr walk) — while the flat build demonstrably trips
+   both detectors, so the asserts are not vacuous;
+5. selection: sketch mode inside the fused + sketch-after-sum + chunked
+   window takes the leaf groups; ``uncompressed``, ``true_topk`` and
+   per-client state take the flat route; no flag or environment variable
+   selects.
 """
 
-import os
+import functools
 import re
 
 import numpy as np
@@ -47,13 +50,13 @@ from commefficient_tpu.federated.server import (
 from commefficient_tpu.federated.worker import WorkerConfig, sketch_grad_tree
 from commefficient_tpu.ops.flat import (
     chunked_unravel,
+    coalesce_segments,
     leaf_segments,
     ravel_pytree,
 )
 from commefficient_tpu.ops.sketch import (
     make_sketch,
-    sketch_chunks_accum,
-    sketch_segment_accum,
+    sketch_segments_accum,
     sketch_vec,
 )
 from tests.test_sharded_server import N, _mesh
@@ -85,8 +88,8 @@ class TestSegmentAccum:
         v = jnp.asarray(np.random.RandomState(3).randn(d), jnp.float32)
         table = jnp.zeros(cs.table_shape, jnp.float32)
         for a, b in self._cuts(bounds):
-            table = sketch_segment_accum(cs, table, v[a:b], a,
-                                         interpret=interpret)
+            table = sketch_segments_accum(cs, table, [v[a:b]], a,
+                                          interpret=interpret)
         want = sketch_vec(cs, v)
         np.testing.assert_array_equal(np.asarray(table), np.asarray(want))
 
@@ -98,29 +101,31 @@ class TestSegmentAccum:
                           jnp.bfloat16)
         table = jnp.zeros(cs.table_shape, jnp.float32)
         for a, b in self._cuts((0, 300, 301, 2000, 3000)):
-            table = sketch_segment_accum(cs, table, v16[a:b], a)
+            table = sketch_segments_accum(cs, table, [v16[a:b]], a)
         want = sketch_vec(cs, v16.astype(jnp.float32))
         np.testing.assert_array_equal(np.asarray(table), np.asarray(want))
 
-    def test_chunks_accum_continues_fold(self):
-        """Full-range accumulate onto a running table (the wd fold):
-        accumulating v onto sketch(u) == streaming u then v per cell."""
+    def test_running_table_continues_fold(self):
+        """A full-range group onto a running table: accumulating v onto
+        sketch(u) in one launch == in two, per cell."""
         cs = make_sketch(2000, 256, 3, seed=2, num_blocks=2)
         rng = np.random.RandomState(9)
         u = jnp.asarray(rng.randn(2000), jnp.float32)
         v = jnp.asarray(rng.randn(2000), jnp.float32)
         base = sketch_vec(cs, u)
-        got = sketch_chunks_accum(cs, base, cs.chunk_layout.chunk(v))
-        want = sketch_segment_accum(cs, base, v, 0)
+        got = sketch_segments_accum(cs, base, [v], 0)
+        want = sketch_segments_accum(cs, base, [v[:700]], 0)
+        want = sketch_segments_accum(cs, want, [v[700:]], 700)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        assert not np.array_equal(np.asarray(got), np.asarray(base))
 
     def test_empty_and_bounds(self):
         cs = make_sketch(1000, 128, 2, seed=3, num_blocks=1)
         t = jnp.zeros(cs.table_shape, jnp.float32)
-        out = sketch_segment_accum(cs, t, jnp.zeros(0), 500)
+        out = sketch_segments_accum(cs, t, [jnp.zeros(0)], 500)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(t))
         with pytest.raises(AssertionError):
-            sketch_segment_accum(cs, t, jnp.zeros(10), 995)  # past d
+            sketch_segments_accum(cs, t, [jnp.zeros(10)], 995)  # past d
 
 
 # ---- 2. tree level: sketch_grad_tree + the offset map -------------------
@@ -134,6 +139,12 @@ def _tree(dtype=jnp.float32, seed=0):
                  jnp.asarray(r.randn(1), dtype)],
         "scalar": jnp.asarray(r.randn(), dtype),
     }
+
+
+def _plan(segs, cs, chunks=4):
+    """A group plan of ``chunks``-chunk groups over ``segs``."""
+    return coalesce_segments(segs, chunks * cs.c_pad * 4,
+                             chunk_elems=cs.c_pad)
 
 
 class TestTreeStreaming:
@@ -156,8 +167,9 @@ class TestTreeStreaming:
         flat, _ = ravel_pytree(tree)  # casts to f32 like the worker path
         d = int(flat.size)
         cs = make_sketch(d, 128, 3, seed=11, num_blocks=1)
+        segs = leaf_segments(tree)
         table = sketch_grad_tree(cs, jnp.zeros(cs.table_shape, jnp.float32),
-                                 tree, leaf_segments(tree))
+                                 tree, segs, _plan(segs, cs))
         want = sketch_vec(cs, flat)
         np.testing.assert_array_equal(np.asarray(table), np.asarray(want))
 
@@ -172,11 +184,38 @@ class TestTreeStreaming:
         scales = tuple(1.0 if i % 2 else 0.5 for i in range(len(segs)))
         cs = make_sketch(d, 128, 3, seed=12, num_blocks=1)
         got = sketch_grad_tree(cs, jnp.zeros(cs.table_shape, jnp.float32),
-                               tree, segs, scales=scales)
+                               tree, segs, _plan(segs, cs), scales=scales)
         mask = np.zeros(d, np.float32)
         for seg, sc in zip(segs, scales):
             mask[seg.offset:seg.offset + seg.size] = sc
         want = sketch_vec(cs, flat * jnp.asarray(mask))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("scaled", [False, True],
+                             ids=["decay", "scales_then_decay"])
+    def test_staged_decay_equals_flat_decay(self, scaled):
+        """``decay=(coef, plane)`` adds ``coef · w`` in each group's staging
+        pass, read from the resident plane's own rows, after the rescale:
+        the flat route's ``g · mask + coef · w`` element for element, with
+        no pass of its own; the boundary chunks' neighbours are masked."""
+        g_tree, w_tree = _tree(seed=6), _tree(seed=7)
+        g, _ = ravel_pytree(g_tree)
+        w, _ = ravel_pytree(w_tree)
+        d = int(g.size)
+        segs = leaf_segments(g_tree)
+        scales = tuple(1.0 if i % 2 else 0.5 for i in range(len(segs))) \
+            if scaled else None
+        coef = jnp.float32(5e-4 / 8) * jnp.float32(32.0)
+        cs = make_sketch(d, 128, 3, seed=12, num_blocks=1)
+        plan = _plan(segs, cs)
+        assert len(plan) > 1 and any(g_.offset % cs.c_pad for g_ in plan)
+        got = sketch_grad_tree(cs, jnp.zeros(cs.table_shape, jnp.float32),
+                               g_tree, segs, plan, scales=scales,
+                               decay=(coef, cs.chunk_layout.chunk(w)))
+        mask = np.ones(d, np.float32)
+        for seg, sc in zip(segs, scales or ()):
+            mask[seg.offset:seg.offset + seg.size] = sc
+        want = sketch_vec(cs, g * jnp.asarray(mask) + coef * w)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     def test_chunked_unravel_bit_exact(self):
@@ -225,6 +264,25 @@ def _mlp_loss(params, model_state, batch, rng, train):
         jnp.sum(m), model_state
 
 
+def _int_loss(params, model_state, batch, rng, train):
+    """A loss whose gradient is integer-valued whatever the backend does:
+    every leaf's gradient is a small integer pattern times an integer
+    feature of the example, so sums over examples, clients and scan steps
+    are exact in float32 in any order. XLA:CPU compiles the MLP's backward
+    pass differently in two programs (its batched products reorder their
+    sums with the consumer), which says nothing about the two routes; on
+    this loss ``==`` tests what the routes themselves do: which
+    microbatches are added, where the decay lands, where each element is
+    sketched."""
+    z = jnp.round(2.0 * batch["inputs"])  # (mb, IN) small integers
+    m = batch["mask"]
+    total = 0.0
+    for i, (name, leaf) in enumerate(sorted(params.items())):
+        pat = (jnp.arange(leaf.size) % 7 - 3.0).reshape(leaf.shape)
+        total = total + jnp.sum(z[:, i] * m) * jnp.sum(leaf * pat)
+    return total, (jnp.sum(jnp.abs(z[:, 0]) * m),), jnp.sum(m), model_state
+
+
 def _batch(seed=0, B=4):
     r = np.random.RandomState(100 + seed)
     return {"inputs": jnp.asarray(r.randn(N, B, IN), jnp.float32),
@@ -234,12 +292,15 @@ def _batch(seed=0, B=4):
             "worker_mask": jnp.ones(N, jnp.float32)}
 
 
-def _build(stream, server_shard=False, fused=False):
-    """A placed sketch round on the 8-device mesh over the multi-leaf MLP
-    (T=33 chunks at c_pad=128, leaf offsets straddling chunk and lane
-    boundaries), with or without --stream_sketch — single microbatch,
-    wd=0: the documented exact-equality window."""
-    mesh = _mesh()
+def _build(leaf=None, server_shard=False, fused=False, micro=-1, wd=0.0,
+           budget=0, mode="sketch", error_type="virtual", loss=_mlp_loss,
+           mesh=None, model_axis=None):
+    """A placed round on the 8-device mesh over the multi-leaf MLP (T=33
+    chunks at c_pad=128, leaf offsets straddling chunk and lane
+    boundaries). ``leaf`` is ``RoundConfig.sketch_leaf_groups`` (None: the
+    build decides, False pins the flat route); ``micro`` the microbatch
+    (4 examples a client: -1 is one scan step, 2 two, 1 four)."""
+    mesh = mesh or _mesh()
     rep = NamedSharding(mesh, P())
     params = _mlp_params()
     flat, unravel = ravel_pytree(params)
@@ -248,20 +309,27 @@ def _build(stream, server_shard=False, fused=False):
     def ravel(tree):
         return ravel_pytree(tree)[0]
 
-    wcfg = WorkerConfig(mode="sketch", error_type="virtual", k=5,
-                        num_workers=N)
-    scfg = ServerConfig(mode="sketch", error_type="virtual", k=5,
-                        grad_size=d, virtual_momentum=0.9,
-                        fused_epilogue=fused)
-    cs_geo = make_sketch(d, 16, 3, seed=0, num_blocks=1)
+    wcfg = WorkerConfig(mode=mode, error_type=error_type, k=5,
+                        num_workers=N, microbatch_size=micro,
+                        weight_decay=wd, model_axis=model_axis)
+    scfg = ServerConfig(mode=mode, error_type=error_type, k=5,
+                        grad_size=d,
+                        virtual_momentum=0.0 if error_type == "local"
+                        else 0.9, fused_epilogue=fused)
+    cs_geo = make_sketch(d, 16, 3, seed=0, num_blocks=1) \
+        if mode == "sketch" else None
     cfg = RoundConfig(worker=wcfg, server=scfg, grad_size=d,
-                      server_shard=server_shard, stream_sketch=stream)
-    steps = build_round_step(_mlp_loss, _mlp_loss, unravel, ravel, cfg,
+                      server_shard=server_shard, sketch_leaf_groups=leaf,
+                      sketch_coalesce_budget=budget,
+                      # every leaf replicated over the model axis: each of
+                      # its shards computes the whole gradient
+                      tp_sliced=(lambda path: False) if model_axis else None)
+    steps = build_round_step(loss, loss, unravel, ravel, cfg,
                              sketch=cs_geo, mesh=mesh)
     ss = init_server_state(scfg, cs_geo)
-    ss = ss._replace(velocity=jax.device_put(ss.velocity, rep),
-                     error=jax.device_put(ss.error, rep))
-    ps = jax.device_put(steps.layout.chunk(flat), rep)
+    ss = jax.tree_util.tree_map(lambda a: jax.device_put(a, rep), ss)
+    ps = jax.device_put(
+        steps.layout.chunk(flat) if steps.layout is not None else flat, rep)
     cstates = jax.tree_util.tree_map(
         lambda a: jax.device_put(a, rep),
         init_client_states(16, d, wcfg, init_weights=flat, sketch=cs_geo))
@@ -277,10 +345,80 @@ def _run_rounds(steps, ps, ss, cstates, rounds=3, lr=0.1):
     return traj, ss, cstates
 
 
-class TestStreamRoundBitIdentity:
-    """Acceptance criterion: fp32 --stream_sketch trajectories are
-    bit-identical to the composed path's across both server planes and
-    both epilogues."""
+class TestLeafGroupRoundBitIdentity:
+    """Acceptance criterion: the leaf-group route's table equals the flat
+    route's under ``==`` for any count of scan steps and any weight decay
+    on one mesh axis, and fp32 trajectories are bit-identical across both
+    server planes and both epilogues."""
+
+    @staticmethod
+    def _tables(loss, micro, wd, shard):
+        out = []
+        for leaf in (False, None):
+            steps, ps, _, cstates, _ = _build(leaf, shard, micro=micro,
+                                              wd=wd, loss=loss)
+            ctx, _, metrics = steps.client_step(
+                ps, cstates, {}, _batch(0), 0.1, jax.random.key(0))
+            out.append((np.asarray(ctx.gradient),
+                        [np.asarray(m) for m in metrics]))
+        (flat_t, flat_m), (leaf_t, leaf_m) = out
+        assert np.any(flat_t != 0)
+        return flat_t, leaf_t, flat_m, leaf_m
+
+    @pytest.mark.parametrize("shard", [False, True],
+                             ids=["replicated", "server_shard"])
+    @pytest.mark.parametrize("wd", [0.0, 5e-4], ids=["wd0", "wd5e-4"])
+    @pytest.mark.parametrize("micro", [-1, 2, 1],
+                             ids=["scan1", "scan2", "scan4"])
+    def test_table_equals_flat_routes(self, micro, wd, shard):
+        """``==`` for every count of scan steps and weight decay, on a
+        gradient whose arithmetic no backend can reorder (``_int_loss``)."""
+        flat_t, leaf_t, _, _ = self._tables(_int_loss, micro, wd, shard)
+        np.testing.assert_array_equal(leaf_t, flat_t)
+
+    @pytest.mark.parametrize("shard", [False, True],
+                             ids=["replicated", "server_shard"])
+    @pytest.mark.parametrize("micro,wd", [(-1, 0.0), (-1, 5e-4),
+                                          (2, 5e-4), (1, 5e-4)],
+                             ids=["scan1-wd0", "scan1-wd5e-4",
+                                  "scan2-wd5e-4", "scan4-wd5e-4"])
+    def test_table_on_the_mlp(self, micro, wd, shard):
+        """The tanh MLP: ``==`` at one scan step (the two programs hold
+        the same backward pass there), and to rounding at more, where
+        XLA:CPU sums the batched products of the two programs' backward
+        passes in different orders (a single step's gradient already
+        differs between them there, with no scan and no sketch)."""
+        flat_t, leaf_t, flat_m, leaf_m = self._tables(_mlp_loss, micro, wd,
+                                                      shard)
+        if micro == -1:
+            np.testing.assert_array_equal(leaf_t, flat_t)
+        else:
+            np.testing.assert_allclose(leaf_t, flat_t, rtol=0,
+                                       atol=2e-6 * np.abs(flat_t).max())
+        for a, b in zip(leaf_m, flat_m):
+            np.testing.assert_allclose(a, b, rtol=1e-6)
+
+    @pytest.mark.parametrize("wd", [0.0, 5e-4], ids=["wd0", "wd5e-4"])
+    def test_second_mesh_axis_rides_the_table(self, wd):
+        """A model axis beside the clients axis: the leaf-group route
+        rescales per leaf, adds the decay on ONE shard of the axis and
+        psums the table, where the flat route psums the gradient, rescales
+        by the mask and decays after. Equal to float32 rounding (the sums
+        reorder); a decay counted once a shard would be off by 1e-4."""
+        from commefficient_tpu.parallel.mesh import make_mesh
+
+        out = []
+        for leaf in (False, None):
+            steps, ps, _, cstates, _ = _build(
+                leaf, micro=2, wd=wd, model_axis="model",
+                mesh=make_mesh([("clients", 2), ("model", 2)],
+                               devices=jax.devices()[:4]))
+            ctx, _, _ = steps.client_step(
+                ps, cstates, {}, _batch(0), 0.1, jax.random.key(0))
+            out.append(np.asarray(ctx.gradient))
+        flat_t, leaf_t = out
+        np.testing.assert_allclose(leaf_t, flat_t, rtol=0,
+                                   atol=2e-6 * np.abs(flat_t).max())
 
     @pytest.mark.parametrize("shard", [False, True],
                              ids=["replicated", "server_shard"])
@@ -291,8 +429,9 @@ class TestStreamRoundBitIdentity:
             # megakernel through the Pallas interpreter (the CPU suite's
             # kernel path, bit-identical math — test_fused_epilogue.py)
             monkeypatch.setenv("COMMEFFICIENT_FUSED_EPILOGUE", "interpret")
-        a, ssa, csa = _run_rounds(*_build(False, shard, fused)[:4])
-        b, ssb, csb = _run_rounds(*_build(True, shard, fused)[:4])
+        kw = dict(server_shard=shard, fused=fused, wd=5e-4)
+        a, ssa, csa = _run_rounds(*_build(False, **kw)[:4])
+        b, ssb, csb = _run_rounds(*_build(None, **kw)[:4])
         for rnd, (x, y) in enumerate(zip(a, b)):
             np.testing.assert_array_equal(
                 x, y,
@@ -302,17 +441,61 @@ class TestStreamRoundBitIdentity:
                 np.asarray(getattr(ssa, name)),
                 np.asarray(getattr(ssb, name)), err_msg=name)
 
-    def test_kill_switch_restores_composed(self, monkeypatch):
-        """COMMEFFICIENT_STREAM_SKETCH=0 must force the composed client
-        phase even with the flag on: the d-sized movement ops reappear in
-        the lowered HLO (structural evidence, not just equal numbers)."""
-        monkeypatch.setenv("COMMEFFICIENT_STREAM_SKETCH", "0")
-        steps, ps, ss, cstates, d = _build(True)
+
+# ---- selection: what the build can observe, and nothing else ------------
+
+class TestRouteSelection:
+    @pytest.mark.parametrize("kw,path", [
+        (dict(), "leaf_groups"),
+        (dict(server_shard=True), "leaf_groups"),
+        (dict(micro=1, wd=5e-4), "leaf_groups"),
+        (dict(mode="uncompressed", error_type="none"), "flat"),
+        (dict(mode="true_topk"), "flat"),
+        (dict(error_type="local"), "flat"),  # per-client sketch tables
+        (dict(leaf=False), "flat"),
+    ], ids=["sketch", "sketch-server_shard", "sketch-scan4-wd",
+            "uncompressed", "true_topk", "per_client_state", "pinned_flat"])
+    def test_route_follows_the_window(self, kw, path):
+        """Sketch mode inside the fused + sketch-after-sum + chunked
+        window takes the leaf groups; modes that need the flat gradient
+        itself and per-client paths take the flat route."""
+        steps = _build(**kw)[0]
+        assert steps.client_sketch_path == path
+        if path == "flat":
+            assert steps.client_sketch_launches == 0
+        else:
+            segs = leaf_segments(jax.eval_shape(_mlp_params))
+            n_leaves = sum(1 for s in segs if s.size)
+            assert 1 <= steps.client_sketch_launches <= n_leaves
+
+    def test_forcing_outside_the_window_is_refused(self):
+        with pytest.raises(AssertionError, match="outside its window"):
+            _build(True, mode="uncompressed", error_type="none")
+
+    def test_pinned_flat_build_holds_d_sized_movement(self):
+        """``sketch_leaf_groups=False`` must build the flat client phase:
+        the d-sized movement ops reappear in the lowered HLO (structural
+        evidence, not just equal numbers)."""
+        steps, ps, ss, cstates, d = _build(False)
         hits = _big_movement_ops(_client_hlo(steps, ps, cstates), d)
-        assert hits, "kill-switch build should contain d-sized movement"
+        assert hits, "flat build should contain d-sized movement"
+
+    @pytest.mark.parametrize("flag", ["--stream_sketch",
+                                      "--sketch_coalesce"])
+    def test_the_flags_are_gone(self, flag):
+        from commefficient_tpu.config import parse_args
+
+        with pytest.raises(SystemExit):
+            parse_args(argv=["--mode", "sketch", flag])
+
+    @pytest.mark.parametrize("name", ["COMMEFFICIENT_STREAM_SKETCH",
+                                      "COMMEFFICIENT_SKETCH_COALESCE"])
+    def test_no_environment_switch_selects(self, name, monkeypatch):
+        monkeypatch.setenv(name, "0")
+        assert _build()[0].client_sketch_path == "leaf_groups"
 
 
-# ---- structural asserts: no d-sized movement, table-sized carry ---------
+# ---- structural asserts: no d-sized movement, a tree-shaped carry -------
 
 _SHAPE_RE = re.compile(
     r"tensor<([0-9]+(?:x[0-9]+)*)x(?:f32|f64|bf16|f16|i32|ui32|i8|i1)>")
@@ -338,85 +521,111 @@ def _big_movement_ops(hlo_text, threshold):
     return hits
 
 
-def _max_scan_carry(fn, *args):
-    """Largest scan-carry aval (elements) anywhere in the jaxpr,
-    descending into pjit/shard_map/scan sub-jaxprs."""
-    best = 0
-
-    def walk(jx):
-        nonlocal best
+def _walk_eqns(fn, args, visit):
+    """``visit(eqn, in_scan)`` for every equation of ``fn``'s jaxpr,
+    descending into pjit/shard_map/scan sub-jaxprs; ``in_scan`` says
+    whether the equation lies inside some scan's body."""
+    def walk(jx, in_scan):
         for eqn in jx.eqns:
-            if eqn.primitive.name == "scan":
-                inner = eqn.params["jaxpr"].jaxpr
-                nc = eqn.params["num_carry"]
-                ncons = eqn.params["num_consts"]
-                for v in inner.invars[ncons:ncons + nc]:
-                    sz = int(np.prod(v.aval.shape)) if v.aval.shape else 1
-                    best = max(best, sz)
+            visit(eqn, in_scan)
+            inner = in_scan or eqn.primitive.name == "scan"
             for val in eqn.params.values():
                 for j in (val if isinstance(val, (list, tuple)) else [val]):
                     if hasattr(j, "eqns"):
-                        walk(j)
+                        walk(j, inner)
                     elif hasattr(j, "jaxpr") and hasattr(j.jaxpr, "eqns"):
-                        walk(j.jaxpr)
+                        walk(j.jaxpr, inner)
 
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return best
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, False)
 
 
-class TestStreamStructure:
-    """Acceptance criterion: with --stream_sketch the jitted client phase
-    contains no d-sized concatenate/pad/reshape and its scan carry is
-    table-sized — asserted against the lowered HLO/jaxpr, with the
-    composed build proving the detectors actually fire."""
+def _scan_carries(fn, *args):
+    """The shapes of every scan carry anywhere in the jaxpr."""
+    shapes = []
 
-    def test_no_d_sized_movement_and_small_carry(self):
-        steps_c, ps, ss, cstates, d = _build(False)
+    def visit(eqn, in_scan):
+        if eqn.primitive.name == "scan":
+            inner = eqn.params["jaxpr"].jaxpr
+            nc = eqn.params["num_carry"]
+            ncons = eqn.params["num_consts"]
+            shapes.extend(tuple(v.aval.shape) for v in
+                          inner.invars[ncons:ncons + nc])
+
+    _walk_eqns(fn, args, visit)
+    return shapes
+
+
+def _max_scan_carry(fn, *args):
+    """Largest scan-carry aval (elements) anywhere in the jaxpr."""
+    return max([int(np.prod(s)) for s in _scan_carries(fn, *args)] + [0])
+
+
+class TestLeafGroupStructure:
+    """Acceptance criterion: the jitted leaf-group client phase contains
+    no d-sized concatenate/pad/reshape — in the scan body or anywhere —
+    and its scan carries a tree of leaf shapes; asserted against the
+    lowered HLO/jaxpr, with the flat build proving the detectors fire."""
+
+    def test_no_d_sized_movement_and_tree_carry(self):
+        kw = dict(micro=2, wd=5e-4)  # a real scan: two steps
+        steps_c, ps, ss, cstates, d = _build(False, **kw)
         args_c = (ps, cstates, {}, _batch(0), 0.1, jax.random.key(0))
-        composed_hits = _big_movement_ops(_client_hlo(steps_c, ps, cstates),
-                                          d)
-        assert composed_hits, \
-            "detector is vacuous: composed build shows no d-sized movement"
-        composed_carry = _max_scan_carry(steps_c.client_step, *args_c)
-        assert composed_carry >= d, \
-            f"composed carry {composed_carry} should be d-sized (d={d})"
+        flat_hits = _big_movement_ops(_client_hlo(steps_c, ps, cstates), d)
+        assert flat_hits, \
+            "detector is vacuous: flat build shows no d-sized movement"
+        flat_carry = _max_scan_carry(steps_c.client_step, *args_c)
+        assert flat_carry >= d, \
+            f"flat carry {flat_carry} should be d-sized (d={d})"
 
-        steps_s, ps_s, ss_s, cstates_s, _ = _build(True)
-        stream_hits = _big_movement_ops(
+        steps_s, ps_s, ss_s, cstates_s, _ = _build(None, **kw)
+        leaf_hits = _big_movement_ops(
             _client_hlo(steps_s, ps_s, cstates_s), d)
-        assert not stream_hits, \
-            f"streaming client phase has d-sized movement ops: {stream_hits}"
-        carry = _max_scan_carry(
+        assert not leaf_hits, \
+            f"leaf-group client phase has d-sized movement ops: {leaf_hits}"
+        carries = _scan_carries(
             steps_s.client_step, ps_s, cstates_s, {}, _batch(0), 0.1,
             jax.random.key(0))
-        cs_geo = make_sketch(d, 16, 3, seed=0, num_blocks=1)
-        table_elems = int(np.prod(cs_geo.table_shape))
-        assert carry <= max(table_elems, 8 * N * 4), \
-            f"streaming scan carry {carry} is not table-sized " \
-            f"(table={table_elems}, d={d})"
-        assert carry < d
+        leaf_shapes = [tuple(x.shape) for x in
+                       jax.tree_util.tree_leaves(_mlp_params())]
+        # the gradient accumulator is the parameter tree, leaf for leaf
+        for shp in leaf_shapes:
+            assert shp in carries, (shp, carries)
+        biggest = max(int(np.prod(s)) for s in carries)
+        assert biggest == max(int(np.prod(s)) for s in leaf_shapes) < d
 
 
-# ---- CLI e2e: the entrypoint path, composed vs streaming ----------------
+# ---- CLI e2e: the entrypoint path, both routes --------------------------
 
 class TestCLIEndToEnd:
-    def test_cv_train_stream_matches_composed(self, tmp_path, monkeypatch):
-        """--stream_sketch through the real cv_train CLI reproduces the
-        composed run's epoch summary EXACTLY (wd=0 + whole-batch
-        microbatching = the documented bit-identity window; the summary's
-        loss/acc means are pure functions of the round trajectory)."""
+    def test_cv_train_leaf_groups_match_flat(self, tmp_path, monkeypatch):
+        """The real cv_train CLI at its default weight decay and two scan
+        steps reproduces the flat route's epoch summary EXACTLY (the
+        summary's loss/acc means are pure functions of the round
+        trajectory). The flat side is pinned from the test: the program
+        has no option for it."""
         import cv_train
+        from commefficient_tpu.federated import aggregator
 
         monkeypatch.setenv("COMMEFFICIENT_TINY_MODEL", "1")
         monkeypatch.setenv("COMMEFFICIENT_SYNTHETIC_PER_CLASS", "24")
+        seen = []
+        build = aggregator.build_round_step
 
-        def run(extra, subdir):
+        def spy(*a, **kw):
+            steps = build(*a, **kw)
+            seen.append(steps.client_sketch_path)
+            return steps
+
+        monkeypatch.setattr(aggregator, "build_round_step", spy)
+
+        def run(subdir):
             argv = [
                 "--dataset_name", "CIFAR10",
                 "--dataset_dir", str(tmp_path / subdir),
                 "--num_epochs", "1",
                 "--num_workers", "2",
                 "--local_batch_size", "4",
+                "--microbatch_size", "2",
                 "--valid_batch_size", "8",
                 "--lr_scale", "0.01",
                 "--pivot_epoch", "0.5",
@@ -424,26 +633,30 @@ class TestCLIEndToEnd:
                 "--iid", "--num_clients", "4",
                 "--mode", "sketch", "--error_type", "virtual",
                 "--local_momentum", "0", "--virtual_momentum", "0.9",
-                "--weight_decay", "0",
                 "--k", "500", "--num_cols", "2048", "--num_rows", "3",
                 "--num_blocks", "2",
-            ] + extra
+            ]
             return cv_train.main(argv)
 
-        a = run([], "a")
-        b = run(["--stream_sketch"], "a")  # same synthetic data dir
+        b = run("a")
+        with monkeypatch.context() as m:
+            m.setattr(aggregator, "RoundConfig", functools.partial(
+                aggregator.RoundConfig, sketch_leaf_groups=False))
+            a = run("a")  # same synthetic data dir
+        assert seen == ["leaf_groups", "flat"]
         for key in ("train_loss", "train_acc", "test_loss", "test_acc"):
             assert a[key] == b[key], \
-                f"{key}: composed {a[key]!r} != streaming {b[key]!r}"
+                f"{key}: flat {a[key]!r} != leaf groups {b[key]!r}"
 
 
-# ---- engine invariant: streaming adds no host syncs ---------------------
+# ---- engine invariant: the route adds no host syncs ---------------------
 
-class TestStreamNoHostSyncs:
+class TestLeafGroupNoHostSyncs:
     def test_dispatch_loop_zero_syncs(self):
         from commefficient_tpu.profiling import host_sync_monitor
 
-        steps, ps, ss, cstates, _ = _build(True)
+        steps, ps, ss, cstates, _ = _build()
+        assert steps.client_sketch_path == "leaf_groups"
         out = steps.train_step(ps, ss, cstates, {}, _batch(0), 0.1,
                                jax.random.key(0))
         jax.block_until_ready(out[0])
@@ -455,4 +668,4 @@ class TestStreamNoHostSyncs:
                 state = out[:4]
         jax.block_until_ready(state[0])
         assert counter.count == 0, \
-            f"streaming round dispatched {counter.count} blocking fetches"
+            f"leaf-group round dispatched {counter.count} blocking fetches"

@@ -119,6 +119,14 @@ def test_resnet9_sketched_round_compiles_for_v5e(kernels_on, n_devices,
 
     traced, compiled = _compile_tpu(steps.client_step, ps, client_states, {},
                                     batch, lr, rng)
+    # the client's one sketch pass: a Mosaic accumulate call per group of
+    # the leaf plan (docs/stream_sketch.md), and no zero-init sketch call
+    assert steps.client_sketch_path == "leaf_groups"
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert sum("fed_sketch_accum" in ln for ln in calls) \
+        == steps.client_sketch_launches == 5, calls
+    assert not any("fed_sketch_vec" in ln for ln in calls)
     # the server phase consumes the client phase's outputs where the
     # compiler left them
     ctx = jax.tree_util.tree_map(

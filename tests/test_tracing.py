@@ -181,6 +181,47 @@ class TestDeviceStages:
         assert back and all(_stages_in(n) == ["fed_client_grad"]
                             for n in back), back[:3]
 
+    def test_leaf_group_sketch_lies_under_fed_client_compress(self,
+                                                              monkeypatch):
+        """Sketch mode's client phase (docs/stream_sketch.md): every
+        operation of the groups' staging (cast, weight decay, concatenate,
+        pad) and of every ``fed_sketch_accum`` launch carries
+        ``fed_client_compress`` — ``compress_ms`` reads them — and the
+        route leaves no operation without a stage that the flat route's
+        program does not leave too."""
+        import functools
+
+        from commefficient_tpu.federated import aggregator
+
+        monkeypatch.setenv("COMMEFFICIENT_PALLAS_SKETCH", "interpret")
+
+        def op_names(want):
+            fm, _ = _model("sketch", weight_decay=5e-4, microbatch_size=1)
+            assert fm.steps.client_sketch_path == want
+            batch = {k: jnp.asarray(v)
+                     for k, v in _host_batch([0, 1], 0).items()}
+            text = fm.steps.client_step.lower(
+                fm.ps_weights, fm.client_states, fm._model_state, batch,
+                0.5, jax.random.key(0)).compile().as_text()
+            return set(re.findall(r'op_name="([^"]*)"', text))
+
+        leaf = op_names("leaf_groups")
+        with monkeypatch.context() as m:
+            m.setattr(aggregator, "RoundConfig", functools.partial(
+                aggregator.RoundConfig, sketch_leaf_groups=False))
+            flat = op_names("flat")
+        kernel = [n for n in leaf if "fed_sketch_accum" in n]
+        staging = [n for n in leaf if "fed_sketch_accum" not in n
+                   and re.search(r"/(pad|concatenate|convert_element_type)$",
+                                 n) and "fed_client_grad" not in n]
+        assert kernel and any(n.endswith("/pad") for n in staging)
+        for n in kernel + staging:
+            assert _stages_in(n) == ["fed_client_compress"], n
+        assert not any("fed_sketch_accum" in n for n in flat)
+        bare = {n for n in leaf if not _stages_in(n)}
+        assert bare <= {n for n in flat if not _stages_in(n)}, \
+            sorted(bare - flat)
+
     def test_accounting_programs_are_scoped(self):
         from commefficient_tpu.federated import aggregator as agg
 
@@ -293,7 +334,7 @@ class TestKernelNames:
         calls = {
             "fed_sketch_vec": lambda: sk._sketch_vec_pallas(v3, *hashes,
                                                             **kw),
-            "fed_sketch_accum": lambda: sk._sketch_accum_pallas(
+            "fed_sketch_accum": lambda: sk._sketch_segments_pallas(
                 tbl3, v3, *hashes, **kw),
             "fed_estimates": lambda: sk._estimates_pallas(
                 sk._doubled_table(cs, jnp.zeros(cs.table_shape)), *hashes,
@@ -340,6 +381,37 @@ def _engine(tmp_path, mode="sketch", window=2, drain_every=4, tracer=None):
     engine = PipelinedRoundEngine(fm, opt, LambdaLR(opt, lambda step: 0.5),
                                   window=window, drain_every=drain_every)
     return fm, engine, rt
+
+
+class TestStartEvent:
+    @pytest.mark.parametrize("mode,over,want", [
+        ("sketch", {}, ("leaf_groups", 1)),
+        ("sketch", dict(microbatch_size=1, weight_decay=5e-4),
+         ("leaf_groups", 1)),
+        ("sketch", dict(error_type="local", virtual_momentum=0.0),
+         ("flat", 0)),
+        ("true_topk", {}, None),
+        ("uncompressed", {}, None)],
+        ids=["sketch", "sketch-scan2-wd", "sketch-per_client_state",
+             "true_topk", "uncompressed"])
+    def test_start_event_says_the_client_sketch_route(self, tmp_path, mode,
+                                                      over, want):
+        """``client_sketch_path`` / ``client_sketch_launches`` (docs/
+        observability.md §Names): sketch mode's run header says which
+        route the gradient takes to the table and how many accumulate
+        launches its group plan makes a round (TinyModel's one leaf: one);
+        a mode with no client sketch has neither."""
+        args = _args(**{**MODES[mode], **over})
+        fm = FedModel(TinyModel(), _loss, args, input_shape=(3,))
+        attach_run_telemetry(args, fm, str(tmp_path), "test").close()
+        start = next(read_events(str(tmp_path / "telemetry.jsonl")))
+        assert start["ev"] == "run_start"
+        got = (start.get("client_sketch_path"),
+               start.get("client_sketch_launches"))
+        assert got == (want or (None, None))
+        if want:
+            assert got == (fm.steps.client_sketch_path,
+                           fm.steps.client_sketch_launches)
 
 
 def _counts(before):
