@@ -216,21 +216,6 @@ def phase_train(ns) -> dict:
 
     import cv_train
 
-    compiled = {"s": 0.0, "hits": 0, "writes": 0}
-
-    def on_duration(event, duration_secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            compiled["s"] += duration_secs
-
-    def on_event(event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            compiled["hits"] += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            compiled["writes"] += 1  # recorded when an entry is WRITTEN
-
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-    jax.monitoring.register_event_listener(on_event)
-
     seen = {}
 
     class ObservedFedModel(cv_train.FedModel):
@@ -306,6 +291,15 @@ def phase_train(ns) -> dict:
          f"test_acc={summary.get('test_acc')}")
     _say(f"{ns.phase}: mesh {mesh_axes}; client-phase output on "
          f"{len(loss_devices)} device(s); peak_bytes_in_use {peaks or 'n/a'}")
+    # the program's own record of what it built (profiling.py's listener,
+    # which cv_train.main registered): backend seconds, the persistent
+    # cache's hits, the entries written to it
+    from commefficient_tpu.profiling import program_totals
+
+    built = program_totals().values()
+    compiled = {"s": sum(t["backend_s"] for t in built),
+                "hits": sum(t["hits"] for t in built),
+                "writes": sum(t["stored"] for t in built)}
     _say(f"{ns.phase}: backend compile {compiled['s']:.1f} s, persistent "
          f"cache hits {compiled['hits']}, entries written "
          f"{compiled['writes']}")

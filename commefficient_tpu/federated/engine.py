@@ -47,7 +47,7 @@ import numpy as np
 
 import jax
 
-from commefficient_tpu.profiling import Heartbeat, annotate
+from commefficient_tpu.profiling import Heartbeat, annotate, memory_sample
 
 __all__ = ["RoundResult", "PipelinedRoundEngine", "cohort_lookahead"]
 
@@ -222,14 +222,12 @@ class PipelinedRoundEngine:
         """Materialize every dispatched-but-unfetched round, oldest first —
         the batched host sync. Safe to call with nothing pending."""
         results = []
-        drain_ms = 0.0
         while self._pending:
             idx, handle = self._pending.popleft()
             rn = self._round_no(handle, idx)
             with annotate("fed_drain", round=rn) as drain_span:
                 results.append(RoundResult(idx,
                                            self.model.finish_round(handle)))
-            drain_ms += drain_span.ms
             if self.heartbeat.enabled:
                 # minimal live monitor even with telemetry off: the
                 # drained round's mean loss + guard verdict ride the
@@ -276,8 +274,16 @@ class PipelinedRoundEngine:
         if results:
             self.drains += 1
             if self.telemetry is not None:
-                self.telemetry.event("drain", rounds=len(results),
-                                     ms=round(drain_ms, 3))
+                # the device's memory once a drain (a host call into the
+                # runtime, no fetch), under a span of its own so that its
+                # cost shows in run_end.spans; every drained round's
+                # computation is complete here, so with nothing in flight
+                # ``bytes_in_use`` is the run at rest
+                with annotate("fed_memory_sample", round=rn):
+                    memory = memory_sample("drain")
+                self.telemetry.event("drain", round=rn, rounds=len(results),
+                                     inflight=len(self._pending),
+                                     memory=memory)
         return results
 
     def close(self) -> List[RoundResult]:

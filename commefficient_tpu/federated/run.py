@@ -3,6 +3,9 @@ each has built its model, its loaders and its ``FedModel``.
 
 - ``attach_planes``: the ops planes' wiring (participation, churn, the
   telemetry recorder, ``--resume``), in the order both entry points need;
+- ``finish_setup``: the phase table printed, the ``setup`` event and the
+  programs built so far written (profiling.py's record of start-up);
+- ``val_pass``: the span and the ``val`` event around a validation pass;
 - ``run_rounds``: one training epoch's round loop over a
   ``PipelinedRoundEngine`` the caller constructed — dispatch, batched metric
   drains, the ``--checkpoint_every_rounds`` and watch-forced saves;
@@ -20,8 +23,10 @@ its OWN module (``cv_train.PipelinedRoundEngine``, ``gpt2_train.FedModel``,
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable, NamedTuple, Optional
 
+from commefficient_tpu import profiling
 from commefficient_tpu.federated.checkpoint import (
     resume_run,
     save_round_state,
@@ -67,6 +72,33 @@ def attach_planes(args, fed_model, opt, lr_scheduler, train_loader, log_dir,
         rt.event("resume", start_epoch=start_epoch,
                  mid_epoch=resume_mid is not None)
     return Planes(fed_model, pc, pm, rt), start_epoch, totals, resume_mid
+
+
+def finish_setup(planes: Optional[Planes]) -> None:
+    """The entry point's set-up is done (its last ``profiling.phase`` has
+    closed): print the phases and hand them to the event log, which from
+    here on also writes every program built (``RunTelemetry.setup``).
+    ``planes`` is None where the run attached none (gpt2_train's eval-only
+    ``--finetune``)."""
+    print(profiling.phase_table(), flush=True)
+    if planes is not None and planes.telemetry is not None:
+        planes.telemetry.setup(profiling.PHASES, profiling.PROCESS_START_T)
+
+
+@contextlib.contextmanager
+def val_pass(model):
+    """Around one validation pass (``run_batches(training=False)``'s loop,
+    whose every batch is fetched, so the device is done when it ends):
+    the span ``fed_val_pass`` and a ``val`` event with its seconds and the
+    device's memory before and after."""
+    rt = getattr(model, "telemetry", None)
+    before = profiling.memory_sample("val_start") if rt is not None else None
+    with profiling.annotate("fed_val_pass") as span:
+        yield
+    if rt is not None:
+        rt.event("val", round=getattr(model, "rounds_dispatched", None),
+                 seconds=round(span.ms / 1e3, 4), memory_start=before,
+                 memory_end=profiling.memory_sample("val_end"))
 
 
 def run_rounds(engine, loader, args, *, epoch: int, i0: int, spe: int,

@@ -18,6 +18,16 @@ the jitted round and its Pallas kernels carry on the device
 (``jax.named_scope`` / ``pallas_call(name=)``); docs/observability.md lists
 every name with its reader.
 
+``phase`` / ``PHASES``, ``program_totals`` and ``memory_sample`` are the
+run's record of itself outside the round loop, built on ``annotate`` and
+``SPAN_TOTALS``: the stretches of start-up (``fed_setup_<name>`` spans, one
+ordered entry each), every program jax traced, lowered and compiled or
+loaded, by name (one set of ``jax.monitoring`` listeners,
+``install_program_listener``), and the device's memory at the end of a
+phase, of a batched drain, around a validation pass and at the run's end.
+The event log writes them (``setup`` / ``program`` / ``drain.memory`` /
+``val`` / ``run_end``), ``scripts/obs_report.py`` prints them.
+
 ``host_sync_monitor`` is the pipelined round engine's audit hook
 (federated/engine.py, docs/round_engine.md): it counts blocking
 device→host materializations so the steady-state zero-syncs-per-round
@@ -36,6 +46,7 @@ backends, where it turns ANY device→host transfer into a hard error.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import os
 import re
@@ -45,7 +56,10 @@ import time
 
 import jax
 
-__all__ = ["annotate", "SPAN_TOTALS", "span_totals", "DEVICE_STAGES",
+__all__ = ["annotate", "SPAN_TOTALS", "span_totals", "phase", "PHASES",
+           "begin_setup", "phase_table", "install_program_listener",
+           "program_totals", "program_summary", "subscribe_programs",
+           "unsubscribe_programs", "memory_sample", "DEVICE_STAGES",
            "INNER_SCOPES", "KERNEL_NAMES", "SyncCounter", "host_sync_monitor",
            "materialize", "offpath_fetches", "Heartbeat", "RoundTracer",
            "parse_trace_rounds", "HEARTBEAT_RE", "parse_heartbeat"]
@@ -337,6 +351,13 @@ SPAN_TOTALS: dict = {}
 _span_lock = threading.Lock()
 
 
+def _count_span(name: str, ns: int) -> None:
+    with _span_lock:
+        tot = SPAN_TOTALS.setdefault(name, [0, 0])
+        tot[0] += 1
+        tot[1] += ns
+
+
 class annotate:
     """THE way to open a host span: ``with annotate("fed_h2d", round=n):``.
 
@@ -363,10 +384,7 @@ class annotate:
     def __exit__(self, *exc):
         self.end_ns = time.perf_counter_ns()
         self._ann.__exit__(*exc)
-        with _span_lock:
-            tot = SPAN_TOTALS.setdefault(self.name, [0, 0])
-            tot[0] += 1
-            tot[1] += self.end_ns - self.start_ns
+        _count_span(self.name, self.end_ns - self.start_ns)
         return False
 
     @property
@@ -380,6 +398,360 @@ def span_totals() -> dict:
     with _span_lock:
         return {name: {"count": c, "ms": round(ns / 1e6, 3)}
                 for name, (c, ns) in sorted(SPAN_TOTALS.items())}
+
+
+# ---------------------------------------------------------------------------
+# the run's record of itself outside the round loop: device memory, the
+# programs built, the phases of start-up (docs/observability.md §Names)
+# ---------------------------------------------------------------------------
+
+MEMORY_FIELDS = ("bytes_in_use", "peak_bytes_in_use", "bytes_reserved",
+                 "peak_bytes_reserved", "largest_free_block_bytes",
+                 "num_allocs")
+
+
+def _local_devices():
+    # never the call that initialises a backend: a sample taken before the
+    # process has touched its devices has nothing to read
+    from jax._src import xla_bridge
+
+    return jax.local_devices() if xla_bridge.backends_are_initialized() \
+        else []
+
+
+def memory_sample(at: str):
+    """``memory_stats()`` of the fullest local device, reduced to
+    ``MEMORY_FIELDS`` and labelled ``at``; ``None`` where the backend gives
+    none (the CPU). A host call into the runtime's allocator: no
+    device-to-host fetch, nothing the sync audit counts. The two peaks are
+    the process's, never reset: a sample says how high each had risen by
+    then."""
+    best = None
+    for dev in _local_devices():
+        stats = dev.memory_stats()
+        if stats and (best is None or stats.get("bytes_in_use", 0)
+                      > best.get("bytes_in_use", 0)):
+            best = stats
+    if best is None:
+        return None
+    out = {"at": at}
+    out.update((k, int(best[k])) for k in MEMORY_FIELDS if k in best)
+    return out
+
+
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_CACHE_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_STORED = "/jax/compilation_cache/cache_misses"  # fires on a WRITE
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_BUILD_SPANS = frozenset((_TRACE, _LOWER, _BACKEND))
+LISTENER_SPAN = "fed_program_listener"
+# the event log gives a build a line of its own from this many seconds in
+# all (telemetry.RunTelemetry); ``run_end.programs`` sums the programs
+# below it under ``other``
+PROGRAM_LOG_S = 0.1
+
+# every build closed in this process, oldest first (bounded: a process
+# that recompiles without end must not grow without end), its totals by
+# name, and who is told of a build as it closes (the event log)
+PROGRAMS: collections.deque = collections.deque(maxlen=4096)
+_PROGRAM_TOTALS: dict = {}
+_program_sinks: list = []
+_built = [0, 0.0]            # builds closed, their seconds in all
+_program_lock = threading.Lock()
+_building = threading.local()
+_listening = False
+
+
+def _emit_build(b: dict) -> None:
+    b.setdefault("trace_s", 0.0)
+    b.setdefault("lower_s", 0.0)
+    b.setdefault("backend_s", 0.0)
+    b["phase"] = _open_phase[0]
+    b["t"] = time.time()
+    secs = b["trace_s"] + b["lower_s"] + b["backend_s"]
+    with _program_lock:
+        PROGRAMS.append(b)
+        _built[0] += 1
+        _built[1] += secs
+        tot = _PROGRAM_TOTALS.setdefault(b["name"], {
+            "builds": 0, "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+            "load_s": 0.0, "hits": 0, "misses": 0, "stored": 0})
+        tot["builds"] += 1
+        for k in ("trace_s", "lower_s", "backend_s"):
+            tot[k] += b[k]
+        tot["load_s"] += b.get("load_s", 0.0)
+        tot["hits"] += b.get("cache") == "hit"
+        tot["misses"] += b.get("cache") == "miss"
+        tot["stored"] += bool(b.get("stored"))
+        sinks = list(_program_sinks)
+    for sink in sinks:
+        sink(b)
+
+
+def _span_closed(event: str, name: str, secs: float) -> None:
+    """A top-level tracing, lowering or backend span closed on this thread.
+    One build is a trace, then a lowering, then a backend compile (which
+    contains the persistent cache's load); a trace or a lowering that
+    nothing compiled (``eval_shape``, ``.lower()``) is a build of its own
+    with no ``backend_s``."""
+    b = getattr(_building, "build", None)
+    if event == _TRACE:
+        if b is not None:
+            _emit_build(b)
+        _building.build = {"name": name, "trace_s": secs}
+    elif event == _LOWER:
+        if b is not None and "lower_s" in b:
+            _emit_build(b)
+            b = None
+        if b is None:
+            b = _building.build = {}
+        b.update(name=name, lower_s=secs)
+    else:
+        if b is not None and "lower_s" in b and b["name"] != name:
+            _emit_build(b)      # lowered ahead of time, never compiled
+            b = None
+        b = b if b is not None else {}
+        _building.build = None
+        c = _building.cache
+        # (jax asks its cache even where no directory is set)
+        b.update(name=name, backend_s=secs,
+                 cache="hit" if c["hit"] else
+                 "miss" if c["requested"]
+                 and jax.config.jax_compilation_cache_dir else "off")
+        if c["hit"]:
+            b["load_s"] = c["load_s"]
+        if c["stored"]:
+            b["stored"] = True
+        _emit_build(b)
+
+
+def _timed(fn):
+    """The listener's own cost, under ``SPAN_TOTALS[LISTENER_SPAN]`` (no
+    profiler annotation: it runs inside jax's compile path)."""
+    def wrapped(event, *a, **kw):
+        t = time.perf_counter_ns()
+        try:
+            fn(event, *a, **kw)
+        finally:
+            _count_span(LISTENER_SPAN, time.perf_counter_ns() - t)
+    return wrapped
+
+
+def _on_scalar(event, value, **kw):
+    # jax marks the START of a tracing / lowering / backend span with a
+    # scalar of the same name: spans nest (a traced function calls jitted
+    # ones), and only the outermost is a build's
+    if event in _BUILD_SPANS:
+        stack = _building.__dict__.setdefault("stack", [])
+        stack.append(event)
+        if event == _BACKEND:
+            _building.cache = {"requested": False, "hit": False,
+                               "stored": False, "load_s": 0.0}
+
+
+def _on_duration(event, secs, **kw):
+    if event in _BUILD_SPANS:
+        stack = _building.__dict__.get("stack")
+        if not stack:
+            return       # opened before the listener was registered
+        stack.pop()
+        if not stack:
+            _span_closed(event, str(kw.get("fun_name", "?")), float(secs))
+    elif event == _CACHE_LOAD:
+        cache = _building.__dict__.get("cache")
+        if cache is not None:
+            cache["load_s"] += float(secs)
+
+
+def _on_event(event, **kw):
+    cache = _building.__dict__.get("cache")
+    if cache is None:
+        return
+    if event == _CACHE_REQUEST:
+        cache["requested"] = True
+    elif event == _CACHE_HIT:
+        cache["hit"] = True
+    elif event == _CACHE_STORED:
+        cache["stored"] = True
+
+
+def install_program_listener() -> None:
+    """Register the process's one set of ``jax.monitoring`` listeners (jax
+    has no way to take one off again); ``utils.configure_compile_cache``
+    calls it, which every entry point does before its first compile."""
+    global _listening
+    with _program_lock:
+        if _listening:
+            return
+        _listening = True
+    jax.monitoring.register_scalar_listener(_timed(_on_scalar))
+    jax.monitoring.register_event_duration_secs_listener(
+        _timed(_on_duration))
+    jax.monitoring.register_event_listener(_timed(_on_event))
+
+
+def _flush_open_build() -> None:
+    b = getattr(_building, "build", None)
+    if b is not None and not getattr(_building, "stack", None):
+        _building.build = None
+        _emit_build(b)
+
+
+def _rounded(tot: dict) -> dict:
+    return {k: round(v, 4) if isinstance(v, float) else v
+            for k, v in tot.items()}
+
+
+def program_totals() -> dict:
+    """``{name: {"builds", "trace_s", "lower_s", "backend_s", "load_s",
+    "hits", "misses", "stored"}}`` of every program built in this process,
+    like ``span_totals()``. ``backend_s`` contains ``load_s``; ``misses``
+    are builds that asked the persistent cache and compiled, ``stored`` the
+    ones written to it (above jax's floors)."""
+    _flush_open_build()
+    with _program_lock:
+        return {name: _rounded(tot)
+                for name, tot in sorted(_PROGRAM_TOTALS.items())}
+
+
+def program_summary(floor_s: float = PROGRAM_LOG_S) -> dict:
+    """``program_totals()`` for ``run_end``: programs under ``floor_s`` in
+    all that never missed the cache are summed under ``other``."""
+    out, other = {}, None
+    for name, tot in program_totals().items():
+        if (tot["trace_s"] + tot["lower_s"] + tot["backend_s"] >= floor_s
+                or tot["misses"]):
+            out[name] = tot
+            continue
+        if other is None:
+            other = dict.fromkeys(tot, 0)
+        for k, v in tot.items():
+            other[k] += v
+    if other is not None:
+        out["other"] = _rounded(other)
+    return out
+
+
+def subscribe_programs(sink) -> list:
+    """Tell ``sink(build)`` of every build from now on, as it closes (on
+    the thread that built it); returns the builds closed so far, which the
+    caller has to take itself."""
+    _flush_open_build()
+    with _program_lock:
+        _program_sinks.append(sink)
+        return list(PROGRAMS)
+
+
+def unsubscribe_programs(sink) -> None:
+    with _program_lock:
+        if sink in _program_sinks:
+            _program_sinks.remove(sink)
+
+
+def _process_start_epoch():
+    """When the OS started this process, as seconds since the epoch; None
+    where it does not say (no /proc)."""
+    try:
+        with open("/proc/self/stat", "rb") as f:
+            ticks = int(f.read().rsplit(b")", 1)[1].split()[19])
+        age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+               - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return time.time() - age if 0.0 <= age < 30 * 86400 else None
+
+
+# The stretches of this process's life so far, oldest first:
+# ``{"phase", "start_s", "seconds", "programs", "build_s", "memory"}``,
+# ``start_s`` since the process started (since this module was imported,
+# where the OS gives no start time). ``begin_setup`` empties it: a process
+# that runs an entry point twice records each run's own.
+PHASES: list = []
+PROCESS_START_T = _process_start_epoch()
+_ORIGIN_T = PROCESS_START_T if PROCESS_START_T is not None else time.time()
+_open_phase = [None]
+_import_recorded = [False]
+
+
+def begin_setup() -> None:
+    """An entry point's first call once its arguments are parsed and its
+    devices announced: forget an earlier run's phases and builds and, on
+    the process's first run, record ``import``, process start to now, where
+    the OS says when the process started; never guessed."""
+    PHASES.clear()
+    if _import_recorded[0]:
+        # a later run of this process: the builds so far were the earlier
+        # runs' (the totals by name stay the process's)
+        _flush_open_build()
+        with _program_lock:
+            PROGRAMS.clear()
+        return
+    _import_recorded[0] = True
+    if PROCESS_START_T is None:
+        return
+    seconds = time.time() - PROCESS_START_T
+    _count_span("fed_setup_import", int(seconds * 1e9))
+    _record_phase("import", 0.0, seconds, (0, 0.0))
+
+
+def _record_phase(name: str, start_s: float, seconds: float, built) -> None:
+    """One entry of ``PHASES``; ``built`` is ``_built`` as the phase began."""
+    with _program_lock:
+        n, s = _built[0] - built[0], _built[1] - built[1]
+    PHASES.append({"phase": name, "start_s": round(start_s, 3),
+                   "seconds": round(seconds, 3), "programs": n,
+                   "build_s": round(s, 3),
+                   "memory": memory_sample("phase:" + name)})
+
+
+class phase(annotate):
+    """One stretch of start-up: ``with phase("data"):`` is an
+    ``annotate("fed_setup_data")`` span plus one entry of ``PHASES`` with
+    the programs built inside it and the device's memory at its end.
+    Phases follow each other on the main thread; they do not nest."""
+
+    __slots__ = ("phase_name", "_t", "_built")
+
+    def __init__(self, name: str):
+        super().__init__("fed_setup_" + name)
+        self.phase_name = name
+
+    def __enter__(self):
+        assert _open_phase[0] is None, \
+            f"phase {self.phase_name!r} inside {_open_phase[0]!r}"
+        _open_phase[0] = self.phase_name
+        self._t = time.time()
+        with _program_lock:
+            self._built = tuple(_built)
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        _flush_open_build()
+        _open_phase[0] = None
+        _record_phase(self.phase_name, self._t - _ORIGIN_T, self.ms / 1e3,
+                      self._built)
+        return False
+
+
+def phase_table() -> str:
+    """``PHASES`` as the table both entry points print once set-up is
+    done."""
+    rows = [f"{'phase':<10}{'start_s':>9}{'seconds':>9}{'programs':>9}"
+            f"{'build_s':>9}{'hbm_in_use_gib':>16}"]
+    for ph in PHASES:
+        mem = ph.get("memory")
+        rows.append(
+            f"{ph['phase']:<10}{ph['start_s']:>9.2f}{ph['seconds']:>9.2f}"
+            f"{ph['programs']:>9d}{ph['build_s']:>9.2f}"
+            + (f"{mem['bytes_in_use'] / 2**30:>16.3f}" if mem
+               else f"{'-':>16}"))
+    total = sum(ph["seconds"] for ph in PHASES)
+    return (f"set-up: {total:.2f} s in {len(PHASES)} phases "
+            "(docs/observability.md)\n" + "\n".join(rows))
 
 
 class SyncCounter:

@@ -41,8 +41,11 @@ separated layers:
    whose profiler was never on.
 
 3. **The JSONL event log**: one line per drained round (spans + metrics +
-   loss + guard verdict), plus immediate lines for run_start / guard_trip
-   / rollback / guard_fatal / checkpoint / epoch / drain / run_end.
+   loss + guard verdict), plus immediate lines for run_start / setup /
+   program / val / guard_trip / rollback / guard_fatal / checkpoint /
+   epoch / drain / run_end (``setup``, ``program``, ``val`` and the
+   memory samples of ``drain`` and ``run_end`` are the run's record of its
+   own start-up and device memory, profiling.py).
    ``scripts/obs_report.py`` renders a run summary (timeline, compression
    ledger, guard/rollback history) and a machine-readable tail from the
    log alone.
@@ -71,6 +74,7 @@ from __future__ import annotations
 import json
 import math
 import os
+import threading
 import time
 from typing import (
     Any, Dict, Iterator, List, NamedTuple, Optional, Sequence, Tuple,
@@ -79,7 +83,16 @@ from typing import (
 import jax
 import jax.numpy as jnp
 
-from commefficient_tpu.profiling import SPAN_TOTALS, annotate, span_totals
+from commefficient_tpu.profiling import (
+    PROGRAM_LOG_S,
+    SPAN_TOTALS,
+    annotate,
+    memory_sample,
+    program_summary,
+    span_totals,
+    subscribe_programs,
+    unsubscribe_programs,
+)
 
 __all__ = [
     "METRIC_FIELDS",
@@ -759,6 +772,13 @@ class RunTelemetry:
         self.rounds = 0
         self.events = 0
         self._closed = False
+        # program builds arrive on whatever thread built them
+        # (profiling.subscribe_programs, from ``setup`` on)
+        self._write_lock = threading.RLock()
+        self._small_builds: Optional[Dict[str, Any]] = None
+        self._builds_sink = None
+        self._dispatched = 0      # rounds dispatched so far: a build's
+        # ``round``
         # the watch/alert rule engine, when attached
         # (attach_run_telemetry): evaluated over each drained round record
         # in on_drained — host arithmetic on already-materialized values,
@@ -780,10 +800,75 @@ class RunTelemetry:
         with annotate("fed_telemetry_host", round=fields.get("round", -1)):
             rec = {"ev": ev, "t": time.time()}
             rec.update(fields)
-            self._f.write(json.dumps(_json_safe(rec), allow_nan=False)
-                          + "\n")
+            self._write(rec)
+
+    def _emit(self, rec: Dict[str, Any]) -> None:
+        self._f.write(json.dumps(_json_safe(rec), allow_nan=False) + "\n")
+        self.events += 1
+
+    def _flush_small_builds(self) -> None:
+        small, self._small_builds = self._small_builds, None
+        if small is not None:
+            self._emit(small)
+
+    def _write(self, rec: Dict[str, Any]) -> None:
+        with self._write_lock:
+            # first the builds too small for a line of their own since the
+            # last line, summed: the log's sums stay whole
+            self._flush_small_builds()
+            self._emit(rec)
             self._f.flush()
-            self.events += 1
+
+    # -- start-up and the programs built (profiling.py) --------------------
+
+    def setup(self, phases: Sequence[dict], t0: Optional[float]) -> None:
+        """The entry point's set-up is done: write its phases once
+        (``[{phase, start_s, seconds, programs, build_s, memory}]``,
+        ``start_s`` since the process started at ``t0``; ``t0`` None where
+        the OS gave no start time, ``start_s`` then counts from the import
+        of profiling.py), then every program built so far, and from here
+        on each build as it closes."""
+        self.event("setup", t0=t0, phases=list(phases))
+        if self._builds_sink is None:
+            self._builds_sink = self._on_build
+            for build in subscribe_programs(self._builds_sink):
+                self._on_build(build, past=True)
+
+    def _on_build(self, build: dict, past: bool = False) -> None:
+        """One program traced, lowered, compiled or loaded. A line of its
+        own (``program``) where it took ``PROGRAM_LOG_S`` in all or was
+        written to the persistent cache (a cold compile the next start is
+        spared); the small ones are summed and written before the next
+        line of any kind. ``round``: the rounds dispatched by then, so the
+        round whose dispatch built it (a build past the first drain is a
+        program built in steady state); None for the builds of set-up."""
+        if self._closed:
+            return
+        secs = build["trace_s"] + build["lower_s"] + build["backend_s"]
+        rnd = None if past else self._dispatched
+        if secs >= PROGRAM_LOG_S or build.get("stored"):
+            rec = {"ev": "program", "round": rnd}
+            rec.update(build)
+            for key in ("trace_s", "lower_s", "backend_s", "load_s"):
+                if key in rec:
+                    rec[key] = round(rec[key], 4)
+            self._write(rec)
+            return
+        with self._write_lock:
+            acc = self._small_builds
+            if acc is None or acc["phase"] != build["phase"]:
+                acc = {"ev": "program", "name": "other", "round": rnd,
+                       "phase": build["phase"], "builds": 0, "trace_s": 0.0,
+                       "lower_s": 0.0, "backend_s": 0.0, "hits": 0,
+                       "misses": 0}
+                self._flush_small_builds()   # another phase's sum
+                self._small_builds = acc
+            acc["t"] = build["t"]
+            acc["builds"] += 1
+            acc["hits"] += build.get("cache") == "hit"
+            acc["misses"] += build.get("cache") == "miss"
+            for key in ("trace_s", "lower_s", "backend_s"):
+                acc[key] = round(acc[key] + build[key], 4)
 
     # -- round-lifecycle spans (buffered; written at drain) ----------------
 
@@ -806,6 +891,7 @@ class RunTelemetry:
         ``input_wait_ms`` the ``fed_input_wait`` time since the previous
         dispatch: what the loop waited for this round's batch (a
         validation pass's waits land on the round after it)."""
+        self._dispatched = round_no + 1
         with annotate("fed_telemetry_host", round=round_no):
             self._spans[round_no] = {
                 "t_wall": time.time(),
@@ -889,10 +975,8 @@ class RunTelemetry:
                     "metrics"):
             if key in buf:
                 rec[key] = buf[key]
-        self._f.write(json.dumps(_json_safe(rec), allow_nan=False) + "\n")
-        self._f.flush()
+        self._write(rec)
         self.rounds += 1
-        self.events += 1
         if self.watch is not None:
             # the watch plane evaluates AFTER the round line lands, so its
             # watch_alert events follow the round they describe in the log
@@ -918,10 +1002,14 @@ class RunTelemetry:
                     rec[key] = span[key]
             self.event("round_partial", **rec)
         self._spans.clear()
+        if self._builds_sink is not None:
+            unsubscribe_programs(self._builds_sink)
         # every program span of the process, by name: the host's side of
-        # the run with the profiler off (scripts/obs_report.py prints it)
+        # the run with the profiler off (scripts/obs_report.py prints it);
+        # every program built, by name; the device's memory at the end
         self.event("run_end", rounds=self.rounds, spans=span_totals(),
-                   **totals)
+                   programs=program_summary(),
+                   memory=memory_sample("run_end"), **totals)
         self._closed = True
         self._f.close()
 

@@ -57,9 +57,16 @@ def configure_compile_cache() -> str:
     a build without a scope then serves that build's executable to one
     with it, and a capture shows the old names (seen on the v5e, PERF.md
     PR 26: the accounting programs came back without ``fed_accounting``).
-    The price is that a moved line recompiles what it touches."""
+    The price is that a moved line recompiles what it touches.
+
+    Also registers the process's one set of ``jax.monitoring`` listeners
+    (``profiling.install_program_listener``): every program traced,
+    lowered, compiled or loaded from here on is recorded by name."""
     import jax
 
+    from commefficient_tpu.profiling import install_program_listener
+
+    install_program_listener()
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     if "JAX_COMPILATION_CACHE_DIR" in os.environ:
         return os.environ["JAX_COMPILATION_CACHE_DIR"]

@@ -46,10 +46,13 @@ from commefficient_tpu.federated.losses import make_cv_losses
 from commefficient_tpu.federated.run import (
     attach_planes,
     close_run,
+    finish_setup,
     population_emptied,
     run_rounds,
+    val_pass,
 )
 from commefficient_tpu.ops.flat import ravel_pytree
+from commefficient_tpu.profiling import begin_setup, phase
 from commefficient_tpu.utils import (
     PiecewiseLinear,
     TableLogger,
@@ -153,12 +156,13 @@ def run_batches(model, opt, lr_scheduler, loader, training, epoch_fraction,
             return None, None, client_download, client_upload
         return (np.mean(losses), np.mean(accs), client_download,
                 client_upload)
-    for batch in loader:
-        loss, acc = model(batch)
-        losses.extend(loss.tolist())
-        accs.extend(acc.tolist())
-        if args.do_test:
-            break
+    with val_pass(model):
+        for batch in loader:
+            loss, acc = model(batch)
+            losses.extend(loss.tolist())
+            accs.extend(acc.tolist())
+            if args.do_test:
+                break
     return np.mean(losses), np.mean(accs), None, None
 
 
@@ -328,6 +332,9 @@ def main(argv=None):
     args = parse_args(argv=argv)
     configure_compile_cache()
     announce_devices()
+    # start-up's phases (profiling.py): `import` ends here, process start
+    # to devices announced
+    begin_setup()
     assert args.model_devices == 1, (
         "--model_devices (tensor parallelism) is GPT-2 only; the CV models "
         "have no model axis — use gpt2_train.py")
@@ -343,59 +350,67 @@ def main(argv=None):
     timer = Timer()
     np.random.seed(args.seed)
 
-    model, input_shape = build_model_and_config(args)
-    train_loader, test_loader = get_data_loaders(args)
+    with phase("data"):
+        train_loader, test_loader = get_data_loaders(args)
 
-    has_bn = args.do_batchnorm and hasattr(model, "do_batchnorm")
-    compute_loss_train, compute_loss_val = make_cv_losses(
-        model, has_batch_stats=has_bn,
-        compute_dtype=jnp.bfloat16 if args.do_bf16 else None)
+    with phase("model"):
+        model, input_shape = build_model_and_config(args)
+        has_bn = args.do_batchnorm and hasattr(model, "do_batchnorm")
+        compute_loss_train, compute_loss_val = make_cv_losses(
+            model, has_batch_stats=has_bn,
+            compute_dtype=jnp.bfloat16 if args.do_bf16 else None)
 
-    init_params = None
-    model_state = None
-    if args.do_finetune:
-        x = jnp.zeros((1,) + input_shape, jnp.float32)
-        variables = model.init(jax.random.key(args.seed), x, train=False)
-        ckpt_params, ckpt_state = load_checkpoint(
-            os.path.join(args.finetune_path, args.model))
-        init_params, loaded, skipped = load_matching(variables["params"],
-                                                     ckpt_params)
-        print(f"finetune: loaded {loaded} tensors, fresh: {skipped}")
-        model_state = variables.get("batch_stats", {})
+        init_params = None
+        model_state = None
+        if args.do_finetune:
+            x = jnp.zeros((1,) + input_shape, jnp.float32)
+            variables = model.init(jax.random.key(args.seed), x,
+                                   train=False)
+            ckpt_params, ckpt_state = load_checkpoint(
+                os.path.join(args.finetune_path, args.model))
+            init_params, loaded, skipped = load_matching(
+                variables["params"], ckpt_params)
+            print(f"finetune: loaded {loaded} tensors, fresh: {skipped}")
+            model_state = variables.get("batch_stats", {})
 
-    fed_model = FedModel(model, compute_loss_train, args, compute_loss_val,
-                         input_shape=input_shape,
-                         num_clients=train_loader.dataset.num_clients,
-                         init_params=init_params, model_state=model_state)
-    param_groups = build_param_groups(args, fed_model.params)
-    opt = FedOptimizer(fed_model, args, param_groups=param_groups)
+    with phase("fed"):
+        fed_model = FedModel(model, compute_loss_train, args,
+                             compute_loss_val, input_shape=input_shape,
+                             num_clients=train_loader.dataset.num_clients,
+                             init_params=init_params,
+                             model_state=model_state)
+        param_groups = build_param_groups(args, fed_model.params)
+        opt = FedOptimizer(fed_model, args, param_groups=param_groups)
 
-    lr_schedule = PiecewiseLinear([0, args.pivot_epoch, args.num_epochs],
-                                  [0, args.lr_scale, 0])
-    spe = train_loader.steps_per_epoch()
-    lr_scheduler = LambdaLR(opt, lr_lambda=lambda step: lr_schedule(step / spe))
+    with phase("planes"):
+        lr_schedule = PiecewiseLinear(
+            [0, args.pivot_epoch, args.num_epochs], [0, args.lr_scale, 0])
+        spe = train_loader.steps_per_epoch()
+        lr_scheduler = LambdaLR(
+            opt, lr_lambda=lambda step: lr_schedule(step / spe))
 
-    log_dir = make_logdir(args)
-    if os.environ.get("COMMEFFICIENT_RUN_DIR"):
-        # orchestrated tenant (scripts/orchestrate.py, docs/packing.md):
-        # the run dir — and with it telemetry.jsonl + trace_round_*
-        # captures — is pinned per tenant so fleet neighbors never
-        # collide
-        print(f"run dir pinned by orchestrator: {log_dir} "
-              f"(tenant {os.environ.get('COMMEFFICIENT_TENANT_ID', '?')})",
-              flush=True)
-    writer = None
-    if args.use_tensorboard:
-        try:
-            from torch.utils.tensorboard import SummaryWriter
+        log_dir = make_logdir(args)
+        if os.environ.get("COMMEFFICIENT_RUN_DIR"):
+            # orchestrated tenant (scripts/orchestrate.py,
+            # docs/packing.md): the run dir — and with it telemetry.jsonl
+            # + trace_round_* captures — is pinned per tenant so fleet
+            # neighbors never collide
+            print(f"run dir pinned by orchestrator: {log_dir} (tenant "
+                  f"{os.environ.get('COMMEFFICIENT_TENANT_ID', '?')})",
+                  flush=True)
+        writer = None
+        if args.use_tensorboard:
+            try:
+                from torch.utils.tensorboard import SummaryWriter
 
-            writer = SummaryWriter(log_dir=log_dir)
-        except ImportError:
-            print("tensorboard unavailable; console logging only")
-    planes, start_epoch, totals, resume_mid = attach_planes(
-        args, fed_model, opt, lr_scheduler, train_loader, log_dir,
-        "cv_train")
-    print(f"Finished initializing in {timer():.2f} seconds")
+                writer = SummaryWriter(log_dir=log_dir)
+            except ImportError:
+                print("tensorboard unavailable; console logging only")
+        planes, start_epoch, totals, resume_mid = attach_planes(
+            args, fed_model, opt, lr_scheduler, train_loader, log_dir,
+            "cv_train")
+    finish_setup(planes)
+    timer()  # the epochs' clock starts here; set-up stays in total_time
 
     try:
         summary = train(fed_model, opt, lr_scheduler, train_loader,

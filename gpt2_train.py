@@ -47,8 +47,10 @@ from commefficient_tpu.federated.losses import (
 from commefficient_tpu.federated.run import (
     attach_planes,
     close_run,
+    finish_setup,
     population_emptied,
     run_rounds,
+    val_pass,
 )
 from commefficient_tpu.models.gpt2 import (
     GPT2DoubleHeads,
@@ -59,6 +61,7 @@ from commefficient_tpu.models.joyai import JoyAIConfig, JoyAIFlash
 from commefficient_tpu.models.laguna import LagunaConfig, LagunaXS2
 from commefficient_tpu.models.ouro import Ouro, OuroConfig
 from commefficient_tpu.ops.attention import GQA_PLAN, PATH_CALLS
+from commefficient_tpu.profiling import begin_setup, phase
 from commefficient_tpu.utils import (
     PiecewiseLinear,
     TableLogger,
@@ -223,12 +226,13 @@ def run_batches(model, opt, lr_scheduler, loader, args, timer, training,
 
     nlls, accs = [], []
     spe = len(loader)
-    for batch_idx, batch in enumerate(loader):
-        if batch_idx > 5 and args.do_test and batch_idx < spe - 5:
-            continue
-        nll, acc = model(batch)
-        nlls.append(float(np.mean(nll)))
-        accs.append(float(np.mean(acc)))
+    with val_pass(model):
+        for batch_idx, batch in enumerate(loader):
+            if batch_idx > 5 and args.do_test and batch_idx < spe - 5:
+                continue
+            nll, acc = model(batch)
+            nlls.append(float(np.mean(nll)))
+            accs.append(float(np.mean(acc)))
     return np.mean(nlls), np.mean(accs), np.exp(np.mean(nlls))
 
 
@@ -332,17 +336,6 @@ def train(argv=None):
     print(args)
     timer = Timer()
 
-    tokenizer = get_tokenizer(args.model_checkpoint)
-    print(f"tokenizer: {type(tokenizer).__name__} (vocab {len(tokenizer)})")
-    tokenizer.add_special_tokens(ATTR_TO_SPECIAL_TOKEN)
-    args.len_tokenizer = len(tokenizer)
-
-    # --finetune points the MODEL load at a previously saved run dir while
-    # the tokenizer stays that of the base checkpoint (reference
-    # gpt2_train.py:270-273); the run itself is then eval-only (see below)
-    if args.do_finetune and not args.do_test:
-        args.model_checkpoint = args.finetune_path
-
     # sequence parallelism (--seq_parallel ring|ulysses): attention runs
     # over the global sequence sharded across the mesh's `seq` axis.
     # Tensor parallelism (--model_devices N): heads/hidden sharded over a
@@ -372,147 +365,184 @@ def train(argv=None):
         print(f"--expert_devices {args.expert_devices} disabled: "
               f"mesh has no expert axis ({dict(mesh.shape)})")
         args.expert_devices = 1
-    geometry = dict(attn_impl=args.seq_parallel) if sp else {}
-    if tp:
-        geometry["model_axis"] = "model"
-    if args.n_experts:
-        # MoE GPT-2 (--n_experts N): every other block gets a Switch-style
-        # MoE MLP; with --expert_devices the experts shard over the
-        # `expert` mesh axis (parallel/moe.py)
-        geometry["n_experts"] = args.n_experts
-        geometry["moe_dispatch"] = args.moe_dispatch
-        geometry["moe_capacity_factor"] = args.moe_capacity_factor
+    # start-up's phases (profiling.py): `import` ends here, process start
+    # to the devices announced and laid out as a mesh
+    begin_setup()
+
+    with phase("data"):
+        tokenizer = get_tokenizer(args.model_checkpoint)
+        print(f"tokenizer: {type(tokenizer).__name__} "
+              f"(vocab {len(tokenizer)})")
+        tokenizer.add_special_tokens(ATTR_TO_SPECIAL_TOKEN)
+        args.len_tokenizer = len(tokenizer)
+        train_loader, val_loader = get_data_loaders(args, tokenizer,
+                                                    emit_shifted=sp)
+
+    # --finetune points the MODEL load at a previously saved run dir while
+    # the tokenizer stays that of the base checkpoint (reference
+    # gpt2_train.py:270-273); the run itself is then eval-only (see below)
+    if args.do_finetune and not args.do_test:
+        args.model_checkpoint = args.finetune_path
+
+    with phase("model"):
+        geometry = dict(attn_impl=args.seq_parallel) if sp else {}
+        if tp:
+            geometry["model_axis"] = "model"
+        if args.n_experts:
+            # MoE GPT-2 (--n_experts N): every other block gets a
+            # Switch-style MoE MLP; with --expert_devices the experts shard
+            # over the `expert` mesh axis (parallel/moe.py)
+            geometry["n_experts"] = args.n_experts
+            geometry["moe_dispatch"] = args.moe_dispatch
+            geometry["moe_capacity_factor"] = args.moe_capacity_factor
+            if ep:
+                geometry["expert_axis"] = "expert"
+
+        # model geometry: tiny when smoke-testing or using the byte fallback
+        tiny = args.do_test or os.environ.get("COMMEFFICIENT_TINY_MODEL")
+        if args.arch in DECODERS:
+            model, compute_loss_train, compute_loss_val = build_decoder(
+                args, tiny)
+        elif tiny:
+            # COMMEFFICIENT_TINY_LAYERS: tests exercising layer-pattern
+            # constraints (e.g. MoE pipeline stage alignment) need more
+            # depth
+            model = GPT2DoubleHeads(vocab_size=max(512, args.len_tokenizer),
+                                    n_positions=args.max_seq_len, n_embd=64,
+                                    n_layer=int(os.environ.get(
+                                        "COMMEFFICIENT_TINY_LAYERS", 2)),
+                                    n_head=2, **geometry)
+        else:
+            model = GPT2DoubleHeads(vocab_size=max(50257 + 5,
+                                                   args.len_tokenizer),
+                                    n_positions=1024, **geometry)
+        if sp and args.seq_parallel == "ulysses":
+            assert model.n_head % args.seq_devices == 0, \
+                "ulysses needs n_head divisible by --seq_devices"
+        if tp:
+            nm = mesh.shape["model"]  # realized size, possibly reduced
+            assert model.n_head % nm == 0, \
+                f"--model_devices (realized {nm}) must divide n_head"
+            assert (4 * model.n_embd) % nm == 0, (
+                f"--model_devices (realized {nm}) must divide the MLP "
+                "hidden dim")
         if ep:
-            geometry["expert_axis"] = "expert"
+            ne = mesh.shape["expert"]  # realized size, possibly reduced
+            assert args.n_experts % ne == 0, \
+                f"--expert_devices (realized {ne}) must divide --n_experts"
+        if args.arch == "gpt2" and pp:
+            # pipeline parallelism (--pipeline_devices): the loss callbacks
+            # carry the GPipe schedule (parallel/pipeline.py); the model
+            # object itself stays the plain dense one
+            n_stages = mesh.shape["stage"]  # realized size, possibly reduced
+            assert model.n_layer >= n_stages, (
+                f"--pipeline_devices (realized {n_stages}) must be <= "
+                "n_layer")
+            from commefficient_tpu.parallel.pipeline import (
+                make_gpt2_pp_losses,
+            )
 
-    # model geometry: tiny when smoke-testing or using the byte fallback
-    tiny = args.do_test or os.environ.get("COMMEFFICIENT_TINY_MODEL")
-    if args.arch in DECODERS:
-        model, compute_loss_train, compute_loss_val = build_decoder(args,
-                                                                    tiny)
-    elif tiny:
-        # COMMEFFICIENT_TINY_LAYERS: tests exercising layer-pattern
-        # constraints (e.g. MoE pipeline stage alignment) need more depth
-        model = GPT2DoubleHeads(vocab_size=max(512, args.len_tokenizer),
-                                n_positions=args.max_seq_len, n_embd=64,
-                                n_layer=int(os.environ.get(
-                                    "COMMEFFICIENT_TINY_LAYERS", 2)),
-                                n_head=2, **geometry)
-    else:
-        model = GPT2DoubleHeads(vocab_size=max(50257 + 5,
-                                               args.len_tokenizer),
-                                n_positions=1024, **geometry)
-    if sp and args.seq_parallel == "ulysses":
-        assert model.n_head % args.seq_devices == 0, \
-            "ulysses needs n_head divisible by --seq_devices"
-    if tp:
-        nm = mesh.shape["model"]  # realized size, possibly reduced
-        assert model.n_head % nm == 0, \
-            f"--model_devices (realized {nm}) must divide n_head"
-        assert (4 * model.n_embd) % nm == 0, \
-            f"--model_devices (realized {nm}) must divide the MLP hidden dim"
-    if ep:
-        ne = mesh.shape["expert"]  # realized size, possibly reduced
-        assert args.n_experts % ne == 0, \
-            f"--expert_devices (realized {ne}) must divide --n_experts"
-    if args.arch == "gpt2" and pp:
-        # pipeline parallelism (--pipeline_devices): the loss callbacks
-        # carry the GPipe schedule (parallel/pipeline.py); the model object
-        # itself stays the plain dense one
-        n_stages = mesh.shape["stage"]  # realized size, possibly reduced
-        assert model.n_layer >= n_stages, \
-            f"--pipeline_devices (realized {n_stages}) must be <= n_layer"
-        from commefficient_tpu.parallel.pipeline import make_gpt2_pp_losses
+            compute_loss_train, compute_loss_val = make_gpt2_pp_losses(
+                model, n_stages, n_micro=args.pp_microbatches,
+                lm_coef=args.lm_coef, mc_coef=args.mc_coef,
+                compute_dtype=jnp.bfloat16 if args.do_bf16 else None,
+                moe_aux_coef=args.moe_aux_coef if args.n_experts else 0.0)
+        elif args.arch == "gpt2":
+            compute_loss_train, compute_loss_val = make_gpt2_losses(
+                model, args.lm_coef, args.mc_coef,
+                seq_axis="seq" if sp else None,
+                compute_dtype=jnp.bfloat16 if args.do_bf16 else None,
+                moe_aux_coef=args.moe_aux_coef if args.n_experts else 0.0)
 
-        compute_loss_train, compute_loss_val = make_gpt2_pp_losses(
-            model, n_stages, n_micro=args.pp_microbatches,
-            lm_coef=args.lm_coef, mc_coef=args.mc_coef,
-            compute_dtype=jnp.bfloat16 if args.do_bf16 else None,
-            moe_aux_coef=args.moe_aux_coef if args.n_experts else 0.0)
-    elif args.arch == "gpt2":
-        compute_loss_train, compute_loss_val = make_gpt2_losses(
-            model, args.lm_coef, args.mc_coef,
-            seq_axis="seq" if sp else None,
-            compute_dtype=jnp.bfloat16 if args.do_bf16 else None,
-            moe_aux_coef=args.moe_aux_coef if args.n_experts else 0.0)
+        # try local pretrained weights (reference loads from the hub,
+        # gpt2_train.py:262-273)
+        x0 = {
+            "input_ids": jnp.zeros(
+                (1, args.num_candidates, args.max_seq_len), jnp.int32),
+        }
+        # init with a non-parallel twin: same parameter structure, but
+        # usable outside shard_map (ring/ulysses need the `seq` axis bound;
+        # TPDense needs the `model` axis bound)
+        init_model = model
+        if sp:
+            init_model = init_model.copy(attn_impl="dense")
+        if tp:
+            init_model = init_model.copy(model_axis=None)
+        if ep:
+            init_model = init_model.copy(expert_axis=None)
+        if args.arch == "gpt2":
+            variables = init_model.init(
+                jax.random.key(args.seed), x0["input_ids"],
+                token_type_ids=x0["input_ids"],
+                mc_token_ids=jnp.zeros((1, args.num_candidates), jnp.int32),
+                train=False)
+        else:
+            variables = jax.jit(init_model.init)(jax.random.key(args.seed),
+                                                 x0["input_ids"][0])
+        init_params = variables["params"]
+        pretrained = (load_hf_gpt2(init_params, args.model_checkpoint)
+                      if args.arch == "gpt2" else None)
+        if pretrained is not None:
+            init_params = resize_token_embeddings(pretrained,
+                                                  args.len_tokenizer)
+            print("loaded local pretrained GPT-2 weights")
+        elif os.path.exists(os.path.join(args.model_checkpoint,
+                                         "model.npz")):
+            # a run dir this framework saved (save_pretrained → model.npz):
+            # the finetune round trip, since HF-format checkpoints are
+            # rarely present in the zero-egress environment
+            ckpt_params, _ = load_checkpoint(
+                os.path.join(args.model_checkpoint, "model"))
+            init_params, loaded, skipped = load_matching(init_params,
+                                                         ckpt_params)
+            assert loaded > 0, (
+                f"--finetune checkpoint {args.model_checkpoint} shares no "
+                f"tensor shapes with the current model geometry "
+                f"(COMMEFFICIENT_TINY_MODEL / --max_seq_len "
+                f"mismatch?) — refusing to silently train from scratch")
+            print(f"loaded saved run dir: {loaded} tensors, "
+                  f"fresh: {len(skipped)}")
 
-    log_dir = make_logdir(args)
-    if os.environ.get("COMMEFFICIENT_RUN_DIR"):
-        # orchestrated tenant (scripts/orchestrate.py, docs/packing.md):
-        # the run dir — and with it telemetry.jsonl + trace_round_*
-        # captures — is pinned per tenant so fleet neighbors never
-        # collide
-        print(f"run dir pinned by orchestrator: {log_dir} "
-              f"(tenant {os.environ.get('COMMEFFICIENT_TENANT_ID', '?')})",
-              flush=True)
-    os.makedirs(log_dir, exist_ok=True)
-    tokenizer.save_pretrained(log_dir)
+        args.num_results_train = 1
+        args.num_results_val = 2
+        # hand the seed's weights over and keep no name on them: from here
+        # on they live in fed_model's flat vector alone, and a caller that
+        # puts its own weights in their place (the benchmark) does not hold
+        # both trees
+        handover = [init_params]
+        del variables, init_params, pretrained
 
-    train_loader, val_loader = get_data_loaders(args, tokenizer,
-                                                emit_shifted=sp)
+    with phase("fed"):
+        fed_model = FedModel(model, compute_loss_train, args,
+                             compute_loss_val,
+                             num_clients=train_loader.dataset.num_clients,
+                             init_params=handover.pop(), mesh=mesh)
+        opt = FedOptimizer(fed_model, args)
 
-    # try local pretrained weights (reference loads from the hub,
-    # gpt2_train.py:262-273)
-    x0 = {
-        "input_ids": jnp.zeros((1, args.num_candidates, args.max_seq_len),
-                               jnp.int32),
-    }
-    # init with a non-parallel twin: same parameter structure, but usable
-    # outside shard_map (ring/ulysses need the `seq` axis bound; TPDense
-    # needs the `model` axis bound)
-    init_model = model
-    if sp:
-        init_model = init_model.copy(attn_impl="dense")
-    if tp:
-        init_model = init_model.copy(model_axis=None)
-    if ep:
-        init_model = init_model.copy(expert_axis=None)
-    if args.arch == "gpt2":
-        variables = init_model.init(
-            jax.random.key(args.seed), x0["input_ids"],
-            token_type_ids=x0["input_ids"],
-            mc_token_ids=jnp.zeros((1, args.num_candidates), jnp.int32),
-            train=False)
-    else:
-        variables = jax.jit(init_model.init)(jax.random.key(args.seed),
-                                             x0["input_ids"][0])
-    init_params = variables["params"]
-    pretrained = (load_hf_gpt2(init_params, args.model_checkpoint)
-                  if args.arch == "gpt2" else None)
-    if pretrained is not None:
-        init_params = resize_token_embeddings(pretrained, args.len_tokenizer)
-        print("loaded local pretrained GPT-2 weights")
-    elif os.path.exists(os.path.join(args.model_checkpoint, "model.npz")):
-        # a run dir this framework saved (save_pretrained → model.npz):
-        # the finetune round trip, since HF-format checkpoints are rarely
-        # present in the zero-egress environment
-        ckpt_params, _ = load_checkpoint(
-            os.path.join(args.model_checkpoint, "model"))
-        init_params, loaded, skipped = load_matching(init_params, ckpt_params)
-        assert loaded > 0, (
-            f"--finetune checkpoint {args.model_checkpoint} shares no "
-            f"tensor shapes with the current model geometry "
-            f"(COMMEFFICIENT_TINY_MODEL / --max_seq_len "
-            f"mismatch?) — refusing to silently train from scratch")
-        print(f"loaded saved run dir: {loaded} tensors, "
-              f"fresh: {len(skipped)}")
+    planes = None
+    with phase("planes"):
+        spe = train_loader.steps_per_epoch()
+        print("Steps per epoch", spe)
+        lr_schedule = PiecewiseLinear([0, args.num_epochs * spe],
+                                      [args.lr_scale, 0.0])
+        scheduler = LambdaLR(opt, lr_lambda=lambda s: lr_schedule(s))
 
-    args.num_results_train = 1
-    args.num_results_val = 2
-    # hand the seed's weights over and keep no name on them: from here on
-    # they live in fed_model's flat vector alone, and a caller that puts its
-    # own weights in their place (the benchmark) does not hold both trees
-    handover = [init_params]
-    del variables, init_params, pretrained
-    fed_model = FedModel(model, compute_loss_train, args, compute_loss_val,
-                         num_clients=train_loader.dataset.num_clients,
-                         init_params=handover.pop(), mesh=mesh)
-    opt = FedOptimizer(fed_model, args)
-    spe = train_loader.steps_per_epoch()
-    print("Steps per epoch", spe)
-    lr_schedule = PiecewiseLinear([0, args.num_epochs * spe],
-                                  [args.lr_scale, 0.0])
-    scheduler = LambdaLR(opt, lr_lambda=lambda s: lr_schedule(s))
+        log_dir = make_logdir(args)
+        if os.environ.get("COMMEFFICIENT_RUN_DIR"):
+            # orchestrated tenant (scripts/orchestrate.py,
+            # docs/packing.md): the run dir — and with it telemetry.jsonl
+            # + trace_round_* captures — is pinned per tenant so fleet
+            # neighbors never collide
+            print(f"run dir pinned by orchestrator: {log_dir} (tenant "
+                  f"{os.environ.get('COMMEFFICIENT_TENANT_ID', '?')})",
+                  flush=True)
+        os.makedirs(log_dir, exist_ok=True)
+        tokenizer.save_pretrained(log_dir)
+        if not args.do_finetune:
+            planes, start_epoch, totals, resume_mid = attach_planes(
+                args, fed_model, opt, scheduler, train_loader, log_dir,
+                "gpt2_train")
+    finish_setup(planes)
 
     if args.do_finetune:
         # --finetune is the reference's eval-only path: load the saved run
@@ -521,9 +551,6 @@ def train(argv=None):
         stats = test_gpt2(fed_model, val_loader, args, logger=TableLogger(),
                           timer=timer)
     else:
-        planes, start_epoch, totals, resume_mid = attach_planes(
-            args, fed_model, opt, scheduler, train_loader, log_dir,
-            "gpt2_train")
         try:
             stats = train_gpt2(fed_model, opt, scheduler, train_loader,
                                val_loader, args, log_dir,

@@ -15,6 +15,11 @@ with ``--telemetry``, the default), print
   tripped verdict — reconstructing the fault story from the log alone
   (the acceptance drill: a fault-injected run's quarantine history must
   be reproducible here without touching the process that ran it);
+- start-up: the run's own phases of set-up, the programs it traced,
+  lowered and compiled or loaded, by name, with the persistent cache's
+  verdict on each, and any program built once the run was steady;
+- device memory: what the run holds at rest, the process's two peaks, and
+  the phase, drain or validation pass at which each peak last rose;
 - checkpoints, resumes, and epoch rows, in timeline order.
 
 The LAST line of output is always one machine-readable JSON object
@@ -110,6 +115,104 @@ def _hist_summary(rounds: List[dict], prefix: str):
         else None
     return {"mean_counts": means, "modal_bin": modal,
             "bins": len(names)}
+
+
+def _memory_samples(events: List[dict]):
+    """Every memory sample of the log, in order, labelled by where it was
+    taken: the set-up phases' ends, the drains, both ends of the validation
+    passes, the run's end."""
+    for e in events:
+        ev = e.get("ev")
+        if ev == "setup":
+            for ph in e.get("phases") or []:
+                if ph.get("memory"):
+                    yield f"phase {ph.get('phase')}", ph["memory"], None
+        elif ev == "drain" and e.get("memory"):
+            yield (f"drain at round {e.get('round')}", e["memory"],
+                   e.get("inflight"))
+        elif ev == "val":
+            for key, what in (("memory_start", "start"),
+                              ("memory_end", "end")):
+                if e.get(key):
+                    yield (f"validation {what} (round {e.get('round')})",
+                           e[key], 0)
+        elif ev == "run_end" and e.get("memory"):
+            yield "run end", e["memory"], 0
+
+
+def summarize_memory(events: List[dict]):
+    """At rest, the two peaks, and where each peak last rose; None for a
+    log without samples (an older one, or a backend that reports none)."""
+    samples = list(_memory_samples(events))
+    if not samples:
+        return None
+    out: Dict[str, Any] = {"samples": len(samples)}
+    rest = [m for _, m, inflight in samples
+            if inflight == 0 and "bytes_in_use" in m]
+    if rest:
+        out["at_rest_bytes"] = rest[-1]["bytes_in_use"]
+    for key in ("peak_bytes_in_use", "peak_bytes_reserved"):
+        seen, rose = None, None
+        for label, mem, _ in samples:
+            if key in mem and (seen is None or mem[key] > seen):
+                seen, rose = mem[key], label
+        if seen is not None:
+            out[key] = seen
+            out[key + "_last_rose"] = rose
+    return out
+
+
+def summarize_startup(events: List[dict]):
+    """The set-up phases, the programs built by name, and the builds that
+    came once the run was steady (past its first drain); None for a log
+    with neither a ``setup`` nor a ``program`` event."""
+    setup = next((e for e in events if e.get("ev") == "setup"), None)
+    builds = [e for e in events if e.get("ev") == "program"]
+    if setup is None and not builds:
+        return None
+    first_drain = next((e.get("round") for e in events
+                        if e.get("ev") == "drain"
+                        and e.get("round") is not None), None)
+    programs: Dict[str, Dict[str, Any]] = {}
+    late = []
+    for b in builds:
+        name = b.get("name", "?")
+        if (first_drain is not None and isinstance(b.get("round"), int)
+                and b["round"] > first_drain):
+            # past the first drain the round's programs exist: a name
+            # built before is a recompile, a new one a late first build
+            # (the first validation pass, an epoch's short last cohort)
+            late.append({
+                "round": b["round"], "name": name,
+                "builds": b.get("builds", 1),
+                "recompile": name in programs and name != "other",
+                "seconds": round(sum(b.get(k) or 0.0 for k in
+                                     ("trace_s", "lower_s", "backend_s")),
+                                 4)})
+        tot = programs.setdefault(name, {
+            "builds": 0, "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+            "hits": 0, "misses": 0})
+        tot["builds"] += b.get("builds", 1)
+        for key in ("trace_s", "lower_s", "backend_s"):
+            tot[key] = round(tot[key] + (b.get(key) or 0.0), 4)
+        tot["hits"] += b.get("hits", b.get("cache") == "hit")
+        tot["misses"] += b.get("misses", b.get("cache") == "miss")
+    phases = (setup or {}).get("phases") or []
+    return {
+        "phases": [{k: ph.get(k) for k in ("phase", "start_s", "seconds",
+                                           "programs", "build_s")}
+                   for ph in phases],
+        "setup_s": round(sum(ph.get("seconds") or 0.0 for ph in phases), 3),
+        "programs": programs,
+        "trace_lower_s": round(sum(t["trace_s"] + t["lower_s"]
+                                   for t in programs.values()), 3),
+        "backend_s": round(sum(t["backend_s"]
+                               for t in programs.values()), 3),
+        "cache_hits": sum(t["hits"] for t in programs.values()),
+        "cache_misses": sum(t["misses"] for t in programs.values()),
+        "steady_state_builds": late,
+        "steady_state_recompiles": sum(b["recompile"] for b in late),
+    }
 
 
 def summarize(events: List[dict]) -> Dict[str, Any]:
@@ -554,6 +657,10 @@ def summarize(events: List[dict]) -> Dict[str, Any]:
             "update": _hist_summary(rounds, "update_hist_"),
             "error": _hist_summary(rounds, "error_hist_"),
         },
+        # the run's record of its own start-up and device memory
+        # (profiling.py); None for a log from before it
+        "startup": summarize_startup(events),
+        "memory": summarize_memory(events),
     }
 
 
@@ -912,6 +1019,54 @@ def render(events: List[dict], out=None) -> Dict[str, Any]:
         if not sv["clean_stop"]:
             p("no serving_stop event — replica crashed, was killed, or "
               "is still serving")
+
+
+    st = s.get("startup")
+    if st:
+        p("\n## Start-up (profiling.phase / the program listener)")
+        if st["phases"]:
+            p("| phase | start s | seconds | programs built | build s |")
+            p("|---|---|---|---|---|")
+            for ph in st["phases"]:
+                p(f"| {ph['phase']} | {ph['start_s']} | {ph['seconds']} | "
+                  f"{ph['programs']} | {ph['build_s']} |")
+            p(f"set-up phases in all: {st['setup_s']} s")
+        if st["programs"]:
+            p(f"programs built: trace + lower {st['trace_lower_s']} s, "
+              f"backend (compile or cache load) {st['backend_s']} s; "
+              f"persistent cache {st['cache_hits']} hit(s), "
+              f"{st['cache_misses']} miss(es)")
+            p("| program | builds | trace s | lower s | backend s | "
+              "cache |")
+            p("|---|---|---|---|---|---|")
+            top = sorted(st["programs"].items(),
+                         key=lambda kv: -kv[1]["backend_s"])
+            for name, t in top[:12]:
+                p(f"| {name} | {t['builds']} | {t['trace_s']} | "
+                  f"{t['lower_s']} | {t['backend_s']} | "
+                  f"{t['hits']} hit / {t['misses']} miss |")
+            if len(top) > 12:
+                p(f"({len(top) - 12} more programs in the machine tail)")
+        for r in st["steady_state_builds"]:
+            what = ("STEADY-STATE RECOMPILE" if r["recompile"]
+                    else "late first build")
+            p(f"- {what} at round {r['round']}: {r['name']} "
+              f"({r['builds']} build(s), {r['seconds']} s)")
+
+    mem = s.get("memory")
+    if mem:
+        p("\n## Memory (profiling.memory_sample, fullest local device)")
+        gib = lambda b: f"{b / 2**30:.3f} GiB"  # noqa: E731
+        if "at_rest_bytes" in mem:
+            p(f"at rest (nothing in flight): {gib(mem['at_rest_bytes'])}")
+        for key, what in (("peak_bytes_in_use", "buffers in use"),
+                          ("peak_bytes_reserved",
+                           "reserved for the programs' temporaries")):
+            if key in mem:
+                p(f"peak {what}: {gib(mem[key])}, last rose at "
+                  f"{mem[key + '_last_rose']}")
+        p(f"{mem['samples']} samples (phase ends, drains, validation "
+          "passes, run end)")
 
     p("\n## Guard / rollback history")
     if not s["guards"]:

@@ -432,6 +432,48 @@ class TestRealRun:
         assert "resume" not in evs
 
 
+    def test_the_run_records_its_start_up(self, real_run):
+        """profiling.py's record in a run of each entry point: the
+        ``setup`` event right after ``run_start``, its phases in order and
+        apart; the programs built, with the phase or round they were built
+        in; a ``val`` event a validation pass; a memory sample (None on
+        the CPU) on every drain and on ``run_end``."""
+        evs = [e["ev"] for e in real_run.events]
+        assert evs[:2] == ["run_start", "setup"] and evs.count("setup") == 1
+        phases = real_run.events[1]["phases"]
+        names = [p["phase"] for p in phases]
+        assert [n for n in names if n != "import"] == \
+            ["data", "model", "fed", "planes"]
+        for a, b in zip(phases, phases[1:]):
+            assert a["start_s"] + a["seconds"] <= b["start_s"] + 2e-3
+        assert sum(p["programs"] for p in phases) > 0
+        # (the test process built programs before this run: its first run
+        # keeps what came before it, as the `import` phase's)
+        began = real_run.events[1]["t"] - 1.0 - sum(
+            p["seconds"] for p in phases if p["phase"] != "import")
+        progs = [e for e in real_run.events if e["ev"] == "program"
+                 and e["t"] >= began]
+        named = {e["name"] for e in progs}
+        assert {"jit(client_step)", "jit(server_step)"} <= named, named
+        step = next(e for e in progs if e["name"] == "jit(client_step)")
+        assert step["round"] == 0 and step["phase"] is None
+        assert step["trace_s"] > 0 and step["backend_s"] > 0
+        assert any(e["phase"] in ("model", "fed") and e["round"] is None
+                   for e in progs if e["t"] < real_run.events[1]["t"])
+        vals = [e for e in real_run.events if e["ev"] == "val"]
+        assert vals and all(v["seconds"] > 0 and v["memory_end"] is None
+                            for v in vals)
+        drains = [e for e in real_run.events if e["ev"] == "drain"]
+        assert drains and all(d["inflight"] == 0 and "memory" in d
+                              for d in drains)
+        end = real_run.events[-1]
+        assert end["ev"] == "run_end" and "memory" in end
+        assert "jit(client_step)" in end["programs"]
+        for name in ("fed_setup_data", "fed_setup_fed", "fed_val_pass",
+                     "fed_memory_sample", "fed_program_listener"):
+            assert end["spans"][name]["count"] >= 1, name
+
+
 @both
 def test_finalize_when_training_raises(entry, tmp_path, monkeypatch):
     """The close-out runs on the error path too: the recorder is closed and

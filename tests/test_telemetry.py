@@ -50,6 +50,7 @@ from commefficient_tpu.federated.server import ServerConfig, init_server_state
 from commefficient_tpu.federated.worker import WorkerConfig
 from commefficient_tpu.ops.flat import ravel_pytree
 from commefficient_tpu.ops.sketch import make_sketch
+from commefficient_tpu import profiling
 from commefficient_tpu.profiling import Heartbeat, host_sync_monitor
 from commefficient_tpu.telemetry import (
     METRIC_FIELDS,
@@ -256,12 +257,33 @@ def _engine(tmp_path, window=2, drain_every=8, heartbeat=None, **over):
     return fm, engine, rt
 
 
+class _MemoryDevice:
+    """A device whose ``memory_stats()`` reads like a TPU's (the CPU's is
+    None): the drain's memory sample with something to read."""
+
+    calls = 0
+
+    def memory_stats(self):
+        type(self).calls += 1
+        return {"bytes_in_use": 1 << 20, "peak_bytes_in_use": 2 << 20,
+                "bytes_reserved": 0, "peak_bytes_reserved": 3 << 20,
+                "largest_free_block_bytes": 1 << 30, "num_allocs": 7}
+
+
 class TestSyncAudit:
-    def test_zero_syncs_strict_with_guards_and_telemetry(self, tmp_path):
+    @pytest.mark.parametrize("memory", ["cpu_none", "device_sample"])
+    def test_zero_syncs_strict_with_guards_and_telemetry(self, tmp_path,
+                                                         monkeypatch,
+                                                         memory):
         """The acceptance audit: guards AND telemetry on, strict monitor —
         5 steady-state engine rounds perform ZERO blocking device→host
         transfers; the batched drain is the one counted fetch and every
-        drained round lands a schema-complete event line."""
+        drained round lands a schema-complete event line. With the
+        drain's memory sample on a device that reports (``memory_stats()``
+        is a host call into the runtime, no fetch) as without."""
+        if memory == "device_sample":
+            monkeypatch.setattr(profiling, "_local_devices",
+                                lambda: [_MemoryDevice()])
         fm, engine, rt = _engine(tmp_path, drain_every=10, guards=True,
                                  snapshot_every=4, max_guard_trips=3,
                                  guard_max_abs=0.0)
@@ -302,6 +324,13 @@ class TestSyncAudit:
         kinds = [e["ev"] for e in events]
         assert kinds[0] == "run_start" and kinds[-1] == "run_end"
         assert "drain" in kinds
+        (drain,) = [e for e in events if e["ev"] == "drain"]
+        assert drain["round"] == 5 and drain["inflight"] == 0
+        if memory == "device_sample":
+            assert drain["memory"]["peak_bytes_reserved"] == 3 << 20
+            assert events[-1]["memory"]["at"] == "run_end"
+        else:
+            assert drain["memory"] is None and events[-1]["memory"] is None
 
     def test_engine_heartbeat_carries_global_round_index(self, tmp_path,
                                                          capfd):
@@ -355,6 +384,97 @@ class TestEventLog:
         for ref, got in zip(per_round, batched):
             for r, g in zip(ref.values, got.values):
                 np.testing.assert_array_equal(r, g)
+
+    def test_memory_samples_feed_nothing_back(self, tmp_path, monkeypatch):
+        """fp32 trajectory bit-identical with the event log, its drain
+        samples and the program listener on, or with no recorder at all."""
+        def run(sub, recorded):
+            if recorded:
+                monkeypatch.setattr(profiling, "_local_devices",
+                                    lambda: [_MemoryDevice()])
+                profiling.install_program_listener()
+            fm, engine, rt = _engine(tmp_path / sub, drain_every=3)
+            if recorded:
+                rt.setup(profiling.PHASES, profiling.PROCESS_START_T)
+            else:
+                rt.close()
+                fm.telemetry = engine.telemetry = None
+            for rnd in range(7):
+                engine.submit(_host_batch([rnd % 4, (rnd + 1) % 4],
+                                          seed=rnd))
+            engine.drain()
+            rt.close()
+            return np.asarray(fm.ps_weights)
+
+        (tmp_path / "on").mkdir()
+        (tmp_path / "off").mkdir()
+        calls = _MemoryDevice.calls
+        on = run("on", True)
+        assert _MemoryDevice.calls >= calls + 3     # 3 drains + run_end
+        monkeypatch.undo()
+        np.testing.assert_array_equal(on, run("off", False))
+
+    def test_setup_event_then_the_programs_built(self, tmp_path,
+                                                 monkeypatch):
+        """``setup`` once, right after ``run_start``; then every program
+        built before it, then each build as it closes: a line of its own
+        from ``PROGRAM_LOG_S`` up, the small ones summed under ``other``
+        before the next line of any kind; ``run_end.programs`` by name."""
+        from commefficient_tpu import telemetry
+
+        profiling.install_program_listener()
+        profiling.begin_setup()
+        with profiling.phase("model"):
+            jax.jit(lambda x: x * 3.0 + 1.0)(jnp.ones(3))   # `<lambda>`
+        rt = RunTelemetry(str(tmp_path / "telemetry.jsonl"))
+        rt._dispatched = 41
+        rt.setup(profiling.PHASES, profiling.PROCESS_START_T)
+        # (the line between small and logged, out of a loaded machine's way)
+        monkeypatch.setattr(telemetry, "PROGRAM_LOG_S", 1e9)
+
+        @jax.jit
+        def lifecycle_small(x):
+            return x - 2.0
+
+        lifecycle_small(jnp.ones(3))
+        rt.event("checkpoint", round=41)
+        monkeypatch.setattr(telemetry, "PROGRAM_LOG_S", 0.0)
+
+        @jax.jit
+        def lifecycle_logged(x):
+            return x / 3.0
+
+        lifecycle_logged(jnp.ones(3))
+        rt.close()
+        events = list(read_events(str(tmp_path / "telemetry.jsonl")))
+        kinds = [e["ev"] for e in events]
+        assert kinds[:2] == ["run_start", "setup"]
+        assert kinds.count("setup") == 1 and kinds[-1] == "run_end"
+        setup = events[1]
+        assert setup["t0"] == profiling.PROCESS_START_T
+        assert [p["phase"] for p in setup["phases"]][-1] == "model"
+        assert setup["phases"][-1]["programs"] >= 1
+        progs = [e for e in events if e["ev"] == "program"]
+        # the backlog: built before the log opened, no round
+        past = [e for e in progs if e["round"] is None]
+        assert past and any(e["phase"] == "model" for e in past)
+        # a small build after it: summed, written before the checkpoint
+        live = [e for e in progs if e["round"] == 41]
+        small = [e for e in live if e["name"] == "other"]
+        assert small and small[0]["builds"] >= 1
+        assert kinds.index("checkpoint") > events.index(small[0])
+        assert not [e for e in progs if "lifecycle_small" in e["name"]]
+        (logged,) = [e for e in live
+                     if e["name"] == "jit(lifecycle_logged)"]
+        assert logged["cache"] in ("hit", "miss", "off")
+        assert logged["backend_s"] > 0 and logged["phase"] is None
+        assert logged["t"] <= events[-1]["t"]
+        end = events[-1]
+        assert "jit(lifecycle_logged)" in profiling.program_totals()
+        assert sum(t["builds"] for t in end["programs"].values()) == \
+            sum(t["builds"] for t in profiling.program_totals().values())
+        # closed: a later build goes nowhere and raises nothing
+        jax.jit(lambda x: x + 7.0)(jnp.ones(2))
 
     def test_collective_ledger(self):
         sketch = make_sketch(1000, 128, 3, seed=0, num_blocks=1)
@@ -429,3 +549,118 @@ class TestObsReport:
         assert tail["tripped_rounds"] == [2, 4]
         assert "guard TRIP at round 2" in out
         assert "guard TRIP at round 4" in out
+
+    @staticmethod
+    def _lifecycle_log():
+        """A run's record of its own start-up and memory, as the program
+        writes it (profiling.py): hand-made, small."""
+        def mem(at, in_use, peak, reserved_peak):
+            return {"at": at, "bytes_in_use": in_use,
+                    "peak_bytes_in_use": peak, "bytes_reserved": 0,
+                    "peak_bytes_reserved": reserved_peak,
+                    "largest_free_block_bytes": 1 << 33, "num_allocs": 9}
+
+        gib = 1 << 30
+        return [
+            {"ev": "run_start", "t": 100.0, "mode": "sketch"},
+            {"ev": "setup", "t": 100.1, "t0": 80.0, "phases": [
+                {"phase": "import", "start_s": 0.0, "seconds": 6.0,
+                 "programs": 0, "build_s": 0.0, "memory": None},
+                {"phase": "data", "start_s": 6.0, "seconds": 9.0,
+                 "programs": 0, "build_s": 0.0,
+                 "memory": mem("phase:data", 0, 0, 0)},
+                {"phase": "fed", "start_s": 15.0, "seconds": 5.0,
+                 "programs": 40, "build_s": 3.5,
+                 "memory": mem("phase:fed", gib, gib + 5, 0)}]},
+            {"ev": "program", "t": 96.0, "round": None, "phase": "fed",
+             "name": "jit(init)", "trace_s": 1.0, "lower_s": 0.5,
+             "backend_s": 1.5, "cache": "hit", "load_s": 1.2},
+            {"ev": "program", "t": 97.0, "round": None, "phase": "fed",
+             "name": "other", "builds": 39, "hits": 39, "trace_s": 0.1,
+             "lower_s": 0.2, "backend_s": 0.2},
+            {"ev": "program", "t": 103.0, "round": 0, "phase": None,
+             "name": "jit(client_step)", "trace_s": 4.0, "lower_s": 2.0,
+             "backend_s": 60.0, "cache": "miss", "stored": True},
+            {"ev": "round", "t": 104.0, "round": 0, "t_dispatch": 100.2},
+            {"ev": "round", "t": 104.0, "round": 1, "t_dispatch": 103.5},
+            {"ev": "drain", "t": 104.1, "round": 1, "rounds": 2,
+             "inflight": 0,
+             "memory": mem("drain", 2 * gib, 3 * gib, 4 * gib)},
+            {"ev": "val", "t": 106.0, "round": 2, "seconds": 1.5,
+             "memory_start": mem("val_start", 2 * gib, 3 * gib, 4 * gib),
+             "memory_end": mem("val_end", 2 * gib, 3 * gib, 5 * gib)},
+            {"ev": "program", "t": 106.5, "round": 2, "phase": None,
+             "name": "jit(val_step)", "trace_s": 0.2, "lower_s": 0.1,
+             "backend_s": 0.4, "cache": "hit", "load_s": 0.3},
+            {"ev": "program", "t": 108.0, "round": 3, "phase": None,
+             "name": "jit(client_step)", "trace_s": 4.0, "lower_s": 2.0,
+             "backend_s": 0.9, "cache": "hit", "load_s": 0.8},
+            {"ev": "round", "t": 109.0, "round": 2, "t_dispatch": 107.0},
+            {"ev": "round", "t": 109.0, "round": 3, "t_dispatch": 108.5},
+            {"ev": "drain", "t": 109.1, "round": 3, "rounds": 2,
+             "inflight": 0,
+             "memory": mem("drain", 2 * gib + 64, 3 * gib, 5 * gib)},
+            {"ev": "run_end", "t": 110.0, "rounds": 4, "spans": {},
+             "programs": {},
+             "memory": mem("run_end", 2 * gib + 64, 3 * gib, 5 * gib)},
+        ]
+
+    def test_start_up_and_memory_sections_from_the_log_alone(self):
+        import io
+
+        import obs_report
+
+        out = io.StringIO()
+        s = obs_report.render(self._lifecycle_log(), out=out)
+        st, mem = s["startup"], s["memory"]
+        assert [p["phase"] for p in st["phases"]] == ["import", "data",
+                                                      "fed"]
+        assert st["setup_s"] == 20.0
+        assert st["programs"]["jit(client_step)"] == {
+            "builds": 2, "trace_s": 8.0, "lower_s": 4.0, "backend_s": 60.9,
+            "hits": 1, "misses": 1}
+        assert st["programs"]["other"]["builds"] == 39
+        assert st["trace_lower_s"] == 14.1 and st["backend_s"] == 63.0
+        assert (st["cache_hits"], st["cache_misses"]) == (42, 1)
+        # past the first drain (round 1): the first validation pass's
+        # program is a late first build, client_step built again a
+        # recompile
+        assert [(b["round"], b["name"], b["recompile"])
+                for b in st["steady_state_builds"]] == [
+            (2, "jit(val_step)", False), (3, "jit(client_step)", True)]
+        assert st["steady_state_recompiles"] == 1
+        gib = 1 << 30
+        assert mem["at_rest_bytes"] == 2 * gib + 64
+        assert mem["peak_bytes_in_use"] == 3 * gib
+        assert mem["peak_bytes_in_use_last_rose"] == "drain at round 1"
+        assert mem["peak_bytes_reserved"] == 5 * gib
+        assert mem["peak_bytes_reserved_last_rose"] == \
+            "validation end (round 2)"
+        text = out.getvalue()
+        assert "## Start-up" in text and "## Memory" in text
+        assert "STEADY-STATE RECOMPILE at round 3: jit(client_step)" in text
+        assert "late first build at round 2: jit(val_step)" in text
+        assert "| jit(client_step) | 2 | 8.0 | 4.0 | 60.9 | 1 hit / 1 miss" \
+            in text
+        assert "last rose at validation end (round 2)" in text
+        json.dumps(s, allow_nan=False)      # the machine tail stays strict
+
+    def test_a_log_from_before_the_record_renders_as_before(self):
+        import io
+
+        import obs_report
+
+        old = [e for e in self._lifecycle_log()
+               if e["ev"] in ("run_start", "round", "run_end")]
+        for e in old:
+            e.pop("memory", None)
+            e.pop("programs", None)
+        old.insert(2, {"ev": "drain", "t": 104.1, "rounds": 2, "ms": 3.0})
+        old.append({"ev": "from_the_future", "t": 111.0})
+        out = io.StringIO()
+        s = obs_report.render(old, out=out)
+        assert s["startup"] is None and s["memory"] is None
+        assert s["drains"] == 1 and s["log_rounds"] == 4
+        text = out.getvalue()
+        assert "## Start-up" not in text and "## Memory" not in text
+        assert "## Guard / rollback history" in text

@@ -554,3 +554,226 @@ class TestOneProfilerStarter:
         tracer.on_submit(2)
         assert tracer._active["dir"] == str(tmp_path / "own")
         assert tracer.close()["round_start"] == 2
+
+
+# ---------------------------------------------------------------------------
+# the run's record of itself outside the round loop (profiling.phase /
+# PHASES, the program listener, memory_sample)
+# ---------------------------------------------------------------------------
+
+class StubDevice:
+    """A device whose ``memory_stats()`` reads like a TPU's."""
+
+    def __init__(self, in_use):
+        self.stats = {
+            "bytes_in_use": in_use, "peak_bytes_in_use": in_use + 7,
+            "bytes_reserved": 11, "peak_bytes_reserved": 13,
+            "largest_free_block_bytes": 17, "num_allocs": 19,
+            "bytes_limit": 23, "pool_bytes": 29}
+        self.calls = 0
+
+    def memory_stats(self):
+        self.calls += 1
+        return dict(self.stats)
+
+
+@pytest.fixture
+def listener():
+    profiling.install_program_listener()
+    profiling.program_totals()          # closes a build another test left
+
+
+def _built_since(n):
+    profiling.program_totals()
+    return list(profiling.PROGRAMS)[n:]
+
+
+@pytest.fixture
+def own_cache(tmp_path):
+    """A persistent compile cache of this test's own, with no floor: every
+    executable is stored."""
+    from jax._src import compilation_cache as cc
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    before = {k: getattr(jax.config, k) for k in keys}
+    jax.config.update(keys[0], str(tmp_path / "cache"))
+    jax.config.update(keys[1], 0.0)
+    jax.config.update(keys[2], -1)
+    cc.reset_cache()
+    yield
+    for k, v in before.items():
+        jax.config.update(k, v)
+    cc.reset_cache()
+
+
+class TestRunRecord:
+    def test_phases_are_ordered_and_do_not_overlap(self):
+        before = span_totals()
+        profiling.begin_setup()
+        for name in ("data", "model", "data"):
+            with profiling.phase(name):
+                sum(range(20000))
+        phases = [p for p in profiling.PHASES if p["phase"] != "import"]
+        assert [p["phase"] for p in phases] == ["data", "model", "data"]
+        for a, b in zip(profiling.PHASES, profiling.PHASES[1:]):
+            assert a["start_s"] + a["seconds"] <= b["start_s"] + 2e-3, (a, b)
+        for p in phases:
+            assert p["seconds"] >= 0.0 and p["memory"] is None  # the CPU
+            assert p["programs"] == 0 and p["build_s"] == 0.0
+        counts = _counts(before)
+        assert counts["fed_setup_data"] == 2
+        assert counts["fed_setup_model"] == 1
+        # an entry point's second run in one process: its own phases, and
+        # no `import` (process start to now would be the first run too)
+        profiling.begin_setup()
+        assert profiling.PHASES == []
+        table = profiling.phase_table()
+        assert table.startswith("set-up: 0.00 s in 0 phases")
+
+    def test_import_phase_is_the_os_start_or_left_out(self, monkeypatch):
+        monkeypatch.setattr(profiling, "_import_recorded", [False])
+        before = span_totals()
+        profiling.begin_setup()
+        if profiling.PROCESS_START_T is None:
+            assert profiling.PHASES == []
+        else:
+            (imp,) = profiling.PHASES
+            assert imp["phase"] == "import" and imp["start_s"] == 0.0
+            # this process has lived at least as long as this module
+            assert 0.0 < imp["seconds"] < 86400.0
+            assert _counts(before)["fed_setup_import"] == 1
+        monkeypatch.setattr(profiling, "_import_recorded", [False])
+        monkeypatch.setattr(profiling, "PROCESS_START_T", None)
+        profiling.begin_setup()
+        assert profiling.PHASES == [], "never guessed"
+        # a later run of the process keeps no earlier run's builds
+        profiling.begin_setup()
+        assert not profiling.PROGRAMS
+
+    def test_phases_do_not_nest(self):
+        with profiling.phase("data"):
+            with pytest.raises(AssertionError):
+                with profiling.phase("model"):
+                    pass
+        assert profiling._open_phase[0] is None
+
+    def test_a_named_program_is_recorded_once(self, listener):
+        @jax.jit
+        def lifecycle_probe(x):
+            return jnp.sin(x) * 2.0 + jnp.cos(x)    # inner jits nest
+
+        n = len(_built_since(0))
+        with profiling.phase("fed"):
+            lifecycle_probe(jnp.ones(5))
+        mine = [b for b in _built_since(n)
+                if b["name"] == "jit(lifecycle_probe)"]
+        assert len(mine) == 1, _built_since(n)
+        (b,) = mine
+        assert b["trace_s"] > 0 and b["lower_s"] > 0 and b["backend_s"] > 0
+        assert b["cache"] in ("hit", "miss", "off") and b["phase"] == "fed"
+        assert profiling.PHASES[-1]["programs"] >= 1
+        assert profiling.PHASES[-1]["build_s"] >= b["backend_s"]
+        n = len(_built_since(0))
+        lifecycle_probe(jnp.ones(5))            # its second call: no build
+        assert not [b for b in _built_since(n)
+                    if "lifecycle_probe" in b["name"]]
+        tot = profiling.program_totals()["jit(lifecycle_probe)"]
+        assert tot["builds"] == 1 and tot["hits"] + tot["misses"] <= 1
+        # traced but never compiled: a build of its own, no backend
+        n = len(_built_since(0))
+        jax.eval_shape(lifecycle_probe, jnp.ones(7))
+        (b,) = [b for b in _built_since(n) if "lifecycle_probe" in b["name"]]
+        assert b["backend_s"] == 0.0 and "cache" not in b
+
+    def test_second_build_reads_cache_hit(self, listener, own_cache):
+        salt = float(np.random.RandomState().randint(1, 1 << 30))
+
+        def make():
+            @jax.jit
+            def lifecycle_cached(x):
+                return jnp.tanh(x) * salt
+            return lifecycle_cached
+
+        # (a second function object of the same text is a second build
+        # with the same cache key: what ``jax.clear_caches()`` would get,
+        # without making the suite's other programs build again; called
+        # from one line, since with ``configure_compile_cache``'s metadata
+        # in the key the caller's line is part of it)
+        n = len(_built_since(0))
+        for _ in range(2):
+            make()(jnp.ones(3))
+        first, second = [b for b in _built_since(n)
+                         if b["name"] == "jit(lifecycle_cached)"]
+        assert first["cache"] == "miss" and first.get("stored") is True
+        assert "load_s" not in first
+        assert second["cache"] == "hit"
+        assert 0.0 < second["load_s"] <= second["backend_s"]
+        tot = profiling.program_totals()["jit(lifecycle_cached)"]
+        assert (tot["builds"], tot["hits"], tot["misses"],
+                tot["stored"]) == (2, 1, 1, 1)
+
+    def test_summary_sums_the_small_programs(self, listener):
+        @jax.jit
+        def lifecycle_small(x):
+            return x + 1
+
+        lifecycle_small(jnp.ones(2))
+        whole = profiling.program_summary(floor_s=0.0)
+        assert "jit(lifecycle_small)" in whole and "other" not in whole
+        few = profiling.program_summary(floor_s=1e9)
+        assert set(few) - {"other"} == {
+            name for name, t in profiling.program_totals().items()
+            if t["misses"]}
+        assert sum(t["builds"] for t in few.values()) == \
+            sum(t["builds"] for t in whole.values())
+        # the listener's own cost is a span total like any other
+        assert span_totals()[profiling.LISTENER_SPAN]["count"] > 0
+
+    def test_memory_sample(self, monkeypatch):
+        assert profiling.memory_sample("here") is None       # the CPU
+        small, full = StubDevice(100), StubDevice(500)
+        monkeypatch.setattr(profiling, "_local_devices",
+                            lambda: [small, full])
+        got = profiling.memory_sample("drain")
+        assert got == {"at": "drain", "bytes_in_use": 500,
+                       "peak_bytes_in_use": 507, "bytes_reserved": 11,
+                       "peak_bytes_reserved": 13,
+                       "largest_free_block_bytes": 17, "num_allocs": 19}
+        assert small.calls == full.calls == 1
+
+    def test_drain_carries_one_sample(self, tmp_path, monkeypatch):
+        """Once a drain, not a round, under its own span; the dispatch path
+        and the drain's sample fetch nothing."""
+        dev = StubDevice(4096)
+        monkeypatch.setattr(profiling, "_local_devices", lambda: [dev])
+        fm, engine, rt = _engine(tmp_path, drain_every=3)
+        before = span_totals()
+        engine.submit(_host_batch([0, 1], seed=0))  # compile round
+        with host_sync_monitor(strict=True) as counter:
+            for rnd in range(1, 3):
+                engine.submit(_host_batch([rnd, rnd + 1], seed=rnd))
+        for rnd in range(3, 7):
+            engine.submit(_host_batch([rnd % 4, (rnd + 1) % 4], seed=rnd))
+        calls = dev.calls
+        with host_sync_monitor(strict=True) as counter:
+            with annotate("fed_memory_sample"):
+                profiling.memory_sample("drain")
+            assert counter.count == 0
+        engine.drain()
+        rt.close()
+        events = list(read_events(str(tmp_path / "telemetry.jsonl")))
+        drains = [e for e in events if e["ev"] == "drain"]
+        assert [(d["round"], d["rounds"], d["inflight"]) for d in drains] \
+            == [(2, 3, 0), (5, 3, 0), (6, 1, 0)]
+        assert all(d["memory"]["bytes_in_use"] == 4096
+                   and d["memory"]["at"] == "drain" for d in drains)
+        assert calls == 2 and dev.calls == 5      # + mine, the last drain,
+        # run_end's
+        assert _counts(before)["fed_memory_sample"] == 4
+        end = events[-1]
+        assert end["ev"] == "run_end"
+        assert end["memory"]["at"] == "run_end"
+        assert "fed_memory_sample" in end["spans"]
+        assert isinstance(end["programs"], dict)
