@@ -321,12 +321,12 @@ def build_parser(default_lr=None) -> argparse.ArgumentParser:
                              "coef/n_moe_layers, so retune rather than "
                              "assuming published values transfer.")
     # The decoder gpt2_train.py builds (models/joyai.py for the DeepSeek-V3
-    # family, models/laguna.py, models/ouro.py) and the cut of it held here:
-    # one chip's share of a deployment in which --layer_chips chips share
-    # each layer.
+    # family, models/laguna.py, models/ouro.py, models/kimi_linear.py) and
+    # the cut of it held here: one chip's share of a deployment in which
+    # --layer_chips chips share each layer.
     parser.add_argument("--arch",
                         choices=["gpt2", "joyai_llm_flash", "laguna_xs2",
-                                 "ouro_2p6b"],
+                                 "ouro_2p6b", "kimi_linear"],
                         default="gpt2",
                         help="gpt2_train.py's model: GPT-2 double heads, "
                              "JoyAI-LLM-Flash (MLA, sigmoid top-8 routed "
@@ -339,7 +339,12 @@ def build_parser(default_lr=None) -> argparse.ArgumentParser:
                              "sandwich-norm layers run four times on shared "
                              "weights, a head and an exit gate after every "
                              "pass, the expected-exit loss; no routed "
-                             "experts: --layer_chips stays 1).")
+                             "experts: --layer_chips stays 1), or "
+                             "Kimi-Linear (Kimi Delta Attention, a gated "
+                             "delta rule with a decay per channel, in three "
+                             "layers of four and NoPE latent attention in "
+                             "the fourth; the routed experts of "
+                             "JoyAI-LLM-Flash's kind).")
     parser.add_argument("--arch_layers", type=int, default=0,
                         help="Layers held, leading dense layer included "
                              "(0 = all the architecture has).")

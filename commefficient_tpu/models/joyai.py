@@ -149,7 +149,13 @@ def rope(x, theta: float):
 
 
 class MLA(nn.Module):
-    cfg: JoyAIConfig
+    """The latent attention of the module docstring. Two settings of another
+    configuration of the family (models/kimi_linear.py) take parts out:
+    ``q_lora_rank`` None gives ``q = x W_q`` (leaf ``q``: no low rank, no q
+    norm), ``mla_use_nope`` turns nothing, so ``q_r`` and ``k_r`` are the
+    64-wide parts as the products wrote them."""
+
+    cfg: Any
 
     @nn.compact
     def __call__(self, x):
@@ -157,28 +163,37 @@ class MLA(nn.Module):
         S, T, C = x.shape
         H, dn, dr, dv = (c.num_attention_heads, c.qk_nope_head_dim,
                          c.qk_rope_head_dim, c.v_head_dim)
-        q = RMSNorm(c.rms_norm_eps, name="q_norm")(
-            x @ _kernel(self, "q_a", (C, c.q_lora_rank)))
-        q = (q @ _kernel(self, "q_b", (c.q_lora_rank, H * (dn + dr)))
-             ).reshape(S, T, H, dn + dr)
+        nope = getattr(c, "mla_use_nope", False)
+        if c.q_lora_rank is None:
+            q = (x @ _kernel(self, "q", (C, H * (dn + dr)))
+                 ).reshape(S, T, H, dn + dr)
+        else:
+            q = RMSNorm(c.rms_norm_eps, name="q_norm")(
+                x @ _kernel(self, "q_a", (C, c.q_lora_rank)))
+            q = (q @ _kernel(self, "q_b", (c.q_lora_rank, H * (dn + dr)))
+                 ).reshape(S, T, H, dn + dr)
         kv = x @ _kernel(self, "kv_a", (C, c.kv_lora_rank + dr))
         c_kv = RMSNorm(c.rms_norm_eps, name="kv_norm")(
             kv[..., :c.kv_lora_rank])
-        k_r = rope(kv[..., None, c.kv_lora_rank:], c.rope_theta)[:, :, 0]
+        if nope:
+            k_r = kv[..., c.kv_lora_rank:]
+        else:
+            k_r = rope(kv[..., None, c.kv_lora_rank:], c.rope_theta)[:, :, 0]
         kv = (c_kv @ _kernel(self, "kv_b", (c.kv_lora_rank, H * (dn + dv)))
               ).reshape(S, T, H, dn + dv)
         w_o = _kernel(self, "o", (H * dv, C))
         with jax.named_scope("fed_mla_attn"):
-            q_r = rope(q[..., dn:], c.rope_theta)
+            q_r = q[..., dn:] if nope else rope(q[..., dn:], c.rope_theta)
             out = mla_attention(q, q_r, kv, k_r).reshape(S, T, H * dv)
         return out @ w_o
 
 
 class Block(nn.Module):
     """One decoder block of a configuration (``JoyAIConfig``, or
-    models/laguna.py's): the configuration says which attention module layer
-    ``layer`` has, whether its feed-forward part is dense, and builds its
-    expert layer."""
+    models/laguna.py's, models/kimi_linear.py's): the configuration says
+    which attention module layer ``layer`` has, whether its feed-forward part
+    is dense, and builds its expert layer. An attention module may return
+    ``(output, counters)``: its counters join the block's."""
 
     cfg: Any
     layer: int
@@ -186,22 +201,26 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x):
         c = self.cfg
-        h = x + c.attention(self.layer)(RMSNorm(c.rms_norm_eps,
-                                                name="attn_norm")(x))
+        a = c.attention(self.layer)(RMSNorm(c.rms_norm_eps,
+                                            name="attn_norm")(x))
+        a, mixer_stats = a if isinstance(a, tuple) else (a, {})
+        h = x + a
         z = RMSNorm(c.rms_norm_eps, name="ffn_norm")(h)
         if c.is_dense(self.layer):
             stats = {"local": jnp.zeros(x.shape[:-1], jnp.int32),
                      "max_load": jnp.int32(0)}
-            return h + SwiGLU(c.intermediate_size, name="mlp")(z), stats
+            return (h + SwiGLU(c.intermediate_size, name="mlp")(z),
+                    {**stats, **mixer_stats})
         y, stats = c.experts()(z)
-        return h + y, stats
+        return h + y, {**stats, **mixer_stats}
 
 
 class Decoder(nn.Module):
     """``input_ids`` (S, T) -> logits (S, T, vocab_rows) and the routing
     counts of the call: held pairs by sequence (``local``, (S,)), the pairs
     routed to absent experts (``absent``, (S,)) and the sum over the expert
-    layers of the largest held expert's load (``max_load``)."""
+    layers of the largest held expert's load (``max_load``); beside them
+    any counter the attention modules return, summed over the layers."""
 
     cfg: Any
 
@@ -211,17 +230,19 @@ class Decoder(nn.Module):
         x = nn.Embed(c.vocab_rows, c.hidden_size, name="embed",
                      embedding_init=nn.initializers.normal(0.02))(input_ids)
         local = jnp.zeros(input_ids.shape[:1], jnp.int32)
-        max_load, n_moe = jnp.int32(0), 0
+        max_load, n_moe, mixers = jnp.int32(0), 0, {}
         for i in range(c.layers):
             x, stats = nn.remat(Block)(c, i, name=f"h{i}")(x)
-            local = local + jnp.sum(stats["local"], axis=-1)
-            max_load = max_load + stats["max_load"]
+            local = local + jnp.sum(stats.pop("local"), axis=-1)
+            max_load = max_load + stats.pop("max_load")
             n_moe += not c.is_dense(i)
+            for name, val in stats.items():      # the mixers' counters
+                mixers[name] = mixers[name] + val if name in mixers else val
         x = RMSNorm(c.rms_norm_eps, name="norm_f")(x)
         logits = x @ _kernel(self, "head", (c.hidden_size, c.vocab_rows))
         absent = (n_moe * c.num_experts_per_tok * input_ids.shape[1]) - local
         return logits, {"local": local, "absent": absent,
-                        "max_load": max_load}
+                        "max_load": max_load, **mixers}
 
 
 class JoyAIFlash(Decoder):

@@ -74,19 +74,24 @@ DEVICE_STAGES = ("fed_client_grad", "fed_client_compress",
                  "fed_server_resketch", "fed_server_apply",
                  "fed_telemetry_metrics", "fed_accounting", "fed_val")
 # Scopes a model opens INSIDE ``fed_client_grad`` (models/joyai.py,
-# models/laguna.py, models/ouro.py, parallel/moe.py): the expert layer's
-# routing (scores, top-k, grouping, gather and scatter of the held pairs),
+# models/laguna.py, models/ouro.py, models/kimi_linear.py,
+# parallel/moe.py): the expert layer's routing (scores, top-k, grouping,
+# gather and scatter of the held pairs),
 # its grouped products, the latent attention's core, and the grouped-query
 # attention's (RoPE, the core, the head gate) with the kind of its layer
 # nested in it (ops/attention.py opens an attention scope again around its
 # backward pass); of a stack run as a recurrence (models/ouro.py) the
 # blocks of a pass (``fed_loop_body``, the attention's scopes nested in it)
 # and what is read after a pass (``fed_loop_head``: final norm, head, NLL,
-# exit gate). Not stages: an operation under one of them still has
-# ``fed_client_grad`` as its one stage.
+# exit gate); the delta-rule mixer (models/kimi_linear.py) from the
+# projections' outputs to ``W_o``'s operand (``fed_kda_attn``) with its
+# chunked core nested in it (``fed_kda_core``, ops/linear_attention.py). Not
+# stages: an operation under one of them still has ``fed_client_grad`` as its
+# one stage.
 INNER_SCOPES = ("fed_moe_route", "fed_moe_experts", "fed_mla_attn",
                 "fed_gqa_attn", "fed_gqa_attn_full", "fed_gqa_attn_window",
-                "fed_loop_body", "fed_loop_head")
+                "fed_loop_body", "fed_loop_head", "fed_kda_attn",
+                "fed_kda_core")
 # ``name=`` of every pallas_call (ops/sketch.py, ops/topk.py,
 # ops/attention.py). The sketch kernels keep ``sketch`` / ``estimates`` /
 # ``epilogue`` in theirs and the top-k and attention kernels do not:

@@ -58,6 +58,7 @@ from commefficient_tpu.models.gpt2 import (
     resize_token_embeddings,
 )
 from commefficient_tpu.models.joyai import JoyAIConfig, JoyAIFlash
+from commefficient_tpu.models.kimi_linear import KimiLinear, KimiLinearConfig
 from commefficient_tpu.models.laguna import LagunaConfig, LagunaXS2
 from commefficient_tpu.models.ouro import Ouro, OuroConfig
 from commefficient_tpu.ops.attention import GQA_PLAN, PATH_CALLS
@@ -283,10 +284,11 @@ def train_gpt2(model, opt, scheduler, train_loader, val_loader, args,
 
 
 # --arch: the configuration and its decoder (models/joyai.py,
-# models/laguna.py, models/ouro.py)
+# models/laguna.py, models/ouro.py, models/kimi_linear.py)
 DECODERS = {"joyai_llm_flash": (JoyAIConfig, JoyAIFlash),
             "laguna_xs2": (LagunaConfig, LagunaXS2),
-            "ouro_2p6b": (OuroConfig, Ouro)}
+            "ouro_2p6b": (OuroConfig, Ouro),
+            "kimi_linear": (KimiLinearConfig, KimiLinear)}
 
 
 def build_decoder(args, tiny):

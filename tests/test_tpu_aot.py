@@ -415,3 +415,106 @@ def _is_product(text, line):
     name = re.search(r"calls=(%[\w.\-]+)", line).group(1)
     start = text.index("\n" + name + " ")
     return " convolution(" in text[start:text.index("\n}", start)]
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+def test_kda_core_compiles_for_v5e(backward):
+    """The delta rule's chunked core (ops/linear_attention.py) at
+    ``kimi_linear_sketch_1c``'s scan step (4 sequences x 1,024 positions x 32
+    heads of 128): XLA:TPU takes the pair matrices, the triangular solve and
+    the scan over chunks, forward and forward + backward. Its temporaries as
+    compiled for the v5e: 0.568 GiB forward, 1.359 GiB with the backward (an
+    (S, T, H, 128) float32 array is 64 MiB): 9 and 22 of them."""
+    from commefficient_tpu.ops.linear_attention import kda_attention
+
+    S, T, H, d = 4, 1024, 32, 128
+    one = SingleDeviceSharding(_v5e_devices()[0])
+    q, k, v, log_alpha, d_out = (
+        jax.ShapeDtypeStruct((S, T, H, d), jnp.float32, sharding=one)
+        for _ in range(5))
+    beta = jax.ShapeDtypeStruct((S, T, H), jnp.float32, sharding=one)
+
+    def bwd(q, k, v, log_alpha, beta, d_out):
+        return jax.vjp(kda_attention, q, k, v, log_alpha, beta)[1](d_out)
+
+    _, compiled = _compile_tpu(
+        jax.jit(bwd if backward else kda_attention),
+        *((q, k, v, log_alpha, beta) + ((d_out,) if backward else ())))
+    assert "triangular-solve" in compiled.as_text() \
+        or "triangular_solve" in compiled.as_text()
+    K = S * T * H * d * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < (24 if backward else 10) * K
+
+
+@pytest.mark.slow
+def test_kimi_linear_cells_client_step_compiles_for_v5e(kernels_on,
+                                                        monkeypatch):
+    """``kimi_linear_sketch_1c``'s ``client_step`` as FedModel builds it
+    (d = 602,434,432, 5 x 500k, four scan steps of 4 x 1,024 tokens, the
+    latent core's kernels and bfloat16 expert operands as on the chip),
+    compiled for a v5e: 11.014 GiB of temporaries (JoyAI's cell 5.884 by
+    the same build), whose peak is the second block's expert-layer
+    backward with the scan's gradient carry beside the step's gradients;
+    the chip reserved 0.71 of the figure in the cell (PERF.md section 5)."""
+    import gpt2_train
+    from jax.flatten_util import ravel_pytree as jax_ravel
+
+    from commefficient_tpu.config import parse_args
+    from commefficient_tpu.federated.aggregator import (
+        server_config_from_args,
+        worker_config_from_args,
+    )
+
+    monkeypatch.setattr(attention, "is_tpu_backend", lambda: True)
+    monkeypatch.setattr(gpt2_train, "is_tpu_backend", lambda: True)
+    Wc, B, T = 4, 4, 1024
+    args = parse_args(default_lr=4e-2, argv=[
+        "--dataset_name", "PERSONA", "--arch", "kimi_linear",
+        "--arch_layers", "5", "--layer_chips", "32", "--vocab_rows",
+        "20480", "--seed", "21", "--mode", "sketch", "--error_type",
+        "virtual", "--num_workers", str(Wc), "--local_batch_size", str(B),
+        "--num_candidates", "1", "--max_seq_len", str(T),
+        "--microbatch_size", "1", "--num_rows", "5", "--num_cols", "500000",
+        "--k", "50000", "--num_blocks", "20", "--local_momentum", "0",
+        "--mc_coef", "0"])
+    args.len_tokenizer = 20480
+    model, train, val = gpt2_train.build_decoder(args, tiny=False)
+    assert model.cfg.expert_operand_dtype == jnp.bfloat16
+    mesh = default_client_mesh(Wc, devices=_v5e_devices()[:1])
+    shapes = jax.eval_shape(model.init, jax.random.key(0),
+                            jnp.zeros((1, 64), jnp.int32))["params"]
+    box = {}
+
+    def flatten(tree):
+        flat, box["unravel"] = jax_ravel(tree)
+        return flat
+
+    flat = jax.eval_shape(flatten, shapes)
+    d = flat.shape[0]
+    assert d == 602_434_432
+    wcfg = worker_config_from_args(args, mesh=mesh)
+    sketch = sketch_ops.make_sketch(d, 500_000, 5, seed=21, num_blocks=20)
+    cfg = RoundConfig(worker=wcfg, server=server_config_from_args(args, d),
+                      grad_size=d, telemetry=True, telemetry_hist=True)
+    steps = build_round_step(train, val, box["unravel"],
+                             lambda t: jax_ravel(t)[0], cfg, sketch=sketch,
+                             mesh=mesh)
+    rep = NamedSharding(mesh, P())
+    ps = _sds(jax.eval_shape(steps.layout.chunk, flat), rep)
+    ids = jnp.zeros((Wc, B, 1, T), jnp.int32)
+    batch = _sds({"input_ids": ids, "lm_labels": ids, "token_type_ids": ids,
+                  "mc_token_ids": jnp.zeros((Wc, B, 1), jnp.int32),
+                  "mc_labels": jnp.zeros((Wc, B), jnp.int32),
+                  "mask": jnp.ones((Wc, B), jnp.float32),
+                  "client_ids": jnp.arange(Wc, dtype=jnp.int32),
+                  "worker_mask": jnp.ones(Wc, jnp.float32)},
+                 NamedSharding(mesh, P("clients")))
+    states = _sds(jax.eval_shape(lambda: init_client_states(680, d, wcfg)),
+                  rep)
+    _, compiled = _compile_tpu(steps.client_step, ps, states, {}, batch,
+                               0.004, jax.random.key(0))
+    text = compiled.as_text()
+    assert "fed_mla_attn_fwd" in text and "fed_kda_core" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 11.5 * 2**30

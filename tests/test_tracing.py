@@ -249,17 +249,23 @@ class TestInnerScopes:
         stage an operation."""
         from commefficient_tpu.federated.losses import make_causal_lm_losses
         from commefficient_tpu.models.joyai import JoyAIConfig, JoyAIFlash
+        from commefficient_tpu.models.kimi_linear import (
+            KimiLinear,
+            KimiLinearConfig,
+        )
         from commefficient_tpu.models.laguna import LagunaConfig, LagunaXS2
         from commefficient_tpu.models.ouro import Ouro, OuroConfig
         from commefficient_tpu.profiling import INNER_SCOPES
 
         assert not set(INNER_SCOPES) & set(DEVICE_STAGES)
         cut = dict(layers=2, experts_held=4, expert_offset=0, vocab_rows=64)
-        # (a full and a sliding layer: LagunaConfig.tiny's first two; the
-        # recurrence's body and head: Ouro's)
+        # (a full and a sliding layer: LagunaConfig.tiny's first two; two
+        # delta-rule layers: KimiLinearConfig.tiny's; the recurrence's body
+        # and head: Ouro's)
         names = {}
         for model in (JoyAIFlash(JoyAIConfig.tiny(**cut)),
                       LagunaXS2(LagunaConfig.tiny(**cut)),
+                      KimiLinear(KimiLinearConfig.tiny(**cut)),
                       Ouro(OuroConfig.tiny(layers=2, vocab_rows=64))):
             ids = jnp.zeros((2, 1, 8), jnp.int32)
             params = model.init(jax.random.key(0), ids[:, 0])["params"]
