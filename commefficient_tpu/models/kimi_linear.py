@@ -71,7 +71,11 @@ from commefficient_tpu.models.joyai import (
     _kernel,
     routing_sums,
 )
-from commefficient_tpu.ops.linear_attention import causal_conv, kda_attention
+from commefficient_tpu.ops.linear_attention import (
+    causal_conv,
+    kda_attention,
+    kda_core_path,
+)
 from commefficient_tpu.parallel.moe import RoutedMoE
 
 __all__ = ["KimiLinear", "KimiLinearConfig", "KDA", "KDA_METRIC_NAMES"]
@@ -171,6 +175,11 @@ class KimiLinearConfig:
 
     def is_dense(self, layer: int) -> bool:
         return layer < self.first_k_dense_replace
+
+    def kda_core_path(self, T: int) -> str:
+        """The path the delta rule's core takes on sequences of T positions
+        in this process (ops/linear_attention.py ``kda_core_path``)."""
+        return kda_core_path(T, self.kda_head_dim, self.kda_head_dim)
 
     def experts(self):
         return RoutedMoE(

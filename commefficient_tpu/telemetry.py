@@ -1124,6 +1124,11 @@ def attach_run_telemetry(args, fed_model, log_dir: str,
         run_info["client_sketch_path"] = fed_model.steps.client_sketch_path
         run_info["client_sketch_launches"] = \
             fed_model.steps.client_sketch_launches
+    cfg = getattr(getattr(fed_model, "model", None), "cfg", None)
+    if hasattr(cfg, "kda_core_path"):
+        # a model with a delta-rule mixer: the path its chunked core takes
+        # (static for a run: ops/linear_attention.kda_core_path has the rule)
+        run_info["kda_core_path"] = cfg.kda_core_path(args.max_seq_len)
     # Participation-layer config (--participation / --inject_client_fault,
     # federated/participation.py): recorded in the run header so a logged
     # run is reproducible from the log alone — the fault schedule is

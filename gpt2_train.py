@@ -62,6 +62,8 @@ from commefficient_tpu.models.kimi_linear import KimiLinear, KimiLinearConfig
 from commefficient_tpu.models.laguna import LagunaConfig, LagunaXS2
 from commefficient_tpu.models.ouro import Ouro, OuroConfig
 from commefficient_tpu.ops.attention import GQA_PLAN, PATH_CALLS
+from commefficient_tpu.ops.linear_attention import \
+    PATH_CALLS as KDA_PATH_CALLS
 from commefficient_tpu.profiling import begin_setup, phase
 from commefficient_tpu.utils import (
     PiecewiseLinear,
@@ -116,8 +118,10 @@ def report_attention_core(model):
     on in this process and how many calls took it (ops/attention.py
     ``PATH_CALLS``), and by kind of grouped-query layer the kernels' tile
     walk and where q and k were turned and the heads gated (``GQA_PLAN``):
-    printed, and a ``model`` event in the run's log; a recurrent stack's
-    passes, layers and block applications a step beside it (``loop``).
+    printed, and a ``model`` event in the run's log, with a delta-rule
+    mixer's core path and calls (ops/linear_attention.py ``PATH_CALLS``)
+    where the model has one; a recurrent stack's passes, layers and block
+    applications a step beside it (``loop``).
     Said once a run, when its first round has been dispatched, so the
     round's own programs are among the traces counted, not the
     initialisation's alone."""
@@ -141,10 +145,18 @@ def report_attention_core(model):
     loop = getattr(model.model.cfg, "loop_plan", None)
     if loop:
         print("recurrence: " + ", ".join(f"{k} {v}" for k, v in loop.items()))
+    # a delta-rule mixer's chunked core (ops/linear_attention.py): its own
+    # path and count, in the same event
+    kda = {}
+    if hasattr(model.model.cfg, "kda_core_path"):
+        path = "pallas" if KDA_PATH_CALLS["pallas"] else "xla"
+        kda = {"kda_core_path": path, "kda_core_calls": KDA_PATH_CALLS[path]}
+        print(f"delta-rule core: {path} path, {kda['kda_core_calls']} calls "
+              "traced (ops/linear_attention.py)")
     rt = getattr(model, "telemetry", None)
     if rt is not None:
         rt.event("model", attn_path=attn_path,
-                 attn_calls=PATH_CALLS[attn_path],
+                 attn_calls=PATH_CALLS[attn_path], **kda,
                  **({"attn_plan": dict(GQA_PLAN)} if GQA_PLAN else {}))
         if loop:
             rt.event("loop", **loop)

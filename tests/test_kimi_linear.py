@@ -4,10 +4,11 @@ chunked core in ops/linear_attention.py) against its plain reference
 (benchmark/configs/kimi_linear_ep32_ref.py), at tiny widths on the CPU,
 float32 ``highest``, seeded random inputs and weights:
 
-(a) the chunked core against the per-token recurrence, output and the
-    gradients of q, k, v, log alpha and beta, including decays strong
-    enough (-10 a channel a token) that a factorisation through the chunk's
-    start overflows;
+(a) the chunked core, the XLA form and the kernels (interpreted), against
+    the per-token recurrence, output and the gradients of q, k, v, log
+    alpha and beta, including decays strong enough (-10 a channel a token)
+    that a factorisation through the chunk's start overflows; the rule that
+    picks the path and counts it;
 (b) the parameter tree is the reference's, the published sizes give the
     configuration's d; logits, loss and every leaf's gradient are the
     reference's; the decay counter is the mean -log alpha;
@@ -16,7 +17,7 @@ float32 ``highest``, seeded random inputs and weights:
 (d) the layer pattern: the published lists of 27 layers, layers 1-5 K K K M
     K in the program and the reference;
 (e) the entry point trains two rounds in sketch and dense modes and writes
-    the counters;
+    the counters and the core's path;
 (f) JoyAI-LLM-Flash's ``MLA`` (low-rank q, RoPE) gives the parameter tree
     and logits it gave before ``MLA`` learnt to leave both out.
 """
@@ -51,7 +52,13 @@ from commefficient_tpu.models.kimi_linear import (  # noqa: E402
     KimiLinear,
     KimiLinearConfig,
 )
-from commefficient_tpu.ops.linear_attention import kda_attention  # noqa: E402
+from commefficient_tpu.ops import linear_attention  # noqa: E402
+from commefficient_tpu.ops.linear_attention import (  # noqa: E402
+    kda_attention,
+    kda_attention_pallas,
+    kda_attention_xla,
+    kda_core_path,
+)
 from commefficient_tpu.parallel.moe import RoutedMoE  # noqa: E402
 
 from test_joyai import assert_trees_close, client_batch  # noqa: E402
@@ -110,18 +117,37 @@ def core_inputs(seed, S, T_, H, d, strongest):
     return q, k, v, log_alpha, beta
 
 
-# (T, chunk, sub-chunk, strongest log alpha): one chunk padded at its end,
-# several chunks through the scan, 16-wide sub-chunks, decays planted at -10
-CORE = {"one_chunk": (40, 64, 16, -0.5),
-        "four_chunks": (40, 16, 4, -0.5),
-        "four_chunks_strong": (40, 16, 4, -10.0),
-        "sub16_strong": (64, 64, 16, -10.0)}
+# (T, chunk, sub-chunk, strongest log alpha, width): one chunk padded at its
+# end, several chunks through the scan, 16-wide sub-chunks, decays planted at
+# -10. The XLA form takes any chunking; the kernels chunks of 64 in
+# sub-chunks of 16, run interpreted
+CORE = {"one_chunk": (40, 64, 16, -0.5, 16),
+        "four_chunks": (40, 16, 4, -0.5, 16),
+        "four_chunks_strong": (40, 16, 4, -10.0, 16),
+        "sub16_strong": (64, 64, 16, -10.0, 16)}
+PALLAS_CORE = {"one_chunk_padded": (40, 64, 16, -0.5, 16),
+               "three_chunks_padded_strong": (150, 64, 16, -10.0, 16),
+               "sub16_strong": (64, 64, 16, -10.0, 16),
+               "width128": (100, 64, 16, -0.5, 128)}
+# the XLA form's cases keep the names they had before the kernels
+CORE_CASES = [pytest.param("xla", c, id=c) for c in sorted(CORE)] + [
+    pytest.param("pallas", c, id=f"pallas-{c}") for c in sorted(PALLAS_CORE)]
 
 
-@pytest.mark.parametrize("case", sorted(CORE))
-def test_chunked_core_equals_the_recurrence(case):
-    T_, chunk, sub, strongest = CORE[case]
-    args = core_inputs(1, 2, T_, 3, 16, strongest)
+def _core_fn(path, chunk, sub):
+    if path == "pallas":
+        return lambda *a: kda_attention_pallas(*a, interpret=True,
+                                               chunk=chunk, sub=sub)
+    return lambda *a: kda_attention_xla(*a, chunk=chunk, sub=sub)
+
+
+@pytest.mark.parametrize("path,case", CORE_CASES)
+def test_chunked_core_equals_the_recurrence(path, case):
+    """Output and the five gradients against the per-token recurrence; the
+    kernels also against the XLA form."""
+    T_, chunk, sub, strongest, d = (CORE if path == "xla"
+                                    else PALLAS_CORE)[case]
+    args = core_inputs(1, 2, T_, 3 if d < 128 else 1, d, strongest)
     if strongest <= -10.0:
         # the planted decay: a chunk's summed log alpha passes float32's
         # exponent, so e^{-G_s} through the chunk's start would overflow
@@ -129,24 +155,60 @@ def test_chunked_core_equals_the_recurrence(case):
         with np.errstate(over="ignore"):
             assert np.isinf(np.exp(-G.astype(np.float32))).any()
 
-    def chunked(*a):
-        return kda_attention(*a, chunk=chunk, sub=sub)
-
-    got, want = chunked(*args), ref_file.Model.delta_rule(*args)
+    chunked = _core_fn(path, chunk, sub)
+    forms = (chunked, ref_file.Model.delta_rule) + (
+        (_core_fn("xla", chunk, sub),) if path == "pallas" else ())
+    outs = [f(*args) for f in forms]
+    got = outs[0]
     assert np.isfinite(np.asarray(got)).all()
     # float32, both at ``highest``: the two forms sum in other orders
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=2e-6)
+    for want in outs[1:]:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-4, atol=2e-6)
     d_out = jax.random.normal(jax.random.key(2), got.shape)
     grads = [jax.grad(lambda *a: jnp.sum(f(*a) * d_out),
                       argnums=(0, 1, 2, 3, 4))(*args)
-             for f in (chunked, ref_file.Model.delta_rule)]
-    for name, g, w in zip(("q", "k", "v", "log_alpha", "beta"), *grads):
-        assert np.isfinite(np.asarray(g)).all(), name
-        # relative to the largest entry: a gradient flows through T steps of
-        # the state, where the two forms' round-off compounds
-        scale = float(jnp.max(jnp.abs(w)))
-        assert float(jnp.max(jnp.abs(g - w))) <= 1e-4 * scale, name
+             for f in forms]
+    for other in grads[1:]:
+        for name, g, w in zip(("q", "k", "v", "log_alpha", "beta"),
+                              grads[0], other):
+            assert np.isfinite(np.asarray(g)).all(), name
+            # relative to the largest entry: a gradient flows through T
+            # steps of the state, where the two forms' round-off compounds
+            scale = float(jnp.max(jnp.abs(w)))
+            assert float(jnp.max(jnp.abs(g - w))) <= 1e-4 * scale, name
+
+
+def test_core_path_rule(monkeypatch):
+    """The kernels where the process can see that their rounding is the
+    chip's and the shape is theirs: a TPU at the default matmul precision
+    (or ``interpret``), dk and dv multiples of 128, chunks of 64 in
+    sub-chunks of 16; the XLA form everywhere else. ``kda_attention``
+    counts the path it traced in ``PATH_CALLS``."""
+    assert kda_core_path(1024, 128, 128) == "xla"             # the CPU
+    assert kda_core_path(1024, 128, 128, interpret=True) == "pallas"
+    monkeypatch.setattr(linear_attention, "is_tpu_backend", lambda: True)
+    # the tests' own ``highest``: a TPU would not round the products
+    assert kda_core_path(1024, 128, 128) == "xla"
+    with jax.default_matmul_precision(None):
+        assert kda_core_path(1024, 128, 128) == "pallas"
+        assert kda_core_path(1000, 256, 128) == "pallas"      # padded
+        for T_, dk, dv, chunk, sub in ((1024, 64, 128, 64, 16),
+                                       (1024, 128, 192, 64, 16),
+                                       (40, 128, 128, 64, 16),  # chunk 48
+                                       (1024, 128, 128, 32, 16),
+                                       (1024, 128, 128, 64, 8)):
+            assert kda_core_path(T_, dk, dv, chunk, sub) == "xla"
+        cfg = KimiLinearConfig()
+        assert cfg.kda_core_path(1024) == "pallas"
+        assert KimiLinearConfig.tiny().kda_core_path(1024) == "xla"
+    args = core_inputs(1, 1, 64, 1, 128, -0.5)
+    for interpret, want in ((False, "xla"), (True, "pallas")):
+        before = dict(linear_attention.PATH_CALLS)
+        jax.eval_shape(lambda *a: kda_attention(*a, interpret=interpret),
+                       *args)
+        assert linear_attention.PATH_CALLS == dict(
+            before, **{want: before[want] + 1})
 
 
 # -- (b) the model against the reference --------------------------------------
@@ -305,6 +367,8 @@ def test_entry_point_trains_two_rounds(mode, monkeypatch, tmp_path):
     for k_, v_ in dict(SIZED,
                        COMMEFFICIENT_RUN_DIR=str(tmp_path / "run")).items():
         monkeypatch.setenv(k_, v_)
+    for path in linear_attention.PATH_CALLS:  # the process's, from here on
+        monkeypatch.setitem(linear_attention.PATH_CALLS, path, 0)
     argv = ["--dataset_name", "PERSONA", "--dataset_dir", str(tmp_path / "d"),
             "--arch", "kimi_linear", "--arch_layers", "5",
             "--layer_chips", "4", "--vocab_rows", "256", "--mode", mode,
@@ -320,6 +384,12 @@ def test_entry_point_trains_two_rounds(mode, monkeypatch, tmp_path):
     stats = gpt2_train.train(argv)
     assert np.isfinite(stats["val_nll"]) and stats["val_ppl"] > 1.0
     events = list(read_events(str(tmp_path / "run" / "telemetry.jsonl")))
+    # the run header names the delta rule's core path: 16-wide heads and
+    # the CPU take the XLA form; the traced calls are counted on it
+    assert events[0]["ev"] == "run_start"
+    assert events[0]["kda_core_path"] == "xla"
+    said = next(e for e in events if e["ev"] == "model")
+    assert said["kda_core_path"] == "xla" and said["kda_core_calls"] > 0
     rounds = [e for e in events if e["ev"] == "round"]
     assert len(rounds) == 2
     for e in rounds:

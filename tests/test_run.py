@@ -432,6 +432,13 @@ class TestRealRun:
         assert all(c["round"] == c["round_in_epoch"] - 1 for c in cps)
         assert "resume" not in evs
 
+    def test_the_run_header_names_no_delta_rule_core(self, real_run):
+        """``kda_core_path`` is in the header of a run whose model has a
+        delta-rule mixer only (tests/test_kimi_linear.py runs one): not
+        ResNet9's, not GPT-2's."""
+        assert real_run.events[0]["ev"] == "run_start"
+        assert "kda_core_path" not in real_run.events[0]
+
 
     def test_the_run_records_its_start_up(self, real_run):
         """profiling.py's record in a run of each entry point: the

@@ -417,16 +417,27 @@ def _is_product(text, line):
     return " convolution(" in text[start:text.index("\n}", start)]
 
 
-@pytest.mark.parametrize("backward", [False, True],
-                         ids=["forward", "backward"])
-def test_kda_core_compiles_for_v5e(backward):
+# the kernel path's temporaries as compiled for the v5e at the cell's scan
+# step, forward and forward + backward (bytes)
+KDA_KERNEL_TEMP = {False: 436_626_944, True: 1_055_737_856}
+
+
+@pytest.mark.parametrize("path,backward", [
+    pytest.param("xla", False, id="forward"),
+    pytest.param("xla", True, id="backward"),
+    pytest.param("pallas", False, id="pallas-forward"),
+    pytest.param("pallas", True, id="pallas-backward")])
+def test_kda_core_compiles_for_v5e(path, backward, monkeypatch):
     """The delta rule's chunked core (ops/linear_attention.py) at
     ``kimi_linear_sketch_1c``'s scan step (4 sequences x 1,024 positions x 32
-    heads of 128): XLA:TPU takes the pair matrices, the triangular solve and
-    the scan over chunks, forward and forward + backward. Its temporaries as
-    compiled for the v5e: 0.568 GiB forward, 1.359 GiB with the backward (an
-    (S, T, H, 128) float32 array is 64 MiB): 9 and 22 of them."""
-    from commefficient_tpu.ops.linear_attention import kda_attention
+    heads of 128), forward and forward + backward, on each path. The XLA
+    form: XLA:TPU takes the pair matrices, the triangular solve and the scan
+    over chunks; its temporaries 0.568 GiB forward, 1.359 GiB with the
+    backward (an (S, T, H, 128) float32 array K is 64 MiB): 9 and 22 K. The
+    kernel path: one Mosaic call forward, one more backward, no triangular
+    solve, its temporaries pinned at what the v5e compile gave (5.5 K and
+    12.7 K: W, U~, M and the scan's rows, and their cotangents)."""
+    from commefficient_tpu.ops import linear_attention
 
     S, T, H, d = 4, 1024, 32, 128
     one = SingleDeviceSharding(_v5e_devices()[0])
@@ -434,18 +445,35 @@ def test_kda_core_compiles_for_v5e(backward):
         jax.ShapeDtypeStruct((S, T, H, d), jnp.float32, sharding=one)
         for _ in range(5))
     beta = jax.ShapeDtypeStruct((S, T, H), jnp.float32, sharding=one)
+    # dispatched as on the chip: a TPU at the default precision
+    monkeypatch.setattr(linear_attention, "is_tpu_backend",
+                        lambda: path == "pallas")
+    assert linear_attention.kda_core_path(T, d, d) == path
+
+    def core(*a):   # a function of its own: jit's trace cache keys on it
+        return linear_attention.kda_attention(*a)
 
     def bwd(q, k, v, log_alpha, beta, d_out):
-        return jax.vjp(kda_attention, q, k, v, log_alpha, beta)[1](d_out)
+        return jax.vjp(core, q, k, v, log_alpha, beta)[1](d_out)
 
     _, compiled = _compile_tpu(
-        jax.jit(bwd if backward else kda_attention),
+        jax.jit(bwd if backward else core),
         *((q, k, v, log_alpha, beta) + ((d_out,) if backward else ())))
-    assert "triangular-solve" in compiled.as_text() \
-        or "triangular_solve" in compiled.as_text()
-    K = S * T * H * d * 4
-    assert compiled.memory_analysis().temp_size_in_bytes \
-        < (24 if backward else 10) * K
+    text = compiled.as_text()
+    solves = "triangular-solve" in text or "triangular_solve" in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if path == "xla":
+        assert solves and "tpu_custom_call" not in text
+        K = S * T * H * d * 4
+        assert temp < (24 if backward else 10) * K
+    else:
+        assert not solves
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        # the calls keep the core's scope: kda_core_ms reads them
+        assert len(calls) == 1 + backward \
+            and all("fed_kda_core" in line for line in calls)
+        assert temp <= KDA_KERNEL_TEMP[backward]
 
 
 @pytest.mark.slow
@@ -453,11 +481,12 @@ def test_kimi_linear_cells_client_step_compiles_for_v5e(kernels_on,
                                                         monkeypatch):
     """``kimi_linear_sketch_1c``'s ``client_step`` as FedModel builds it
     (d = 602,434,432, 5 x 500k, four scan steps of 4 x 1,024 tokens, the
-    latent core's kernels and bfloat16 expert operands as on the chip),
-    compiled for a v5e: 11.014 GiB of temporaries (JoyAI's cell 5.884 by
-    the same build), whose peak is the second block's expert-layer
-    backward with the scan's gradient carry beside the step's gradients;
-    the chip reserved 0.71 of the figure in the cell (PERF.md section 5)."""
+    latent core's and the delta rule's kernels and bfloat16 expert operands
+    as on the chip), compiled for a v5e: 11.014 GiB of temporaries with the
+    delta rule's XLA form (JoyAI's cell 5.884 by the same build), whose
+    peak is the second block's expert-layer backward with the scan's
+    gradient carry beside the step's gradients; the chip reserved 0.71 of
+    the figure in the cell (PERF.md section 5)."""
     import gpt2_train
     from jax.flatten_util import ravel_pytree as jax_ravel
 
@@ -467,7 +496,10 @@ def test_kimi_linear_cells_client_step_compiles_for_v5e(kernels_on,
         worker_config_from_args,
     )
 
+    from commefficient_tpu.ops import linear_attention
+
     monkeypatch.setattr(attention, "is_tpu_backend", lambda: True)
+    monkeypatch.setattr(linear_attention, "is_tpu_backend", lambda: True)
     monkeypatch.setattr(gpt2_train, "is_tpu_backend", lambda: True)
     Wc, B, T = 4, 4, 1024
     args = parse_args(default_lr=4e-2, argv=[
@@ -517,4 +549,6 @@ def test_kimi_linear_cells_client_step_compiles_for_v5e(kernels_on,
                                0.004, jax.random.key(0))
     text = compiled.as_text()
     assert "fed_mla_attn_fwd" in text and "fed_kda_core" in text
+    # the delta rule's chunk-local phase in its kernels, as on the chip
+    assert "triangular-solve" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 11.5 * 2**30
